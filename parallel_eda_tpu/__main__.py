@@ -99,9 +99,12 @@ def build_parser() -> argparse.ArgumentParser:
                    "bit-identical results, used for isolating pipeline "
                    "issues and by the parity suite")
     p.add_argument("--compile_cache_dir", default="",
-                   help="persistent XLA compile-cache directory: a "
-                   "second run deserializes the route window programs "
-                   "instead of recompiling them")
+                   help="persistent XLA compile-cache directory "
+                   "(default <checkout>/.jax_cache; the "
+                   "JAX_COMPILATION_CACHE_DIR environment variable, "
+                   "when set, wins over both): a second run "
+                   "deserializes the programs instead of recompiling "
+                   "them")
     p.add_argument("--no_timing", action="store_true",
                    help="congestion-driven only (NO_TIMING algorithm)")
     p.add_argument("--sdc", default="",
@@ -254,9 +257,11 @@ def _run_flow(args) -> int:
     from .netlist.files import read_net_file, read_place_file
     from .netlist.generate import generate_circuit
     from .place.sa import PlacerOpts
-    from .route.router import RouterOpts
+    from .route.router import RouterOpts, enable_persistent_compile_cache
 
     t_flow = time.time()
+    # before the first compile (the placer's programs are cached too)
+    enable_persistent_compile_cache(args.compile_cache_dir or None)
     if args.arch == "k6_n10":
         arch = k6_n10_arch()
     elif args.arch == "minimal":
@@ -329,8 +334,7 @@ def _run_flow(args) -> int:
             batch_size=args.batch_size, sink_group=args.sink_group,
             crop=args.crop, finish_precise=not args.no_finish,
             stats_dir=args.stats_dir or None,
-            pipeline=not args.sync,
-            compile_cache_dir=args.compile_cache_dir or None)
+            pipeline=not args.sync)
         import contextlib
         prof = contextlib.nullcontext()
         if args.profile:

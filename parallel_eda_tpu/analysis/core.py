@@ -25,7 +25,8 @@ from typing import Dict, Iterable, List, Optional
 SUPPRESS_RE = re.compile(r"#\s*graftlint:\s*ignore\[([^\]]*)\]")
 
 #: repo-relative scan roots (files or directories)
-DEFAULT_TARGETS = ("parallel_eda_tpu", "tools", "bench.py", "scale_bench.py")
+DEFAULT_TARGETS = ("parallel_eda_tpu", "tools", "bench.py", "scale_bench.py",
+                   "chip_smoke.py")
 #: path fragments excluded from the scan
 EXCLUDE_PARTS = ("__pycache__", "tests/", ".git/")
 #: markdown docs a project rule may want (metric registry)

@@ -47,6 +47,29 @@ from .metrics import get_metrics
 DELTA_BAND_LOG10 = 2.0
 
 
+# THE peak table (one place; bench.py --sweep_only and
+# tools/kernel_bench.py read it): peak HBM bandwidth in bytes/s keyed
+# by jax's ``device_kind``.  Source: Google Cloud documentation, "TPU
+# v5e" — 16 GB of HBM at 819 GB/s per chip.  A device that is not in
+# the table is an error, not a default.
+PEAK_HBM_BYTES_PER_S = {"TPU v5 lite": 819e9}
+
+
+def peak_hbm_bytes_per_s(device) -> Optional[float]:
+    """Published peak HBM bandwidth of ``device``; None on a CPU (a CPU
+    run reports no roofline share); KeyError for an accelerator the
+    table does not know."""
+    if device.platform == "cpu":
+        return None
+    try:
+        return PEAK_HBM_BYTES_PER_S[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak HBM bandwidth for device_kind "
+            f"{device.device_kind!r}: add it, with its source, to "
+            f"obs/devprof.PEAK_HBM_BYTES_PER_S") from None
+
+
 def _jsonable(x):
     if isinstance(x, (list, tuple)):
         return [_jsonable(v) for v in x]

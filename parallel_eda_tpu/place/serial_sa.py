@@ -4,37 +4,27 @@ The C++ annealer is the CPU measurement baseline for BASELINE.md's "SA
 moves/sec/chip" metric (semantics of vpr/SRC/place/place.c try_place):
 an honest serial-CPU speed class to hold the batched TPU placer against
 — a pure-Python loop would overstate the device win by an order of
-magnitude.  Built on first use with g++ -O3 (toolchain is in the image);
-the .so is cached next to the source.
+magnitude.  Built on first use with g++ -O3 (toolchain is in the image) on the
+machine that loads it (nativelib.build_native).
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import time
 from dataclasses import dataclass
 
 import numpy as np
 
+from ..nativelib import build_native
 from ..netlist.packed import PackedNetlist
 from ..rr.grid import DeviceGrid
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "native", "serial_sa.cc")
-_SO = os.path.join(os.path.dirname(_SRC), "build", "libserial_sa.so")
+_FLAGS = ("-O3", "-march=native")
 
 
 def _build_lib() -> str:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    if (not os.path.exists(_SO)
-            or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-             _SRC, "-o", _SO],
-            check=True, capture_output=True)
-    return _SO
+    return build_native("serial_sa.cc", _FLAGS)
 
 
 _lib = None

@@ -121,7 +121,8 @@ class JournalStore:
 class Heartbeat:
     """Liveness heartbeat file + worst-gap tracker.
 
-    ``beat()`` is called once per daemon cycle; it rewrites the file
+    ``beat()`` is called by the daemon loop between slices (and by its
+    helper thread while a slice holds the loop); it rewrites the file
     (atomically) only when ``interval_s`` has elapsed, and records the
     worst observed inter-beat gap — the number the doctor's
     heartbeat-gap rule checks against ``interval_s``."""

@@ -302,6 +302,15 @@ def _bpad(a, n: int, fill=0):
                    constant_values=fill)
 
 
+def pallas_interpret(interpret=None) -> bool:
+    """Resolve the kernels' ``interpret=None`` auto-select: the Pallas
+    interpreter off-TPU (tests/CPU), the chip's compiler on it.  Benches
+    call this too — no printed result from an interpreted kernel may
+    lack ``interpret: true``."""
+    return jax.default_backend() != "tpu" if interpret is None \
+        else bool(interpret)
+
+
 @functools.partial(jax.jit, static_argnames=("nsweeps", "interpret",
                                              "block_nets", "lane_mult",
                                              "plane_dtype"))
@@ -318,8 +327,7 @@ def planes_relax_pallas(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
     wenter/congestion refs (and their out_shapes) in bfloat16 — the
     per-sweep state really moves half the bytes — and stays
     bit-identical to planes_relax run with the same plane_dtype."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     B = d0_flat.shape[0]
     W, NX, NYp1 = pg.shape_x
     _, NXp1, NY = pg.shape_y
@@ -492,8 +500,7 @@ def planes_relax_cropped_pallas(pg: PlanesGraph, d0_flat, cc_flat,
     program; inside the kernel the folded tiles are sliced back to
     their unpadded shapes, so results are bit-identical to the
     one-net-per-step path for any block size."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     sdt = plane_jnp_dtype(plane_dtype)
     isz = jnp.dtype(sdt).itemsize
     B = d0_flat.shape[0]
@@ -624,14 +631,31 @@ def remote_slab_permute(slab, axis_name, n_shards, fwd=True):
     def kernel(x_ref, o_ref, send_sem, recv_sem):
         me = jax.lax.axis_index(axis_name)
         if fwd:
-            neighbor, sends, recvs = me + 1, me < n_shards - 1, me > 0
+            neighbor, sender = me + 1, me - 1
+            sends, recvs = me < n_shards - 1, me > 0
         else:
-            neighbor, sends, recvs = me - 1, me > 0, me < n_shards - 1
+            neighbor, sender = me - 1, me + 1
+            sends, recvs = me > 0, me < n_shards - 1
+        logical = pltpu.DeviceIdType.LOGICAL
+        # handshake: a receiver tells its sender it has entered the
+        # kernel (its output buffer is live) before the sender pushes
+        # into it; each sender consumes exactly the one signal it is
+        # sent, so the barrier semaphore is back at zero on exit
+        barrier = pltpu.get_barrier_semaphore()
+
+        @pl.when(recvs)
+        def _ready():
+            pltpu.semaphore_signal(barrier, 1, device_id=sender,
+                                   device_id_type=logical)
+
+        @pl.when(sends)
+        def _await_ready():
+            pltpu.semaphore_wait(barrier, 1)
+
         copy = pltpu.make_async_remote_copy(
             src_ref=x_ref, dst_ref=o_ref,
             send_sem=send_sem, recv_sem=recv_sem,
-            device_id=neighbor,
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+            device_id=neighbor, device_id_type=logical)
 
         @pl.when(jnp.logical_not(recvs))
         def _zero_edge():
@@ -653,10 +677,10 @@ def remote_slab_permute(slab, axis_name, n_shards, fwd=True):
         kernel,
         out_shape=jax.ShapeDtypeStruct(slab.shape, slab.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True,
             # fwd/bwd exchanges of one sweep overlap; distinct barrier
-            # semaphores keep their matched-send/recv pairs separate.
+            # semaphores keep their handshakes separate
             collective_id=0 if fwd else 1,
         ),
     )(slab)

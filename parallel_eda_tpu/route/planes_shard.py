@@ -89,7 +89,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .planes import (INF, PlanesGeom, PlanesGraph, _dequantize_plane_state,
@@ -426,7 +426,7 @@ def planes_relax_sharded(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
         in_specs=(P(ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS),
                   P(ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS), P()),
         out_specs=(P(ROW_AXIS),) * 7,
-        check_rep=False)
+        check_vma=False)
     dxs, dys, pxs, pys, wxs, wys, stats = shmap(
         gm_blocks, dxb, dyb, ccxb, ccyb, wxb, wyb, crit_c)
 

@@ -5,39 +5,29 @@ C++; the pure-Python serial_ref understates the wall-clock bar by the
 interpreter factor).  It implements the EXACT algorithm of
 route/serial_ref.py — same cost model, same double arithmetic, same heap
 tie-breaks — so the cross-oracle test asserts identical route trees.
-Built on first use with g++ -O3; the .so is cached next to the source.
+Built on first use with g++ -O3 on the machine that loads it
+(nativelib.build_native keys the artefact by source, flags and CPU).
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import time
 from typing import Optional
 
 import numpy as np
 
+from ..nativelib import build_native
 from ..rr.graph import CHANX, CHANY, RRGraph
 from ..rr.terminals import NetTerminals
 from .serial_ref import (SerialRouteResult, SerialRouter,
                          tree_order)
 
-_SRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "native",
-    "serial_route.cc")
-_SO = os.path.join(os.path.dirname(_SRC), "build", "libserial_route.so")
+_FLAGS = ("-O3", "-march=native", "-ffp-contract=off", "-std=c++17")
 
 
 def _build_lib() -> str:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    if (not os.path.exists(_SO)
-            or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-        subprocess.run(
-            ["g++", "-O3", "-march=native", "-ffp-contract=off",
-             "-std=c++17", "-shared", "-fPIC", _SRC, "-o", _SO],
-            check=True, capture_output=True)
-    return _SO
+    return build_native("serial_route.cc", _FLAGS)
 
 
 _lib = None

@@ -113,10 +113,12 @@ def main(argv=None) -> int:
 
     from ..flow import synth_flow
     from ..obs.metrics import get_metrics
-    from ..route.router import RouterOpts
+    from ..route.router import (RouterOpts,
+                                enable_persistent_compile_cache)
     from .service import RouteService, ServeJobSpec
 
     get_metrics().enabled = True
+    enable_persistent_compile_cache(args.compile_cache_dir or None)
     flows = [synth_flow(num_luts=args.luts,
                         chan_width=args.chan_width,
                         seed=args.seed0 + j)
@@ -136,7 +138,6 @@ def main(argv=None) -> int:
         batch_size=args.batch_size,
         max_router_iterations=args.max_router_iterations,
         sink_group=0, pipeline=not args.sync,
-        compile_cache_dir=args.compile_cache_dir or None,
         program_library_dir=args.library or None)
     resil = None
     if args.chaos or args.checkpoint_dir or args.diag_dir:

@@ -30,7 +30,9 @@ per-dispatch one: a single-process daemon that
   (``resil/checkpoint.py``) — a SIGKILL between windows changes
   timing only, never QoR.
 
-Liveness is a heartbeat file next to the inbox; health is
+Liveness is a heartbeat file next to the inbox, beaten by the loop
+between slices and by a helper thread while a slice holds the loop
+(``_alive_through_slice``); health is
 ``flow_doctor --daemon-summary`` over the summary JSON the daemon
 prints on exit (rejection-without-reason, shed-without-overload-cause,
 heartbeat gaps, recovery-without-journal all fail the gate).
@@ -53,7 +55,9 @@ import hashlib
 import json
 import os
 import statistics
+import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, replace as dc_replace
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -448,6 +452,11 @@ class RouteDaemon:
         self._t0 = clock()
         self.cycles = 0
         self._idle_cycles = 0
+        self._hb_state: Dict[str, Any] = {}
+        # how long the helper thread vouches for one slice: a
+        # dispatch's watchdog budget (resil/watchdog.py)
+        guard = getattr(getattr(service, "resil", None), "guard", None)
+        self._vouch_s = float(getattr(guard, "timeout_s", 120.0))
         self._stop = False
 
     # ----------------------------------------------- spec handling
@@ -878,6 +887,63 @@ class RouteDaemon:
         if f is not None:
             self.lease.force_expire(held[0])
 
+    # ------------------------------------------------- liveness
+
+    @contextmanager
+    def _alive_through_slice(self):
+        """Liveness while a slice holds the loop.
+
+        The loop beats and renews between slices, but one slice can
+        hold it for many heartbeat intervals and a whole lease — a
+        cold window-program compile does — and a live worker that
+        goes silent is reported unhealthy and loses its leases to
+        peers that then redo its work.  So for the duration of the
+        service's runner a helper thread keeps the heartbeat and this
+        worker's LIVE leases fresh.  It vouches for at most one
+        dispatch watchdog budget: a slice stuck past that goes silent
+        and its leases lapse, exactly as for a dead process.  It only
+        renews a lease that still names this worker, unreleased and
+        unexpired (an expired one is the sweep's steal race to run).
+        The runner touches neither heartbeat nor leases, and the
+        thread is joined before the loop's own bookkeeping goes on,
+        so each file keeps one writer at a time.  Paced on real time
+        (it guards a real blocking call); the beats themselves read
+        the daemon's injectable clock."""
+        period = self.opts.heartbeat_s
+        ls = self.lease
+        live: List[str] = []
+        if ls is not None:
+            period = min(period, self.opts.lease_ttl_s / 3.0)
+            live = [j.job_id for j in self.service.queue.jobs
+                    if j.state in (JobState.QUEUED, JobState.RUNNING)]
+        # the instruments the thread moves exist before it starts: the
+        # loop's thread may be iterating the registry for a snapshot
+        m = get_metrics()
+        m.gauge("route.daemon.heartbeat_age_s")
+        m.counter("route.fleet.lease_renewals")
+        m.counter("route.fleet.leases_lost")
+        stop = threading.Event()
+        deadline = time.monotonic() + self._vouch_s
+
+        def keep():
+            while not stop.wait(period) and time.monotonic() < deadline:
+                self.heartbeat.beat(**self._hb_state)
+                for job_id in live:
+                    doc = ls.read(job_id)
+                    if doc and doc.get("worker") == self.worker \
+                            and not doc.get("released") \
+                            and not ls.expired(doc):
+                        ls.renew(job_id)
+
+        t = threading.Thread(target=keep, name="slice-keepalive",
+                             daemon=True)
+        t.start()
+        try:
+            yield
+        finally:
+            stop.set()
+            t.join()
+
     # ------------------------------------------- slice SLO sampling
 
     def _stall_seconds(self) -> float:
@@ -913,13 +979,14 @@ class RouteDaemon:
         slice raises: the queue's verdict loop owns the exception)."""
         tr = get_tracer()
         t_start, c0, s0 = self._slice_marks()
-        if tr is None:
-            verdict, value = self.service._runner(job)
-        else:
-            with tr.span("route.trace.slice", cat="lifecycle",
-                         job_id=job.job_id, slice=job.slices + 1,
-                         worker=self.worker or "solo"):
+        with self._alive_through_slice():
+            if tr is None:
                 verdict, value = self.service._runner(job)
+            else:
+                with tr.span("route.trace.slice", cat="lifecycle",
+                             job_id=job.job_id, slice=job.slices + 1,
+                             worker=self.worker or "solo"):
+                    verdict, value = self.service._runner(job)
         self._observe_slice(job, t_start, c0, s0)
         self._last_slice = {"job_id": job.job_id,
                             "slice": job.slices + 1, "verdict": verdict}
@@ -943,14 +1010,15 @@ class RouteDaemon:
         tr = get_tracer()
         ids = ",".join(j.job_id for j in jobs)
         t_start, c0, s0 = self._slice_marks()
-        if tr is None:
-            verdicts = self.service._batch_runner(jobs)
-        else:
-            with tr.span("route.trace.slice", cat="lifecycle",
-                         job_id=f"fused[{ids}]",
-                         slice=max(j.slices for j in jobs),
-                         worker=self.worker or "solo"):
+        with self._alive_through_slice():
+            if tr is None:
                 verdicts = self.service._batch_runner(jobs)
+            else:
+                with tr.span("route.trace.slice", cat="lifecycle",
+                             job_id=f"fused[{ids}]",
+                             slice=max(j.slices for j in jobs),
+                             worker=self.worker or "solo"):
+                    verdicts = self.service._batch_runner(jobs)
         for job in jobs:
             # lockstep costs are joint: every member LIVED through the
             # whole fused wall, so each job's waterfall is charged the
@@ -1176,6 +1244,7 @@ class RouteDaemon:
                     "draining": self.service.draining}
         if self.worker:
             hb_state["worker"] = self.worker
+        self._hb_state = hb_state
         self.heartbeat.beat(**hb_state)
         polled = self.reader.poll()
         for sub in polled:
@@ -1191,8 +1260,10 @@ class RouteDaemon:
             self._flush_journal()
         before = sum(j.slices for j in q.jobs)
         # one slice at a time with a beat (and a lease fence) between:
-        # a compile-heavy slice must not silence the heartbeat, and a
-        # stolen job must never get another local slice
+        # a stolen job must never get another local slice.  INSIDE a
+        # slice the runners' helper thread keeps beating and renewing
+        # (_alive_through_slice): one cold window-program compile
+        # outlasts many heartbeat intervals and a lease
         for _ in range(self.opts.slices_per_cycle):
             self._lease_sweep()
             if q.depth() == 0:
@@ -1331,7 +1402,6 @@ def build_daemon(inbox_dir: str, *, luts: int, chan_width: int = 16,
                  batch_size: int = 32, max_router_iterations: int = 50,
                  slice_iters: int = 2,
                  library_dir: Optional[str] = None,
-                 compile_cache_dir: Optional[str] = None,
                  runs_dir: Optional[str] = None,
                  scenario: Optional[str] = None,
                  checkpoint_dir: Optional[str] = None,
@@ -1342,9 +1412,10 @@ def build_daemon(inbox_dir: str, *, luts: int, chan_width: int = 16,
     """Wire a production-shaped daemon: real synth flow on one device
     graph, resilience layer armed with durable checkpoints under the
     inbox, service corpus rows feeding the admission estimator.
-    Fleet members share the inbox/checkpoints/leases/AOT library but
-    MUST NOT share a compile cache dir (see BENCHMARKS.md on the
-    cross-process compile-cache crash)."""
+    Fleet members share the inbox/checkpoints/leases/AOT library; the
+    compile cache follows the one rule
+    (router.enable_persistent_compile_cache, called by the daemon CLI:
+    per-worker directories unless the environment places the cache)."""
     from ..flow import synth_flow
     from ..resil import ResilOpts
 
@@ -1355,7 +1426,6 @@ def build_daemon(inbox_dir: str, *, luts: int, chan_width: int = 16,
         batch_size=batch_size,
         max_router_iterations=max_router_iterations,
         sink_group=0, pipeline=not sync,
-        compile_cache_dir=compile_cache_dir or None,
         program_library_dir=library_dir or None)
     resil = ResilOpts(
         fault_plan=fault_plan,

@@ -151,8 +151,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="SHARED AOT program library (safe across "
                    "workers; compile caches are per-worker)")
     f.add_argument("--cache_base", default="",
-                   help="per-worker compile caches under "
-                   "<cache_base>/<worker> — never shared")
+                   help="compile-cache base: each worker keeps its own "
+                   "under <cache_base>/<worker> (default base "
+                   "<checkout>/.jax_cache); with "
+                   "JAX_COMPILATION_CACHE_DIR set the cache is there "
+                   "instead, for every worker")
     f.add_argument("--runs_dir", default="")
     f.add_argument("--scenario", default="")
     f.add_argument("--sync", action="store_true")
@@ -200,12 +203,15 @@ def build_parser() -> argparse.ArgumentParser:
 
 def _cmd_run(args) -> int:
     from ..obs.metrics import get_metrics
+    from ..route.router import enable_persistent_compile_cache
     from .daemon import DaemonOpts, build_daemon
     from .queue import JobState
 
     t_start = time.perf_counter()
     get_metrics().enabled = True
     worker = getattr(args, "worker", "")
+    enable_persistent_compile_cache(args.compile_cache_dir or None,
+                                    worker=worker)
     trace_path = getattr(args, "trace", "")
     if trace_path:
         # install the process tracer BEFORE any daemon construction so
@@ -238,7 +244,6 @@ def _cmd_run(args) -> int:
         max_router_iterations=args.max_router_iterations,
         slice_iters=args.slice_iters,
         library_dir=args.library or None,
-        compile_cache_dir=args.compile_cache_dir or None,
         runs_dir=args.runs_dir or None,
         scenario=args.scenario or None,
         opts=opts, fault_plan=plan, sync=args.sync)
@@ -261,6 +266,15 @@ def _cmd_run(args) -> int:
         if tr is not None:
             tr.export(trace_path, atomic=True)
     summary = daemon.summary()
+    import jax
+    dev = jax.devices()[0]
+    # which device this daemon held; "chip" is the host chip a fleet
+    # supervisor pinned the worker to (its only device, so its id
+    # alone cannot tell workers apart)
+    summary["device"] = {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "id": dev.id, "count": len(jax.devices()),
+        "chip": os.environ.get("TPU_VISIBLE_CHIPS")}
     summary["library_exported"] = exported
     summary["wall_s"] = round(time.perf_counter() - t_start, 3)
     blob = json.dumps(summary, default=str)

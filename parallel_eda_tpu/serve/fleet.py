@@ -3,10 +3,13 @@
 ``python -m parallel_eda_tpu daemon fleet`` spawns N worker daemons
 (`daemon run --worker wK --workers w0,..`) that share the inbox, the
 run corpus, the durable checkpoints, the lease directory, and the AOT
-program library — but NEVER a compile cache directory (each worker
-gets ``<cache_base>/<worker>``; see BENCHMARKS.md for the
-cross-process compile-cache crash verdict this fences).  The
-supervisor:
+program library.  Unless ``JAX_COMPILATION_CACHE_DIR`` places the
+compile cache, each worker keeps its own under the fixed name
+``<base>/<worker>`` (see BENCHMARKS.md for the cross-process
+compile-cache crash this fences).  On a TPU host every worker is
+pinned to one chip through its environment before it starts, at most
+one worker per chip, and its stderr is kept in
+``<inbox>/stderr.<worker>.log``.  The supervisor:
 
 * partitions admission capacity: each worker's ``max_queue_depth`` is
   its share of the fleet total, so the fleet as a whole enforces the
@@ -26,8 +29,9 @@ supervisor:
   lease state) into ONE fleet summary JSON, the artifact
   ``flow_doctor --fleet-summary`` gates.
 
-Stdlib + repo-internal imports only; the workers are full daemons in
-their own processes, the supervisor never imports jax.
+The workers are full daemons in their own processes; the supervisor
+never initialises a JAX backend (its imports load jax but run nothing
+on it), so every chip stays free for the workers.
 """
 
 from __future__ import annotations
@@ -50,6 +54,62 @@ from .transport import InboxHTTPServer
 #: chaos sites the supervisor itself owns; everything else in a fleet
 #: --chaos spec is forwarded to the workers
 SUPERVISOR_SITES = ("worker.kill", "transport.drop")
+
+
+#: Google's PCI vendor id (TPU chips; also the vendor's network cards,
+#: which are PCI class 0x02)
+TPU_PCI_VENDOR = "0x1ae0"
+PCI_DEVICES = "/sys/bus/pci/devices"
+VFIO_DIR = "/dev/vfio"
+
+
+def count_tpu_chips() -> int:
+    """TPU chips this host lets a process open, WITHOUT touching a JAX
+    backend (the supervisor must leave every chip to its workers): the
+    PCI functions of the TPU's vendor, network cards apart, whose IOMMU
+    group has a VFIO node here — the node the TPU runtime opens on v5e
+    and later.  A chip the machine lists but does not hand over (no
+    node), and any other passthrough device behind VFIO (a NIC, a
+    GPU), is not counted.  0 on a host with no TPU (workers then run
+    wherever JAX puts them, e.g. the CPU)."""
+    import glob
+
+    def read(path):
+        try:
+            with open(path) as f:
+                return f.read().strip()
+        except OSError:
+            return ""
+
+    n = 0
+    for dev in glob.glob(os.path.join(PCI_DEVICES, "*")):
+        if read(os.path.join(dev, "vendor")) != TPU_PCI_VENDOR \
+                or read(os.path.join(dev, "class")).startswith("0x02"):
+            continue
+        try:
+            group = os.path.basename(
+                os.readlink(os.path.join(dev, "iommu_group")))
+        except OSError:
+            continue
+        n += os.path.exists(os.path.join(VFIO_DIR, group))
+    return n
+
+
+def chip_env(chip: int, n_chips: int) -> Dict[str, str]:
+    """Environment that pins ONE process to ONE chip of this host
+    before it starts (a chip belongs to one process at a time; without
+    this every worker would try for every chip and all but the first
+    die).  Empty on a host with no TPU.  Bounds of 1,1,1 make the
+    process a one-chip slice of its own: no rendezvous with its peers,
+    and the runtime accepts being loaded by several processes.  The
+    bounds go under the names a TPU host itself sets for the whole
+    board (``TPU_CHIPS_PER_HOST_BOUNDS=2,2,1`` on a v5e-4), so the
+    worker's value replaces the host's."""
+    if n_chips <= 0:
+        return {}
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+            "TPU_HOST_BOUNDS": "1,1,1"}
 
 
 def split_chaos(spec: str) -> tuple:
@@ -131,6 +191,9 @@ class FleetSupervisor:
     def _summary_path(self, worker: str) -> str:
         return os.path.join(self.inbox_dir, f"summary.{worker}.json")
 
+    def _stderr_path(self, worker: str) -> str:
+        return os.path.join(self.inbox_dir, f"stderr.{worker}.log")
+
     def _shard_path(self, worker: str) -> str:
         return os.path.join(self.inbox_dir, f"trace.{worker}.json")
 
@@ -157,9 +220,10 @@ class FleetSupervisor:
         if o.library_dir:
             cmd += ["--library", o.library_dir]
         if o.cache_base:
-            # the segfault fence: one compile cache dir PER WORKER
-            cmd += ["--compile_cache_dir",
-                    os.path.join(o.cache_base, worker)]
+            # the worker's own cache rule (router.
+            # enable_persistent_compile_cache) fences it into
+            # <cache_base>/<worker> unless the environment places it
+            cmd += ["--compile_cache_dir", o.cache_base]
         if o.runs_dir:
             cmd += ["--runs_dir", o.runs_dir]
         if o.scenario:
@@ -179,6 +243,11 @@ class FleetSupervisor:
 
     def start(self) -> "FleetSupervisor":
         m = get_metrics()
+        n_chips = count_tpu_chips()
+        if n_chips and len(self.roster) > n_chips:
+            raise ValueError(
+                f"fleet of {len(self.roster)} workers on a host with "
+                f"{n_chips} TPU chip(s): one worker per chip")
         if self.opts.transport:
             self.server = InboxHTTPServer(
                 self.inbox_dir, host=self.opts.host,
@@ -188,11 +257,14 @@ class FleetSupervisor:
             _atomic_write_json(
                 os.path.join(self.inbox_dir, "transport.json"),
                 {"url": self.server.url})
-        for worker in self.roster:
-            self.procs[worker] = subprocess.Popen(
-                self._worker_cmd(worker),
-                stdout=subprocess.DEVNULL,
-                stderr=subprocess.DEVNULL)
+        for i, worker in enumerate(self.roster):
+            # each worker's stderr is kept: a worker that cannot reach
+            # its chip must not die unseen
+            with open(self._stderr_path(worker), "ab") as err:
+                self.procs[worker] = subprocess.Popen(
+                    self._worker_cmd(worker),
+                    stdout=subprocess.DEVNULL, stderr=err,
+                    env={**os.environ, **chip_env(i, n_chips)})
             m.counter("route.fleet.workers_spawned").inc()
         return self
 

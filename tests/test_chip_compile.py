@@ -1,0 +1,258 @@
+"""Ask the chip's compiler (on-chip-measurement guide, section 2.3).
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is DESCRIBED, not attached: these tests lower the main path's
+programs for a ``v5e:2x2`` topology and keep the compiler's answers.
+A compile that passes is not a chip run — nothing executes, so nothing
+here says anything about results or times.
+
+All in ONE file (one xdist worker loads the TPU library and keeps its
+lock), the topology described inside a module-scoped fixture (never at
+import), compiled in the test's own process, compilation cache off
+around the compiles.
+"""
+
+import functools
+import unittest.mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from parallel_eda_tpu.obs.devprof import DevProfiler, _avatarize
+
+# the chip_smoke `route` phase's real canvas: synth_flow(1200, W=20)
+# sizes a 25 x 25 grid and routes with the default batch of 64; its
+# crop ladder uses the 16 x 16 tile and the full canvas (the dispatch
+# variants of that route, PR 22 rehearsal)
+ROUTE_NX, ROUTE_W, ROUTE_B, ROUTE_TILE, ROUTE_SWEEPS = 25, 20, 64, 16, 16
+HBM_BYTES = 16 * 1024 ** 3          # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without the chip
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def row_mesh(topo):
+    from parallel_eda_tpu.route.planes_shard import ROW_AXIS
+    return Mesh(np.array(topo.devices[:4]), (ROW_AXIS,))
+
+
+def _on(tree, sharding):
+    """Give every shape avatar of ``tree`` the described sharding."""
+    def leaf(x):
+        if isinstance(x, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=sharding)
+        return x
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+@functools.lru_cache(maxsize=None)
+def _planes(nx: int, W: int):
+    """PlanesGraph of an nx x nx device, from shapes alone (no route)."""
+    from parallel_eda_tpu.arch.builtin import minimal_arch
+    from parallel_eda_tpu.route.planes import build_planes
+    from parallel_eda_tpu.rr.graph import build_rr_graph
+    from parallel_eda_tpu.rr.grid import DeviceGrid
+
+    arch = minimal_arch(chan_width=W)
+    return build_planes(
+        build_rr_graph(arch, DeviceGrid(nx, nx, arch.io_capacity)))
+
+
+def _relax_avatars(pg, B, sharding):
+    S = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    args = (S((B, pg.ncells), f32), S((B, pg.ncells), f32),
+            S((B, 1, 1, 1), f32), S((B, pg.ncells), f32))
+    return _on((_avatarize(pg),) + args, sharding)
+
+
+def _fits_hbm(compiled) -> int:
+    ma = compiled.memory_analysis()
+    total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+             + ma.output_size_in_bytes + ma.generated_code_size_in_bytes)
+    assert total < HBM_BYTES, f"{total} B does not fit one v5e chip"
+    return ma.temp_size_in_bytes
+
+
+# ---- the default path's kernels at the route phase's real canvas ---
+
+def test_planes_relax_compiles_at_route_canvas(one_chip):
+    from parallel_eda_tpu.route.planes import planes_relax
+
+    pg = _planes(ROUTE_NX, ROUTE_W)
+    fn = jax.jit(planes_relax, static_argnames=("nsweeps",))
+    compiled = fn.lower(*_relax_avatars(pg, ROUTE_B, one_chip),
+                        nsweeps=ROUTE_SWEEPS).compile()
+    _fits_hbm(compiled)
+
+
+def test_planes_relax_cropped_compiles_at_route_tile(one_chip):
+    from parallel_eda_tpu.route.planes import planes_relax_cropped
+
+    pg = _planes(ROUTE_NX, ROUTE_W)
+    origin = jax.ShapeDtypeStruct((ROUTE_B,), jnp.int32, sharding=one_chip)
+    fn = jax.jit(planes_relax_cropped,
+                 static_argnames=("nsweeps", "cnx", "cny"))
+    compiled = fn.lower(*_relax_avatars(pg, ROUTE_B, one_chip),
+                        nsweeps=ROUTE_SWEEPS, ox=origin, oy=origin,
+                        cnx=ROUTE_TILE, cny=ROUTE_TILE).compile()
+    _fits_hbm(compiled)
+
+
+# ---- the whole window program once, small --------------------------
+
+def test_route_window_program_compiles_for_every_bench_variant(one_chip):
+    """The code AROUND the kernels (fused STA, _mis_colors, traceback,
+    the while_loop exit): every dispatch variant of the 60-LUT bench
+    route, from the shape avatars obs/devprof keeps of a CPU route."""
+    from parallel_eda_tpu.flow import run_route, synth_flow
+    from parallel_eda_tpu.obs import get_devprof, set_devprof
+    from parallel_eda_tpu.route.router import RouterOpts
+
+    prev = get_devprof()
+    prof = set_devprof(DevProfiler(enabled=True))
+    try:
+        # bench.py's default config (bench.build)
+        flow = synth_flow(num_luts=60, num_inputs=12, num_outputs=12,
+                          chan_width=12, seed=11)
+        run_route(flow, RouterOpts(batch_size=64), timing_driven=True)
+    finally:
+        set_devprof(prev)
+    assert flow.route.success
+    pending = prof._pending
+    assert pending, "the route noted no dispatch variant"
+    for key, _meta, fn, args, kwargs in pending:
+        args, kwargs = _on((args, kwargs), one_chip)
+        compiled = fn.lower(*args, **kwargs).compile()
+        _fits_hbm(compiled)
+
+
+# ---- the packed Pallas kernels, asked for real ---------------------
+
+_PALLAS_REFUSAL = (
+    "Mosaic refuses lax.associative_scan inside the kernel "
+    "(planes.py _minplus_scan): 'vector types must have positive "
+    "constant sizes but got 16, 12, 0, 9' — ROADMAP Queue 1 item 2; "
+    "flip this when the kernel compiles")
+
+
+@pytest.mark.xfail(strict=True, reason=_PALLAS_REFUSAL)
+def test_planes_relax_pallas_compiles(one_chip):
+    from parallel_eda_tpu.route.planes_pallas import planes_relax_pallas
+
+    pg = _planes(8, 12)
+    fn = jax.jit(functools.partial(planes_relax_pallas, interpret=False),
+                 static_argnames=("nsweeps",))
+    fn.lower(*_relax_avatars(pg, 16, one_chip), nsweeps=12).compile()
+
+
+@pytest.mark.xfail(strict=True, reason=_PALLAS_REFUSAL)
+def test_planes_relax_cropped_pallas_compiles(one_chip):
+    from parallel_eda_tpu.route.planes_pallas import (
+        planes_relax_cropped_pallas)
+
+    pg = _planes(8, 12)
+    origin = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    fn = jax.jit(
+        functools.partial(planes_relax_cropped_pallas, interpret=False),
+        static_argnames=("nsweeps", "cnx", "cny"))
+    fn.lower(*_relax_avatars(pg, 16, one_chip), nsweeps=12,
+             ox=origin, oy=origin, cnx=4, cny=4).compile()
+
+
+# ---- the paths that exist only across chips ------------------------
+
+def test_remote_slab_permute_compiles_on_four_chip_mesh(row_mesh):
+    from parallel_eda_tpu.route.planes_pallas import remote_slab_permute
+    from parallel_eda_tpu.route.planes_shard import ROW_AXIS
+
+    n = row_mesh.devices.size
+    pg = _planes(ROUTE_NX, ROUTE_W)
+    W, _, NYp1 = pg.shape_x
+    # one dx halo slab per shard: [B, W, 1, NY+1]
+    slab = jax.ShapeDtypeStruct(
+        (n * ROUTE_B, W, 1, NYp1), jnp.float32,
+        sharding=NamedSharding(row_mesh, P(ROW_AXIS)))
+    for fwd in (True, False):
+        fn = jax.jit(jax.shard_map(
+            functools.partial(remote_slab_permute, axis_name=ROW_AXIS,
+                              n_shards=n, fwd=fwd),
+            mesh=row_mesh, in_specs=P(ROW_AXIS), out_specs=P(ROW_AXIS),
+            check_vma=False))
+        compiled = fn.lower(slab).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+def _sharded_relax(row_mesh, impl):
+    from parallel_eda_tpu.route.planes_shard import (RowMesh,
+                                                     planes_relax_sharded)
+
+    pg = _planes(ROUTE_NX, ROUTE_W)
+    rm = RowMesh(row_mesh, row_mesh.devices.size, impl)
+    fn = jax.jit(planes_relax_sharded,
+                 static_argnames=("nsweeps", "rmesh"))
+    replicated = NamedSharding(row_mesh, P())
+    return fn.lower(*_relax_avatars(pg, ROUTE_B, replicated),
+                    nsweeps=ROUTE_SWEEPS, rmesh=rm).compile()
+
+
+def test_planes_relax_sharded_spreads_over_four_chips(one_chip, row_mesh):
+    """ppermute transport: the collectives are in the program and the
+    per-device working set is a fraction of the one-device program's —
+    nothing was put whole on the first device."""
+    from parallel_eda_tpu.route.planes import planes_relax
+
+    compiled = _sharded_relax(row_mesh, "ppermute")
+    text = compiled.as_text()
+    assert "collective-permute" in text      # the halo exchange
+    assert "all-reduce" in text              # the global fixpoint vote
+    sharded_temp = _fits_hbm(compiled)
+    pg = _planes(ROUTE_NX, ROUTE_W)
+    single = jax.jit(planes_relax, static_argnames=("nsweeps",)).lower(
+        *_relax_avatars(pg, ROUTE_B, one_chip),
+        nsweeps=ROUTE_SWEEPS).compile()
+    single_temp = single.memory_analysis().temp_size_in_bytes
+    assert sharded_temp < 0.5 * single_temp, (sharded_temp, single_temp)
+
+
+def test_planes_relax_sharded_with_pallas_halo_transport(row_mesh):
+    """The transport the router picks FIRST on a TPU backend
+    (router._active_row_mesh): the whole sharded relaxation with the
+    remote-DMA kernel in it.  planes_shard asks jax.default_backend(),
+    which sees the CPU here, so the test steers it."""
+    with unittest.mock.patch.object(jax, "default_backend",
+                                    lambda: "tpu"):
+        compiled = _sharded_relax(row_mesh, "pallas_halo")
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_hbm(compiled)

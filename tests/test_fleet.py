@@ -45,6 +45,7 @@ from parallel_eda_tpu.serve.daemon import (SUBMIT_NAME, DaemonOpts,
                                            RouteDaemon, heartbeat_name,
                                            preferred_worker, submit_job)
 from parallel_eda_tpu.serve.daemon import InboxReader, LEASE_DIR
+from parallel_eda_tpu.serve import fleet as fleet_mod
 from parallel_eda_tpu.serve.fleet import SUPERVISOR_SITES, split_chaos
 from parallel_eda_tpu.serve.queue import JobQueue, JobState, RouteJob
 from parallel_eda_tpu.serve.transport import (InboxHTTPServer,
@@ -324,6 +325,52 @@ def test_heartbeat_name_solo_vs_fleet():
     assert heartbeat_name("w3") == "heartbeat.w3.json"
 
 
+def test_count_tpu_chips_counts_only_tpu_vfio_groups(tmp_path,
+                                                     monkeypatch):
+    """The layout of a one-chip v5e machine (four TPU functions listed,
+    one VFIO node handed over) plus what must not count: a chip without
+    a node, the vendor's own NIC and another vendor's device behind
+    VFIO.  No sysfs, no chips."""
+    pci, vfio = tmp_path / "pci", tmp_path / "vfio"
+    vfio.mkdir()
+    for name, vendor, klass, group, node in (
+            ("0000:00:08.0", "0x1ae0", "0xff0000", "1", True),   # TPU
+            ("0000:00:09.0", "0x1ae0", "0xff0000", "0", False),  # no node
+            ("0000:00:0a.0", "0x1ae0", "0x120000", "2", True),   # TPU
+            ("0000:00:06.0", "0x1ae0", "0x020000", "5", True),   # NIC
+            ("0000:00:07.0", "0x10de", "0x030000", "6", True)):  # GPU
+        d = pci / name
+        d.mkdir(parents=True)
+        (d / "vendor").write_text(vendor + "\n")
+        (d / "class").write_text(klass + "\n")
+        os.symlink(f"../../../kernel/iommu_groups/{group}",
+                   d / "iommu_group")
+        if node:
+            (vfio / group).write_text("")
+    (vfio / "vfio").write_text("")
+    monkeypatch.setattr(fleet_mod, "PCI_DEVICES", str(pci))
+    monkeypatch.setattr(fleet_mod, "VFIO_DIR", str(vfio))
+    assert fleet_mod.count_tpu_chips() == 2
+    monkeypatch.setattr(fleet_mod, "PCI_DEVICES", str(tmp_path / "none"))
+    assert fleet_mod.count_tpu_chips() == 0
+
+
+def test_fleet_pins_one_worker_per_chip(tmp_path, monkeypatch):
+    """No TPU: nothing injected.  On a TPU host every worker gets a
+    chip of its own, and more workers than chips are refused before
+    anything is spawned."""
+    assert fleet_mod.chip_env(0, 0) == {}
+    envs = [fleet_mod.chip_env(i, 4) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(set(e) == set(envs[0]) for e in envs)
+    monkeypatch.setattr(fleet_mod, "count_tpu_chips", lambda: 1)
+    sup = fleet_mod.FleetSupervisor(
+        str(tmp_path / "box"), fleet_mod.FleetOpts(n_workers=2))
+    with pytest.raises(ValueError, match="one worker per chip"):
+        sup.start()
+    assert sup.procs == {}
+
+
 class _FakeFlow:
     def __init__(self, nets):
         self.term = types.SimpleNamespace(source=list(range(nets)))
@@ -467,6 +514,51 @@ def test_fleet_foreign_grace_takeover_of_unleased_job(tmp_path):
     job = s1.queue.get(j0)
     assert job is not None and job.state is JobState.DONE
     assert d1.lease.read(j0)["released"]
+
+
+@pytest.mark.parametrize("vouch_s,alive", [(30.0, True), (0.2, False)])
+def test_worker_stays_live_through_a_long_slice(tmp_path, vouch_s, alive):
+    """One slice that outlasts the lease and many heartbeat intervals
+    (a cold compile on a chip): the slice helper thread keeps beating
+    and renewing, so peers and the doctor see a live owner — but only
+    for the dispatch watchdog budget; a slice stuck past it goes
+    silent and its lease lapses, as for a dead process."""
+    box = str(tmp_path / "box")
+    seen = {}
+
+    def runner(job):
+        time.sleep(2.0)
+        peer = LeaseStore(os.path.join(box, LEASE_DIR), "w1", ttl_s=1.0)
+        doc = peer.read(job.job_id)
+        seen.update(expired=peer.expired(doc), renewals=doc["renewals"],
+                    hb_age=Heartbeat.read(os.path.join(
+                        box, heartbeat_name("w0")))["age_s"])
+        return ("done", {"wirelength": 7, "iterations": 2, "nets": 10})
+
+    svc = _FakeService(time.monotonic, runner=runner)
+    d = RouteDaemon(
+        svc, box,
+        DaemonOpts(default_nets_per_s=10.0, cold_start_factor=1.0,
+                   worker="w0", workers=ROSTER, heartbeat_s=0.1,
+                   lease_ttl_s=1.0),
+        flow_builder=lambda spec: _FakeFlow(10))
+    d._vouch_s = vouch_s
+    j0 = _ids_for("w0")
+    _submit_fake(tmp_path, j0)
+    d.cycle()
+    # either way the owner finishes and releases: nobody stole
+    assert svc.queue.get(j0).state is JobState.DONE
+    assert d.lease.read(j0)["released"]
+    errs, _ = _doctor().check_daemon(d.summary())
+    if alive:
+        assert not seen["expired"] and seen["renewals"] >= 3
+        assert seen["hb_age"] < 1.0
+        assert d.heartbeat.max_gap_s < 1.0
+        assert errs == []
+    else:
+        assert seen["expired"]
+        assert seen["hb_age"] > 1.0
+        assert any("heartbeat gap" in e for e in errs)
 
 
 class _TickClock(_Clock):

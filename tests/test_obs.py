@@ -392,6 +392,20 @@ def test_bench_stderr_filter_scrubs_noise():
     assert "SIGILL" in r.stderr and "sse4a" in r.stderr
 
 
+def test_bench_without_cpu_flag_refuses_a_cpu_only_host():
+    """No fallback: without --cpu, on a host where JAX finds no TPU,
+    bench.py exits non-zero and prints NO metric line (a row measured
+    elsewhere must never stand in for a device number)."""
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "--luts", "10",
+         "--no_corpus"],
+        capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "no TPU found" in r.stderr
+
+
 # ---- CLI surface ----
 
 def test_cli_trace_smoke(tmp_path, capsys):

@@ -432,25 +432,105 @@ def test_service_two_tenants_preemption_parity(tmp_path):
     assert all(r["job_id"] for r in recs)
 
 
-def test_router_reuse_reasserts_compile_cache(tmp_path):
-    """Satellite: two Routers with different compile_cache_dirs in one
-    process — route() must re-assert ITS dir (the process global moved
-    when the second Router initialized)."""
+@pytest.fixture
+def _restore_compile_cache():
+    import jax
+
+    was_dir = jax.config.jax_compilation_cache_dir
+    was_state = router_mod._COMPILE_CACHE_DIR
+    yield
+    jax.config.update("jax_compilation_cache_dir", was_dir)
+    router_mod._COMPILE_CACHE_DIR = was_state
+
+
+@pytest.mark.parametrize("case", ["variable", "option", "neither",
+                                  "worker_fence", "variable_no_fence"])
+def test_compile_cache_rule(case, tmp_path, monkeypatch,
+                            _restore_compile_cache):
+    """THE cache rule (router.enable_persistent_compile_cache): the
+    environment variable wins, else the explicit option, else the fixed
+    <checkout>/.jax_cache; fleet workers are fenced into <base>/<worker>
+    only when the variable is unset."""
+    import jax
+
+    env_dir = str(tmp_path / "from_env")
+    opt_dir = str(tmp_path / "from_option")
+    default = str(tmp_path / "checkout" / ".jax_cache")
+    # the real default is inside the checkout, under a fixed name
+    assert router_mod._DEFAULT_COMPILE_CACHE_DIR == os.path.join(
+        REPO, ".jax_cache")
+    monkeypatch.setattr(router_mod, "_DEFAULT_COMPILE_CACHE_DIR", default)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    if case.startswith("variable"):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    got = {
+        "variable": lambda: router_mod.enable_persistent_compile_cache(
+            opt_dir),
+        "option": lambda: router_mod.enable_persistent_compile_cache(
+            opt_dir),
+        "neither": lambda: router_mod.enable_persistent_compile_cache(),
+        "worker_fence": lambda: router_mod.enable_persistent_compile_cache(
+            opt_dir, worker="w1"),
+        "variable_no_fence":
+            lambda: router_mod.enable_persistent_compile_cache(
+                opt_dir, worker="w1"),
+    }[case]()
+    want = {"variable": env_dir, "option": opt_dir, "neither": default,
+            "worker_fence": os.path.join(opt_dir, "w1"),
+            "variable_no_fence": env_dir}[case]
+    assert got == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want)
+    # and no other directory was made
+    assert sorted(os.listdir(tmp_path)) == [
+        os.path.relpath(want, tmp_path).split(os.sep)[0]]
+
+
+def test_cache_lands_where_the_variable_says_and_nowhere_else(tmp_path):
+    """A tiny CPU route through the CLI with JAX_COMPILATION_CACHE_DIR
+    set AND a --compile_cache_dir given: the entries are under the
+    variable's directory, the option's directory is never made and the
+    checkout's default is untouched."""
+    import subprocess
+    import sys
+
+    env_dir = tmp_path / "from_env"
+    opt_dir = tmp_path / "from_option"
+    default = os.path.join(REPO, ".jax_cache")
+
+    def listing(d):
+        # missing == empty: a concurrent cache-free test may make the
+        # (empty) default directory, but nothing writes entries there
+        return sorted(os.listdir(d)) if os.path.isdir(d) else []
+
+    before = listing(default)
+    r = subprocess.run(
+        [sys.executable, "-m", "parallel_eda_tpu", "--luts", "10",
+         "--arch", "minimal", "--route_chan_width", "12", "--no_place",
+         "--no_timing", "--batch_size", "16",
+         "--compile_cache_dir", str(opt_dir),
+         "--out_dir", str(tmp_path / "out")],
+        cwd=REPO, capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "JAX_ENABLE_COMPILATION_CACHE": "true",
+             "JAX_COMPILATION_CACHE_DIR": str(env_dir)})
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert listing(env_dir), "no cache entry under the variable's dir"
+    assert not opt_dir.exists()
+    assert listing(default) == before
+
+
+def test_router_reuse_zeroes_pipeline_gauges():
+    """Satellite: the serve loop calls route() many times on one
+    process — route() zeroes the per-route pipeline gauges at entry so
+    a job never inherits the previous job's value."""
     from parallel_eda_tpu.flow import synth_flow
 
-    dir_a = str(tmp_path / "cc_a")
-    dir_b = str(tmp_path / "cc_b")
     f = synth_flow(num_luts=10, seed=1)
-    ra = Router(f.rr, RouterOpts(batch_size=16, sink_group=0,
-                                 compile_cache_dir=dir_a))
-    assert router_mod._COMPILE_CACHE_DIR == dir_a
-    Router(f.rr, RouterOpts(batch_size=16, sink_group=0,
-                            compile_cache_dir=dir_b))
-    assert router_mod._COMPILE_CACHE_DIR == dir_b
+    ra = Router(f.rr, RouterOpts(batch_size=16, sink_group=0))
     # leak a previous job's pipeline gauge; route() zeroes it at entry
     get_metrics().gauge("route.pipeline.stall_ms_total").set(1e9)
     res = ra.route(f.term)
     assert res.success
-    assert router_mod._COMPILE_CACHE_DIR == dir_a
     v = get_metrics().values("route.pipeline.")
     assert v["route.pipeline.stall_ms_total"] < 1e9

@@ -7,7 +7,7 @@ Stdlib-only like its siblings (trace_report.py / ledger_report.py,
 whose --check rule sets it reuses by import): it must run anywhere the
 artifacts land, without jax or the repo on the path.
 
-    python tools/flow_doctor.py --row BENCH_r05.json --bench-dir .
+    python tools/flow_doctor.py --row row.json --against previous_row.json
     python tools/flow_doctor.py --trace out.json --metrics metrics.json \
                                 --devprof devprof.json
 

@@ -82,23 +82,6 @@ def log(msg: str) -> None:
     print(f"kernel_bench: {msg}", file=sys.stderr, flush=True)
 
 
-def peak_hbm_bw(dev) -> float:
-    """Peak HBM bandwidth by device kind (same table as bench.py's
-    sweep microbench; CPU number is a laptop-class stand-in)."""
-    kind = (getattr(dev, "device_kind", "") or dev.platform).lower()
-    if dev.platform == "cpu":
-        return 50e9
-    if "v5 lite" in kind or "v5e" in kind or "v5litepod" in kind:
-        return 819e9
-    if "v5" in kind:
-        return 2765e9
-    if "v4" in kind:
-        return 1228e9
-    if "v6" in kind or "trillium" in kind:
-        return 1638e9
-    return 819e9
-
-
 def _instance(nx: int, ny: int, W: int, B: int):
     """Bench problem at the 60-LUT canvas scale: minimal arch, uniform
     congestion, a few zero-delay seeds per net (the relaxation's cost
@@ -131,7 +114,7 @@ def _time_best(fn, d0, reps: int):
     for _ in range(reps):
         t0 = time.time()
         out = fn(d0)
-        np.asarray(out[0])              # real sync
+        out[0].block_until_ready()
         best = min(best, time.time() - t0)
     return best, np.asarray(out[1])
 
@@ -148,7 +131,9 @@ def _row(variant, tile, block_nets, occupancy, bytes_per_sweep,
         "wall_ms": round(wall_s * 1e3, 3),
         "sweeps_executed": int(sweeps),
         "achieved_gbps": round(achieved / 1e9, 3),
-        "roofline_fraction": round(achieved / peak_bw, 4),
+        # None off the chip: a CPU run reports no roofline share
+        "roofline_fraction": (round(achieved / peak_bw, 4)
+                              if peak_bw else None),
         "plane_dtype": plane_dtype,
     }
 
@@ -166,15 +151,19 @@ def run_bench(args) -> dict:
         planes_relax_cropped_pallas, planes_relax_pallas,
         unpacked_lane_occupancy, xla_bytes_per_cell)
 
+    from parallel_eda_tpu.obs.devprof import peak_hbm_bytes_per_s
+    from parallel_eda_tpu.route.planes_pallas import pallas_interpret
+
     dev = jax.devices()[0]
-    peak_bw = peak_hbm_bw(dev)
-    interpret = dev.platform != "tpu"
+    peak_bw = peak_hbm_bytes_per_s(dev)
+    interpret = pallas_interpret(None)
     B, nsw, reps = args.batch, args.nsweeps, args.reps
     dtypes = (("f32", "bf16") if args.plane_dtype == "both"
               else (args.plane_dtype,))
     pg, d0, cc, crit, w0 = _instance(args.nx, args.ny, args.chan_width,
                                      B)
-    log(f"device {dev.platform} (peak HBM {peak_bw / 1e9:.0f} GB/s, "
+    log(f"device {dev.platform} (peak HBM "
+        f"{f'{peak_bw / 1e9:.0f} GB/s' if peak_bw else 'not known'}, "
         f"pallas interpret={interpret}); canvas {args.nx}x{args.ny} "
         f"W={args.chan_width} B={B}, {pg.ncells} cells/net, "
         f"dtypes {'/'.join(dtypes)}")
@@ -247,7 +236,8 @@ def run_bench(args) -> dict:
             log(f"[{dt:<4}] {r['variant']:<22} G={g:<3} "
                 f"occ={occ:.3f} {r['wall_ms']:8.2f} ms  "
                 f"{r['achieved_gbps']:8.2f} GB/s "
-                f"({r['roofline_fraction']:.1%} of roofline)")
+                + (f"({r['roofline_fraction']:.1%} of roofline)"
+                   if peak_bw else "(no roofline share off the chip)"))
 
     def bench_dispatch(dt):
         """Fixed per-dispatch cost: best-of-reps wall of a MINIMAL
@@ -286,8 +276,9 @@ def run_bench(args) -> dict:
                    "block": args.block or None,
                    "plane_dtype": args.plane_dtype},
         "device": {"platform": dev.platform,
-                   "kind": getattr(dev, "device_kind", dev.platform),
-                   "peak_hbm_gbps": round(peak_bw / 1e9, 1)},
+                   "kind": dev.device_kind,
+                   "peak_hbm_gbps": (round(peak_bw / 1e9, 1)
+                                     if peak_bw else None)},
         "interpret": interpret,
         "dispatch_overhead": dispatch,
         "rows": rows,
@@ -337,7 +328,8 @@ def check_ledger(doc) -> list:
         if not r.get("bytes_per_sweep", 0) > 0:
             errs.append(f"row {i}: bytes_per_sweep must be positive")
         rf = r.get("roofline_fraction")
-        if not isinstance(rf, (int, float)) or rf < 0:
+        if rf is not None and (not isinstance(rf, (int, float))
+                               or rf < 0):
             errs.append(f"row {i}: bad roofline_fraction {rf!r}")
         g = r.get("block_nets", 0)
         if not (isinstance(g, int) and g >= 1):

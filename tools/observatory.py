@@ -230,8 +230,7 @@ def print_report(rs, runs_dir: str, scenario=None, last: int = 10,
             for r in grecs[-last:]:
                 qor = r.get("qor") or {}
                 era = "pre_pr2" if (r.get("tags") or {}).get("pre_pr2") \
-                    else ("replay" if (r.get("tags") or {}).get("replay")
-                          else "")
+                    else ""
                 line = (f"| {r.get('ts')} | {r.get('git_rev')} "
                         f"| {r.get('backend')} | {r.get('device_kind')} "
                         f"| {r.get('metric')} | {_fmt(r.get('value'))} "
