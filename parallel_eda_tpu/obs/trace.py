@@ -15,9 +15,18 @@ Two things the tp.h design could not give us come for free here:
   "jax.compile.*" span, so XLA compilation — tens of seconds per
   window program — is separable from iteration timings instead of
   polluting the first window of every route.
-- disabled = no-op: with no tracer installed, span() hands back one
-  shared null context and does nothing else (no allocation, no file,
-  no clock read), like the reference's compiled-out log macros.
+- disabled = no-op: with no tracer installed and no profiler session
+  running, span() hands back one shared null context (one TraceMe
+  level check, no allocation, no file, no clock read), like the
+  reference's compiled-out log macros.
+
+span() is the one way to open a span and it has two sinks: the
+installed Tracer (Chrome trace-event JSON on the host's clock) and, as
+a ``jax.profiler.TraceAnnotation`` of the same name and args, whatever
+profiler session is running -- so the program's own spans land on the
+profiler's clock beside the device ops.  The device side of the same
+picture is DEVICE_SCOPES: the fixed ``jax.named_scope`` vocabulary of
+the window program, the names a trace reduction keys on.
 """
 
 from __future__ import annotations
@@ -30,8 +39,43 @@ import time
 from typing import Optional
 
 
+# the window program's stages on the device: every op of
+# route_window_planes / _fused / _multi lies under exactly one
+# top-level name (nested ones only under route.dev.relax).  The names
+# are what a reduction of a device trace keys on (benchmark/
+# scope_reduce.py, OBSERVABILITY.md), so they outlive any refactor of
+# what is inside them; tests/test_device_scopes.py holds the compiled
+# programs to the list.
+DEVICE_SCOPES = (
+    "route.dev.ripup",           # batch rows, dirty predicate, rip-up
+    "route.dev.cost_fields",     # congestion cost into cell space, seeds
+    "route.dev.relax",           # the min-plus relaxation
+    "route.dev.relax.scan",      #   directional scans of one sweep
+    "route.dev.relax.turn",      #   turn candidates of one sweep
+    "route.dev.relax.crop",      #   crop to / scatter from net tiles
+    "route.dev.sink_pick",       # sink candidates, direct, the pick
+    "route.dev.traceback",       # pointer chase, path rows, store
+    "route.dev.tree_grow",       # grow the tree in cell space
+    "route.dev.commit",          # occupancy commit, scatter to state
+    "route.dev.history",         # per-iteration acc / pres escalation
+    "route.dev.sta",             # the fused STA
+    "route.dev.mis_colors",      # conflict colouring of the dirty set
+    "route.dev.window_summary",  # packed status / scal, rung stacking
+)
+
+
+def device_scope(name: str):
+    """``jax.named_scope`` of one declared DEVICE_SCOPES name: the only
+    way the package names device work.  Metadata only -- the compiled
+    program and its compile-cache key do not change."""
+    if name not in DEVICE_SCOPES:
+        raise KeyError(f"{name!r} is not in obs.trace.DEVICE_SCOPES")
+    import jax
+    return jax.named_scope(name)
+
+
 class _NullSpan:
-    """Shared do-nothing context: the disabled-tracer fast path."""
+    """Shared do-nothing context: the fast path with no sink active."""
     __slots__ = ()
 
     def __enter__(self):
@@ -40,28 +84,83 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    event = None
+
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("tracer", "name", "cat", "args", "_t_in")
+class _NoAnnotation:
+    """Stand-in for TraceAnnotation where jax is not importable (tools,
+    docs builds): never enabled, so never constructed."""
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+
+_annotation = None      # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _annotation_cls():
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except ImportError:
+            _annotation = _NoAnnotation
+    return _annotation
+
+
+class _Span:
+    """One open span, written to both sinks: the profiler session (a
+    TraceAnnotation of the same name and args, or None when no session
+    runs) and the Tracer (or None when none is installed)."""
+    __slots__ = ("tracer", "name", "cat", "args", "event", "_ann",
+                 "_t_in")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 args: dict, ann=None):
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self.event = None       # the tracer's event, once closed
+        self._ann = ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t_in = time.perf_counter()
         return self
 
+    def set(self, **args) -> None:
+        """Args known only inside the span (``first=True`` of a
+        dispatch that turned out to compile)."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**_annotation_args(args))
+
     def __exit__(self, *exc):
         t = time.perf_counter()
-        self.tracer.add_complete(self.name, self._t_in, t - self._t_in,
-                                 cat=self.cat, **self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.tracer is not None:
+            self.event = self.tracer.add_complete(
+                self.name, self._t_in, t - self._t_in, cat=self.cat,
+                **self.args)
         return False
+
+
+def _annotation_args(args: dict) -> dict:
+    """Span args as profiler stats: the profiler keeps numbers and
+    strings, so anything else (None, a tile's list) goes as its str."""
+    return {k: v if isinstance(v, (bool, int, float, str)) else str(v)
+            for k, v in args.items()}
 
 
 class Tracer:
@@ -79,19 +178,24 @@ class Tracer:
         self.declared_counter_tracks: set = set()
         self._lock = threading.Lock()
 
-    def span(self, name: str, cat: str = "flow", **args) -> _Span:
-        return _Span(self, name, cat, args)
+    def span(self, name: str, cat: str = "flow", **args):
+        """A span recorded on THIS tracer (and in a running profiler
+        session); the package's own call sites use the module's
+        span(), which finds the installed tracer itself."""
+        return _open_span(self, name, cat, args)
 
     def add_complete(self, name: str, t_abs: float, dur: float,
-                     cat: str = "flow", **args) -> None:
-        """Record a complete event from absolute perf_counter seconds."""
+                     cat: str = "flow", **args) -> dict:
+        """Record a complete event from absolute perf_counter seconds.
+        Returns the event: a caller that learns more about the span
+        later (the window's deferred ledger) adds it to its args."""
         ev = {"name": name, "ph": "X", "cat": cat,
               "ts": (t_abs - self.t0) * 1e6, "dur": max(0.0, dur) * 1e6,
-              "pid": 1, "tid": threading.get_ident() & 0x7FFFFFFF}
-        if args:
-            ev["args"] = args
+              "pid": 1, "tid": threading.get_ident() & 0x7FFFFFFF,
+              "args": args}
         with self._lock:
             self.events.append(ev)
+        return ev
 
     def mark(self, name: str, t_begin: float, t_end: float,
              cat: str = "flow", **args) -> None:
@@ -239,12 +343,23 @@ def set_tracer(tracer: Optional[Tracer]) -> None:
 
 
 def span(name: str, cat: str = "flow", **args):
-    """`with span("route.iter", it=3):` — records a complete event on
-    the installed tracer; a shared no-op context when tracing is off."""
-    t = _tracer
+    """`with span("route.window", window=3):` -- THE way to open a
+    span.  Two sinks: a running profiler session gets a
+    ``jax.profiler.TraceAnnotation`` of the same name and args (on the
+    profiler's clock, beside the device ops), the installed tracer a
+    complete event.  With neither active the cost is one TraceMe level
+    check and the shared no-op context comes back."""
+    return _open_span(_tracer, name, cat, args)
+
+
+def _open_span(t: Optional[Tracer], name: str, cat: str, args: dict):
+    cls = _annotation_cls()
+    if cls.is_enabled():
+        return _Span(t, name, cat, args,
+                     cls(name, **_annotation_args(args)))
     if t is None:
         return _NULL_SPAN
-    return t.span(name, cat=cat, **args)
+    return _Span(t, name, cat, args)
 
 
 class _StageCtx:
@@ -279,23 +394,28 @@ def stage(name: str, times: Optional[dict] = None, **args) -> _StageCtx:
 # ---- JAX compile-phase capture (/jax/core/compile/* monitoring) ----
 
 _compile_s = 0.0
+_phase_s: dict = {}     # seconds per compile phase, by short name
 _capture_on = False
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def _on_event_duration(event: str, duration: float, **kw) -> None:
+    if event == _CACHE_READ:
+        # inside backend_compile: a split of it, not more compile time
+        _phase_s["cache_read"] = _phase_s.get("cache_read", 0.0) + duration
+        return
     if not event.startswith("/jax/core/compile/"):
         return
     global _compile_s
     _compile_s += duration
+    phase = event.rsplit("/", 1)[1].removesuffix("_duration")
+    _phase_s[phase] = _phase_s.get(phase, 0.0) + duration
     t = _tracer
     if t is not None:
         # the listener fires at phase END with only a duration: anchor
         # the span backwards from now (the phase ran synchronously, so
         # it nests inside whatever host span is open)
-        name = event.rsplit("/", 1)[1]
-        if name.endswith("_duration"):
-            name = name[: -len("_duration")]
-        t.add_complete("jax.compile." + name,
+        t.add_complete("jax.compile." + phase,
                        time.perf_counter() - duration, duration,
                        cat="jax.compile")
 
@@ -324,6 +444,15 @@ def compile_seconds() -> float:
     enabled (monotone between resets; diff around a region to
     attribute it)."""
     return _compile_s
+
+
+def compile_phases() -> dict:
+    """Seconds per compile phase since capture was enabled, by the
+    event's short name (``jaxpr_trace``, ``jaxpr_to_mlir_module``,
+    ``backend_compile``, and ``cache_read``: the part of
+    ``backend_compile`` spent reading the persistent cache).  Diff
+    around a first dispatch for its split."""
+    return dict(_phase_s)
 
 
 def reset_compile_seconds() -> None:

@@ -80,10 +80,6 @@ class CheckpointStore:
         if removed:
             get_metrics().counter(
                 "route.resil.checkpoint_gc").inc(removed)
-            tr = get_tracer()
-            if tr is not None:
-                tr.instant("route.resil.checkpoint.gc", cat="resil",
-                           removed=removed)
         return removed
 
     def _path(self, job_id: str) -> str:
@@ -105,10 +101,6 @@ class CheckpointStore:
             os.replace(path, path + ".prev")
         os.replace(tmp, path)
         get_metrics().counter("route.resil.checkpoint_writes").inc()
-        tr = get_tracer()
-        if tr is not None:
-            tr.instant("route.resil.checkpoint.write", cat="resil",
-                       job=str(job_id), bytes=len(blob))
         if self.plan is not None:
             f = self.plan.fire("checkpoint.corrupt", detail=str(job_id))
             if f is not None:

@@ -60,6 +60,7 @@ import numpy as np
 from flax import struct
 from jax import lax
 
+from ..obs.trace import device_scope
 from ..rr.graph import CHANX, CHANY, RRGraph
 from .device_graph import DeviceRRGraph
 from .search import JITTER_EPS, congestion_cost, usage_from_paths
@@ -781,24 +782,28 @@ def _sweep_once(gm: PlanesGeom, s, crit_c, cc_x, cc_y, costs):
     payloads stay in global cell-id space under cropping."""
     cfx, cbx, cfy, cby, wfx, wbx, wfy, wby = costs
     dx, dy, predx, predy, wx, wy = s
-    dx, predx, wx = _scan_update(dx, predx, wx, cfx, wfx, gm.idxx,
-                                 gm.stride_x, 2, False)
-    dx, predx, wx = _scan_update(dx, predx, wx, cbx, wbx, gm.idxx,
-                                 gm.stride_x, 2, True)
-    tv, ts, tw = _turn_triples_into_y(gm, dx, crit_c, cc_y)
-    imp = tv < dy
-    dy = jnp.where(imp, tv, dy)
-    predy = jnp.where(imp, ts, predy)
-    wy = jnp.where(imp, tw, wy)
-    dy, predy, wy = _scan_update(dy, predy, wy, cfy, wfy, gm.idxy,
-                                 1, 3, False)
-    dy, predy, wy = _scan_update(dy, predy, wy, cby, wby, gm.idxy,
-                                 1, 3, True)
-    tv, ts, tw = _turn_triples_into_x(gm, dy, crit_c, cc_x)
-    imp = tv < dx
-    dx = jnp.where(imp, tv, dx)
-    predx = jnp.where(imp, ts, predx)
-    wx = jnp.where(imp, tw, wx)
+    with device_scope("route.dev.relax.scan"):
+        dx, predx, wx = _scan_update(dx, predx, wx, cfx, wfx, gm.idxx,
+                                     gm.stride_x, 2, False)
+        dx, predx, wx = _scan_update(dx, predx, wx, cbx, wbx, gm.idxx,
+                                     gm.stride_x, 2, True)
+    with device_scope("route.dev.relax.turn"):
+        tv, ts, tw = _turn_triples_into_y(gm, dx, crit_c, cc_y)
+        imp = tv < dy
+        dy = jnp.where(imp, tv, dy)
+        predy = jnp.where(imp, ts, predy)
+        wy = jnp.where(imp, tw, wy)
+    with device_scope("route.dev.relax.scan"):
+        dy, predy, wy = _scan_update(dy, predy, wy, cfy, wfy, gm.idxy,
+                                     1, 3, False)
+        dy, predy, wy = _scan_update(dy, predy, wy, cby, wby, gm.idxy,
+                                     1, 3, True)
+    with device_scope("route.dev.relax.turn"):
+        tv, ts, tw = _turn_triples_into_x(gm, dy, crit_c, cc_x)
+        imp = tv < dx
+        dx = jnp.where(imp, tv, dx)
+        predx = jnp.where(imp, ts, predx)
+        wx = jnp.where(imp, tw, wx)
     return dx, dy, predx, predy, wx, wy
 
 
@@ -991,6 +996,7 @@ def planes_relax(pg: PlanesGraph, d0_flat, cc_flat, crit_c, wenter0,
     return flat(dx, dy), flat(predx, predy), flat(wx, wy), stats
 
 
+@device_scope("route.dev.relax.crop")
 def crop_state(pg: PlanesGraph, d0_flat, cc_flat, wenter0, ox, oy,
                cnx: int, cny: int):
     """Shared crop scaffolding of the two cropped programs (XLA and
@@ -1018,6 +1024,7 @@ def crop_state(pg: PlanesGraph, d0_flat, cc_flat, wenter0, ox, oy,
              crop4(wxf, cnx, cny + 1), crop4(wyf, cnx + 1, cny)))
 
 
+@device_scope("route.dev.relax.crop")
 def scatter_state(gm_full: PlanesGeom, fulls, tiles, ox, oy):
     """Shared scatter-back: write each net's relaxed tile into its full
     canvases (cells outside the tile keep d0 / SELF-pred / wenter0 —
@@ -1097,7 +1104,8 @@ def planes_relax_cropped(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
 
     Same (dist, pred, wenter, stats) returns as planes_relax."""
     gm_full = geom_full(pg)
-    gm = geom_cropped(pg, ox, oy, cnx, cny, full=gm_full)
+    with device_scope("route.dev.relax.crop"):
+        gm = geom_cropped(pg, ox, oy, cnx, cny, full=gm_full)
     fulls, (dx, dy, cc_x, cc_y, wx, wy) = crop_state(
         pg, d0_flat, cc_flat, wenter0, ox, oy, cnx, cny)
     if plane_dtype != "f32":
@@ -1166,340 +1174,350 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
     ncells = pg.ncells
     Kw = max_len - 4            # walk budget: sink+ipin+opin+source slots
 
-    b_paths = paths[sel]
-    b_src = source_all[sel]
-    b_sinks = sinks_all[sel]
-    b_bb = bb[sel]
-    b_crit = crit_all[sel]
-    b_opin = opin_node_all[sel]                  # [B, O]
-    b_ecell = entry_cell_all[sel]                # [B, Ko]
-    b_eoidx = entry_oidx_all[sel]
-    b_edelay = entry_delay_all[sel]
-    b_uid = sink_uid_all[sel]                    # [B, S]
-    b_scell = uid_cell[b_uid]                    # [B, S, K]
-    b_sipin = uid_ipin[b_uid]
-    b_swdel = uid_delay[b_uid]
-    b_doidx = direct_oidx_all[sel]               # [B, S] (-1 = none)
-    b_dipin = direct_ipin_all[sel]
-    b_ddel = direct_delay_all[sel]
-    if mesh is not None and _as_row_mesh(mesh) is None:
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    with device_scope("route.dev.ripup"):
+        b_paths = paths[sel]
+        b_src = source_all[sel]
+        b_sinks = sinks_all[sel]
+        b_bb = bb[sel]
+        b_crit = crit_all[sel]
+        b_opin = opin_node_all[sel]                  # [B, O]
+        b_ecell = entry_cell_all[sel]                # [B, Ko]
+        b_eoidx = entry_oidx_all[sel]
+        b_edelay = entry_delay_all[sel]
+        b_uid = sink_uid_all[sel]                    # [B, S]
+        b_scell = uid_cell[b_uid]                    # [B, S, K]
+        b_sipin = uid_ipin[b_uid]
+        b_swdel = uid_delay[b_uid]
+        b_doidx = direct_oidx_all[sel]               # [B, S] (-1 = none)
+        b_dipin = direct_ipin_all[sel]
+        b_ddel = direct_delay_all[sel]
+        if mesh is not None and _as_row_mesh(mesh) is None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-        def c(x, *spec):
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh, P(*spec)))
-        b_paths = c(b_paths, "net", None, None)
-        b_src = c(b_src, "net")
-        b_sinks = c(b_sinks, "net", None)
-        b_bb = c(b_bb, "net", None)
-        b_crit = c(b_crit, "net", None)
-        b_opin = c(b_opin, "net", None)
-        b_ecell = c(b_ecell, "net", None)
-        b_eoidx = c(b_eoidx, "net", None)
-        b_edelay = c(b_edelay, "net", None)
-        b_scell = c(b_scell, "net", None, None)
-        b_sipin = c(b_sipin, "net", None, None)
-        b_swdel = c(b_swdel, "net", None, None)
-        b_doidx = c(b_doidx, "net", None)
-        b_dipin = c(b_dipin, "net", None)
-        b_ddel = c(b_ddel, "net", None)
+            def c(x, *spec):
+                return jax.lax.with_sharding_constraint(
+                    x, NamedSharding(mesh, P(*spec)))
+            b_paths = c(b_paths, "net", None, None)
+            b_src = c(b_src, "net")
+            b_sinks = c(b_sinks, "net", None)
+            b_bb = c(b_bb, "net", None)
+            b_crit = c(b_crit, "net", None)
+            b_opin = c(b_opin, "net", None)
+            b_ecell = c(b_ecell, "net", None)
+            b_eoidx = c(b_eoidx, "net", None)
+            b_edelay = c(b_edelay, "net", None)
+            b_scell = c(b_scell, "net", None, None)
+            b_sipin = c(b_sipin, "net", None, None)
+            b_swdel = c(b_swdel, "net", None, None)
+            b_doidx = c(b_doidx, "net", None)
+            b_dipin = c(b_dipin, "net", None)
+            b_ddel = c(b_ddel, "net", None)
 
-    arangeB = jnp.arange(B)
-    O = b_opin.shape[1]
-    Ko = b_ecell.shape[1]
-    K = b_scell.shape[2]
+        arangeB = jnp.arange(B)
+        O = b_opin.shape[1]
+        Ko = b_ecell.shape[1]
+        K = b_scell.shape[2]
 
-    # device-side reroute predicate: skip clean nets unless forced
-    over_now = jnp.append(occ > dev.capacity, False)
-    dirty = over_now[b_paths].any(axis=(1, 2)) | ~all_reached[sel]
-    valid = valid & (dirty | force)
+        # device-side reroute predicate: skip clean nets unless forced
+        over_now = jnp.append(occ > dev.capacity, False)
+        dirty = over_now[b_paths].any(axis=(1, 2)) | ~all_reached[sel]
+        valid = valid & (dirty | force)
 
-    # --- rip up (identical to the ELL resident program) ---
-    nodes_p1 = jnp.zeros(N + 1, dtype=jnp.float32)
-    old_usage = usage_from_paths(b_paths, nodes_p1) & valid[:, None]
-    occ_rip = occ - jnp.sum(old_usage, axis=0, dtype=jnp.int32)
-    occ_view = occ[None, :] - old_usage.astype(jnp.int32)
+        # --- rip up (identical to the ELL resident program) ---
+        nodes_p1 = jnp.zeros(N + 1, dtype=jnp.float32)
+        old_usage = usage_from_paths(b_paths, nodes_p1) & valid[:, None]
+        occ_rip = occ - jnp.sum(old_usage, axis=0, dtype=jnp.int32)
+        occ_view = occ[None, :] - old_usage.astype(jnp.int32)
 
-    cong = congestion_cost(dev, occ_view, acc, pres_fac)      # [B, N]
-    # deterministic per-(net, node) jitter — same hash as search.py so the
-    # two programs negotiate identically
-    h = (sel.astype(jnp.int32)[:, None] * jnp.int32(2654435761 & 0x7FFFFFFF)
-         + jnp.arange(N, dtype=jnp.int32)[None, :] * jnp.int32(40503))
-    jitter = 1.0 + JITTER_EPS * ((h & 0xFFFF).astype(jnp.float32) / 65536.0)
-    inside = ((dev.xhigh[None, :] >= b_bb[:, 0, None])
-              & (dev.xlow[None, :] <= b_bb[:, 1, None])
-              & (dev.yhigh[None, :] >= b_bb[:, 2, None])
-              & (dev.ylow[None, :] <= b_bb[:, 3, None]))
-    congj = jnp.where(inside, cong * jitter, INF)             # [B, N]
-    congj_p1 = jnp.concatenate(
-        [congj, jnp.full((B, 1), INF, jnp.float32)], axis=1)
-    noc_b = jnp.broadcast_to(pg.node_of_cell[None, :], (B, ncells))
-    cc_flat_base = jnp.take_along_axis(congj_p1, noc_b, axis=1)
-    opin_congj = jnp.take_along_axis(
-        congj_p1, jnp.clip(b_opin, 0, N), axis=1)              # [B, O]
-    ipin_congj = jnp.take_along_axis(
-        congj_p1, b_sipin.reshape(B, -1), axis=1).reshape(B, S, K)
+    with device_scope("route.dev.cost_fields"):
+        cong = congestion_cost(dev, occ_view, acc, pres_fac)      # [B, N]
+        # deterministic per-(net, node) jitter — same hash as search.py so
+        # the two programs negotiate identically
+        h = (sel.astype(jnp.int32)[:, None]
+             * jnp.int32(2654435761 & 0x7FFFFFFF)
+             + jnp.arange(N, dtype=jnp.int32)[None, :] * jnp.int32(40503))
+        jitter = 1.0 + JITTER_EPS * (
+            (h & 0xFFFF).astype(jnp.float32) / 65536.0)
+        inside = ((dev.xhigh[None, :] >= b_bb[:, 0, None])
+                  & (dev.xlow[None, :] <= b_bb[:, 1, None])
+                  & (dev.yhigh[None, :] >= b_bb[:, 2, None])
+                  & (dev.ylow[None, :] <= b_bb[:, 3, None]))
+        congj = jnp.where(inside, cong * jitter, INF)             # [B, N]
+        congj_p1 = jnp.concatenate(
+            [congj, jnp.full((B, 1), INF, jnp.float32)], axis=1)
+        noc_b = jnp.broadcast_to(pg.node_of_cell[None, :], (B, ncells))
+        cc_flat_base = jnp.take_along_axis(congj_p1, noc_b, axis=1)
+        opin_congj = jnp.take_along_axis(
+            congj_p1, jnp.clip(b_opin, 0, N), axis=1)              # [B, O]
+        ipin_congj = jnp.take_along_axis(
+            congj_p1, b_sipin.reshape(B, -1), axis=1).reshape(B, S, K)
 
-    # initial tree: empty in cell space; SOURCE entries come via opin_du
-    seed0 = jnp.zeros((B, ncells), bool)
+        # initial tree: empty in cell space; SOURCE entries come via opin_du
+        seed0 = jnp.zeros((B, ncells), bool)
 
-    # per-net crop origins (static (cnx, cny) tile, route.h:70-165 bb
-    # semantics as a crop): anchored on the net's STATIC INITIAL bb
-    # (bb0_all — terminal extent + bb_factor), NOT the live bb, so a
-    # net whose bb widened device-side (unreached sink -> full_bb)
-    # keeps a tile that COVERS ALL ITS TERMINALS and stays routable —
-    # its search is tile-clamped until the host re-classifies it into
-    # the full-canvas window at the next sync (the dev_wide summary
-    # output).  The tile covers every bb0-intersecting wire (margin
-    # max_span)
-    if crop_tile is not None:
-        cnx_t, cny_t = crop_tile
-        NXg = pg.shape_x[1]
-        NYg = pg.shape_y[2]
-        Lm = pg.max_span
-        bb_anchor = bb0_all[sel] if bb0_all is not None else b_bb
-        crop_ox = jnp.clip(bb_anchor[:, 0] - Lm, 0, NXg - cnx_t
-                           ).astype(jnp.int32)
-        crop_oy = jnp.clip(bb_anchor[:, 2] - Lm, 0, NYg - cny_t
-                           ).astype(jnp.int32)
+        # per-net crop origins (static (cnx, cny) tile, route.h:70-165 bb
+        # semantics as a crop): anchored on the net's STATIC INITIAL bb
+        # (bb0_all — terminal extent + bb_factor), NOT the live bb, so a
+        # net whose bb widened device-side (unreached sink -> full_bb)
+        # keeps a tile that COVERS ALL ITS TERMINALS and stays routable —
+        # its search is tile-clamped until the host re-classifies it into
+        # the full-canvas window at the next sync (the dev_wide summary
+        # output).  The tile covers every bb0-intersecting wire (margin
+        # max_span)
+        if crop_tile is not None:
+            cnx_t, cny_t = crop_tile
+            NXg = pg.shape_x[1]
+            NYg = pg.shape_y[2]
+            Lm = pg.max_span
+            bb_anchor = bb0_all[sel] if bb0_all is not None else b_bb
+            crop_ox = jnp.clip(bb_anchor[:, 0] - Lm, 0, NXg - cnx_t
+                               ).astype(jnp.int32)
+            crop_oy = jnp.clip(bb_anchor[:, 2] - Lm, 0, NYg - cny_t
+                               ).astype(jnp.int32)
 
     def wave_run(wave, state):
         (seed_cells, tdel_cells, opin_used, remaining, wpaths, delay,
          reached_all, st) = state
-        crit_w = jnp.max(jnp.where(remaining, b_crit, 0.0), axis=1)  # [B]
-        cw = 1.0 - crit_w
-        cc_flat = cw[:, None] * cc_flat_base
-        crit_c = crit_w[:, None, None, None]
+        with device_scope("route.dev.cost_fields"):
+            crit_w = jnp.max(jnp.where(remaining, b_crit, 0.0), axis=1)  # [B]
+            cw = 1.0 - crit_w
+            cc_flat = cw[:, None] * cc_flat_base
+            crit_c = crit_w[:, None, None, None]
 
-        # --- seed + SOURCE-side entries ---
-        d_seed = jnp.where(seed_cells, 0.0, INF)
-        opin_du = jnp.where(opin_used, 0.0, cw[:, None] * opin_congj)
-        e_du = jnp.take_along_axis(opin_du, b_eoidx, axis=1)   # [B, Ko]
-        cc_flat_p1 = jnp.concatenate(
-            [cc_flat, jnp.full((B, 1), INF)], axis=1)
-        e_cc = jnp.take_along_axis(cc_flat_p1,
-                                   jnp.minimum(b_ecell, ncells), axis=1)
-        # invalid/clean slots get all-INF entry seeds: their canvases
-        # then never improve, so they neither extend the batch's
-        # convergence loop nor do any discoverable work (their results
-        # were always discarded at the sel_v scatter below)
-        e_cost = jnp.where(valid[:, None],
-                           e_du + crit_w[:, None] * b_edelay + e_cc, INF)
-        d0 = d_seed.at[arangeB[:, None], b_ecell].min(e_cost, mode="drop")
-        entry_flag = d0 < d_seed                               # [B, Ncells]
-        # winning entry index per cell (ties -> lowest k, deterministic)
-        d0_at_e = jnp.take_along_axis(
-            jnp.concatenate([d0, jnp.full((B, 1), INF)], axis=1),
-            jnp.minimum(b_ecell, ncells), axis=1)
-        e_won = d0_at_e == e_cost
-        wk = jnp.full((B, ncells), Ko, jnp.int32).at[
-            arangeB[:, None], b_ecell].min(
-            jnp.where(e_won, jnp.arange(Ko, dtype=jnp.int32)[None, :], Ko),
-            mode="drop")
-        edelay_p1 = jnp.concatenate(
-            [b_edelay, jnp.zeros((B, 1))], axis=1)
-        wenter0 = jnp.where(
-            entry_flag,
-            jnp.take_along_axis(edelay_p1, jnp.minimum(wk, Ko), axis=1),
-            0.0)
+            # --- seed + SOURCE-side entries ---
+            d_seed = jnp.where(seed_cells, 0.0, INF)
+            opin_du = jnp.where(opin_used, 0.0, cw[:, None] * opin_congj)
+            e_du = jnp.take_along_axis(opin_du, b_eoidx, axis=1)   # [B, Ko]
+            cc_flat_p1 = jnp.concatenate(
+                [cc_flat, jnp.full((B, 1), INF)], axis=1)
+            e_cc = jnp.take_along_axis(cc_flat_p1,
+                                       jnp.minimum(b_ecell, ncells), axis=1)
+            # invalid/clean slots get all-INF entry seeds: their canvases
+            # then never improve, so they neither extend the batch's
+            # convergence loop nor do any discoverable work (their results
+            # were always discarded at the sel_v scatter below)
+            e_cost = jnp.where(valid[:, None],
+                               e_du + crit_w[:, None] * b_edelay + e_cc, INF)
+            d0 = d_seed.at[arangeB[:, None], b_ecell].min(e_cost, mode="drop")
+            entry_flag = d0 < d_seed                          # [B, Ncells]
+            # winning entry index per cell (ties -> lowest k, deterministic)
+            d0_at_e = jnp.take_along_axis(
+                jnp.concatenate([d0, jnp.full((B, 1), INF)], axis=1),
+                jnp.minimum(b_ecell, ncells), axis=1)
+            e_won = d0_at_e == e_cost
+            wk = jnp.full((B, ncells), Ko, jnp.int32).at[
+                arangeB[:, None], b_ecell].min(
+                jnp.where(e_won, jnp.arange(Ko, dtype=jnp.int32)[None, :], Ko),
+                mode="drop")
+            edelay_p1 = jnp.concatenate(
+                [b_edelay, jnp.zeros((B, 1))], axis=1)
+            wenter0 = jnp.where(
+                entry_flag,
+                jnp.take_along_axis(edelay_p1, jnp.minimum(wk, Ko), axis=1),
+                0.0)
 
-        if use_pallas:
-            if crop_tile is not None:
-                from .planes_pallas import planes_relax_cropped_pallas
-                dist, pred, wenter, rst = planes_relax_cropped_pallas(
+        with device_scope("route.dev.relax"):
+            if use_pallas:
+                if crop_tile is not None:
+                    from .planes_pallas import planes_relax_cropped_pallas
+                    dist, pred, wenter, rst = planes_relax_cropped_pallas(
+                        pg, d0, cc_flat, crit_c, wenter0, nsweeps,
+                        crop_ox, crop_oy, cnx_t, cny_t,
+                        block_nets=1 if pallas_g1 else None,
+                        plane_dtype=plane_dtype)
+                else:
+                    from .planes_pallas import planes_relax_pallas
+                    dist, pred, wenter, rst = planes_relax_pallas(
+                        pg, d0, cc_flat, crit_c, wenter0, nsweeps,
+                        block_nets=1 if pallas_g1 else None,
+                        plane_dtype=plane_dtype)
+            elif crop_tile is not None:
+                dist, pred, wenter, rst = planes_relax_cropped(
                     pg, d0, cc_flat, crit_c, wenter0, nsweeps,
                     crop_ox, crop_oy, cnx_t, cny_t,
-                    block_nets=1 if pallas_g1 else None,
                     plane_dtype=plane_dtype)
-            else:
-                from .planes_pallas import planes_relax_pallas
-                dist, pred, wenter, rst = planes_relax_pallas(
+            elif _as_row_mesh(mesh) is not None:
+                from .planes_shard import planes_relax_sharded
+                dist, pred, wenter, rst = planes_relax_sharded(
                     pg, d0, cc_flat, crit_c, wenter0, nsweeps,
-                    block_nets=1 if pallas_g1 else None,
-                    plane_dtype=plane_dtype)
-        elif crop_tile is not None:
-            dist, pred, wenter, rst = planes_relax_cropped(
-                pg, d0, cc_flat, crit_c, wenter0, nsweeps,
-                crop_ox, crop_oy, cnx_t, cny_t,
-                plane_dtype=plane_dtype)
-        elif _as_row_mesh(mesh) is not None:
-            from .planes_shard import planes_relax_sharded
-            dist, pred, wenter, rst = planes_relax_sharded(
-                pg, d0, cc_flat, crit_c, wenter0, nsweeps,
-                _as_row_mesh(mesh), plane_dtype=plane_dtype)
-        else:
-            dist, pred, wenter, rst = planes_relax(pg, d0, cc_flat,
-                                                   crit_c, wenter0,
-                                                   nsweeps, mesh,
-                                                   plane_dtype)
-        st = st + rst
+                    _as_row_mesh(mesh), plane_dtype=plane_dtype)
+            else:
+                dist, pred, wenter, rst = planes_relax(pg, d0, cc_flat,
+                                                       crit_c, wenter0,
+                                                       nsweeps, mesh,
+                                                       plane_dtype)
+            st = st + rst
 
-        # --- sink extraction from the per-net candidate tables ---
-        dist_p1 = jnp.concatenate([dist, jnp.full((B, 1), INF)], axis=1)
-        cand = (jnp.take_along_axis(
-            dist_p1, b_scell.reshape(B, -1), axis=1).reshape(B, S, K)
-            + crit_w[:, None, None] * b_swdel
-            + cw[:, None, None] * ipin_congj)
-        kstar = jnp.argmin(cand, axis=2)                       # [B, S]
-        sink_dist = jnp.take_along_axis(cand, kstar[:, :, None],
-                                        axis=2)[:, :, 0]
-        ent_cell = jnp.take_along_axis(b_scell, kstar[:, :, None],
-                                       axis=2)[:, :, 0]
-        ent_ipin = jnp.take_along_axis(b_sipin, kstar[:, :, None],
-                                       axis=2)[:, :, 0]
-        ent_wdel = jnp.take_along_axis(b_swdel, kstar[:, :, None],
-                                       axis=2)[:, :, 0]
+        with device_scope("route.dev.sink_pick"):
+            # --- sink extraction from the per-net candidate tables ---
+            dist_p1 = jnp.concatenate([dist, jnp.full((B, 1), INF)], axis=1)
+            cand = (jnp.take_along_axis(
+                dist_p1, b_scell.reshape(B, -1), axis=1).reshape(B, S, K)
+                + crit_w[:, None, None] * b_swdel
+                + cw[:, None, None] * ipin_congj)
+            kstar = jnp.argmin(cand, axis=2)                       # [B, S]
+            sink_dist = jnp.take_along_axis(cand, kstar[:, :, None],
+                                            axis=2)[:, :, 0]
+            ent_cell = jnp.take_along_axis(b_scell, kstar[:, :, None],
+                                           axis=2)[:, :, 0]
+            ent_ipin = jnp.take_along_axis(b_sipin, kstar[:, :, None],
+                                           axis=2)[:, :, 0]
+            ent_wdel = jnp.take_along_axis(b_swdel, kstar[:, :, None],
+                                           axis=2)[:, :, 0]
 
-        # --- dedicated direct candidate (OPIN->IPIN->SINK, bypassing
-        # the fabric): competes with the relaxation candidates; the
-        # fabric wins exact ties (strict <) for determinism ---
-        has_d = b_doidx >= 0
-        ddu = jnp.take_along_axis(
-            opin_du, jnp.clip(b_doidx, 0, O - 1), axis=1)      # [B, S]
-        dip_cong = jnp.take_along_axis(congj_p1, b_dipin, axis=1)
-        dcost = jnp.where(has_d,
-                          ddu + crit_w[:, None] * b_ddel
-                          + cw[:, None] * dip_cong, INF)
-        use_direct = dcost < sink_dist
-        sink_dist = jnp.minimum(sink_dist, dcost)
+            # --- dedicated direct candidate (OPIN->IPIN->SINK, bypassing
+            # the fabric): competes with the relaxation candidates; the
+            # fabric wins exact ties (strict <) for determinism ---
+            has_d = b_doidx >= 0
+            ddu = jnp.take_along_axis(
+                opin_du, jnp.clip(b_doidx, 0, O - 1), axis=1)      # [B, S]
+            dip_cong = jnp.take_along_axis(congj_p1, b_dipin, axis=1)
+            dcost = jnp.where(has_d,
+                              ddu + crit_w[:, None] * b_ddel
+                              + cw[:, None] * dip_cong, INF)
+            use_direct = dcost < sink_dist
+            sink_dist = jnp.minimum(sink_dist, dcost)
 
-        # --- pick up to `group` sinks: most critical, then nearest ---
-        score = jnp.where(remaining & jnp.isfinite(sink_dist),
-                          sink_dist - b_crit * 1e3, INF)
-        order = jnp.argsort(score, axis=1)[:, :group]          # [B, G]
-        pick_valid = (jnp.take_along_axis(remaining, order, axis=1)
-                      & jnp.isfinite(jnp.take_along_axis(score, order,
-                                                         axis=1)))
-        if doubling:
-            # doubling schedule: wave k routes <= 2^k sinks, so a trunk
-            # forms before the bulk fan-out (the all-at-once variant
-            # costs ~20% wirelength, measured; this costs ~3%)
-            limit = jnp.int32(1) << jnp.minimum(wave, 30)
-            pick_valid = pick_valid & (jnp.arange(group)[None, :] < limit)
-        G = group
-        pick_sink = jnp.where(
-            pick_valid, jnp.take_along_axis(b_sinks, order, axis=1), -1)
-        pick_ipin = jnp.take_along_axis(ent_ipin, order, axis=1)
-        pick_cell = jnp.where(
-            pick_valid, jnp.take_along_axis(ent_cell, order, axis=1), 0)
-        pick_wdel = jnp.take_along_axis(ent_wdel, order, axis=1)
-        # direct-connection picks: no canvas walk, 4-node path
-        pick_direct = (jnp.take_along_axis(use_direct, order, axis=1)
-                       & pick_valid)
-        pick_dipin = jnp.take_along_axis(b_dipin, order, axis=1)
-        pick_doidx = jnp.take_along_axis(jnp.clip(b_doidx, 0, O - 1),
-                                         order, axis=1)
-        pick_ddel = jnp.take_along_axis(b_ddel, order, axis=1)
-        pick_ipin = jnp.where(pick_direct, pick_dipin, pick_ipin)
-        pick_cell = jnp.where(pick_direct, 0, pick_cell)
+            # --- pick up to `group` sinks: most critical, then nearest ---
+            score = jnp.where(remaining & jnp.isfinite(sink_dist),
+                              sink_dist - b_crit * 1e3, INF)
+            order = jnp.argsort(score, axis=1)[:, :group]          # [B, G]
+            pick_valid = (jnp.take_along_axis(remaining, order, axis=1)
+                          & jnp.isfinite(jnp.take_along_axis(score, order,
+                                                             axis=1)))
+            if doubling:
+                # doubling schedule: wave k routes <= 2^k sinks, so a trunk
+                # forms before the bulk fan-out (the all-at-once variant
+                # costs ~20% wirelength, measured; this costs ~3%)
+                limit = jnp.int32(1) << jnp.minimum(wave, 30)
+                pick_valid = pick_valid & (jnp.arange(group)[None, :] < limit)
+            G = group
+            pick_sink = jnp.where(
+                pick_valid, jnp.take_along_axis(b_sinks, order, axis=1), -1)
+            pick_ipin = jnp.take_along_axis(ent_ipin, order, axis=1)
+            pick_cell = jnp.where(
+                pick_valid, jnp.take_along_axis(ent_cell, order, axis=1), 0)
+            pick_wdel = jnp.take_along_axis(ent_wdel, order, axis=1)
+            # direct-connection picks: no canvas walk, 4-node path
+            pick_direct = (jnp.take_along_axis(use_direct, order, axis=1)
+                           & pick_valid)
+            pick_dipin = jnp.take_along_axis(b_dipin, order, axis=1)
+            pick_doidx = jnp.take_along_axis(jnp.clip(b_doidx, 0, O - 1),
+                                             order, axis=1)
+            pick_ddel = jnp.take_along_axis(b_ddel, order, axis=1)
+            pick_ipin = jnp.where(pick_direct, pick_dipin, pick_ipin)
+            pick_cell = jnp.where(pick_direct, 0, pick_cell)
 
-        # --- pointer-chase traceback in cell space ---
-        ar_b = arangeB[:, None]
-        ar_g = jnp.arange(G)[None, :]
-        noc_p1 = jnp.append(pg.node_of_cell, N)
+        with device_scope("route.dev.traceback"):
+            # --- pointer-chase traceback in cell space ---
+            ar_b = arangeB[:, None]
+            ar_g = jnp.arange(G)[None, :]
+            noc_p1 = jnp.append(pg.node_of_cell, N)
 
-        def walk_step(pos, ws):
-            cur, done, cells_w, nodes_w, wst = ws
-            nd = jnp.take(noc_p1, cur)                 # [B, G]
-            cells_w = cells_w.at[ar_b, ar_g, pos].set(
-                jnp.where(done, ncells, cur))
-            nodes_w = nodes_w.at[ar_b, ar_g, pos].set(
-                jnp.where(done, N, nd))
-            w = jnp.take_along_axis(
-                wenter, jnp.clip(cur, 0, ncells - 1), axis=1)
-            wst = wst.at[ar_b, ar_g, pos].set(jnp.where(done, 0.0, w))
-            nxt = jnp.take_along_axis(
+            def walk_step(pos, ws):
+                cur, done, cells_w, nodes_w, wst = ws
+                nd = jnp.take(noc_p1, cur)                 # [B, G]
+                cells_w = cells_w.at[ar_b, ar_g, pos].set(
+                    jnp.where(done, ncells, cur))
+                nodes_w = nodes_w.at[ar_b, ar_g, pos].set(
+                    jnp.where(done, N, nd))
+                w = jnp.take_along_axis(
+                    wenter, jnp.clip(cur, 0, ncells - 1), axis=1)
+                wst = wst.at[ar_b, ar_g, pos].set(jnp.where(done, 0.0, w))
+                nxt = jnp.take_along_axis(
+                    pred, jnp.clip(cur, 0, ncells - 1), axis=1)
+                stop = done | (nxt == cur)
+                return jnp.where(stop, cur, nxt), stop, cells_w, nodes_w, wst
+
+            cells_w0 = jnp.full((B, G, Kw), ncells, jnp.int32)
+            nodes_w0 = jnp.full((B, G, Kw), N, jnp.int32)
+            wst0 = jnp.zeros((B, G, Kw), jnp.float32)
+            cur, done, cells_w, nodes_w, wst = lax.fori_loop(
+                0, Kw, walk_step,
+                (pick_cell, ~pick_valid | pick_direct, cells_w0, nodes_w0,
+                 wst0))
+            # a walk is complete iff it reached a pred==self cell in budget
+            nxt_last = jnp.take_along_axis(
                 pred, jnp.clip(cur, 0, ncells - 1), axis=1)
-            stop = done | (nxt == cur)
-            return jnp.where(stop, cur, nxt), stop, cells_w, nodes_w, wst
+            okw = pick_valid & (nxt_last == cur)
+            # direct picks skip the walk entirely
+            ok = jnp.where(pick_direct, pick_valid, okw)          # [B, G]
 
-        cells_w0 = jnp.full((B, G, Kw), ncells, jnp.int32)
-        nodes_w0 = jnp.full((B, G, Kw), N, jnp.int32)
-        wst0 = jnp.zeros((B, G, Kw), jnp.float32)
-        cur, done, cells_w, nodes_w, wst = lax.fori_loop(
-            0, Kw, walk_step,
-            (pick_cell, ~pick_valid | pick_direct, cells_w0, nodes_w0,
-             wst0))
-        # a walk is complete iff it reached a pred==self cell in budget
-        nxt_last = jnp.take_along_axis(
-            pred, jnp.clip(cur, 0, ncells - 1), axis=1)
-        okw = pick_valid & (nxt_last == cur)
-        # direct picks skip the walk entirely
-        ok = jnp.where(pick_direct, pick_valid, okw)          # [B, G]
+            join = jnp.clip(cur, 0, ncells - 1)
+            at_entry = (jnp.take_along_axis(entry_flag, join, axis=1) & ok
+                        & ~pick_direct)
+            tdel_base = jnp.where(
+                at_entry, 0.0,
+                jnp.take_along_axis(tdel_cells, join, axis=1))     # [B, G]
+            wsum = jnp.flip(jnp.cumsum(jnp.flip(wst, 2), axis=2), 2)
+            d_new = jnp.where(pick_direct, pick_ddel,
+                              tdel_base + wsum[:, :, 0] + pick_wdel)
 
-        join = jnp.clip(cur, 0, ncells - 1)
-        at_entry = (jnp.take_along_axis(entry_flag, join, axis=1) & ok
-                    & ~pick_direct)
-        tdel_base = jnp.where(
-            at_entry, 0.0,
-            jnp.take_along_axis(tdel_cells, join, axis=1))     # [B, G]
-        wsum = jnp.flip(jnp.cumsum(jnp.flip(wst, 2), axis=2), 2)
-        d_new = jnp.where(pick_direct, pick_ddel,
-                          tdel_base + wsum[:, :, 0] + pick_wdel)
+            # entry suffix: which OPIN fed the winning entry cell
+            wk_join = jnp.take_along_axis(wk, join, axis=1)        # [B, G]
+            eoidx_p1 = jnp.concatenate(
+                [b_eoidx, jnp.zeros((B, 1), jnp.int32)], axis=1)
+            oidx_join = jnp.take_along_axis(eoidx_p1,
+                                            jnp.minimum(wk_join, Ko), axis=1)
+            opin_join = jnp.take_along_axis(b_opin, oidx_join, axis=1)
 
-        # entry suffix: which OPIN fed the winning entry cell
-        wk_join = jnp.take_along_axis(wk, join, axis=1)        # [B, G]
-        eoidx_p1 = jnp.concatenate(
-            [b_eoidx, jnp.zeros((B, 1), jnp.int32)], axis=1)
-        oidx_join = jnp.take_along_axis(eoidx_p1,
-                                        jnp.minimum(wk_join, Ko), axis=1)
-        opin_join = jnp.take_along_axis(b_opin, oidx_join, axis=1)
+            # --- assemble path rows:
+            # [sink, ipin, nodes..., (opin, source)] ---
+            dup = jnp.concatenate(
+                [jnp.zeros((B, G, 1), bool),
+                 nodes_w[:, :, 1:] == nodes_w[:, :, :-1]], axis=2)
+            keep = ~dup & (nodes_w < N) & (ok & ~pick_direct)[:, :, None]
+            posn = jnp.cumsum(keep, axis=2) - 1
+            seg = jnp.full((B, G, max_len), N, jnp.int32)
+            seg = seg.at[:, :, 0].set(jnp.where(ok, pick_sink, N))
+            seg = seg.at[:, :, 1].set(jnp.where(ok, pick_ipin, N))
+            seg = seg.at[ar_b[:, :, None], ar_g[:, :, None],
+                         jnp.where(keep, posn + 2, max_len)].set(
+                nodes_w, mode="drop")
+            nkeep = jnp.sum(keep, axis=2)                          # [B, G]
+            put_e = at_entry & ok
+            seg = seg.at[ar_b, ar_g,
+                         jnp.where(put_e, nkeep + 2, max_len)].set(
+                opin_join, mode="drop")
+            seg = seg.at[ar_b, ar_g,
+                         jnp.where(put_e, nkeep + 3, max_len)].set(
+                jnp.broadcast_to(b_src[:, None], (B, G)), mode="drop")
+            # direct picks: 4-node path [sink, ipin, opin, source]
+            pdm = pick_direct & ok
+            d_opin = jnp.take_along_axis(b_opin, pick_doidx, axis=1)
+            seg = seg.at[ar_b, ar_g,
+                         jnp.where(pdm, 2, max_len)].set(d_opin, mode="drop")
+            seg = seg.at[ar_b, ar_g,
+                         jnp.where(pdm, 3, max_len)].set(
+                jnp.broadcast_to(b_src[:, None], (B, G)), mode="drop")
 
-        # --- assemble path rows: [sink, ipin, nodes..., (opin, source)] ---
-        dup = jnp.concatenate(
-            [jnp.zeros((B, G, 1), bool),
-             nodes_w[:, :, 1:] == nodes_w[:, :, :-1]], axis=2)
-        keep = ~dup & (nodes_w < N) & (ok & ~pick_direct)[:, :, None]
-        posn = jnp.cumsum(keep, axis=2) - 1
-        seg = jnp.full((B, G, max_len), N, jnp.int32)
-        seg = seg.at[:, :, 0].set(jnp.where(ok, pick_sink, N))
-        seg = seg.at[:, :, 1].set(jnp.where(ok, pick_ipin, N))
-        seg = seg.at[ar_b[:, :, None], ar_g[:, :, None],
-                     jnp.where(keep, posn + 2, max_len)].set(
-            nodes_w, mode="drop")
-        nkeep = jnp.sum(keep, axis=2)                          # [B, G]
-        put_e = at_entry & ok
-        seg = seg.at[ar_b, ar_g,
-                     jnp.where(put_e, nkeep + 2, max_len)].set(
-            opin_join, mode="drop")
-        seg = seg.at[ar_b, ar_g,
-                     jnp.where(put_e, nkeep + 3, max_len)].set(
-            jnp.broadcast_to(b_src[:, None], (B, G)), mode="drop")
-        # direct picks: 4-node path [sink, ipin, opin, source]
-        pdm = pick_direct & ok
-        d_opin = jnp.take_along_axis(b_opin, pick_doidx, axis=1)
-        seg = seg.at[ar_b, ar_g,
-                     jnp.where(pdm, 2, max_len)].set(d_opin, mode="drop")
-        seg = seg.at[ar_b, ar_g,
-                     jnp.where(pdm, 3, max_len)].set(
-            jnp.broadcast_to(b_src[:, None], (B, G)), mode="drop")
+            # --- store results at the picked sink slots ---
+            old = jnp.take_along_axis(wpaths, order[:, :, None], axis=1)
+            wpaths = wpaths.at[ar_b, order].set(
+                jnp.where(ok[:, :, None], seg, old))
+            old_d = jnp.take_along_axis(delay, order, axis=1)
+            delay = delay.at[ar_b, order].set(jnp.where(ok, d_new, old_d))
+            old_r = jnp.take_along_axis(reached_all, order, axis=1)
+            reached_all = reached_all.at[ar_b, order].set(ok | old_r)
+            old_rem = jnp.take_along_axis(remaining, order, axis=1)
+            remaining = remaining.at[ar_b, order].set(old_rem & ~ok)
 
-        # --- store results at the picked sink slots ---
-        old = jnp.take_along_axis(wpaths, order[:, :, None], axis=1)
-        wpaths = wpaths.at[ar_b, order].set(
-            jnp.where(ok[:, :, None], seg, old))
-        old_d = jnp.take_along_axis(delay, order, axis=1)
-        delay = delay.at[ar_b, order].set(jnp.where(ok, d_new, old_d))
-        old_r = jnp.take_along_axis(reached_all, order, axis=1)
-        reached_all = reached_all.at[ar_b, order].set(ok | old_r)
-        old_rem = jnp.take_along_axis(remaining, order, axis=1)
-        remaining = remaining.at[ar_b, order].set(old_rem & ~ok)
-
-        # --- grow the tree (cell space), deterministically via min ---
-        walk_cells = jnp.where((ok & ~pick_direct)[:, :, None], cells_w,
-                               ncells).reshape(B, -1)
-        walk_tdel = (tdel_base[:, :, None] + wsum).reshape(B, -1)
-        buf = jnp.full((B, ncells + 1), INF, jnp.float32)
-        buf = buf.at[arangeB[:, None], walk_cells].min(walk_tdel)
-        newly = jnp.isfinite(buf[:, :ncells])
-        tdel_cells = jnp.where(newly, buf[:, :ncells], tdel_cells)
-        seed_cells = seed_cells | newly
-        opin_used = opin_used.at[arangeB[:, None],
-                                 jnp.where(put_e, oidx_join, O)].set(
-            True, mode="drop") | opin_used
-        opin_used = opin_used.at[arangeB[:, None],
-                                 jnp.where(pdm, pick_doidx, O)].set(
-            True, mode="drop") | opin_used
+        with device_scope("route.dev.tree_grow"):
+            # --- grow the tree (cell space), deterministically via min ---
+            walk_cells = jnp.where((ok & ~pick_direct)[:, :, None], cells_w,
+                                   ncells).reshape(B, -1)
+            walk_tdel = (tdel_base[:, :, None] + wsum).reshape(B, -1)
+            buf = jnp.full((B, ncells + 1), INF, jnp.float32)
+            buf = buf.at[arangeB[:, None], walk_cells].min(walk_tdel)
+            newly = jnp.isfinite(buf[:, :ncells])
+            tdel_cells = jnp.where(newly, buf[:, :ncells], tdel_cells)
+            seed_cells = seed_cells | newly
+            opin_used = opin_used.at[arangeB[:, None],
+                                     jnp.where(put_e, oidx_join, O)].set(
+                True, mode="drop") | opin_used
+            opin_used = opin_used.at[arangeB[:, None],
+                                     jnp.where(pdm, pick_doidx, O)].set(
+                True, mode="drop") | opin_used
         return (seed_cells, tdel_cells, opin_used, remaining, wpaths,
                 delay, reached_all, st)
 
@@ -1508,44 +1526,48 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
         # identity passes — skip their relaxations entirely (exact: a
         # wave with no remaining sinks picks nothing and commits
         # nothing, verified against the unconditional body)
-        return lax.cond(state[3].any(),
+        with device_scope("route.dev.sink_pick"):
+            pending = state[3].any()
+        return lax.cond(pending,
                         lambda s: wave_run(wave, s), lambda s: s, state)
 
-    state0 = (seed0, jnp.zeros((B, ncells), jnp.float32),
-              jnp.zeros((B, O), bool),
-              (b_sinks >= 0) & valid[:, None],
-              jnp.full((B, S, max_len), N, jnp.int32),
-              jnp.full((B, S), INF, jnp.float32),
-              jnp.zeros((B, S), bool),
-              jnp.zeros((2,), jnp.int32))
+    with device_scope("route.dev.cost_fields"):
+        state0 = (seed0, jnp.zeros((B, ncells), jnp.float32),
+                  jnp.zeros((B, O), bool),
+                  (b_sinks >= 0) & valid[:, None],
+                  jnp.full((B, S, max_len), N, jnp.int32),
+                  jnp.full((B, S), INF, jnp.float32),
+                  jnp.zeros((B, S), bool),
+                  jnp.zeros((2,), jnp.int32))
     (_, _, _, _, p, delay, reached, st) = lax.fori_loop(
         0, num_waves, wave_body, state0)
 
-    usage = usage_from_paths(p, nodes_p1) & valid[:, None]
-    occ_new = occ_rip + jnp.sum(usage, axis=0, dtype=jnp.int32)
+    with device_scope("route.dev.commit"):
+        usage = usage_from_paths(p, nodes_p1) & valid[:, None]
+        occ_new = occ_rip + jnp.sum(usage, axis=0, dtype=jnp.int32)
 
-    smask = b_sinks >= 0
-    ok = (reached | ~smask).all(axis=1)
-    # unreached-sink widening retry — gated per net by widen_ok: a net
-    # routed under a REDUCED sweep budget (RouterOpts.sweep_budget_div)
-    # must not take a full-device bb for what may only be an
-    # under-budgeted relaxation; the host promotes it to the full
-    # budget first (the unreached summary output) and only a
-    # full-budget failure widens
-    if widen_ok is None:
-        may_widen = jnp.ones((B,), bool)
-    else:
-        may_widen = widen_ok[sel]
-    new_bb = jnp.where((ok | ~may_widen)[:, None], b_bb,
-                       full_bb[None, :])
+        smask = b_sinks >= 0
+        ok = (reached | ~smask).all(axis=1)
+        # unreached-sink widening retry — gated per net by widen_ok: a net
+        # routed under a REDUCED sweep budget (RouterOpts.sweep_budget_div)
+        # must not take a full-device bb for what may only be an
+        # under-budgeted relaxation; the host promotes it to the full
+        # budget first (the unreached summary output) and only a
+        # full-budget failure widens
+        if widen_ok is None:
+            may_widen = jnp.ones((B,), bool)
+        else:
+            may_widen = widen_ok[sel]
+        new_bb = jnp.where((ok | ~may_widen)[:, None], b_bb,
+                           full_bb[None, :])
 
-    sel_v = jnp.where(valid, sel, R).astype(jnp.int32)
-    paths = paths.at[sel_v].set(p, mode="drop")
-    sink_delay = sink_delay.at[sel_v].set(delay, mode="drop")
-    all_reached = all_reached.at[sel_v].set(ok, mode="drop")
-    bb = bb.at[sel_v].set(new_bb, mode="drop")
-    return (paths, sink_delay, all_reached, bb, occ_new,
-            valid.sum(dtype=jnp.int32), st[0], st[1])
+        sel_v = jnp.where(valid, sel, R).astype(jnp.int32)
+        paths = paths.at[sel_v].set(p, mode="drop")
+        sink_delay = sink_delay.at[sel_v].set(delay, mode="drop")
+        all_reached = all_reached.at[sel_v].set(ok, mode="drop")
+        bb = bb.at[sel_v].set(new_bb, mode="drop")
+        return (paths, sink_delay, all_reached, bb, occ_new,
+                valid.sum(dtype=jnp.int32), st[0], st[1])
 
 
 @functools.partial(
@@ -1586,6 +1608,7 @@ def route_batch_resident_planes(
     return (paths, sink_delay, all_reached, bb, occ, st_exec)
 
 
+@device_scope("route.dev.mis_colors")
 def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
                 topk: int, n_colors: int):
     """Device-side conflict scheduling: greedy parallel MIS coloring of
@@ -1694,12 +1717,15 @@ def _window_body(
     def it_body(it, st):
         (occ, acc, paths, sink_delay, all_reached, bb, pres, nroutes,
          nexec, crit_all, dmax_hist, s_exec, s_useful) = st
-        force = (it0 + it) < force_until
+        with device_scope("route.dev.ripup"):
+            force = (it0 + it) < force_until
 
         def g_step(g, st2):
             def run(st3):
                 (occ2, paths2, sink_delay2, all_reached2, bb2, nr, ng,
                  se, su) = st3
+                with device_scope("route.dev.ripup"):
+                    sel_g, valid_g = sel_plan[g], valid_plan[g]
                 (paths2, sink_delay2, all_reached2, bb2, occ2,
                  n_act, st_exec, st_useful) = _step_core(
                     pg, dev, occ2, acc, pres,
@@ -1709,23 +1735,26 @@ def _window_body(
                     entry_delay_all,
                     sink_uid_all, uid_cell, uid_ipin, uid_delay,
                     direct_oidx_all, direct_ipin_all, direct_delay_all,
-                    sel_plan[g], valid_plan[g], force, full_bb,
+                    sel_g, valid_g, force, full_bb,
                     nsweeps, max_len, num_waves, group, doubling, mesh,
                     use_pallas, crop_tile, bb0_all, widen_ok, pallas_g1,
                     plane_dtype)
-                return (occ2, paths2, sink_delay2, all_reached2, bb2,
-                        nr + n_act, ng + 1, se + st_exec, su + st_useful)
+                with device_scope("route.dev.commit"):
+                    return (occ2, paths2, sink_delay2, all_reached2, bb2,
+                            nr + n_act, ng + 1, se + st_exec,
+                            su + st_useful)
 
             # skip pow2-padding groups and fully-clean groups outright
             # (the group plan is padded to a power of two to bound the
             # compiled-program count; without the cond every pad group
             # would still pay the full relax).  ng counts the groups that
             # actually executed, so relax-step stats reflect real work
-            over_g = jnp.append(st2[0] > dev.capacity, False)
-            sel_g = sel_plan[g]
-            any_dirty = (valid_plan[g]
-                         & (over_g[st2[1][sel_g]].any(axis=(1, 2))
-                            | ~st2[3][sel_g] | force)).any()
+            with device_scope("route.dev.ripup"):
+                over_g = jnp.append(st2[0] > dev.capacity, False)
+                sel_g = sel_plan[g]
+                any_dirty = (valid_plan[g]
+                             & (over_g[st2[1][sel_g]].any(axis=(1, 2))
+                                | ~st2[3][sel_g] | force)).any()
             return lax.cond(any_dirty, run, lambda s: s, st2)
 
         (occ, paths, sink_delay, all_reached, bb, nroutes,
@@ -1733,20 +1762,22 @@ def _window_body(
             0, G, g_step,
             (occ, paths, sink_delay, all_reached, bb, nroutes, nexec,
              s_exec, s_useful))
-        # PathFinder history/present escalation once per iteration
-        acc = acc + acc_fac * jnp.maximum(
-            occ - dev.capacity, 0).astype(jnp.float32)
-        pres = jnp.minimum(max_pres, pres * pres_mult)
+        with device_scope("route.dev.history"):
+            # PathFinder history/present escalation once per iteration
+            acc = acc + acc_fac * jnp.maximum(
+                occ - dev.capacity, 0).astype(jnp.float32)
+            pres = jnp.minimum(max_pres, pres * pres_mult)
         if tdev is not None:
             # device-resident analyze_timing + update_sink_criticalities
             from ..timing.sta import sta_crit
-            flat = jnp.append(
-                sink_delay.reshape(-1), jnp.float32(0.0))
-            crit_flat, dmax, _, _ = sta_crit(
-                tdev, flat, sta_depth, crit_exp, max_crit,
-                req_seed=req_seed, use_sdc=use_sdc)
-            crit_all = crit_flat.reshape(R, Smax)
-            dmax_hist = dmax_hist.at[it].set(dmax)
+            with device_scope("route.dev.sta"):
+                flat = jnp.append(
+                    sink_delay.reshape(-1), jnp.float32(0.0))
+                crit_flat, dmax, _, _ = sta_crit(
+                    tdev, flat, sta_depth, crit_exp, max_crit,
+                    req_seed=req_seed, use_sdc=use_sdc)
+                crit_all = crit_flat.reshape(R, Smax)
+                dmax_hist = dmax_hist.at[it].set(dmax)
         return (occ, acc, paths, sink_delay, all_reached, bb, pres,
                 nroutes, nexec, crit_all, dmax_hist, s_exec, s_useful)
 
@@ -1760,50 +1791,51 @@ def _window_body(
 
     rrm, colors = _mis_colors(dev, occ, paths, all_reached,
                               topk, n_colors)
-    over = jnp.maximum(occ - dev.capacity, 0)
-    # max bb half-perimeter of a still-dirty net: the host compares it
-    # against the current path-slot budget and regrows the (bb-adaptive)
-    # paths array when a device-side widening outgrew it
-    span = (bb[:, 1] - bb[:, 0]) + (bb[:, 3] - bb[:, 2])
-    max_span = jnp.max(jnp.where(rrm, span, 0))
-    # nets whose live bb widened to device scale (unreached-sink
-    # widening inside _step_core): the host folds this into its `wide`
-    # classification so they take the full-canvas window next time
-    NXg = pg.shape_x[1]
-    NYg = pg.shape_y[2]
-    dev_wide = span >= (NXg + NYg)
-    # measured per-net live bb sizes, packed ((ceil(w/8) << 8) |
-    # ceil(h/8), uint16 — 2 bytes/net of device->host traffic): the
-    # host re-partitions the next window's narrow/wide split, crop tile
-    # and sweep budget from MEASURED state, the analogue of the
-    # reference's measured-cost re-partition between iterations
-    # (mpi_route_load_balanced_nonblocking_send_recv_encoded.cxx:909-916)
-    wb = jnp.clip(-(-(bb[:, 1] - bb[:, 0] + 1) // 8), 0, 255)
-    hb = jnp.clip(-(-(bb[:, 3] - bb[:, 2] + 1) // 8), 0, 255)
-    live_wh = ((wb << 8) | hb).astype(jnp.uint16)
-    # per-net unreached flag: the host's sweep-budget promotion signal
-    # (reduced-budget nets that missed a sink retry at full budget
-    # before any widening)
-    unreached = ~all_reached
-    # packed per-net status word + scalar summary vector: EVERYTHING the
-    # host control loop needs from a window, as two tiny int32 arrays a
-    # single copy_to_host_async can stream while the host keeps working
-    # (the async-pipeline replacement for the 13-array blocking
-    # jax.device_get).  Layout (unpack_window_status is the only
-    # reader): bit0 rrm, bit1 dev_wide, bit2 unreached, bits3-7 color,
-    # bits8-15 live-h bucket, bits16-23 live-w bucket (same 8-tile
-    # buckets as live_wh above).
-    status = (rrm.astype(jnp.int32)
-              | (dev_wide.astype(jnp.int32) << 1)
-              | (unreached.astype(jnp.int32) << 2)
-              | ((colors.astype(jnp.int32) & 0x1F) << 3)
-              | (hb.astype(jnp.int32) << 8)
-              | (wb.astype(jnp.int32) << 16))
-    n_over_s = (over > 0).sum(dtype=jnp.int32)
-    over_tot_s = over.sum(dtype=jnp.int32)
-    scal = jnp.stack([n_over_s, over_tot_s, nroutes, nexec,
-                      max_span.astype(jnp.int32),
-                      s_exec, s_useful]).astype(jnp.int32)
+    with device_scope("route.dev.window_summary"):
+        over = jnp.maximum(occ - dev.capacity, 0)
+        # max bb half-perimeter of a still-dirty net: the host compares it
+        # against the current path-slot budget and regrows the (bb-adaptive)
+        # paths array when a device-side widening outgrew it
+        span = (bb[:, 1] - bb[:, 0]) + (bb[:, 3] - bb[:, 2])
+        max_span = jnp.max(jnp.where(rrm, span, 0))
+        # nets whose live bb widened to device scale (unreached-sink
+        # widening inside _step_core): the host folds this into its `wide`
+        # classification so they take the full-canvas window next time
+        NXg = pg.shape_x[1]
+        NYg = pg.shape_y[2]
+        dev_wide = span >= (NXg + NYg)
+        # measured per-net live bb sizes, packed ((ceil(w/8) << 8) |
+        # ceil(h/8), uint16 — 2 bytes/net of device->host traffic): the
+        # host re-partitions the next window's narrow/wide split, crop tile
+        # and sweep budget from MEASURED state, the analogue of the
+        # reference's measured-cost re-partition between iterations
+        # (mpi_route_load_balanced_nonblocking_send_recv_encoded.cxx:909-916)
+        wb = jnp.clip(-(-(bb[:, 1] - bb[:, 0] + 1) // 8), 0, 255)
+        hb = jnp.clip(-(-(bb[:, 3] - bb[:, 2] + 1) // 8), 0, 255)
+        live_wh = ((wb << 8) | hb).astype(jnp.uint16)
+        # per-net unreached flag: the host's sweep-budget promotion signal
+        # (reduced-budget nets that missed a sink retry at full budget
+        # before any widening)
+        unreached = ~all_reached
+        # packed per-net status word + scalar summary vector: EVERYTHING the
+        # host control loop needs from a window, as two tiny int32 arrays a
+        # single copy_to_host_async can stream while the host keeps working
+        # (the async-pipeline replacement for the 13-array blocking
+        # jax.device_get).  Layout (unpack_window_status is the only
+        # reader): bit0 rrm, bit1 dev_wide, bit2 unreached, bits3-7 color,
+        # bits8-15 live-h bucket, bits16-23 live-w bucket (same 8-tile
+        # buckets as live_wh above).
+        status = (rrm.astype(jnp.int32)
+                  | (dev_wide.astype(jnp.int32) << 1)
+                  | (unreached.astype(jnp.int32) << 2)
+                  | ((colors.astype(jnp.int32) & 0x1F) << 3)
+                  | (hb.astype(jnp.int32) << 8)
+                  | (wb.astype(jnp.int32) << 16))
+        n_over_s = (over > 0).sum(dtype=jnp.int32)
+        over_tot_s = over.sum(dtype=jnp.int32)
+        scal = jnp.stack([n_over_s, over_tot_s, nroutes, nexec,
+                          max_span.astype(jnp.int32),
+                          s_exec, s_useful]).astype(jnp.int32)
     return (occ, acc, paths, sink_delay, all_reached, bb, pres, rrm,
             colors, n_over_s, over_tot_s, nroutes, nexec, crit_all,
             dmax_hist, max_span, dev_wide, live_wh, unreached,
@@ -1905,7 +1937,9 @@ def _fused_ladder(
         (occ, acc, paths, sink_delay, all_reached, bb) = out[:6]
         crit_all = out[13]
         scals.append(out[22])
-    return out + (jnp.stack(scals),)
+    with device_scope("route.dev.window_summary"):
+        ladder_scals = jnp.stack(scals)
+    return out + (ladder_scals,)
 
 
 @functools.partial(

@@ -26,6 +26,8 @@ analogue of --num_threads.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import math
 import os
 import time
@@ -36,7 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs import get_devprof, get_metrics, get_tracer
+from ..obs import get_devprof, get_metrics, get_tracer, span
+from ..obs.trace import compile_phases, enable_compile_capture
 from ..rr.graph import RRGraph
 from ..rr.terminals import NetTerminals
 from .device_graph import DeviceRRGraph, to_device
@@ -588,6 +591,57 @@ def _note_dispatch_variant(key) -> bool:
     return True
 
 
+# the per-route pipeline gauges, zeroed when a route starts (the serve
+# loop routes many times in one process: a job that never reaches a
+# gauge must not inherit the previous job's value)
+_PIPELINE_GAUGES = (
+    "route.pipeline.host_plan_ms",
+    "route.pipeline.dispatch_ms",
+    "route.pipeline.device_exec_ms",
+    "route.pipeline.stall_ms",
+    "route.pipeline.overlap_frac",
+    "route.pipeline.host_overlap_frac",
+    "route.pipeline.host_plan_ms_total",
+    "route.pipeline.dispatch_ms_total",
+    "route.pipeline.device_exec_ms_total",
+    "route.pipeline.stall_ms_total",
+    "route.pipeline.host_serial_ms_total",
+)
+
+# routes of this process, numbered: the id the route's spans share
+_ROUTE_IDS = itertools.count(1)
+
+
+@contextlib.contextmanager
+def dispatching(**args):
+    """The hand-off of a window's program(s) to the runtime, as a
+    ``route.pipeline.dispatch`` span, timed where the work happens: its
+    host milliseconds go to ``route.pipeline.dispatch_ms_total`` and,
+    when a dispatch variant was new inside it (tracing, lowering,
+    compile or cache read, executable load), to the never-reset counter
+    ``route.dispatch.first_call_ms_total``; such a span carries
+    ``first=True`` and jax.monitoring's phase seconds."""
+    enable_compile_capture()
+    reg = get_metrics()
+    compiles = reg.counter("route.dispatch.compiles")
+    first_ms = reg.counter("route.dispatch.first_call_ms_total")
+    n0, ph0 = compiles.value, compile_phases()
+    t0 = time.perf_counter()
+    with span("route.pipeline.dispatch", cat="route", **args) as sp:
+        try:
+            yield
+        finally:
+            ms = (time.perf_counter() - t0) * 1e3
+            if compiles.value > n0:
+                first_ms.inc(ms)
+                sp.set(first=True, **{
+                    k + "_s": round(v - ph0.get(k, 0.0), 6)
+                    for k, v in compile_phases().items()
+                    if v > ph0.get(k, 0.0)})
+            total = reg.gauge("route.pipeline.dispatch_ms_total")
+            total.set((total.value or 0.0) + ms)
+
+
 class WindowDispatchRequest:
     """One planned fused-window dispatch, externalized by the
     generator-mode driver (Router.route_gen): the canonical variant
@@ -600,16 +654,19 @@ class WindowDispatchRequest:
     program per lockstep step; the solo driver executes them one at a
     time — either way the 24-tuple result is sent back into the
     yielding generator unchanged, so per-job results are bit-identical
-    by construction."""
+    by construction.  ``span_args`` (window index, route id) go on the
+    dispatch span whoever issues it."""
     __slots__ = ("vkey", "f_args", "f_kwargs", "per_rung_fb",
-                 "resil_rt")
+                 "resil_rt", "span_args")
 
-    def __init__(self, vkey, f_args, f_kwargs, per_rung_fb, resil_rt):
+    def __init__(self, vkey, f_args, f_kwargs, per_rung_fb, resil_rt,
+                 span_args):
         self.vkey = vkey
         self.f_args = f_args
         self.f_kwargs = f_kwargs
         self.per_rung_fb = per_rung_fb
         self.resil_rt = resil_rt
+        self.span_args = span_args
 
 
 # bf16 shadow-oracle acceptance band (RouterOpts.dtype_guard): the
@@ -1045,16 +1102,17 @@ class Router:
         alone is bit-identical to the pre-generator code path."""
         from .planes import route_window_planes_fused
         resil_rt = req.resil_rt
-        if resil_rt is not None and resil_rt.guard is not None:
-            return self._guarded_dispatch_fused(
-                resil_rt, req.vkey, req.f_args, req.f_kwargs,
-                req.per_rung_fb)
-        _note_dispatch_variant(req.vkey)
-        if self._library is not None:
-            return self._library.dispatch(
-                req.vkey, route_window_planes_fused, req.f_args,
-                req.f_kwargs)
-        return route_window_planes_fused(*req.f_args, **req.f_kwargs)
+        with dispatching(fused=True, **req.span_args):
+            if resil_rt is not None and resil_rt.guard is not None:
+                return self._guarded_dispatch_fused(
+                    resil_rt, req.vkey, req.f_args, req.f_kwargs,
+                    req.per_rung_fb)
+            _note_dispatch_variant(req.vkey)
+            if self._library is not None:
+                return self._library.dispatch(
+                    req.vkey, route_window_planes_fused, req.f_args,
+                    req.f_kwargs)
+            return route_window_planes_fused(*req.f_args, **req.f_kwargs)
 
     def _drive_windows(self, gen) -> "RouteResult":
         """Trivial solo executor over a window-dispatch generator
@@ -1093,14 +1151,20 @@ class Router:
                     pres: float, cpd: float, batches: int,
                     relax_useful: Optional[int] = None,
                     bucket_occ=(), compaction: float = 1.0,
-                    kernel_plans=(), tw1: Optional[float] = None) -> None:
-        """Trace + metrics for one committed window: a route.window
-        span, K route.iter child spans, and the per-iteration registry
-        snapshot.  Iteration boundaries inside a K>1 fused window are
-        not host-visible, so the window's wall time is attributed
-        evenly across its iterations and the spans carry approx=True —
-        the stats_dir / host-callback paths force K=1 and get exact
-        per-iteration spans.
+                    kernel_plans=(), tw1: Optional[float] = None,
+                    win_ev: Optional[dict] = None) -> None:
+        """Trace + metrics for one committed window: the window's
+        ledger on its route.window span, a route.iter child span where
+        the window IS one iteration, and the per-iteration registry
+        snapshot.  Iteration boundaries inside a K>1 window are not
+        host-visible, so such a window carries first_iter / last_iter
+        and no route.iter spans — the stats_dir / host-callback paths
+        force K=1 and get exact per-iteration spans.
+
+        ``win_ev`` is the tracer's event of the window's live
+        route.window span (the planes driver opens one; None without a
+        tracer): the ledger is added to its args.  The ELL driver has
+        no live span and gets the event recorded here.
 
         ``relax_useful`` / ``bucket_occ`` / ``compaction`` feed the
         work-efficiency ledger: sweeps that improved a distance vs.
@@ -1119,24 +1183,25 @@ class Router:
             tw1 = time.perf_counter()
         useful = relax_steps if relax_useful is None else relax_useful
         tr = get_tracer()
+        ledger = dict(
+            first_iter=it_done - K + 1, last_iter=it_done, K=K,
+            rerouted=rerouted, overused_nodes=n_over,
+            relax_steps=relax_steps, relax_steps_useful=int(useful),
+            relax_steps_wasted=int(relax_steps - useful))
+        if win_ev is not None:
+            win_ev["args"].update(ledger)
+        elif tr is not None:
+            tr.add_complete("route.window", tw0, tw1 - tw0, cat="route",
+                            **ledger)
         if tr is not None:
-            tr.add_complete(
-                "route.window", tw0, tw1 - tw0, cat="route",
-                first_iter=it_done - K + 1, last_iter=it_done, K=K,
-                rerouted=rerouted, overused_nodes=n_over,
-                relax_steps=relax_steps,
-                relax_steps_useful=int(useful),
-                relax_steps_wasted=int(relax_steps - useful))
             for kp in kernel_plans:
                 tr.add_complete("route.kernel", tw0, 0.0, cat="route",
                                 **kp)
-            dt = (tw1 - tw0) / max(1, K)
-            for j in range(K):
-                tr.add_complete("route.iter", tw0 + j * dt, dt,
-                                cat="route", it=it_done - K + 1 + j,
+            if K == 1:
+                tr.add_complete("route.iter", tw0, tw1 - tw0,
+                                cat="route", it=it_done,
                                 overused=int(n_over),
-                                pres_fac=round(float(pres), 4),
-                                approx=K > 1)
+                                pres_fac=round(float(pres), 4))
         reg = get_metrics()
         reg.counter("route.iterations").inc(K)
         reg.counter("route.relax_steps").inc(relax_steps)
@@ -1216,7 +1281,8 @@ class Router:
                          relax_useful=w_useful,
                          bucket_occ=bk["bucket_occ"],
                          compaction=bk["compaction"],
-                         kernel_plans=bk["kplans"], tw1=bk["tw1"])
+                         kernel_plans=bk["kplans"], tw1=bk["tw1"],
+                         win_ev=bk["win_ev"])
         if mesh_info is not None:
             reg = get_metrics()
             reg.counter("route.mesh.halo_bytes").inc(halo_b)
@@ -1602,6 +1668,9 @@ class Router:
         book = None           # deferred bookkeeping of the last window
         reg = get_metrics()
         tr = get_tracer()
+        rid = next(_ROUTE_IDS)      # shared by every span of this route
+        ctl = None      # the open route.pipeline.control span, if any
+        disp_total = reg.gauge("route.pipeline.dispatch_ms_total")
         # reduced-precision plane config (RouterOpts.plane_dtype /
         # dtype_guard): guarded bf16 commits the f32 oracle every
         # window and replays a bf16 shadow on non-donated state copies
@@ -1890,9 +1959,9 @@ class Router:
                             widen_ok=p["wok"], plane_dtype=pd_main,
                             **sta_kw)
 
-            def window_call(p, esc, pres_in):
+            def window_call(p, esc, pres_in, ri):
                 """One route_window_planes dispatch of planned rung
-                ``p`` (one rung of this window's dispatch ladder)."""
+                ``p`` (rung ``ri`` of this window's dispatch ladder)."""
                 # canonical dispatch signature: everything jit traces
                 # as a static arg or shape.  New key = a fresh XLA
                 # compile (or persistent-cache load); known key = a jit
@@ -1902,10 +1971,6 @@ class Router:
                         p["sel_shape"][1], p["wok"] is None,
                         self.use_pallas, mesh_vk,
                         bool(sta_kw), R, Smax, N, pd_main)
-                if resil_rt is None or resil_rt.guard is None:
-                    # resil dispatch notes per executed rung instead
-                    # (a degraded rung compiles a different program)
-                    _note_dispatch_variant(vkey)
                 wp_args = rung_args(
                     p, (occ, acc, paths, sink_delay, all_reached, bb,
                         crit_d), esc, pres_in)
@@ -1924,26 +1989,40 @@ class Router:
                     # swapped; plans/tables are reused, not donated)
                     sh_stash.append((route_window_planes, wp_args,
                                      wp_kwargs, vkey))
-                if resil_rt is not None and resil_rt.guard is not None:
-                    # guarded dispatch: watchdog + retry/backoff over
-                    # a chain of bit-identical rungs (AOT -> jit ->
-                    # Pallas G=1 -> XLA); injected faults fire before
-                    # the call so donated buffers survive retries
-                    out = self._guarded_dispatch(
-                        resil_rt, vkey, wp_args, wp_kwargs)
-                elif self._library is not None:
-                    # AOT library serve: known variants run from the
-                    # deserialized exported executable (no trace/
-                    # lower); misses note their avatarized args for
-                    # export_program_library() and take the jit path
-                    out = self._library.dispatch(
-                        vkey, route_window_planes, wp_args, wp_kwargs)
-                else:
-                    out = route_window_planes(*wp_args, **wp_kwargs)
-                return out
+                with dispatching(window=widx, route=rid, rung=ri):
+                    if resil_rt is not None \
+                            and resil_rt.guard is not None:
+                        # guarded dispatch: watchdog + retry/backoff
+                        # over a chain of bit-identical rungs (AOT ->
+                        # jit -> Pallas G=1 -> XLA), each noting the
+                        # variant it runs; injected faults fire before
+                        # the call so donated buffers survive retries
+                        return self._guarded_dispatch(
+                            resil_rt, vkey, wp_args, wp_kwargs)
+                    _note_dispatch_variant(vkey)
+                    if self._library is not None:
+                        # AOT library serve: known variants run from
+                        # the deserialized exported executable (no
+                        # trace/lower); misses note their avatarized
+                        # args for export_program_library() and take
+                        # the jit path
+                        return self._library.dispatch(
+                            vkey, route_window_planes, wp_args,
+                            wp_kwargs)
+                    return route_window_planes(*wp_args, **wp_kwargs)
 
             t0 = time.time()
+            # the window's live span, closed after its stall; opened
+            # and closed by hand because a `yield` (the fused dispatch)
+            # and most of this loop's body lie between the two
+            if ctl is not None:
+                ctl.__exit__(None, None, None)
+            win = span("route.window", cat="route", window=widx,
+                       route=rid, first_iter=it_done + 1,
+                       last_iter=it_done + K, K=K)
+            win.__enter__()
             tw0 = time.perf_counter()
+            disp0_ms = disp_total.value or 0.0
             # dispatch order: cropped size classes ascending (the first
             # carries the acc escalation), full-canvas remainder last.
             # (A further split by fanout class — per-call num_waves
@@ -1975,6 +2054,11 @@ class Router:
                 # 0 only, mirroring esc=True-then-False) with one
                 # dispatch's overhead instead of one per rung ----
                 tp0 = time.perf_counter()
+                plan_sp = span("route.pipeline.plan", cat="route",
+                               stage="plan", window=widx, route=rid,
+                               rung=0, nets=len(dirty), fused=True,
+                               rungs=len(dispatch))
+                plan_sp.__enter__()
                 plans = [plan_rung(sub0, tile, ri)
                          for ri, (sub0, tile) in enumerate(dispatch)]
                 for p in plans:
@@ -2047,8 +2131,10 @@ class Router:
                 # into one multi-job program — issues the request and
                 # sends the 24-tuple back in (_exec_window_request
                 # holds the old guarded/AOT/jit dispatch chain)
+                plan_sp.__exit__(None, None, None)
                 out24 = yield WindowDispatchRequest(
-                    vkey, f_args, f_kwargs, run_per_rung_fb, resil_rt)
+                    vkey, f_args, f_kwargs, run_per_rung_fb, resil_rt,
+                    dict(window=widx, route=rid))
                 o = tuple(out24[:23])
                 retire.append((occ, acc, paths, sink_delay,
                                all_reached, bb, crit_d))
@@ -2069,17 +2155,14 @@ class Router:
                 # the whole plan time is rung-0-equivalent (unoverlapped)
                 plan_s = plan0_s = tp1 - tp0
                 t_disp0 = tp1
-                if tr is not None:
-                    tr.mark("route.pipeline.plan", tp0, tp1,
-                            cat="route", stage="plan", window=widx,
-                            rung=0, nets=len(dirty), fused=True,
-                            rungs=len(dispatch))
                 if not pipelined:
                     # --sync escape hatch: drain before ANY further
                     # host work (trace_report --check contract)
-                    # graftlint: ignore[pipeline-sync] — this IS the
-                    # sanctioned --sync drain
-                    jax.block_until_ready(o[21])
+                    with span("route.pipeline.stall", cat="route",
+                              window=widx, route=rid, sync=True):
+                        # graftlint: ignore[pipeline-sync] — this IS
+                        # the sanctioned --sync drain
+                        jax.block_until_ready(o[21])
                     te1 = time.perf_counter()
                     sync_block_s += te1 - tp1
                     reg.counter("route.pipeline.blocking_syncs").inc()
@@ -2092,8 +2175,11 @@ class Router:
                 esc = True
                 for ri, (sub0, tile) in enumerate(dispatch):
                     tp0 = time.perf_counter()
-                    p = plan_rung(sub0, tile, ri)
-                    o = window_call(p, esc, pres)
+                    with span("route.pipeline.plan", cat="route",
+                              stage="plan", window=widx, route=rid,
+                              rung=ri, nets=len(sub0), tile=tile):
+                        p = plan_rung(sub0, tile, ri)
+                    o = window_call(p, esc, pres, ri)
                     esc = False
                     kplans.append(p["kplan"])
                     # park the just-donated state refs before
@@ -2119,20 +2205,17 @@ class Router:
                     if ri == 0:
                         plan0_s = tp1 - tp0
                         t_disp0 = tp1
-                    if tr is not None:
-                        tr.mark("route.pipeline.plan", tp0, tp1,
-                                cat="route", stage="plan",
-                                window=widx, rung=ri, nets=len(sub0),
-                                tile=(None if tile is None
-                                      else list(tile)))
                     if not pipelined:
                         # --sync escape hatch: drain the rung before
                         # ANY further host work, so plan spans can
                         # never overlap device execution
                         # (trace_report --check asserts exactly this)
-                        # graftlint: ignore[pipeline-sync] — this IS
-                        # the sanctioned --sync drain
-                        jax.block_until_ready(o[21])
+                        with span("route.pipeline.stall", cat="route",
+                                  window=widx, route=rid, rung=ri,
+                                  sync=True):
+                            # graftlint: ignore[pipeline-sync] — this
+                            # IS the sanctioned --sync drain
+                            jax.block_until_ready(o[21])
                         te1 = time.perf_counter()
                         sync_block_s += te1 - tp1
                         reg.counter(
@@ -2171,11 +2254,13 @@ class Router:
             if sh_stash:
                 s_st = sh_state
                 for s_fn, a_r, kw_r, s_vk in sh_stash:
-                    _note_dispatch_variant(s_vk + ("shadow_bf16",))
-                    s_out = s_fn(
-                        *(a_r[:2] + s_st[:6] + a_r[8:10]
-                          + (s_st[6],) + a_r[11:]),
-                        **{**kw_r, "plane_dtype": "bf16"})
+                    with dispatching(window=widx, route=rid,
+                                     shadow=True):
+                        _note_dispatch_variant(s_vk + ("shadow_bf16",))
+                        s_out = s_fn(
+                            *(a_r[:2] + s_st[:6] + a_r[8:10]
+                              + (s_st[6],) + a_r[11:]),
+                            **{**kw_r, "plane_dtype": "bf16"})
                     retire.append(s_st)
                     s_st = tuple(s_out[:6]) + (s_out[13],)
                     sh_out = s_out
@@ -2190,31 +2275,33 @@ class Router:
             book_s = 0.0
             if book is not None:
                 tb0 = time.perf_counter()
-                bwidx = book["widx"]
-                self._book_window(book, result, mlog)
+                with span("route.pipeline.plan", cat="route",
+                          stage="summary", window=book["widx"],
+                          route=rid):
+                    self._book_window(book, result, mlog)
                 book = None
-                tb1 = time.perf_counter()
-                book_s = tb1 - tb0
-                if tr is not None:
-                    tr.mark("route.pipeline.plan", tb0, tb1,
-                            cat="route", stage="summary", window=bwidx)
+                book_s = time.perf_counter() - tb0
 
             # ---- stall: block until THIS window's packed summary is
             # host-side (the one blocking point per pipelined window) ----
             t_st0 = time.perf_counter()
-            status_np = np.asarray(out[21])  # graftlint: ignore[pipeline-sync]
-            scal_np = np.asarray(out[22])    # graftlint: ignore[pipeline-sync]
-            dmax_hist = (np.asarray(out[14])  # graftlint: ignore[pipeline-sync]
-                         if analyzer is not None
-                         else None)
+            with span("route.pipeline.stall", cat="route", window=widx,
+                      route=rid):
+                status_np = np.asarray(out[21])  # graftlint: ignore[pipeline-sync]
+                scal_np = np.asarray(out[22])    # graftlint: ignore[pipeline-sync]
+                dmax_hist = (np.asarray(out[14])  # graftlint: ignore[pipeline-sync]
+                             if analyzer is not None
+                             else None)
+                if sh_out is not None:
+                    # waiting for the bf16 shadow is the guard's cost
+                    # (it queued behind the committed window, so this
+                    # read is usually already streamed)
+                    s_status = np.asarray(sh_out[21])  # graftlint: ignore[pipeline-sync]
+                    s_scal = np.asarray(sh_out[22])    # graftlint: ignore[pipeline-sync]
             if sh_out is not None:
                 # the dtype-guard decision point: band-compare the
                 # bf16 shadow's packed summary against the committed
-                # f32 oracle (waiting here is the guard's cost — the
-                # shadow queued behind the committed window, so this
-                # read is usually already streamed)
-                s_status = np.asarray(sh_out[21])  # graftlint: ignore[pipeline-sync]
-                s_scal = np.asarray(sh_out[22])    # graftlint: ignore[pipeline-sync]
+                # f32 oracle
                 if _dtype_band_ok(status_np, scal_np, s_status,
                                   s_scal):
                     if guard_mode == "route":
@@ -2229,6 +2316,13 @@ class Router:
                         lad.step("dtype", "bf16 window summary left "
                                  "the declared ulp band")
             t_st1 = time.perf_counter()
+            win.__exit__(None, None, None)
+            # the host's control step: from this window's summary to
+            # the next window's first plan (or the route's end) nothing
+            # is in flight, so a device gap here is the host's
+            ctl = span("route.pipeline.control", cat="route",
+                       window=widx, route=rid)
+            ctl.__enter__()
             # everything donated into this window has now completed:
             # releasing the graveyard is a plain refcount drop
             del retire[:]
@@ -2267,6 +2361,8 @@ class Router:
             pl_serial += serial_s
             reg.set_gauges({
                 "route.pipeline.host_plan_ms": round(tot_host_w * 1e3, 3),
+                "route.pipeline.dispatch_ms": round(
+                    (disp_total.value or 0.0) - disp0_ms, 3),
                 "route.pipeline.device_exec_ms": round(exec_s * 1e3, 3),
                 "route.pipeline.stall_ms": round(stall_s * 1e3, 3),
                 "route.pipeline.overlap_frac": round(
@@ -2310,7 +2406,7 @@ class Router:
                 widx=widx, it_done=it_done, K=K, n_over=n_over,
                 over_total=over_total, ndirty=len(dirty), pres=pres,
                 cpd=cpd, t_wall0=t0, t_wall1=time.time(), tw0=tw0,
-                tw1=t_st1,
+                tw1=t_st1, win_ev=win.event,
                 rung_scals=rung_scals,
                 bucket_occ=bucket_occ,
                 compaction=comp_num / max(1, comp_den), kplans=kplans,
@@ -2497,6 +2593,8 @@ class Router:
                     break
         else:
             result.iterations = opts.max_router_iterations
+        if ctl is not None:
+            ctl.__exit__(None, None, None)
 
         if book is not None:
             # drain the in-flight bookkeeping (loop exited via break or
@@ -2589,17 +2687,7 @@ class Router:
         # that never reaches a given gauge doesn't inherit the previous
         # job's value.  The dispatch-variant seen-set is process state
         # on purpose and is NOT reset: warm variants stay warm.
-        get_metrics().set_gauges({k: 0.0 for k in (
-            "route.pipeline.host_plan_ms",
-            "route.pipeline.device_exec_ms",
-            "route.pipeline.stall_ms",
-            "route.pipeline.overlap_frac",
-            "route.pipeline.host_overlap_frac",
-            "route.pipeline.host_plan_ms_total",
-            "route.pipeline.device_exec_ms_total",
-            "route.pipeline.stall_ms_total",
-            "route.pipeline.host_serial_ms_total",
-        )})
+        get_metrics().set_gauges(dict.fromkeys(_PIPELINE_GAUGES, 0.0))
         # normalized into a LOCAL — never mutate the caller's
         # RouterOpts (the same opts object may drive several routers,
         # and the caller may compare it against what it passed in)
@@ -2715,17 +2803,7 @@ class Router:
         # that never reaches a given gauge doesn't inherit the previous
         # job's value.  The dispatch-variant seen-set is process state
         # on purpose and is NOT reset: warm variants stay warm.
-        get_metrics().set_gauges({k: 0.0 for k in (
-            "route.pipeline.host_plan_ms",
-            "route.pipeline.device_exec_ms",
-            "route.pipeline.stall_ms",
-            "route.pipeline.overlap_frac",
-            "route.pipeline.host_overlap_frac",
-            "route.pipeline.host_plan_ms_total",
-            "route.pipeline.device_exec_ms_total",
-            "route.pipeline.stall_ms_total",
-            "route.pipeline.host_serial_ms_total",
-        )})
+        get_metrics().set_gauges(dict.fromkeys(_PIPELINE_GAUGES, 0.0))
         # normalized into a LOCAL — never mutate the caller's
         # RouterOpts (the same opts object may drive several routers,
         # and the caller may compare it against what it passed in)

@@ -64,7 +64,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from ..obs.metrics import get_metrics
 from ..obs.slo import (CapacityForecaster, SLOPlane, load_objectives,
                        slo_name)
-from ..obs.trace import FlightRecorder, compile_seconds, get_tracer
+from ..obs.trace import (FlightRecorder, compile_seconds, get_tracer,
+                         span)
 from ..route.router import RouterOpts
 from .queue import JobState, RouteJob
 from .service import RouteService, ServeJobSpec
@@ -977,16 +978,12 @@ class RouteDaemon:
         long multi-slice job never lapses mid-flight — wrapped in the
         job's per-slice lifecycle span (the span records even when the
         slice raises: the queue's verdict loop owns the exception)."""
-        tr = get_tracer()
         t_start, c0, s0 = self._slice_marks()
-        with self._alive_through_slice():
-            if tr is None:
-                verdict, value = self.service._runner(job)
-            else:
-                with tr.span("route.trace.slice", cat="lifecycle",
-                             job_id=job.job_id, slice=job.slices + 1,
-                             worker=self.worker or "solo"):
-                    verdict, value = self.service._runner(job)
+        with self._alive_through_slice(), \
+                span("route.trace.slice", cat="lifecycle",
+                     job_id=job.job_id, slice=job.slices + 1,
+                     worker=self.worker or "solo"):
+            verdict, value = self.service._runner(job)
         self._observe_slice(job, t_start, c0, s0)
         self._last_slice = {"job_id": job.job_id,
                             "slice": job.slices + 1, "verdict": verdict}
@@ -1007,18 +1004,14 @@ class RouteDaemon:
         """Batched queue runner (continuous batching): the service's
         fused lockstep slice over the whole co-admitted set, then the
         same per-job verdict/lease bookkeeping ``_runner`` does."""
-        tr = get_tracer()
         ids = ",".join(j.job_id for j in jobs)
         t_start, c0, s0 = self._slice_marks()
-        with self._alive_through_slice():
-            if tr is None:
-                verdicts = self.service._batch_runner(jobs)
-            else:
-                with tr.span("route.trace.slice", cat="lifecycle",
-                             job_id=f"fused[{ids}]",
-                             slice=max(j.slices for j in jobs),
-                             worker=self.worker or "solo"):
-                    verdicts = self.service._batch_runner(jobs)
+        with self._alive_through_slice(), \
+                span("route.trace.slice", cat="lifecycle",
+                     job_id=f"fused[{ids}]",
+                     slice=max(j.slices for j in jobs),
+                     worker=self.worker or "solo"):
+            verdicts = self.service._batch_runner(jobs)
         for job in jobs:
             # lockstep costs are joint: every member LIVED through the
             # whole fused wall, so each job's waterfall is charged the
@@ -1228,9 +1221,17 @@ class RouteDaemon:
         return os.path.exists(os.path.join(self.inbox_dir, DRAIN_NAME))
 
     def cycle(self) -> int:
-        """One daemon cycle; returns the number of queue slices that
-        actually ran (0 = idle)."""
+        """One daemon cycle (inbox scan, admission, run, snapshot) as a
+        ``route.daemon.cycle`` span; returns the number of queue slices
+        that actually ran (0 = idle)."""
         self.cycles += 1
+        with span("route.daemon.cycle", cat="daemon", cycle=self.cycles,
+                  worker=self.worker or "solo") as sp:
+            ran = self._cycle()
+            sp.set(slices=ran)
+        return ran
+
+    def _cycle(self) -> int:
         q = self.service.queue
         tr = get_tracer()
         if tr is not None:

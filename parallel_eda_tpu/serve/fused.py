@@ -37,11 +37,11 @@ gated by flow_doctor's rebatch rules).
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional
 
 from ..obs.metrics import get_metrics
-from ..route.router import WindowDispatchRequest, _note_dispatch_variant
+from ..route.router import (WindowDispatchRequest,
+                            _note_dispatch_variant, dispatching)
 
 #: merged-dispatch width cap: pack shapes quantize to at most this many
 #: jobs per multi program, so the compiled pack-shape variety stays a
@@ -173,12 +173,14 @@ class FusedSliceRunner:
                 # injected dispatch faults fire at the merged site too,
                 # exercising the per-job degradation below
                 rt.plan.raise_if("dispatch.error", detail="multi")
-            _note_dispatch_variant(vkey)
-            if self.router._library is not None:
-                outs = self.router._library.dispatch(
-                    vkey, route_window_planes_multi, m_args, m_kwargs)
-            else:
-                outs = route_window_planes_multi(*m_args, **m_kwargs)
+            with dispatching(fused=True, jobs=len(group)):
+                _note_dispatch_variant(vkey)
+                if self.router._library is not None:
+                    outs = self.router._library.dispatch(
+                        vkey, route_window_planes_multi, m_args,
+                        m_kwargs)
+                else:
+                    outs = route_window_planes_multi(*m_args, **m_kwargs)
         except Exception:
             # degrade: the SAME requests, one at a time, through the
             # guarded solo chain — bit-identical by construction
@@ -200,7 +202,6 @@ class FusedSliceRunner:
     def _step(self, pend: List[SliceEntry]) -> Dict[str, Any]:
         """One lockstep step: dispatch every pending request — merged
         where possible — and return {job_id: 24-tuple}."""
-        m = get_metrics()
         outs: Dict[str, Any] = {}
         merge = [e for e in pend if _mergeable(e.pending)]
         solo = [e for e in pend if not _mergeable(e.pending)]
@@ -227,7 +228,6 @@ class FusedSliceRunner:
             self.router.opts = e.opts
             self.router._staging_prefix = e.prefix
             outs[e.job_id] = self.router._exec_window_request(e.pending)
-            m.counter("route.serve.fused.solo_windows").inc()
         return outs
 
     # -------------------------------------------------------- slice
@@ -237,8 +237,6 @@ class FusedSliceRunner:
         route completion/error).  Returns the entries with
         result/error set; per-entry wall share is left to the caller
         (lockstep wall is a joint cost)."""
-        m = get_metrics()
-        t0 = time.perf_counter()
         for e in entries:
             self._advance(e, None, first=True)
         steps = 0
@@ -251,9 +249,7 @@ class FusedSliceRunner:
             for e in pend:
                 e.windows += 1
                 self._advance(e, outs[e.job_id], first=False)
-        m.counter("route.serve.fused.steps").inc(steps)
-        m.gauge("route.serve.fused.slice_wall_s").set(
-            round(time.perf_counter() - t0, 4))
+        get_metrics().counter("route.serve.fused.steps").inc(steps)
         return entries
 
     def close(self, entries: List[SliceEntry]) -> None:

@@ -348,6 +348,17 @@ def summarize(doc) -> str:
                          "window; wall time evenly attributed)")
 
     windows = [e for e in evs if e.get("name") == "route.window"]
+    if windows:
+        # a K>1 window is one device dispatch: its iterations have no
+        # spans of their own, the window carries first/last
+        lines.append(f"route windows: {len(windows)}")
+        lines.append("  window      iters    wall_s  overused")
+        for e in windows:
+            a = e.get("args", {})
+            its = f"{a.get('first_iter', '?')}-{a.get('last_iter', '?')}"
+            lines.append(f"  {a.get('window', '?'):>6}  {its:>9}"
+                         f"  {e['dur'] / us:8.3f}"
+                         f"  {a.get('overused_nodes', '?'):>8}")
     w_tot = sum(e.get("args", {}).get("relax_steps", 0)
                 for e in windows)
     w_use = sum(e.get("args", {}).get("relax_steps_useful", 0)
