@@ -1147,6 +1147,61 @@ def _as_row_mesh(mesh):
     return mesh if isinstance(mesh, RowMesh) else None
 
 
+def traceback_walk(pred, wenter, noc_p1, pick_cell, done0, Kw: int):
+    """The traceback's pointer chase, alone: from each ``pick_cell``
+    [B, G] follow ``pred`` [B, ncells] until a cell that is its own
+    predecessor, for at most ``Kw`` steps.  Step ``pos`` records, for
+    every walk not yet ``done``, the cell it stands on, that cell's rr
+    node (``noc_p1`` [ncells + 1], last entry = the node sentinel) and
+    its entry weight (``wenter`` [B, ncells]); an ended walk keeps the
+    fill (``ncells``, the sentinel, 0.0).  ``done0`` marks the walks
+    that never start (invalid or direct picks).
+
+    The loop ends when every walk of the batch has: a step taken with
+    ``done`` all true writes the fill over the fill and moves nothing,
+    so the outputs equal the full ``Kw``-step walk's.  A walk that
+    overruns the budget keeps the loop going to ``Kw`` and ends not
+    ``done``.  The records are kept time-major inside the loop, so a
+    step writes one contiguous [1, B, G] slab in place instead of
+    scattering B * G elements along the minor axis, and are transposed
+    once after it.
+
+    Returns (cur [B, G], done [B, G], cells_w, nodes_w, wst — each
+    [B, G, Kw] — and the number of steps run, int32)."""
+    B, G = pick_cell.shape
+    ncells = pred.shape[1]
+
+    def record(buf, pos, row):
+        return lax.dynamic_update_slice(buf, row[None], (pos, 0, 0))
+
+    def unfinished(ws):
+        pos, _, done = ws[:3]
+        return (pos < Kw) & ~done.all()
+
+    def walk_step(ws):
+        pos, cur, done, cells_w, nodes_w, wst = ws
+        at = jnp.where(done, ncells, cur)
+        cells_w = record(cells_w, pos, at)
+        nodes_w = record(nodes_w, pos, jnp.take(noc_p1, at))
+        here = jnp.clip(cur, 0, ncells - 1)
+        w = jnp.take_along_axis(wenter, here, axis=1)
+        wst = record(wst, pos, jnp.where(done, 0.0, w).astype(wst.dtype))
+        nxt = jnp.take_along_axis(pred, here, axis=1)
+        stop = done | (nxt == cur)
+        return (pos + 1, jnp.where(stop, cur, nxt), stop, cells_w,
+                nodes_w, wst)
+
+    steps, cur, done, cells_w, nodes_w, wst = lax.while_loop(
+        unfinished, walk_step,
+        (jnp.int32(0), pick_cell, done0,
+         jnp.full((Kw, B, G), ncells, jnp.int32),
+         jnp.broadcast_to(noc_p1[ncells], (Kw, B, G)),
+         jnp.zeros((Kw, B, G), jnp.float32)))
+    cells_w, nodes_w, wst = (jnp.transpose(a, (1, 2, 0))
+                             for a in (cells_w, nodes_w, wst))
+    return cur, done, cells_w, nodes_w, wst, steps
+
+
 def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                paths, sink_delay, all_reached, bb,
                source_all, sinks_all, crit_all,
@@ -1166,7 +1221,12 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
     unless it needs rerouting (an overused node on its tree or an
     unreached sink — route_timing.c should_route_net semantics) or
     `force` is true, so a static batch plan can cover all nets every
-    iteration and the device skips the clean ones."""
+    iteration and the device skips the clean ones.
+
+    Returns (paths, sink_delay, all_reached, bb, occ, n_active, st):
+    ``st`` [4] int32 is the step's ledger — relaxation sweeps executed,
+    sweeps that improved a distance, traceback walk steps run, walk
+    steps budgeted — in the order of scal's SCAL_S_EXEC.. tail."""
     N = dev.num_nodes
     R = paths.shape[0]
     B = sel.shape[0]
@@ -1346,7 +1406,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                                                        crit_c, wenter0,
                                                        nsweeps, mesh,
                                                        plane_dtype)
-            st = st + rst
+            st = st.at[:2].add(rst)
 
         with device_scope("route.dev.sink_pick"):
             # --- sink extraction from the per-net candidate tables ---
@@ -1413,29 +1473,11 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             ar_b = arangeB[:, None]
             ar_g = jnp.arange(G)[None, :]
             noc_p1 = jnp.append(pg.node_of_cell, N)
-
-            def walk_step(pos, ws):
-                cur, done, cells_w, nodes_w, wst = ws
-                nd = jnp.take(noc_p1, cur)                 # [B, G]
-                cells_w = cells_w.at[ar_b, ar_g, pos].set(
-                    jnp.where(done, ncells, cur))
-                nodes_w = nodes_w.at[ar_b, ar_g, pos].set(
-                    jnp.where(done, N, nd))
-                w = jnp.take_along_axis(
-                    wenter, jnp.clip(cur, 0, ncells - 1), axis=1)
-                wst = wst.at[ar_b, ar_g, pos].set(jnp.where(done, 0.0, w))
-                nxt = jnp.take_along_axis(
-                    pred, jnp.clip(cur, 0, ncells - 1), axis=1)
-                stop = done | (nxt == cur)
-                return jnp.where(stop, cur, nxt), stop, cells_w, nodes_w, wst
-
-            cells_w0 = jnp.full((B, G, Kw), ncells, jnp.int32)
-            nodes_w0 = jnp.full((B, G, Kw), N, jnp.int32)
-            wst0 = jnp.zeros((B, G, Kw), jnp.float32)
-            cur, done, cells_w, nodes_w, wst = lax.fori_loop(
-                0, Kw, walk_step,
-                (pick_cell, ~pick_valid | pick_direct, cells_w0, nodes_w0,
-                 wst0))
+            cur, _, cells_w, nodes_w, wst, wsteps = traceback_walk(
+                pred, wenter, noc_p1, pick_cell,
+                ~pick_valid | pick_direct, Kw)
+            # the ledger's walk half: steps this wave ran, of its budget
+            st = st.at[2:].add(jnp.stack([wsteps, jnp.int32(Kw)]))
             # a walk is complete iff it reached a pred==self cell in budget
             nxt_last = jnp.take_along_axis(
                 pred, jnp.clip(cur, 0, ncells - 1), axis=1)
@@ -1538,7 +1580,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                   jnp.full((B, S, max_len), N, jnp.int32),
                   jnp.full((B, S), INF, jnp.float32),
                   jnp.zeros((B, S), bool),
-                  jnp.zeros((2,), jnp.int32))
+                  jnp.zeros((4,), jnp.int32))
     (_, _, _, _, p, delay, reached, st) = lax.fori_loop(
         0, num_waves, wave_body, state0)
 
@@ -1567,7 +1609,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
         all_reached = all_reached.at[sel_v].set(ok, mode="drop")
         bb = bb.at[sel_v].set(new_bb, mode="drop")
         return (paths, sink_delay, all_reached, bb, occ_new,
-                valid.sum(dtype=jnp.int32), st[0], st[1])
+                valid.sum(dtype=jnp.int32), st)
 
 
 @functools.partial(
@@ -1596,7 +1638,7 @@ def route_batch_resident_planes(
         # its own terminals (silently unroutable)
         raise ValueError("crop_tile requires bb0_all (static initial "
                          "bbs) as the crop anchor")
-    paths, sink_delay, all_reached, bb, occ, _, st_exec, _ = _step_core(
+    paths, sink_delay, all_reached, bb, occ, _, st = _step_core(
         pg, dev, occ, acc, pres_fac, paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
@@ -1605,7 +1647,7 @@ def route_batch_resident_planes(
         sel, valid, jnp.bool_(True), full_bb,
         nsweeps, max_len, num_waves, group, doubling, mesh, use_pallas,
         crop_tile, bb0_all, plane_dtype=plane_dtype)
-    return (paths, sink_delay, all_reached, bb, occ, st_exec)
+    return (paths, sink_delay, all_reached, bb, occ, st[0])
 
 
 @device_scope("route.dev.mis_colors")
@@ -1703,31 +1745,33 @@ def _window_body(
     Returns (occ, acc, paths, sink_delay, all_reached, bb, pres,
     rrm [R], colors [R], n_over, over_total, nroutes, nexec, crit_all,
     dmax_hist, max_span, dev_wide, live_wh, unreached, steps_exec,
-    steps_useful, status [R], scal [7]) — steps_exec/steps_useful are
-    the MEASURED relaxation-sweep counters summed over every executed
-    group/wave of the window (executed trips of the bounded while_loop,
-    and the subset that improved some distance); ``status``/``scal``
-    repack the per-net mask/color/bb fields and the scalar counters
-    into two small int32 arrays so the pipelined driver can pull the
-    whole window summary with one async copy (unpack_window_status /
-    SCAL_* below)."""
+    steps_useful, status [R], scal [SCAL_LEN]) — steps_exec/
+    steps_useful are the MEASURED relaxation-sweep counters summed over
+    every executed group/wave of the window (executed trips of the
+    bounded while_loop, and the subset that improved some distance);
+    scal's last two entries are the traceback walk's ledger summed the
+    same way (steps run, and the Kw budgeted per executed wave);
+    ``status``/``scal`` repack the per-net mask/color/bb fields and the
+    scalar counters into two small int32 arrays so the pipelined driver
+    can pull the whole window summary with one async copy
+    (unpack_window_status / SCAL_* below)."""
     G = sel_plan.shape[0]
     R, Smax = sinks_all.shape
 
     def it_body(it, st):
         (occ, acc, paths, sink_delay, all_reached, bb, pres, nroutes,
-         nexec, crit_all, dmax_hist, s_exec, s_useful) = st
+         nexec, crit_all, dmax_hist, led) = st
         with device_scope("route.dev.ripup"):
             force = (it0 + it) < force_until
 
         def g_step(g, st2):
             def run(st3):
                 (occ2, paths2, sink_delay2, all_reached2, bb2, nr, ng,
-                 se, su) = st3
+                 led2) = st3
                 with device_scope("route.dev.ripup"):
                     sel_g, valid_g = sel_plan[g], valid_plan[g]
                 (paths2, sink_delay2, all_reached2, bb2, occ2,
-                 n_act, st_exec, st_useful) = _step_core(
+                 n_act, led_g) = _step_core(
                     pg, dev, occ2, acc, pres,
                     paths2, sink_delay2, all_reached2, bb2,
                     source_all, sinks_all, crit_all,
@@ -1741,8 +1785,7 @@ def _window_body(
                     plane_dtype)
                 with device_scope("route.dev.commit"):
                     return (occ2, paths2, sink_delay2, all_reached2, bb2,
-                            nr + n_act, ng + 1, se + st_exec,
-                            su + st_useful)
+                            nr + n_act, ng + 1, led2 + led_g)
 
             # skip pow2-padding groups and fully-clean groups outright
             # (the group plan is padded to a power of two to bound the
@@ -1758,10 +1801,10 @@ def _window_body(
             return lax.cond(any_dirty, run, lambda s: s, st2)
 
         (occ, paths, sink_delay, all_reached, bb, nroutes,
-         nexec, s_exec, s_useful) = lax.fori_loop(
+         nexec, led) = lax.fori_loop(
             0, G, g_step,
             (occ, paths, sink_delay, all_reached, bb, nroutes, nexec,
-             s_exec, s_useful))
+             led))
         with device_scope("route.dev.history"):
             # PathFinder history/present escalation once per iteration
             acc = acc + acc_fac * jnp.maximum(
@@ -1779,15 +1822,16 @@ def _window_body(
                 crit_all = crit_flat.reshape(R, Smax)
                 dmax_hist = dmax_hist.at[it].set(dmax)
         return (occ, acc, paths, sink_delay, all_reached, bb, pres,
-                nroutes, nexec, crit_all, dmax_hist, s_exec, s_useful)
+                nroutes, nexec, crit_all, dmax_hist, led)
 
     (occ, acc, paths, sink_delay, all_reached, bb, pres, nroutes,
-     nexec, crit_all, dmax_hist, s_exec, s_useful) = lax.fori_loop(
+     nexec, crit_all, dmax_hist, led) = lax.fori_loop(
         0, K_iters, it_body,
         (occ, acc, paths, sink_delay, all_reached, bb, pres0,
          jnp.int32(0), jnp.int32(0), crit_all,
          jnp.full(K_iters, jnp.nan, jnp.float32),
-         jnp.int32(0), jnp.int32(0)))
+         jnp.zeros((4,), jnp.int32)))
+    s_exec, s_useful = led[0], led[1]
 
     rrm, colors = _mis_colors(dev, occ, paths, all_reached,
                               topk, n_colors)
@@ -1833,9 +1877,10 @@ def _window_body(
                   | (wb.astype(jnp.int32) << 16))
         n_over_s = (over > 0).sum(dtype=jnp.int32)
         over_tot_s = over.sum(dtype=jnp.int32)
-        scal = jnp.stack([n_over_s, over_tot_s, nroutes, nexec,
-                          max_span.astype(jnp.int32),
-                          s_exec, s_useful]).astype(jnp.int32)
+        scal = jnp.concatenate([
+            jnp.stack([n_over_s, over_tot_s, nroutes, nexec,
+                       max_span.astype(jnp.int32)]).astype(jnp.int32),
+            led])
     return (occ, acc, paths, sink_delay, all_reached, bb, pres, rrm,
             colors, n_over_s, over_tot_s, nroutes, nexec, crit_all,
             dmax_hist, max_span, dev_wide, live_wh, unreached,
@@ -2076,9 +2121,11 @@ SCAL_OVER_TOTAL = 1
 SCAL_NROUTES = 2
 SCAL_NEXEC = 3
 SCAL_MAX_SPAN = 4
-SCAL_S_EXEC = 5
+SCAL_S_EXEC = 5       # 5..8: _step_core's ledger vector, in its order
 SCAL_S_USEFUL = 6
-SCAL_LEN = 7
+SCAL_WALK_STEPS = 7
+SCAL_WALK_BUDGET = 8
+SCAL_LEN = 9
 
 
 def unpack_window_status(status):

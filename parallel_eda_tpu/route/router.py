@@ -310,6 +310,11 @@ class RouteResult:
     # area — the two cost very different device time; bench projections
     # need the split)
     total_relax_steps_cropped: int = 0
+    # traceback ledger (windowed planes program): pointer-chase steps
+    # the walks ran, and the steps budgeted (max_len - 4 per executed
+    # wave).  A share near 1 means paths are pressing on the budget.
+    total_walk_steps: int = 0
+    total_walk_budget: int = 0
     # nets whose bb was widened to the full device (left the windowed
     # program; 0 on a healthy windowed run of a routable circuit)
     widened_nets: int = 0
@@ -1241,7 +1246,8 @@ class Router:
         value captured at that window's control step — later control
         mutations (pres, plateau state, widened_nets) cannot leak in."""
         from .planes import (SCAL_NEXEC, SCAL_NROUTES, SCAL_S_EXEC,
-                             SCAL_S_USEFUL)
+                             SCAL_S_USEFUL, SCAL_WALK_BUDGET,
+                             SCAL_WALK_STEPS)
 
         w_steps = w_useful = w_steps_crop = 0
         nroutes = nexec = 0
@@ -1253,6 +1259,8 @@ class Router:
             nexec += int(v[SCAL_NEXEC])
             w_steps += int(v[SCAL_S_EXEC])
             w_useful += int(v[SCAL_S_USEFUL])
+            result.total_walk_steps += int(v[SCAL_WALK_STEPS])
+            result.total_walk_budget += int(v[SCAL_WALK_BUDGET])
             if cropped:
                 w_steps_crop += int(v[SCAL_S_EXEC])
             if mesh_info is not None and mesh_info[0] > 1 \
@@ -1522,14 +1530,15 @@ class Router:
 
         With ``opts.pipeline`` (default), the driver is a two-stage
         software pipeline: each window's summary comes back as a packed
-        [R] status word + [7] scal vector whose copy_to_host_async
-        starts at dispatch, later rungs are planned and staged (hash-
-        skipped non-blocking device_put) while earlier rungs execute,
-        and the previous window's bookkeeping (_book_window) runs while
-        the current window is in flight.  Every dispatch is still
-        planned from a fully consumed summary — lag-0 — so results are
-        bit-identical to pipeline=False, which drains each rung before
-        any further host work (the --sync escape hatch)."""
+        [R] status word + [SCAL_LEN] scal vector whose
+        copy_to_host_async starts at dispatch, later rungs are planned
+        and staged (hash-skipped non-blocking device_put) while earlier
+        rungs execute, and the previous window's bookkeeping
+        (_book_window) runs while the current window is in flight.
+        Every dispatch is still planned from a fully consumed summary —
+        lag-0 — so results are bit-identical to pipeline=False, which
+        drains each rung before any further host work (the --sync
+        escape hatch)."""
         from .planes import (PLANE_DTYPES, route_window_planes,
                              route_window_planes_fused,
                              unpack_window_status)

@@ -130,3 +130,94 @@ def test_ledger_report_check_rejects_missing_and_garbage(tmp_path):
     r = subprocess.run([sys.executable, str(LEDGER_TOOL), str(g),
                         "--check"], capture_output=True, text=True)
     assert r.returncode == 2
+
+
+# ---- the traceback walk's half of the ledger ----
+
+def _route_tiny():
+    f = synth_flow(num_luts=15, chan_width=10, seed=0)
+    return Router(f.rr, RouterOpts(batch_size=16)).route(f.term)
+
+
+@pytest.mark.ledger
+def test_walk_ledger_counts_and_repeats(routed):
+    """The walks ran some of the steps they were budgeted, never more,
+    and a second route counts the same."""
+    res, _, _ = routed
+    assert 0 < res.total_walk_steps <= res.total_walk_budget
+    again = _route_tiny()
+    assert again.total_walk_steps == res.total_walk_steps
+    assert again.total_walk_budget == res.total_walk_budget
+
+
+def _full_budget_walk(pred, wenter, noc_p1, pick_cell, done0, Kw):
+    """The walk as it was before it could end early: a fixed-trip loop
+    of Kw steps, each a scatter at one position of [B, G, Kw]."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    B, G = pick_cell.shape
+    ncells = pred.shape[1]
+    N = noc_p1[ncells]
+    ar_b = jnp.arange(B)[:, None]
+    ar_g = jnp.arange(G)[None, :]
+
+    def walk_step(pos, ws):
+        cur, done, cells_w, nodes_w, wst = ws
+        nd = jnp.take(noc_p1, cur)
+        cells_w = cells_w.at[ar_b, ar_g, pos].set(
+            jnp.where(done, ncells, cur))
+        nodes_w = nodes_w.at[ar_b, ar_g, pos].set(jnp.where(done, N, nd))
+        w = jnp.take_along_axis(
+            wenter, jnp.clip(cur, 0, ncells - 1), axis=1)
+        wst = wst.at[ar_b, ar_g, pos].set(jnp.where(done, 0.0, w))
+        nxt = jnp.take_along_axis(
+            pred, jnp.clip(cur, 0, ncells - 1), axis=1)
+        stop = done | (nxt == cur)
+        return jnp.where(stop, cur, nxt), stop, cells_w, nodes_w, wst
+
+    return lax.fori_loop(
+        0, Kw, walk_step,
+        (pick_cell, done0, jnp.full((B, G, Kw), ncells, jnp.int32),
+         jnp.broadcast_to(N, (B, G, Kw)),
+         jnp.zeros((B, G, Kw), jnp.float32))) + (jnp.int32(Kw),)
+
+
+@pytest.mark.ledger
+def test_early_ending_walk_routes_as_the_full_budget_walk(
+        routed, monkeypatch):
+    """The route under the early-ending walk is the route under the
+    fixed 'Kw steps whatever the paths' walk, node for node: same
+    iterations, wirelength, sweeps, paths, delays and occupancy.  Only
+    the steps differ: the full-budget walk runs all it was given."""
+    from parallel_eda_tpu.route import planes
+
+    programs = (planes.route_window_planes,
+                planes.route_window_planes_fused,
+                planes.route_window_planes_multi,
+                planes.route_batch_resident_planes)
+
+    def forget():
+        # the walk is traced into jitted programs: drop what they hold
+        for prog in programs:
+            prog.clear_cache()
+
+    res, _, _ = routed
+    monkeypatch.setattr(planes, "traceback_walk", _full_budget_walk)
+    forget()
+    try:
+        full = _route_tiny()
+    finally:
+        monkeypatch.undo()
+        forget()
+    assert full.total_walk_steps == full.total_walk_budget \
+        == res.total_walk_budget
+    assert res.total_walk_steps < full.total_walk_steps
+    assert (res.success, res.iterations, res.wirelength,
+            res.total_relax_steps, res.total_relax_steps_useful) == (
+        full.success, full.iterations, full.wirelength,
+        full.total_relax_steps, full.total_relax_steps_useful)
+    assert np.array_equal(np.asarray(res.paths), np.asarray(full.paths))
+    assert np.array_equal(np.asarray(res.sink_delay),
+                          np.asarray(full.sink_delay))
+    assert np.array_equal(np.asarray(res.occ), np.asarray(full.occ))

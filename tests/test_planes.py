@@ -298,3 +298,140 @@ def test_crop_timing_driven_crit_path_parity():
     r3, cpd3 = run("off")
     assert r3.success
     assert cpd1 <= cpd3 * 1.01 + 1e-12          # the <=1% BASELINE bar
+
+
+# ---- the traceback walk alone (planes.traceback_walk) ----
+
+_WALK_B, _WALK_G, _WALK_KW, _WALK_NC, _WALK_N = 3, 4, 12, 64, 200
+
+
+def _walk_reference(pred, wenter, noc_p1, pick_cell, done0, Kw):
+    """The full-budget walk, one (net, pick) at a time in NumPy: every
+    one of the Kw steps is taken, whatever the walks' lengths."""
+    B, G = pick_cell.shape
+    ncells = pred.shape[1]
+    cells = np.full((B, G, Kw), ncells, np.int32)
+    nodes = np.full((B, G, Kw), noc_p1[ncells], np.int32)
+    wst = np.zeros((B, G, Kw), np.float32)
+    cur = pick_cell.copy()
+    done = done0.copy()
+    longest = 0
+    for b in range(B):
+        for g in range(G):
+            for pos in range(Kw):
+                if done[b, g]:
+                    break
+                c = cur[b, g]
+                cells[b, g, pos] = c
+                nodes[b, g, pos] = noc_p1[c]
+                wst[b, g, pos] = wenter[b, c]
+                longest = max(longest, pos + 1)
+                if pred[b, c] == c:
+                    done[b, g] = True
+                else:
+                    cur[b, g] = pred[b, c]
+    return cur, done, cells, nodes, wst, longest
+
+
+def _walk_case(kind):
+    """Seeded pred fields [B, ncells]: cell c points at c - 1 inside
+    runs of random length, a run's first cell at itself (a root)."""
+    B, G, Kw, NC = _WALK_B, _WALK_G, _WALK_KW, _WALK_NC
+    rng = np.random.default_rng(sum(map(ord, kind)))
+    pred = np.empty((B, NC), np.int32)
+    for b in range(B):
+        c = 0
+        while c < NC:
+            e = min(NC, c + int(rng.integers(1, Kw)))
+            pred[b, c] = c
+            pred[b, c + 1:e] = np.arange(c, e - 1)
+            c = e
+    pick = rng.integers(0, NC, (B, G)).astype(np.int32)
+    done0 = rng.random((B, G)) < 0.25
+    done0[0, 0] = False
+    if kind == "ends_at_budget":
+        # one chain of exactly Kw cells: the last step finds the root
+        pred[1, 20], pred[1, 20 + Kw] = 20, 20 + Kw
+        pred[1, 21:20 + Kw] = np.arange(20, 19 + Kw)
+        pick[1, 2], done0[1, 2] = 19 + Kw, False
+    elif kind == "overruns_budget":
+        pred[2, 10], pred[2, 40] = 10, 40
+        pred[2, 11:40] = np.arange(10, 39)
+        pick[2, 1], done0[2, 1] = 10 + Kw + 5, False
+    elif kind == "none_starts":
+        done0[:] = True
+    elif kind == "cycle_of_two":
+        pred[0, 30], pred[0, 31] = 31, 30
+        pick[0, 3], done0[0, 3] = 30, False
+    wenter = rng.random((B, NC), dtype=np.float32)
+    noc_p1 = np.append(rng.integers(0, _WALK_N, NC),
+                       _WALK_N).astype(np.int32)
+    return pred, wenter, noc_p1, pick, done0
+
+
+@pytest.mark.parametrize("kind,steps,all_done", [
+    ("mixed_lengths", None, True),
+    ("ends_at_budget", _WALK_KW, True),
+    ("overruns_budget", _WALK_KW, False),
+    ("none_starts", 0, True),
+    ("cycle_of_two", _WALK_KW, False),
+])
+def test_traceback_walk_equals_full_budget_walk(kind, steps, all_done):
+    """The early-ending walk returns what the full-budget walk returns,
+    in every output, and reports the longest walk (capped at Kw) as its
+    step count: nothing that a step would have written is dropped."""
+    from parallel_eda_tpu.route.planes import traceback_walk
+
+    pred, wenter, noc_p1, pick, done0 = _walk_case(kind)
+    want = _walk_reference(pred, wenter, noc_p1, pick, done0, _WALK_KW)
+    got = traceback_walk(jnp.asarray(pred), jnp.asarray(wenter),
+                         jnp.asarray(noc_p1), jnp.asarray(pick),
+                         jnp.asarray(done0), _WALK_KW)
+    for name, g, w in zip(("cur", "done", "cells", "nodes", "wst"),
+                          got, want):
+        assert np.array_equal(np.asarray(g), w), name
+    assert int(got[5]) == want[5]
+    if steps is not None:
+        assert int(got[5]) == steps
+    else:
+        assert 1 < int(got[5]) < _WALK_KW
+    assert bool(want[1].all()) == all_done
+    # a walk is `ok` only where it stands on a root when the loop ends
+    cur = want[0]
+    on_root = np.take_along_axis(pred, cur, axis=1) == cur
+    assert on_root[~done0].all() == all_done
+
+
+# ---- the standalone resident batch step (__graft_entry__.entry) ----
+
+def test_resident_batch_step_equals_a_one_group_window():
+    """`route_batch_resident_planes` (the driver's entry() program) is
+    `_step_core` alone: one forced group of a one-iteration window
+    program must leave the same paths, delays, reached flags, boxes and
+    occupancy, and count the same sweeps."""
+    import jax
+
+    import __graft_entry__ as graft
+    from parallel_eda_tpu.route.planes import (SCAL_S_EXEC,
+                                               route_window_planes)
+
+    fn, args = graft.entry()
+    res = jax.jit(fn)(*(jnp.array(a) for a in args))
+
+    p = graft.planes_step_problem()
+    occ, acc, paths, sink_delay, all_reached, bb = p["state"]
+    win = route_window_planes(
+        p["pg"], p["dev"], occ, acc, paths, sink_delay, all_reached, bb,
+        *p["nets"], p["sel"][None], p["valid"][None], p["full_bb"],
+        jnp.float32(0.5), jnp.float32(1.0), jnp.float32(1.0),
+        jnp.float32(0.0), jnp.int32(0), jnp.int32(1),
+        1, p["nsweeps"], p["max_len"], p["num_waves"], p["group"],
+        True, topk=64)
+    w_occ, _, w_paths, w_delay, w_reached, w_bb = win[:6]
+    for name, got, want in (("paths", res[0], w_paths),
+                            ("sink_delay", res[1], w_delay),
+                            ("all_reached", res[2], w_reached),
+                            ("bb", res[3], w_bb), ("occ", res[4], w_occ)):
+        assert np.array_equal(np.asarray(got), np.asarray(want)), name
+    assert np.asarray(res[2]).any()
+    assert int(res[5]) == int(win[-1][SCAL_S_EXEC]) > 0
