@@ -1,9 +1,11 @@
 """Built-in architectures.
 
-The driver's config ladder (BASELINE.md) starts at ``k6_N10_40nm``; we ship a
-built-in equivalent so the flow runs without an XML file.  Numbers are in the
-ballpark of the VTR 40 nm models (not copied from any file in the reference
-tree — the reference bundles no arch XMLs).
+The driver's config ladder (BASELINE.md) starts at ``k6_N10_40nm``:
+``k6_n10_40nm_arch`` is that file's routing architecture (length-4
+single-driver wires at the published Fc), built without an XML file;
+``k6_n10_arch`` is the tests' length-1 bidirectional fixture with the
+same cluster.  The reference tree bundles no arch XMLs: the published
+numbers are those of ``tests/golden/k6_frac_n10_mem.xml:23-46``.
 """
 
 from __future__ import annotations
@@ -13,8 +15,10 @@ from .model import (Arch, ColumnSpec, SegmentInf, SwitchInf, make_clb_type,
 
 
 def k6_n10_arch() -> Arch:
-    """K=6, N=10, I=33 soft-logic architecture, single wire type (length 1),
-    buffered switches.  Stand-in for the k6_N10_40nm VTR arch."""
+    """K=6, N=10, I=33 soft-logic architecture, single wire type (length 1,
+    bidirectional), buffered switches: the tests' fixture for the k6_N10
+    cluster.  The published k6_N10_40nm routing architecture is
+    ``k6_n10_40nm_arch``."""
     arch = Arch(
         name="k6_N10",
         K=6, N=10, I=33, io_capacity=8,
@@ -35,6 +39,47 @@ def k6_n10_arch() -> Arch:
         make_io_type(index=0, capacity=arch.io_capacity),
         make_clb_type(index=1, K=arch.K, N=arch.N, I=arch.I,
                       T_comb=261e-12, T_setup=66e-12, T_clk_to_q=124e-12),
+    ]
+    return arch
+
+
+def k6_n10_40nm_arch(chan_width: int = 120) -> Arch:
+    """The routing architecture of VTR 7.0's
+    ``vtr_flow/arch/timing/k6_N10_40nm.xml``, every number as published
+    (``tests/golden/k6_frac_n10_mem.xml:23-46`` carries the same
+    lines): K=6, N=10, I=33, 8 pads a perimeter tile; ONE segment type,
+    length 4, single-driver, Rmetal 101, Cmetal 22.5e-15, sb pattern
+    ``1 1 1 1 1``, cb pattern ``1 1 1 1``, driven (wire and OPIN alike)
+    through the one mux R=551, Tdel=58e-12, Cin=0.77e-15, Cout=4e-15;
+    Fc_in 0.15, Fc_out 0.10; the input connection block T=7.247e-11,
+    C=1.47e-15; cluster inputs equivalent, outputs not (each BLE drives
+    its own pin).  Block timing is ``k6_n10_arch``'s.  The file asks for a
+    Wilton switch block, Fs=3; the rr builder realises its own Fs=3 box
+    (rr/graph.py "Unidir switch box") and says so in a warning."""
+    arch = Arch(
+        name="k6_N10_40nm",
+        K=6, N=10, I=33, io_capacity=8,
+        segments=[SegmentInf(name="l4", length=4, frequency=1.0,
+                             Rmetal=101.0, Cmetal=22.5e-15,
+                             wire_switch=0, opin_switch=0,
+                             directionality="unidir",
+                             sb=(1, 1, 1, 1, 1), cb=(1, 1, 1, 1))],
+        switches=[
+            SwitchInf(name="0", buffered=True, R=551.0,
+                      Cin=0.77e-15, Cout=4e-15, Tdel=58e-12),
+            SwitchInf(name="ipin_cblock", buffered=True, R=0.0,
+                      Cin=1.47e-15, Cout=0.0, Tdel=7.247e-11),
+        ],
+        Fc_out=0.10, Fc_in=0.15,
+        ipin_switch=1,
+        default_chan_width=chan_width,
+        sb_type="wilton", sb_fs=3,
+    )
+    arch.block_types = [
+        make_io_type(index=0, capacity=arch.io_capacity),
+        make_clb_type(index=1, K=arch.K, N=arch.N, I=arch.I,
+                      T_comb=261e-12, T_setup=66e-12, T_clk_to_q=124e-12,
+                      output_equivalent=False),
     ]
     return arch
 

@@ -43,6 +43,52 @@ class SegmentInf:
     # VTR/Titan arch; reference rr_graph.c:432-548 UNI_DIRECTIONAL).
     # The rr builder requires all segments to agree.
     directionality: str = "bidir"
+    # <sb type="pattern">: length + 1 marks, one per switch point from
+    # the wire's start (0) to its end (length); a marked point has a
+    # switch box on the wire.  None = all ones (the published pattern of
+    # every VTR timing arch); "1 0 0 0 1" is a wire that turns at its
+    # ends only.  Read by the unidir rr builder (rr/graph.py: a wire
+    # EXITS at every marked point past its start); both ends are
+    # always marked.
+    sb: Optional[Tuple[int, ...]] = None
+    # <cb type="pattern">: length marks, one per logic block spanned;
+    # only the all-ones pattern (every spanned block taps the wire) is
+    # built, so it is carried for the record and checked, not consulted
+    cb: Optional[Tuple[int, ...]] = None
+
+    def __post_init__(self):
+        L = max(1, int(self.length))
+        for name, want in (("sb", L + 1), ("cb", L)):
+            pat = getattr(self, name)
+            if pat is None:
+                continue
+            pat = tuple(int(bool(v)) for v in pat)
+            setattr(self, name, pat)
+            if len(pat) != want:
+                raise ValueError(
+                    f"segment {self.name!r}: <{name}> pattern has "
+                    f"{len(pat)} marks, a length-{L} wire needs {want}")
+        if self.sb is not None and not (self.sb[0] and self.sb[-1]):
+            raise ValueError(
+                f"segment {self.name!r}: <sb> pattern {self.sb} leaves a "
+                "wire end without a switch box; the rr builder always "
+                "switches at both ends")
+        if self.cb is not None and not all(self.cb):
+            raise ValueError(
+                f"segment {self.name!r}: depopulated <cb> pattern "
+                f"{self.cb} is not built (every spanned block taps the "
+                "wire)")
+        if (self.sb is not None and not all(self.sb)
+                and self.directionality != "unidir"):
+            raise ValueError(
+                f"segment {self.name!r}: <sb> pattern {self.sb} on a "
+                "bidirectional wire is not built (the bidir box gates "
+                "on wire ends, not on a pattern)")
+
+    def sb_marks(self) -> Tuple[int, ...]:
+        """The sb pattern as length + 1 marks (None = all ones)."""
+        L = max(1, int(self.length))
+        return self.sb if self.sb is not None else (1,) * (L + 1)
 
 
 @dataclass
@@ -236,9 +282,15 @@ class Arch:
 def make_clb_type(index: int, K: int, N: int, I: int,
                   T_comb: float = 400e-12,
                   T_setup: float = 60e-12,
-                  T_clk_to_q: float = 80e-12) -> BlockType:
-    """Build a CLB block type: I input pins (one class), N output pins (one
-    class), 1 clock pin.  Mirrors the k6_N10 soft logic cluster."""
+                  T_clk_to_q: float = 80e-12,
+                  output_equivalent: bool = True) -> BlockType:
+    """Build a CLB block type: I input pins (one class), N output pins, 1
+    clock pin.  Mirrors the k6_N10 soft logic cluster.  The outputs are
+    one class of N equivalent pins, or, with ``output_equivalent``
+    false (k6_N10_40nm.xml: ``<output name="O" num_pins="10"
+    equivalent="false"/>``, each BLE drives its own pin), N classes of
+    one pin after the clock's, so a net leaves by the pin the packer
+    gave it and can never take two."""
     num_pins = I + N + 1
     pin_classes = [
         PinClass(PIN_CLASS_RECEIVER, list(range(0, I))),
@@ -246,6 +298,11 @@ def make_clb_type(index: int, K: int, N: int, I: int,
         PinClass(PIN_CLASS_RECEIVER, [I + N], is_clock=True),
     ]
     pin_class_of = [0] * I + [1] * N + [2]
+    if not output_equivalent:
+        pin_classes[1] = PinClass(PIN_CLASS_DRIVER, [I])
+        pin_classes += [PinClass(PIN_CLASS_DRIVER, [I + j])
+                        for j in range(1, N)]
+        pin_class_of = [0] * I + [1] + list(range(3, N + 2)) + [2]
     return BlockType(
         name="clb", index=index, num_pins=num_pins, capacity=1,
         pin_classes=pin_classes, pin_class_of=pin_class_of, is_io=False,

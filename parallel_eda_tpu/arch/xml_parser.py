@@ -79,7 +79,17 @@ def read_arch_xml(path: str) -> Arch:
             dir_attr = a.get("type", "").lower()
             if dir_attr not in ("unidir", "bidir"):
                 dir_attr = "unidir" if mux is not None else "bidir"
+            # <sb>/<cb type="pattern">: kept as data; SegmentInf
+            # refuses what the rr builder cannot realise (wrong
+            # length, an unswitched end, a depopulated cb, a bidir sb)
+            pats = {}
+            for tag in ("sb", "cb"):
+                el = seg.find(tag)
+                if el is not None and (el.text or "").strip():
+                    pats[tag] = tuple(int(float(v))
+                                      for v in el.text.split())
             segments.append(SegmentInf(
+                sb=pats.get("sb"), cb=pats.get("cb"),
                 name=a.get("name", f"seg{len(segments)}"),
                 length=int(float(a.get("length", 1))),
                 frequency=_f(a, "freq", 1.0),

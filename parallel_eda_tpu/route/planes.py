@@ -19,7 +19,9 @@ Every wire relaxation is a structured tensor op:
   * switchbox turns — shifted masked mins between the dx/dy canvases; the
     builder's rotated-subset pattern (CHANX t <-> CHANY (t+1+parity) mod W)
     is literally a jnp.roll along the track axis with a checkerboard parity
-    mask.
+    mask.  Single-driver (unidir) graphs turn by GROUPS of tracks (rr/graph.py
+    "Unidir switch box"): a min over a group's lanes at each corner, a roll
+    over groups at odd corners, a broadcast onto the cells that start there.
   * terminal hops (SOURCE->OPIN->wire, wire->IPIN->SINK) — small per-net
     tables, outside the sweep loop entirely: pins are only ever endpoints
     (OPIN is reachable only from SOURCE, IPIN leads only to SINK), so the
@@ -61,7 +63,7 @@ from flax import struct
 from jax import lax
 
 from ..obs.trace import device_scope
-from ..rr.graph import CHANX, CHANY, RRGraph
+from ..rr.graph import CHANX, CHANY, RRGraph, unidir_exit_point
 from .device_graph import DeviceRRGraph
 from .search import JITTER_EPS, congestion_cost, usage_from_paths
 
@@ -89,7 +91,12 @@ class PlanesGraph:
     brk_after_x: jnp.ndarray
     brk_before_y: jnp.ndarray       # bool [W, NX+1, NY]
     brk_after_y: jnp.ndarray
-    # span endpoint masks (for the endpoint-gated switchbox rule)
+    # span endpoint masks (for the endpoint-gated switchbox rule).
+    # Directional graphs: the mask of a track's DRIVING side -- last_*
+    # on an INC track, first_* on a DEC one -- marks every cell whose
+    # corner on that side is an EXIT of its wire (the wire's sb pattern,
+    # rr/graph.py "Unidir switch box": the true end and whatever the
+    # pattern marks before it); the other mask stays the wire's START
     first_x: jnp.ndarray            # bool: cell is its node's span start
     last_x: jnp.ndarray
     first_y: jnp.ndarray
@@ -111,6 +118,10 @@ class PlanesGraph:
     # per-track INC mask
     directional: bool = struct.field(pytree_node=False, default=False)
     inc_track: Optional[jnp.ndarray] = None     # bool [W]
+    # directional graphs: consecutive tracks that form one turn group
+    # (static; W is a multiple of it): a turn lands on the lane of the
+    # group that STARTS at the corner, whatever its track
+    group_tracks: int = struct.field(pytree_node=False, default=0)
     # longest wire span in grid units (static): the bb-crop margin —
     # a wire INTERSECTING a net's bb can overhang it by max_span-1
     max_span: int = struct.field(pytree_node=False, default=1)
@@ -197,6 +208,25 @@ def build_planes(rr: RRGraph) -> PlanesGraph:
     last_x = rr.xhigh[nx_pl] == xcoord
     first_y = rr.ylow[ny_pl] == ycoord
     last_y = rr.yhigh[ny_pl] == ycoord
+    if rr.unidir:
+        # the driving side's mask takes the wire's exits: an INC cell
+        # at position p exits on corner p, a DEC cell on corner p - 1
+        tr = np.arange(W)[:, None, None]
+        dec = tr % 2 == 1
+        L = rr.seg_len_of_track.astype(np.int64)[:, None, None]
+
+        def exits(pl, coord, lo, hi):
+            k = unidir_exit_point(dec, L, (tr // 2) % L,
+                                  coord - dec, lo[pl].astype(np.int64),
+                                  hi[pl].astype(np.int64))
+            return rr.sb_of_track[tr, k] & (k > 0)
+
+        ex = exits(nx_pl, xcoord, rr.xlow, rr.xhigh)
+        ey = exits(ny_pl, ycoord, rr.ylow, rr.yhigh)
+        last_x, first_x = np.where(dec, last_x, ex), np.where(dec, ex,
+                                                              first_x)
+        last_y, first_y = np.where(dec, last_y, ey), np.where(dec, ey,
+                                                              first_y)
 
     # enter-delay planes: Tdel[sw] + C[node]*(R[sw] + R[node]/2) — the
     # exact in_delay formula of the builder (rr/graph.py in_delay)
@@ -227,6 +257,7 @@ def build_planes(rr: RRGraph) -> PlanesGraph:
         delay_y_rot0=j(delay_y_rot0), delay_y_rot1=j(delay_y_rot1),
         directional=rr.unidir,
         inc_track=(j(rr.dir_of_track == 0) if rr.unidir else None),
+        group_tracks=int(rr.group_tracks) if rr.unidir else 0,
         max_span=int(max(
             (rr.xhigh[is_x] - rr.xlow[is_x] + 1).max(initial=1),
             (rr.yhigh[is_y] - rr.ylow[is_y] + 1).max(initial=1))),
@@ -484,6 +515,7 @@ class PlanesGeom:
     stride_x: int = struct.field(pytree_node=False, default=0)  # global NY+1
     directional: bool = struct.field(pytree_node=False, default=False)
     inc_track: Optional[jnp.ndarray] = None     # bool [W] (shared)
+    group_tracks: int = struct.field(pytree_node=False, default=0)
 
     @property
     def shape_x(self):
@@ -514,7 +546,7 @@ def geom_full(pg: PlanesGraph) -> PlanesGeom:
         delay_y_rot1=pg.delay_y_rot1[None],
         idxx=idxx, idxy=idxy, base_par=base_par,
         stride_x=NYp1, directional=pg.directional,
-        inc_track=pg.inc_track)
+        inc_track=pg.inc_track, group_tracks=pg.group_tracks)
 
 
 def geom_cropped(pg: PlanesGraph, ox, oy, cnx: int, cny: int,
@@ -555,7 +587,54 @@ def geom_cropped(pg: PlanesGraph, ox, oy, cnx: int, cny: int,
         idxy=crop(full.idxy, cnx + 1, cny),
         base_par=crop2(full.base_par, cnx + 1, cny + 1),
         stride_x=NYp1, directional=pg.directional,
-        inc_track=pg.inc_track)
+        inc_track=pg.inc_track, group_tracks=pg.group_tracks)
+
+
+def _group_corner_min(gm: PlanesGeom, src, idx, axis: int, shift: int):
+    """Unidir turns, the source half: ``src`` [B, W, NX+2, NY+2] is a
+    padded canvas of one plane, INF wherever a cell has no exit, with
+    positions as indices along ``axis``; ``idx`` its global cell ids.
+    Returns, per corner and turn GROUP, the cheapest wire exiting there
+    -- an INC track's cell at the corner's own position, a DEC track's
+    one further -- as (value [B, G, NX+1, NY+1], source cell), read
+    from group g - ``shift`` at odd corners (rr/graph.py
+    ``unidir_turn_group``): one fold over a group's lanes and one roll
+    over groups, no gather over tracks."""
+    W = src.shape[1]
+    gt = gm.group_tracks
+    n = src.shape[axis] - 1
+    inc = gm.inc_track[:, None, None]
+
+    def at_corner(a):
+        lo = lax.slice_in_dim(a, 0, n, axis=axis)
+        hi = lax.slice_in_dim(a, 1, n + 1, axis=axis)
+        other = 5 - axis
+        m = a.shape[other] - 1
+        a = jnp.where(inc, lax.slice_in_dim(lo, 0, m, axis=other),
+                      lax.slice_in_dim(hi, 0, m, axis=other))
+        return a.reshape(a.shape[0], W // gt, gt, *a.shape[2:])
+
+    v, i = at_corner(src), at_corner(idx)
+    best, bi = v[:, :, 0], jnp.broadcast_to(i[:, :, 0], v[:, :, 0].shape)
+    for lane in range(1, gt):
+        better = v[:, :, lane] < best
+        best = jnp.where(better, v[:, :, lane], best)
+        bi = jnp.where(better, i[:, :, lane], bi)
+    odd = (gm.base_par == 1)[:, None]
+    return (jnp.where(odd, jnp.roll(best, shift, axis=1), best),
+            jnp.where(odd, jnp.roll(bi, shift, axis=1), bi))
+
+
+def _group_to_tracks(gm: PlanesGeom, for_inc, for_dec):
+    """Unidir turns, the target half: per-group corner values [B, G,
+    X, Y], one view aligned to the cells whose INC wire starts at the
+    corner and one to the DEC ones, spread onto every track of the
+    group ([B, W, X, Y]; track = (group, lane, direction))."""
+    B, G, X, Y = for_inc.shape
+    lanes = gm.group_tracks // 2
+    a = jnp.stack([for_inc, for_dec], axis=2)[:, :, None]
+    return jnp.broadcast_to(a, (B, G, lanes, 2, X, Y)).reshape(
+        B, G * lanes * 2, X, Y)
 
 
 def _turn_triples_into_y(gm: PlanesGeom, dx, crit_c, cc_y):
@@ -578,10 +657,29 @@ def _turn_triples_into_y(gm: PlanesGeom, dx, crit_c, cc_y):
         c = jnp.full((a.shape[0], W, NX + 2, NY + 2), fill, a.dtype)
         return c.at[:, :, 1:NX + 1, 0:NY + 1].set(a)
 
+    ix = canvas_x(gm.idxx, jnp.int32(0))            # [G, W, NX+2, NY+2]
+
+    if gm.directional:
+        # unidir: every chanx wire that EXITS at corner (x, y) -- an INC
+        # cell at position x, a DEC cell at x + 1 -- drives the chany
+        # wires that START there in its group, or the next group at an
+        # odd corner: INC starts at first_y (the corner below, b=1),
+        # DEC at last_y (b=0).  Target's switch throughout (delay_y).
+        exit_x = jnp.where(gm.inc_track[:, None, None], gm.last_x,
+                           gm.first_x)
+        cv, ci = _group_corner_min(
+            gm, canvas_x(jnp.where(exit_x, dx, INF), INF), ix,
+            axis=2, shift=1)
+        tv, ts = (_group_to_tracks(gm, a[..., 0:NY], a[..., 1:NY + 1])
+                  for a in (cv, ci))
+        start_y = jnp.where(gm.inc_track[:, None, None], gm.first_y,
+                            gm.last_y)
+        cand = jnp.where(start_y, tv, INF) + crit_c * gm.delay_y + cc_y
+        return cand, ts, jnp.broadcast_to(gm.delay_y, cand.shape)
+
     cx_all = canvas_x(dx, INF)
     cx_last = canvas_x(jnp.where(gm.last_x, dx, INF), INF)
     cx_first = canvas_x(jnp.where(gm.first_x, dx, INF), INF)
-    ix = canvas_x(gm.idxx, jnp.int32(0))            # [G, W, NX+2, NY+2]
 
     best = jnp.full((B, W, NX + 1, NY), INF, dx.dtype)
     bsrc = jnp.zeros((B, W, NX + 1, NY), jnp.int32)
@@ -592,41 +690,6 @@ def _turn_triples_into_y(gm: PlanesGeom, dx, crit_c, cc_y):
         return (jnp.where(better, cand, best),
                 jnp.where(better, src, bsrc),
                 jnp.where(better, w, bw))
-
-    if gm.directional:
-        # unidir (single-driver): the edge exists iff the SOURCE's
-        # driving end is on the corner AND the TARGET starts there —
-        # an AND of directed gates replaces the bidir endpoint OR.
-        # INC chanx drives from last_x, DEC from first_x; INC chany
-        # starts at first_y (corner below, b=1), DEC at last_y (b=0).
-        # All edges use the target's switch (delay_y).
-        inc = gm.inc_track[:, None, None]
-        cx_src_inc = canvas_x(jnp.where(gm.last_x & inc, dx, INF), INF)
-        cx_src_dec = canvas_x(jnp.where(gm.first_x & ~inc, dx, INF), INF)
-        tgt_of_b = (gm.last_y & ~inc, gm.first_y & inc)
-        for b_off in (0, 1):
-            tgt_gate = tgt_of_b[b_off]
-            par = gm.base_par[:, :, 1 - b_off:1 - b_off + NY]
-            for a_off in (0, 1):
-                src_c = cx_src_inc if a_off == 0 else cx_src_dec
-                sl = (slice(None), slice(None),
-                      slice(a_off, a_off + NX + 1),
-                      slice(1 - b_off, 1 - b_off + NY))
-                cand = jnp.where(tgt_gate, src_c[sl], INF)
-                cand = cand + crit_c * gm.delay_y + cc_y
-                best, bsrc, bw = fold(best, bsrc, bw, cand,
-                                      ix[sl], gm.delay_y)
-                for p in (0, 1):
-                    if (1 + p) % W == 0:
-                        continue
-                    r_src = jnp.roll(src_c, 1 + p, axis=1)[sl]
-                    r_i = jnp.roll(ix, 1 + p, axis=1)[sl]
-                    cand = jnp.where(tgt_gate, r_src, INF)
-                    cand = cand + crit_c * gm.delay_y + cc_y
-                    cand = jnp.where(par[:, None] == p, cand, INF)
-                    best, bsrc, bw = fold(best, bsrc, bw, cand, r_i,
-                                          gm.delay_y)
-        return best, bsrc, bw
 
     for b_off in (0, 1):
         tgt_gate = gm.last_y if b_off == 0 else gm.first_y
@@ -671,10 +734,28 @@ def _turn_triples_into_x(gm: PlanesGeom, dy, crit_c, cc_x):
         c = jnp.full((a.shape[0], W, NX + 2, NY + 2), fill, a.dtype)
         return c.at[:, :, 0:NX + 1, 1:NY + 1].set(a)
 
+    iy = canvas_y(gm.idxy, jnp.int32(0))            # [G, W, NX+2, NY+2]
+
+    if gm.directional:
+        # unidir mirror: chany wires that exit at corner (x, y) -- INC
+        # at position y, DEC at y + 1 -- drive the chanx wires starting
+        # there in their group, or the previous group at an odd corner:
+        # INC starts at first_x (the corner to its left), DEC at last_x
+        exit_y = jnp.where(gm.inc_track[:, None, None], gm.last_y,
+                           gm.first_y)
+        cv, ci = _group_corner_min(
+            gm, canvas_y(jnp.where(exit_y, dy, INF), INF), iy,
+            axis=3, shift=-1)
+        tv, ts = (_group_to_tracks(gm, a[:, :, 0:NX], a[:, :, 1:NX + 1])
+                  for a in (cv, ci))
+        start_x = jnp.where(gm.inc_track[:, None, None], gm.first_x,
+                            gm.last_x)
+        cand = jnp.where(start_x, tv, INF) + crit_c * gm.delay_x + cc_x
+        return cand, ts, jnp.broadcast_to(gm.delay_x, cand.shape)
+
     cy_all = canvas_y(dy, INF)
     cy_last = canvas_y(jnp.where(gm.last_y, dy, INF), INF)
     cy_first = canvas_y(jnp.where(gm.first_y, dy, INF), INF)
-    iy = canvas_y(gm.idxy, jnp.int32(0))            # [G, W, NX+2, NY+2]
 
     best = jnp.full((B, W, NX, NY + 1), INF, dy.dtype)
     bsrc = jnp.zeros((B, W, NX, NY + 1), jnp.int32)
@@ -685,39 +766,6 @@ def _turn_triples_into_x(gm: PlanesGeom, dy, crit_c, cc_x):
         return (jnp.where(better, cand, best),
                 jnp.where(better, src, bsrc),
                 jnp.where(better, w, bw))
-
-    if gm.directional:
-        # unidir mirror: INC chany drives from last_y (b=0, below the
-        # corner), DEC from first_y (b=1); INC chanx starts at first_x
-        # (corner left, a=1), DEC at last_x (a=0).  Target switch
-        # throughout (delay_x, matching the builder's mux-at-start rule).
-        inc = gm.inc_track[:, None, None]
-        cy_src_inc = canvas_y(jnp.where(gm.last_y & inc, dy, INF), INF)
-        cy_src_dec = canvas_y(jnp.where(gm.first_y & ~inc, dy, INF), INF)
-        tgt_of_a = (gm.last_x & ~inc, gm.first_x & inc)
-        for a_off in (0, 1):
-            tgt_gate = tgt_of_a[a_off]
-            par = gm.base_par[:, 1 - a_off:1 - a_off + NX, :]
-            for b_off in (0, 1):
-                src_c = cy_src_inc if b_off == 0 else cy_src_dec
-                sl = (slice(None), slice(None),
-                      slice(1 - a_off, 1 - a_off + NX),
-                      slice(b_off, b_off + NY + 1))
-                cand = jnp.where(tgt_gate, src_c[sl], INF)
-                cand = cand + crit_c * gm.delay_x + cc_x
-                best, bsrc, bw = fold(best, bsrc, bw, cand,
-                                      iy[sl], gm.delay_x)
-                for p in (0, 1):
-                    if (1 + p) % W == 0:
-                        continue
-                    r_src = jnp.roll(src_c, -(1 + p), axis=1)[sl]
-                    r_i = jnp.roll(iy, -(1 + p), axis=1)[sl]
-                    cand = jnp.where(tgt_gate, r_src, INF)
-                    cand = cand + crit_c * gm.delay_x + cc_x
-                    cand = jnp.where(par[:, None] == p, cand, INF)
-                    best, bsrc, bw = fold(best, bsrc, bw, cand, r_i,
-                                          gm.delay_x)
-        return best, bsrc, bw
 
     for a_off in (0, 1):
         tgt_gate = gm.last_x if a_off == 0 else gm.first_x
@@ -1224,9 +1272,10 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
     iteration and the device skips the clean ones.
 
     Returns (paths, sink_delay, all_reached, bb, occ, n_active, st):
-    ``st`` [4] int32 is the step's ledger — relaxation sweeps executed,
+    ``st`` [5] int32 is the step's ledger — relaxation sweeps executed,
     sweeps that improved a distance, traceback walk steps run, walk
-    steps budgeted — in the order of scal's SCAL_S_EXEC.. tail."""
+    steps budgeted, waves executed (one relaxation each) — in the
+    order of scal's SCAL_S_EXEC.. tail."""
     N = dev.num_nodes
     R = paths.shape[0]
     B = sel.shape[0]
@@ -1476,8 +1525,10 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             cur, _, cells_w, nodes_w, wst, wsteps = traceback_walk(
                 pred, wenter, noc_p1, pick_cell,
                 ~pick_valid | pick_direct, Kw)
-            # the ledger's walk half: steps this wave ran, of its budget
-            st = st.at[2:].add(jnp.stack([wsteps, jnp.int32(Kw)]))
+            # the ledger's walk half: steps this wave ran, of its
+            # budget, and the wave itself
+            st = st.at[2:].add(jnp.stack([wsteps, jnp.int32(Kw),
+                                          jnp.int32(1)]))
             # a walk is complete iff it reached a pred==self cell in budget
             nxt_last = jnp.take_along_axis(
                 pred, jnp.clip(cur, 0, ncells - 1), axis=1)
@@ -1580,7 +1631,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                   jnp.full((B, S, max_len), N, jnp.int32),
                   jnp.full((B, S), INF, jnp.float32),
                   jnp.zeros((B, S), bool),
-                  jnp.zeros((4,), jnp.int32))
+                  jnp.zeros((5,), jnp.int32))
     (_, _, _, _, p, delay, reached, st) = lax.fori_loop(
         0, num_waves, wave_body, state0)
 
@@ -1749,8 +1800,9 @@ def _window_body(
     steps_useful are the MEASURED relaxation-sweep counters summed over
     every executed group/wave of the window (executed trips of the
     bounded while_loop, and the subset that improved some distance);
-    scal's last two entries are the traceback walk's ledger summed the
-    same way (steps run, and the Kw budgeted per executed wave);
+    scal's last three entries are the traceback walk's ledger summed
+    the same way (steps run, and the Kw budgeted per executed wave) and
+    the executed waves themselves;
     ``status``/``scal`` repack the per-net mask/color/bb fields and the
     scalar counters into two small int32 arrays so the pipelined driver
     can pull the whole window summary with one async copy
@@ -1830,7 +1882,7 @@ def _window_body(
         (occ, acc, paths, sink_delay, all_reached, bb, pres0,
          jnp.int32(0), jnp.int32(0), crit_all,
          jnp.full(K_iters, jnp.nan, jnp.float32),
-         jnp.zeros((4,), jnp.int32)))
+         jnp.zeros((5,), jnp.int32)))
     s_exec, s_useful = led[0], led[1]
 
     rrm, colors = _mis_colors(dev, occ, paths, all_reached,
@@ -2121,11 +2173,12 @@ SCAL_OVER_TOTAL = 1
 SCAL_NROUTES = 2
 SCAL_NEXEC = 3
 SCAL_MAX_SPAN = 4
-SCAL_S_EXEC = 5       # 5..8: _step_core's ledger vector, in its order
+SCAL_S_EXEC = 5       # 5..9: _step_core's ledger vector, in its order
 SCAL_S_USEFUL = 6
 SCAL_WALK_STEPS = 7
 SCAL_WALK_BUDGET = 8
-SCAL_LEN = 9
+SCAL_WAVES = 9
+SCAL_LEN = 10
 
 
 def unpack_window_status(status):

@@ -254,6 +254,7 @@ def _sweep_kernel(pg_template: PlanesGraph, nsweeps: int, G: int,
         idxx=idxx, idxy=idxy, base_par=base_par, stride_x=NYp1,
         directional=pg_template.directional,
         inc_track=(inc_ref[:] != 0 if pg_template.directional else None),
+        group_tracks=pg_template.group_tracks,
     )
 
     dx = _load_packed(dx_ref, G, shx, pad_yx)
@@ -422,7 +423,7 @@ def planes_relax_pallas(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
     return flat(dx, dy), flat(px, py), flat(wx, wy), bstats
 
 
-def _crop_sweep_kernel(directional: bool, stride_x: int, nsweeps: int,
+def _crop_sweep_kernel(group_tracks: int, stride_x: int, nsweeps: int,
                        G: int, shx, shy, pad_yx: int, pad_yy: int,
                        geo_meta, plane_dtype, *refs):
     """One grid step = a BLOCK of G nets' bb TILES, whole nsweeps loop
@@ -447,8 +448,9 @@ def _crop_sweep_kernel(directional: bool, stride_x: int, nsweeps: int,
         delay_x=delx, delay_y=dely,
         delay_y_rot0=delr0, delay_y_rot1=delr1,
         idxx=idxx, idxy=idxy, base_par=par, stride_x=stride_x,
-        directional=directional,
-        inc_track=(inc_ref[:] != 0 if directional else None),
+        directional=group_tracks > 0,
+        inc_track=(inc_ref[:] != 0 if group_tracks else None),
+        group_tracks=group_tracks,
     )
     dx = _load_packed(dx_ref, G, shx, pad_yx)
     dy = _load_packed(dy_ref, G, shy, pad_yy)
@@ -572,7 +574,7 @@ def planes_relax_cropped_pallas(pg: PlanesGraph, d0_flat, cc_flat,
                  rowspec(rx), rowspec(ry),
                  pl.BlockSpec((1, 2), lambda b: (b, 0))]
 
-    kern = functools.partial(_crop_sweep_kernel, pg.directional, NYp1,
+    kern = functools.partial(_crop_sweep_kernel, pg.group_tracks, NYp1,
                              nsweeps, G, shx, shy, pyx, pyy, geo_meta,
                              plane_dtype)
     dx, dy, px, py, wx, wy, stats = pl.pallas_call(

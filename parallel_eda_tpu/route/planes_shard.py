@@ -262,7 +262,8 @@ def _geom_blocks(pg: PlanesGraph, s: int, kx: int) -> PlanesGeom:
         stride_x=NYp1, directional=pg.directional,
         inc_track=(jnp.broadcast_to(pg.inc_track,
                                     (s,) + pg.inc_track.shape)
-                   if pg.inc_track is not None else None))
+                   if pg.inc_track is not None else None),
+        group_tracks=pg.group_tracks)
 
 
 def planes_relax_sharded(pg: PlanesGraph, d0_flat, cc_flat, crit_c,

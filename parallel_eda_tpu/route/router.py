@@ -315,6 +315,9 @@ class RouteResult:
     # wave).  A share near 1 means paths are pressing on the budget.
     total_walk_steps: int = 0
     total_walk_budget: int = 0
+    # executed waves of the windowed planes program (one relaxation to
+    # a fixpoint each): total_relax_steps over it is sweeps a wave
+    total_waves: int = 0
     # nets whose bb was widened to the full device (left the windowed
     # program; 0 on a healthy windowed run of a routable circuit)
     widened_nets: int = 0
@@ -1247,7 +1250,7 @@ class Router:
         mutations (pres, plateau state, widened_nets) cannot leak in."""
         from .planes import (SCAL_NEXEC, SCAL_NROUTES, SCAL_S_EXEC,
                              SCAL_S_USEFUL, SCAL_WALK_BUDGET,
-                             SCAL_WALK_STEPS)
+                             SCAL_WALK_STEPS, SCAL_WAVES)
 
         w_steps = w_useful = w_steps_crop = 0
         nroutes = nexec = 0
@@ -1261,6 +1264,7 @@ class Router:
             w_useful += int(v[SCAL_S_USEFUL])
             result.total_walk_steps += int(v[SCAL_WALK_STEPS])
             result.total_walk_budget += int(v[SCAL_WALK_BUDGET])
+            result.total_waves += int(v[SCAL_WAVES])
             if cropped:
                 w_steps_crop += int(v[SCAL_S_EXEC])
             if mesh_info is not None and mesh_info[0] > 1 \

@@ -24,20 +24,22 @@ UNI_DIRECTIONAL vs BI_DIRECTIONAL segment split):
   * unidir (every modern VTR/Titan arch): tracks pair by parity —
     even = INC (left->right / bottom->top), odd = DEC — and every wire
     has a SINGLE DRIVER at its start: OPINs and switchbox muxes connect
-    only where a wire STARTS, wire->wire edges go from a wire's driving
-    end to a wire starting at that corner (mux switch of the TARGET
-    segment), and only IPIN taps stay span-wide.  W is rounded up to
-    even.
+    only where a wire STARTS (mux switch of the TARGET segment), and
+    only IPIN taps stay span-wide.  W is rounded up to a multiple of
+    twice the longest segment (even, and whole turn groups).  The box
+    is build_rr_graph's "Unidir switch box".
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..arch.model import Arch, PIN_CLASS_DRIVER, PIN_CLASS_RECEIVER
+from ..obs import get_metrics, span
 from .grid import DeviceGrid
 
 # rr-node types (order matches files.py writers and the reference's t_rr_type)
@@ -90,6 +92,12 @@ class RRGraph:
     wire_switch_of_track: Optional[np.ndarray] = None  # int32 [W]
     # unidir graphs: per-track direction (0 = INC, 1 = DEC); None = bidir
     dir_of_track: Optional[np.ndarray] = None       # int32 [W]
+    # unidir graphs, the switch box's data ("Unidir switch box",
+    # build_rr_graph): per-track segment length and sb marks, and the
+    # number of consecutive tracks that form a turn group
+    seg_len_of_track: Optional[np.ndarray] = None   # int32 [W]
+    sb_of_track: Optional[np.ndarray] = None        # bool [W, Lmax + 1]
+    group_tracks: int = 0
 
     @property
     def unidir(self) -> bool:
@@ -121,9 +129,128 @@ def _fc_tracks(pin_ptc: int, side: int, W: int, fc: float) -> List[int]:
     return [ (start + (j * W) // fc_abs) % W for j in range(fc_abs) ]
 
 
+def _adjacent_channels(grid: DeviceGrid, x: int, y: int):
+    """The channels tile (x, y) faces, as (kind, channel index, position
+    along it): a CHANX adjacency is ('x', y_chan, x), a CHANY one
+    ('y', x_chan, y).  A cluster faces four, a pad tile the one beside
+    the perimeter."""
+    nx, ny = grid.nx, grid.ny
+    if grid.is_clb(x, y):
+        return [("x", y, x), ("x", y - 1, x), ("y", x, y), ("y", x - 1, y)]
+    if x == 0:                            # left IO
+        return [("y", 0, y)]
+    if x == nx + 1:                       # right IO
+        return [("y", nx, y)]
+    if y == 0:                            # bottom IO
+        return [("x", 0, x)]
+    if y == ny + 1:                       # top IO
+        return [("x", ny, x)]
+    return []
+
+
+def _spread_picks(n: int, f: int, phase: int) -> List[int]:
+    """f of n candidates for the pin-and-side of ordinal ``phase``
+    (unidir graphs): evenly spaced, offset in units of 1/f of a
+    candidate by a golden-ratio stride coprime to n * f, so that n * f
+    successive pins differ in offset or in rounding and no stride of
+    pins collapses onto one set (``_fc_tracks``' stride 7 gives every
+    pin of a side the same tracks when 7 divides W, and pins 8 apart
+    the same starts when 16 start)."""
+    m = n * f
+    k = int(0.6180339887 * m) | 1
+    while math.gcd(k, m) != 1:
+        k += 2
+    o = (phase * k) % m
+    return [((j * n + o) // f) % n for j in range(f)]
+
+
+def unidir_exit_point(dec, seg_len, stag, c, lo, hi):
+    """Which switch point (1..L) of a single-driver wire lies on the
+    corner of coordinate c, 0 if the wire has no exit there ("Unidir
+    switch box", build_rr_graph).  Everything broadcasts: ``dec`` the
+    track's direction, ``seg_len`` its segment's length L, ``stag`` its
+    stagger, ``lo`` and ``hi`` the wire's span along its channel.  A
+    corner has the coordinate of the position on its low side; an INC
+    wire exits on the high side of each of its positions, a DEC wire on
+    the low side.  Points count from the wire's start, by the track's
+    stagger (a wire the device's edge cut short keeps the count of the
+    whole wire); the point where the wire physically ends is point L."""
+    L = np.asarray(seg_len)
+    k_inc = np.where(c == hi, L, (c - stag - 1) % L + 1)
+    k_dec = np.where(c == lo - 1, L, L - (c - stag) % L)
+    on = np.where(dec, (c >= lo - 1) & (c <= hi - 1),
+                  (c >= lo) & (c <= hi))
+    return np.where(on, np.where(dec, k_dec, k_inc), 0)
+
+
+def unidir_turn_group(grp, into_y: bool, par, n_grp: int):
+    """The group of tracks on which a turn out of group ``grp`` lands
+    at a corner of parity ``par`` = (x + y) % 2: its own at an even
+    corner; at an odd one the next group (cyclic) for a turn from CHANX
+    into CHANY and the previous for a turn from CHANY into CHANX, so an
+    X -> Y -> X detour nets -1, 0 or +1 by the corners it picks
+    ("Unidir switch box", build_rr_graph)."""
+    return (grp + (par if into_y else -par)) % n_grp
+
+
 def build_rr_graph(arch: Arch, grid: DeviceGrid,
                    chan_width: Optional[int] = None) -> RRGraph:
-    """Build the full rr-graph (semantics of rr_graph.c:385 build_rr_graph)."""
+    """Build the full rr-graph (semantics of rr_graph.c:385 build_rr_graph).
+
+    Runs under the span ``rr.build``; a unidir graph sets the gauges
+    ``rr.exit_turns_min`` and ``rr.opin_starts_min`` (``unidir_box_stats``).
+
+    **Unidir switch box** (single-driver segments; the one statement of
+    the rule: ``route/planes.py``'s directional stencils and
+    ``tests/test_unidir.py``'s plain-Python rule set follow it).
+
+    * Lanes and groups.  Tracks 2q (INC) and 2q + 1 (DEC) are lane q;
+      lane q's span breaks stagger by q % L (L its segment's length).
+      ``group_tracks`` = 2 * Lmax consecutive tracks are a GROUP, g =
+      t // group_tracks: with one segment type, each whole group has
+      exactly one INC and one DEC wire STARTING at every corner of
+      every channel (all its wires of a direction start where the
+      device's edge cuts them).
+    * Exits.  A wire is driven only at its start (OPINs of the blocks
+      there, and the muxes of this box).  It EXITS at every switch
+      point k = 1..L its segment's ``sb`` pattern marks, counted from
+      its start (``unidir_exit_point``); its physical end is point L
+      and always marked; point 0, its own mux, is no exit.  ``sb`` all
+      ones is the published pattern; ``1 0 0 0 1`` turns at the end
+      only.
+    * Targets (Fs = 3).  An exit at corner (x, y), parity p = (x + y)
+      % 2, from a CHANX wire of group g drives every CHANY wire that
+      STARTS at the corner in group (g + p) % G -- one going up, one
+      going down -- and, only where the wire ends, the wire of its own
+      track that starts there (straight on).  From a CHANY wire: the
+      CHANX starters of group (g - p) % G, and straight on at the end
+      (``unidir_turn_group``).  Which track a target has inside its
+      group does not matter: it is the group's lane that starts there.
+      On the planes that is a min over a group's lanes, a roll over
+      groups at odd corners, and a broadcast onto the start cells.
+    * Pins.  An OPIN drives Fc_out x W of the wires that START at its
+      position in each channel it faces, an IPIN hears Fc_in x W of the
+      wires passing it, both spread by ``_spread_picks``.
+
+    Wilton's permutation is not built: this box is the repo's own Fs=3.
+    """
+    with span("rr.build", cat="rr") as sp:
+        rr = _build_rr_graph(arch, grid, chan_width)
+        is_wire = (rr.node_type == CHANX) | (rr.node_type == CHANY)
+        sp.set(unidir=rr.unidir, W=int(rr.chan_width),
+               max_span=int(np.maximum(
+                   rr.xhigh - rr.xlow, rr.yhigh - rr.ylow)[is_wire].max(
+                       initial=0)) + 1,
+               nodes=rr.num_nodes, edges=rr.num_edges)
+        if rr.unidir:
+            exit_min, opin_min = unidir_box_stats(rr)
+            get_metrics().set_gauges({"rr.exit_turns_min": exit_min,
+                                      "rr.opin_starts_min": opin_min})
+    return rr
+
+
+def _build_rr_graph(arch: Arch, grid: DeviceGrid,
+                    chan_width: Optional[int] = None) -> RRGraph:
     W = chan_width or arch.default_chan_width
     nx, ny = grid.nx, grid.ny
     num_seg = len(arch.segments)
@@ -134,21 +261,25 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
 
         warnings.warn(
             f"arch requests switch_block type={arch.sb_type!r} "
-            f"fs={arch.sb_fs}; this builder implements its co-designed "
-            "subset+rotated pattern (same O(W) switch count, the Wilton "
-            "index-permutation property via parity-rotated turns — "
-            "rr/graph.py switch-box notes).  Connectivity is a superset "
-            "of subset and QoR-equivalent in the committed gates, but "
-            "track-level topology will differ from VPR's "
-            f"{arch.sb_type} box.")
+            f"fs={arch.sb_fs}; this builder implements its own Fs=3 "
+            "boxes, co-designed with the planes kernel (bidir: subset + "
+            "parity-rotated turns; unidir: build_rr_graph's \"Unidir "
+            "switch box\").  Same O(W) switch count and the "
+            "index-permutation property, but track-level topology "
+            f"differs from VPR's {arch.sb_type} box.")
 
     dirs = {s.directionality for s in arch.segments}
     if len(dirs) > 1:
         raise ValueError(f"segments mix directionalities {dirs}; the rr "
                          f"builder requires one mode (rr_graph.c:432)")
     unidir = dirs == {"unidir"}
-    if unidir and W % 2:
-        W += 1          # unidir tracks pair INC/DEC; VPR forces even W
+    if unidir:
+        # unidir tracks pair INC/DEC (VPR forces even W) and turn by
+        # whole groups of Lmax lanes ("Unidir switch box"): W rounds up
+        # to a multiple of 2 * Lmax, so no group lacks a lane that
+        # starts at some corner
+        group_tracks = 2 * max(max(1, s.length) for s in arch.segments)
+        W = -(-W // group_tracks) * group_tracks
 
     # segment type per track: frequency-proportional contiguous blocks
     # (unidir: assigned per INC/DEC track PAIR so both directions of a
@@ -164,6 +295,19 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
         lo = hi
     seg_assign[lo:] = num_seg - 1
     seg_of_track = np.repeat(seg_assign, 2) if unidir else seg_assign
+    seg_len_of_track = np.array([max(1, arch.segments[s].length)
+                                 for s in seg_of_track], dtype=np.int64)
+    # stagger of a track's span breaks.  Unidir: by LANE (the INC/DEC
+    # track pair), so the wire starts of each direction spread over all
+    # positions (t % L would give every INC track the same phase,
+    # leaving whole columns with no drive point)
+    stag_of_track = ((np.arange(W) // 2) if unidir
+                     else np.arange(W)) % seg_len_of_track
+    if unidir:
+        sb_of_track = np.zeros((W, group_tracks // 2 + 1), dtype=bool)
+        for t in range(W):
+            m = arch.segments[seg_of_track[t]].sb_marks()
+            sb_of_track[t, :len(m)] = m
 
     def type_at(x: int, y: int):
         """Block type on tile (x, y), or None (corner/empty).  Interior
@@ -237,17 +381,11 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
                 a = p + 1
         return spans
 
-    def stagger(t: int, L: int) -> int:
-        # unidir: stagger by LANE PAIR so wire starts of each direction
-        # spread over all positions (t % L would give every INC track
-        # the same phase, leaving whole columns with no drive point)
-        return ((t // 2) % L) if unidir else (t % L)
-
     for y in range(ny + 1):
         for t in range(W):
             seg = arch.segments[seg_of_track[t]]
             L = max(1, seg.length)
-            for (a, b) in wire_spans(1, nx, L, stagger(t, L)):
+            for (a, b) in wire_spans(1, nx, L, int(stag_of_track[t])):
                 span = b - a + 1
                 node = add_node(CHANX, a, y, b, y, t, 1,
                                 seg.Rmetal * span, seg.Cmetal * span,
@@ -257,7 +395,7 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
         for t in range(W):
             seg = arch.segments[seg_of_track[t]]
             L = max(1, seg.length)
-            for (a, b) in wire_spans(1, ny, L, stagger(t, L)):
+            for (a, b) in wire_spans(1, ny, L, int(stag_of_track[t])):
                 span = b - a + 1
                 node = add_node(CHANY, x, a, x, b, t, 1,
                                 seg.Rmetal * span, seg.Cmetal * span,
@@ -300,23 +438,6 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
                             add_edge(ipin_of[(x, y, z, p)], snk, delayless)
 
     # ---- pin <-> channel edges ----
-    # adjacent channels of tile (x,y): list of (kind, chan_idx, row_idx, pos)
-    # where a CHANX adjacency is ('x', y_chan, x) and CHANY is ('y', x_chan, y)
-    def adjacent_channels(x: int, y: int):
-        adj = []
-        if grid.is_clb(x, y):
-            adj = [("x", y, x), ("x", y - 1, x),
-                   ("y", x, y), ("y", x - 1, y)]
-        elif x == 0:                      # left IO
-            adj = [("y", 0, y)]
-        elif x == nx + 1:                 # right IO
-            adj = [("y", nx, y)]
-        elif y == 0:                      # bottom IO
-            adj = [("x", 0, x)]
-        elif y == ny + 1:                 # top IO
-            adj = [("x", ny, x)]
-        return adj
-
     def starting_tracks(kind: str, ci: int, pos: int) -> List[int]:
         """Unidir: tracks whose wire STARTS at this channel position (the
         only legal drive points; INC starts at its low end, DEC at its
@@ -340,7 +461,7 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
             bt = type_at(x, y)
             if bt is None:
                 continue
-            adj = adjacent_channels(x, y)
+            adj = _adjacent_channels(grid, x, y)
             for z in range(bt.capacity):
                 for p in range(bt.num_pins):
                     k = bt.pin_class_of[p]
@@ -350,25 +471,28 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
                     fc = arch.fc_frac(W, is_out, type_name=bt.name, pin=p)
                     pin_ptc = z * bt.num_pins + p
                     for side, (kind, ci, pos) in enumerate(adj):
-                        if unidir and is_out:
-                            # single-driver wires: OPINs drive only wire
-                            # STARTS; spread Fc over the start set
-                            cands = starting_tracks(kind, ci, pos)
+                        if unidir:
+                            # single-driver wires: an OPIN drives only
+                            # wires that START at its position, an IPIN
+                            # hears any wire passing it; Fc of W, spread
+                            # over the candidates
+                            cands = (starting_tracks(kind, ci, pos)
+                                     if is_out else list(range(W)))
                             if not cands:
                                 continue
                             fc_abs = min(len(cands),
                                          max(1, int(round(fc * W))))
-                            st = (pin_ptc * 7 + side * 3) % len(cands)
-                            picks = {cands[(st + (j * len(cands))
-                                            // fc_abs) % len(cands)]
-                                     for j in range(fc_abs)}
-                            for t in sorted(picks):
-                                wire = (chanx_wire[ci][t, pos]
-                                        if kind == "x"
-                                        else chany_wire[ci][t, pos])
-                                sw = arch.segments[
-                                    seg_of_track[t]].opin_switch
-                                add_edge(node, int(wire), sw)
+                            for i in _spread_picks(len(cands), fc_abs,
+                                                   4 * pin_ptc + side):
+                                t = cands[i]
+                                wire = int(chanx_wire[ci][t, pos]
+                                           if kind == "x"
+                                           else chany_wire[ci][t, pos])
+                                if is_out:
+                                    add_edge(node, wire, arch.segments[
+                                        seg_of_track[t]].opin_switch)
+                                else:
+                                    add_edge(wire, node, arch.ipin_switch)
                             continue
                         for t in _fc_tracks(pin_ptc, side, W, fc):
                             wire = (chanx_wire[ci][t, pos] if kind == "x"
@@ -426,82 +550,54 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
         return yhigh[w] == y or ylow[w] == y + 1
 
     if unidir:
-        # ---- directed switch box (single-driver rule,
-        # rr_graph.c:432-548): at corner (x, y) every wire whose DRIVING
-        # end lands on the corner (INC ends at its high end, DEC at its
-        # low end) drives wires STARTING at the corner — straight
-        # continuation on the same track, same-index turns, and rotated
-        # turns with the same corner-parity shift as the bidir box (so
-        # the planes kernel keeps its roll structure).  Each edge uses
-        # the TARGET segment's mux switch (the mux belongs to the driven
-        # wire's start).
-        def cxw(t, pos, y):
-            return int(chanx_wire[y][t, pos]) if 1 <= pos <= nx else -1
+        # ---- directed switch box: build_rr_graph's docstring,
+        # "Unidir switch box", is the rule; unidir_exit_point (shared
+        # with route/planes.py build_planes) and unidir_turn_group are
+        # its two halves ----
+        tr = np.arange(W)
+        dec = tr % 2 == 1
+        grp = tr // group_tracks
+        n_grp = int(grp[-1]) + 1
+        wsw = [arch.segments[s].wire_switch for s in seg_of_track]
 
-        def cyw(t, pos, x):
-            return int(chany_wire[x][t, pos]) if 1 <= pos <= ny else -1
+        def at_corner(wire_tc, c, n, lo_a, hi_a):
+            """Of one channel (wire_tc [W, n + 1]) at the corner of
+            coordinate c: per track the wire that EXITS there (-1:
+            none) with whether it ends there, and the wire that STARTS
+            there (-1: none).  A wire reaches the corner from its low
+            side (INC, position c) or its high side (DEC, c + 1) and
+            starts away from it on the other."""
+            p_src = np.where(dec, c + 1, c)
+            p_tgt = np.where(dec, c, c + 1)
+            w_src = np.where((p_src >= 1) & (p_src <= n),
+                             wire_tc[tr, np.clip(p_src, 1, n)], -1)
+            w_tgt = np.where((p_tgt >= 1) & (p_tgt <= n),
+                             wire_tc[tr, np.clip(p_tgt, 1, n)], -1)
+            lo, hi = lo_a[w_src], hi_a[w_src]
+            k = unidir_exit_point(dec, seg_len_of_track, stag_of_track,
+                                  c, lo, hi)
+            w_src = np.where(sb_of_track[tr, k] & (k > 0), w_src, -1)
+            ends = np.where(dec, lo == c + 1, hi == c)
+            starts = np.where(dec, hi_a[w_tgt] == c,
+                              lo_a[w_tgt] == c + 1)
+            return w_src, ends, np.where(starts, w_tgt, -1)
 
         for x in range(nx + 1):
             for y in range(ny + 1):
-                par = (x + y) % 2
-                shift = (1 + par) % W
-                drv_x = [-1] * W
-                tgt_x = [-1] * W
-                drv_y = [-1] * W
-                tgt_y = [-1] * W
-                for t in range(W):
-                    if t % 2 == 0:              # INC
-                        w = cxw(t, x, y)
-                        if w >= 0 and xhi[w] == x:
-                            drv_x[t] = w
-                        w = cxw(t, x + 1, y)
-                        if w >= 0 and xlo[w] == x + 1:
-                            tgt_x[t] = w
-                        w = cyw(t, y, x)
-                        if w >= 0 and yhi[w] == y:
-                            drv_y[t] = w
-                        w = cyw(t, y + 1, x)
-                        if w >= 0 and ylo[w] == y + 1:
-                            tgt_y[t] = w
-                    else:                       # DEC
-                        w = cxw(t, x + 1, y)
-                        if w >= 0 and xlo[w] == x + 1:
-                            drv_x[t] = w
-                        w = cxw(t, x, y)
-                        if w >= 0 and xhi[w] == x:
-                            tgt_x[t] = w
-                        w = cyw(t, y + 1, x)
-                        if w >= 0 and ylo[w] == y + 1:
-                            drv_y[t] = w
-                        w = cyw(t, y, x)
-                        if w >= 0 and yhi[w] == y:
-                            tgt_y[t] = w
-                for t in range(W):
-                    sw_t = arch.segments[seg_of_track[t]].wire_switch
-                    # straight continuation, same track
-                    if drv_x[t] >= 0 and tgt_x[t] >= 0:
-                        add_edge(drv_x[t], tgt_x[t], sw_t)
-                    if drv_y[t] >= 0 and tgt_y[t] >= 0:
-                        add_edge(drv_y[t], tgt_y[t], sw_t)
-                    # same-index turns
-                    if drv_x[t] >= 0 and tgt_y[t] >= 0:
-                        add_edge(drv_x[t], tgt_y[t], sw_t)
-                    if drv_y[t] >= 0 and tgt_x[t] >= 0:
-                        add_edge(drv_y[t], tgt_x[t], sw_t)
-                    # rotated turns (chanx t -> chany t+shift;
-                    # chany u -> chanx u-shift: the bidir box's symmetric
-                    # pair, kept as two directed rules)
-                    if shift:
-                        ty = (t + shift) % W
-                        if drv_x[t] >= 0 and tgt_y[ty] >= 0:
-                            add_edge(drv_x[t], tgt_y[ty],
-                                     arch.segments[
-                                         seg_of_track[ty]].wire_switch)
-                        tx = (t - shift) % W
-                        if drv_y[t] >= 0 and tgt_x[tx] >= 0:
-                            add_edge(drv_y[t], tgt_x[tx],
-                                     arch.segments[
-                                         seg_of_track[tx]].wire_switch)
+                sx, ex, tx = at_corner(chanx_wire[y], x, nx, xlow, xhigh)
+                sy, ey, ty = at_corner(chany_wire[x], y, ny, ylow, yhigh)
+                for src, ends, own, other, into_y in (
+                        (sx, ex, tx, ty, True), (sy, ey, ty, tx, False)):
+                    for t in np.flatnonzero(src >= 0):
+                        w = int(src[t])
+                        for t2 in np.flatnonzero(
+                                (other >= 0)
+                                & (grp == unidir_turn_group(
+                                    grp[t], into_y, (x + y) % 2,
+                                    n_grp))):
+                            add_edge(w, int(other[t2]), wsw[t2])
+                        if ends[t] and own[t] >= 0:
+                            add_edge(w, int(own[t]), wsw[t])
 
     for x in (range(nx + 1) if not unidir else ()):
         # bidir switch box (the unidir box was emitted above)
@@ -611,6 +707,10 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
             dtype=np.int32),
         dir_of_track=(np.arange(W, dtype=np.int32) % 2) if unidir
         else None,
+        seg_len_of_track=(seg_len_of_track.astype(np.int32) if unidir
+                          else None),
+        sb_of_track=sb_of_track if unidir else None,
+        group_tracks=group_tracks if unidir else 0,
     )
 
 
@@ -624,9 +724,136 @@ _LEGAL_EDGES = {
 }
 
 
-def check_rr_graph(rr: RRGraph, reachability: bool = True) -> None:
+def _wire_axis(rr: RRGraph, w):
+    """Of wire nodes ``w``: (is CHANX, DEC, span lo, span hi, the
+    coordinate across the channel)."""
+    is_x = rr.node_type[w] == CHANX
+    lo = np.where(is_x, rr.xlow[w], rr.ylow[w]).astype(np.int64)
+    hi = np.where(is_x, rr.xhigh[w], rr.yhigh[w]).astype(np.int64)
+    fixed = np.where(is_x, rr.ylow[w], rr.xlow[w]).astype(np.int64)
+    return is_x, rr.ptc[w] % 2 == 1, lo, hi, fixed
+
+
+def unidir_box_stats(rr: RRGraph):
+    """The two numbers a unidir box can get wrong in silence, read back
+    from the edges alone.  ``exit_turns_min``: fewest turn edges (onto
+    the other channel type) at any switch point a wire's ``sb`` pattern
+    marks, the device's edge rows and columns apart -- 0 when a marked
+    exit turns nowhere.  ``opin_starts_min``: fewest distinct wires an
+    OPIN drives in any one channel it drives at all."""
+    wire = (rr.node_type == CHANX) | (rr.node_type == CHANY)
+    src = np.repeat(np.arange(rr.num_nodes), np.diff(rr.out_row_ptr))
+    dst = rr.out_dst.astype(np.int64)
+    nx, ny = rr.grid.nx, rr.grid.ny
+
+    # a turn edge lies on the corner its TARGET starts at; along the
+    # source's own axis that corner has the target's cross coordinate
+    turn = (wire[src] & wire[dst]
+            & (rr.node_type[src] != rr.node_type[dst]))
+    ts, td = src[turn], dst[turn]
+    _, _, _, _, d_fixed = _wire_axis(rr, td)
+    keys = ts * (max(nx, ny) + 2) + d_fixed
+    have = dict(zip(*np.unique(keys, return_counts=True)))
+
+    w = np.flatnonzero(wire)
+    is_x, dec, lo, hi, fixed = _wire_axis(rr, w)
+    t = rr.ptc[w]
+    L = rr.seg_len_of_track[t].astype(np.int64)
+    n_ax = np.where(is_x, nx, ny)
+    n_cross = np.where(is_x, ny, nx)
+    exit_min = None
+    for j in range(int(L.max()) + 1):
+        c = lo - 1 + j
+        k = unidir_exit_point(dec, L, (t // 2) % L, c, lo, hi)
+        need = (rr.sb_of_track[t, k] & (k > 0) & (c > 0) & (c < n_ax)
+                & (fixed > 0) & (fixed < n_cross))
+        for wi, ci in zip(w[need], c[need]):
+            n = have.get(int(wi) * (max(nx, ny) + 2) + int(ci), 0)
+            exit_min = n if exit_min is None else min(exit_min, n)
+
+    drive = (rr.node_type[src] == OPIN) & wire[dst]
+    os_, od = src[drive], dst[drive]
+    d_is_x, _, _, _, d_fixed = _wire_axis(rr, od)
+    chan = (os_ * 2 + d_is_x) * (max(nx, ny) + 2) + d_fixed
+    pairs = np.unique(np.stack([chan, od], axis=1), axis=0)
+    _, per_chan = np.unique(pairs[:, 0], return_counts=True)
+    return (int(exit_min) if exit_min is not None else 0,
+            int(per_chan.min()) if len(per_chan) else 0)
+
+
+def _check_unidir_box(rr: RRGraph, arch: Optional[Arch]) -> None:
+    """The unidir rules of check_rr_graph: wire -> wire and OPIN -> wire
+    edges land only on wire STARTS; every marked switch point off the
+    device's edge turns somewhere; with the architecture given, every
+    OPIN drives its Fc share of distinct starts in every channel it
+    faces."""
+    wire = (rr.node_type == CHANX) | (rr.node_type == CHANY)
+    src = np.repeat(np.arange(rr.num_nodes), np.diff(rr.out_row_ptr))
+    dst = rr.out_dst.astype(np.int64)
+    into = wire[dst]
+    s, d = src[into], dst[into]
+    d_is_x, d_dec, d_lo, d_hi, _ = _wire_axis(rr, d)
+    start = np.where(d_dec, d_hi, d_lo)
+    # an OPIN sits AT the start position; a wire's exit corner touches it
+    s_is_wire = wire[s]
+    pos = np.where(d_is_x, rr.xlow[s], rr.ylow[s]).astype(np.int64)
+    assert np.all(pos[~s_is_wire] == start[~s_is_wire]), \
+        "an OPIN drives a wire away from its start"
+    sw, dw = s[s_is_wire], d[s_is_wire]
+    same = rr.node_type[sw] == rr.node_type[dw]
+    _, s_dec, s_lo, s_hi, s_fixed = _wire_axis(rr, sw)
+    _, w_dec, w_lo, w_hi, w_fixed = _wire_axis(rr, dw)
+    w_start_c = np.where(w_dec, w_hi, w_lo - 1)     # its start corner
+    s_end_c = np.where(s_dec, s_lo - 1, s_hi)
+    assert np.all(w_start_c[same] == s_end_c[same]), \
+        "a wire continues straight away from its end"
+    # a turn: the source covers the corner the target starts at
+    tc = w_fixed[~same]
+    assert np.all(np.where(s_dec[~same], (tc >= s_lo[~same] - 1)
+                           & (tc <= s_hi[~same] - 1),
+                           (tc >= s_lo[~same]) & (tc <= s_hi[~same]))
+                  & (w_start_c[~same] == s_fixed[~same])), \
+        "a turn lands on a wire that does not start at the corner"
+    exit_min, opin_min = unidir_box_stats(rr)
+    assert exit_min >= 1, "a marked switch point has no turn edge"
+    assert opin_min >= 1
+    if arch is None:
+        return
+    starts: Dict[Tuple[bool, int, int], int] = {}
+    w = np.flatnonzero(wire)
+    is_x, dec, lo, hi, fixed = _wire_axis(rr, w)
+    for kx, f, p in zip(is_x, fixed, np.where(dec, hi, lo)):
+        key = (bool(kx), int(f), int(p))
+        starts[key] = starts.get(key, 0) + 1
+    drive = (rr.node_type[src] == OPIN) & wire[dst]
+    got: Dict[Tuple[int, bool, int], set] = {}
+    for o, t in zip(src[drive], dst[drive]):
+        kx = bool(rr.node_type[t] == CHANX)
+        f = int(rr.ylow[t] if kx else rr.xlow[t])
+        got.setdefault((int(o), kx, f), set()).add(int(t))
+    for (x, y, z, p), o in rr.opin_of.items():
+        bt = (arch.io_type if rr.grid.is_io(x, y)
+              else arch.block_type(rr.grid.interior_type_name(x)))
+        fc = arch.fc_frac(rr.chan_width, True, type_name=bt.name, pin=p)
+        want = max(1, int(round(fc * rr.chan_width)))
+        for kind, f, pos_ in _adjacent_channels(rr.grid, x, y):
+            kx = kind == "x"
+            n = starts.get((kx, f, pos_), 0)
+            if n == 0:
+                continue
+            have = len(got.get((o, kx, f), ()))
+            assert have >= min(want, n), (
+                f"OPIN {rr.describe(o)} drives {have} starts of "
+                f"{'CHANX' if kx else 'CHANY'} {f}, its Fc share is "
+                f"{min(want, n)}")
+
+
+def check_rr_graph(rr: RRGraph, reachability: bool = True,
+                   arch: Optional[Arch] = None) -> None:
     """Graph sanity checker (vpr/SRC/route/check_rr_graph.c equivalent).
-    Raises AssertionError on any violation."""
+    Raises AssertionError on any violation.  A unidir graph is also held
+    to its switch box's rules (``_check_unidir_box``; the Fc share of
+    every OPIN needs ``arch``)."""
     N, E = rr.num_nodes, rr.num_edges
     assert rr.out_row_ptr[0] == 0 and rr.out_row_ptr[-1] == E
     assert rr.in_row_ptr[0] == 0 and rr.in_row_ptr[-1] == E
@@ -660,6 +887,8 @@ def check_rr_graph(rr: RRGraph, reachability: bool = True) -> None:
     assert np.all(in_deg[ipins] >= 1), "dead IPIN (no driving wire)"
     assert np.all(out_deg[rr.node_type == SINK] == 0)
     assert np.all(in_deg[rr.node_type == SOURCE] == 0)
+    if rr.unidir:
+        _check_unidir_box(rr, arch)
 
     if reachability and N <= 200000:
         # all SINKs reachable from the union of SOURCEs (frontier sweep)

@@ -130,6 +130,35 @@ def test_planes_relax_cropped_compiles_at_route_tile(one_chip):
     _fits_hbm(compiled)
 
 
+def test_directional_planes_relax_compiles_at_k6n10_canvas(one_chip):
+    """The directional relaxation (unidir graphs: group-min turns) at
+    the canvas of the cell ``route_k6n10_relaxed``: 11 x 11, W = 64 of
+    length-4 single-driver wires, 64 nets -- whole and cropped."""
+    import warnings
+
+    from parallel_eda_tpu.arch.builtin import k6_n10_40nm_arch
+    from parallel_eda_tpu.route.planes import (build_planes, planes_relax,
+                                               planes_relax_cropped)
+    from parallel_eda_tpu.rr.graph import build_rr_graph
+    from parallel_eda_tpu.rr.grid import DeviceGrid
+
+    arch = k6_n10_40nm_arch(chan_width=64)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # the file asks for Wilton
+        pg = build_planes(build_rr_graph(
+            arch, DeviceGrid(11, 11, arch.io_capacity)))
+    assert pg.directional and pg.group_tracks == 8 and pg.max_span == 4
+    fn = jax.jit(planes_relax, static_argnames=("nsweeps",))
+    _fits_hbm(fn.lower(*_relax_avatars(pg, ROUTE_B, one_chip),
+                       nsweeps=ROUTE_SWEEPS).compile())
+    origin = jax.ShapeDtypeStruct((ROUTE_B,), jnp.int32, sharding=one_chip)
+    fn = jax.jit(planes_relax_cropped,
+                 static_argnames=("nsweeps", "cnx", "cny"))
+    _fits_hbm(fn.lower(*_relax_avatars(pg, ROUTE_B, one_chip),
+                       nsweeps=ROUTE_SWEEPS, ox=origin, oy=origin,
+                       cnx=8, cny=8).compile())
+
+
 # ---- the whole window program once, small --------------------------
 
 def test_route_window_program_compiles_for_every_bench_variant(one_chip):

@@ -150,6 +150,17 @@ def test_walk_ledger_counts_and_repeats(routed):
     assert again.total_walk_budget == res.total_walk_budget
 
 
+def test_wave_ledger_counts_and_repeats(routed):
+    """Executed waves are counted beside the sweeps they ran (the
+    fifth entry of the step's ledger vector): every wave is one
+    relaxation, so at least one sweep each, every net routed took at
+    least one, and a second route counts the same."""
+    res, _, _ = routed
+    assert 0 < res.total_waves <= res.total_relax_steps
+    assert res.total_walk_budget >= res.total_waves
+    assert _route_tiny().total_waves == res.total_waves
+
+
 def _full_budget_walk(pred, wenter, noc_p1, pick_cell, done0, Kw):
     """The walk as it was before it could end early: a fixed-trip loop
     of Kw steps, each a scatter at one position of [B, G, Kw]."""
