@@ -1250,6 +1250,66 @@ def traceback_walk(pred, wenter, noc_p1, pick_cell, done0, Kw: int):
     return cur, done, cells_w, nodes_w, wst, steps
 
 
+def node_cost_field(congj_p1, node_of_cell):
+    """Per-net node costs ``congj_p1`` [B, N + 1] laid over the canvas:
+    [B, ncells], cell c of every net reading node ``node_of_cell[c]``.
+    The index is ONE vector for all nets: ncells indices, each moving
+    the B-net column of its node (the TPU compiler lays the table out
+    node-major and fetches contiguous rows), never B * ncells element
+    reads out of a per-net table."""
+    return jnp.take(congj_p1, node_of_cell, axis=1)
+
+
+def entry_fields(seed_cells, opin_du, cc_flat, crit_w, valid,
+                 ecell, eoidx, edelay):
+    """A wave's SOURCE-side fields over the canvas, from the nets' entry
+    tables [B, Ko] (every OPIN -> wire edge as its wire cell ``ecell``,
+    pad ncells; its OPIN's index ``eoidx`` into ``opin_du`` [B, O]; its
+    edge delay ``edelay``):
+
+    d0 [B, ncells]   0.0 on the tree (``seed_cells``), else the cheapest
+                     entry cost into the cell, else INF
+    entry_flag       the cells an entry beat the seed on
+    wk (int32)       the winning entry of each cell (ties -> lowest k,
+                     deterministic; Ko where no entry won)
+    wenter0 (f32)    the winning entry's delay on flagged cells, else 0.0
+
+    Only the B * Ko entries are ever looked up or written: a canvas is
+    read AT the entries' cells and written by a scatter over them.  The
+    winner of a cell is known in entry space -- entry k holds its cell
+    iff ``wk`` there is k -- and a net's winners have distinct cells, so
+    the store of their delays is deterministic.  An invalid or clean
+    net's costs are all INF: it gets no flag and no weight, and its
+    canvases never improve, so it neither extends the batch's
+    convergence loop nor does any discoverable work (its results are
+    discarded at the step's final scatter)."""
+    B, ncells = seed_cells.shape
+    Ko = ecell.shape[1]
+    rows = jnp.arange(B)[:, None]
+    ks = jnp.arange(Ko, dtype=jnp.int32)[None, :]
+    padded = ecell >= ncells
+    at_e = jnp.minimum(ecell, ncells - 1)
+
+    def at_entries(field, pad):
+        return jnp.where(padded, pad,
+                         jnp.take_along_axis(field, at_e, axis=1))
+
+    d_seed = jnp.where(seed_cells, 0.0, INF)
+    e_du = jnp.take_along_axis(opin_du, eoidx, axis=1)           # [B, Ko]
+    e_cost = jnp.where(
+        valid[:, None],
+        e_du + crit_w[:, None] * edelay + at_entries(cc_flat, INF), INF)
+    d0 = d_seed.at[rows, ecell].min(e_cost, mode="drop")
+    entry_flag = d0 < d_seed
+    e_won = at_entries(d0, INF) == e_cost
+    wk = jnp.full((B, ncells), Ko, jnp.int32).at[rows, ecell].min(
+        jnp.where(e_won, ks, Ko), mode="drop")
+    holds = (at_entries(wk, Ko) == ks) & at_entries(entry_flag, False)
+    wenter0 = jnp.zeros((B, ncells), jnp.float32).at[
+        rows, jnp.where(holds, ecell, ncells)].set(edelay, mode="drop")
+    return d0, entry_flag, wk, wenter0
+
+
 def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                paths, sink_delay, all_reached, bb,
                source_all, sinks_all, crit_all,
@@ -1300,7 +1360,8 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
         b_doidx = direct_oidx_all[sel]               # [B, S] (-1 = none)
         b_dipin = direct_ipin_all[sel]
         b_ddel = direct_delay_all[sel]
-        if mesh is not None and _as_row_mesh(mesh) is None:
+        gspmd = mesh is not None and _as_row_mesh(mesh) is None
+        if gspmd:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             def c(x, *spec):
@@ -1354,8 +1415,9 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
         congj = jnp.where(inside, cong * jitter, INF)             # [B, N]
         congj_p1 = jnp.concatenate(
             [congj, jnp.full((B, 1), INF, jnp.float32)], axis=1)
-        noc_b = jnp.broadcast_to(pg.node_of_cell[None, :], (B, ncells))
-        cc_flat_base = jnp.take_along_axis(congj_p1, noc_b, axis=1)
+        cc_flat_base = node_cost_field(congj_p1, pg.node_of_cell)
+        if gspmd:
+            cc_flat_base = c(cc_flat_base, "net", None)
         opin_congj = jnp.take_along_axis(
             congj_p1, jnp.clip(b_opin, 0, N), axis=1)              # [B, O]
         ipin_congj = jnp.take_along_axis(
@@ -1394,36 +1456,10 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             crit_c = crit_w[:, None, None, None]
 
             # --- seed + SOURCE-side entries ---
-            d_seed = jnp.where(seed_cells, 0.0, INF)
             opin_du = jnp.where(opin_used, 0.0, cw[:, None] * opin_congj)
-            e_du = jnp.take_along_axis(opin_du, b_eoidx, axis=1)   # [B, Ko]
-            cc_flat_p1 = jnp.concatenate(
-                [cc_flat, jnp.full((B, 1), INF)], axis=1)
-            e_cc = jnp.take_along_axis(cc_flat_p1,
-                                       jnp.minimum(b_ecell, ncells), axis=1)
-            # invalid/clean slots get all-INF entry seeds: their canvases
-            # then never improve, so they neither extend the batch's
-            # convergence loop nor do any discoverable work (their results
-            # were always discarded at the sel_v scatter below)
-            e_cost = jnp.where(valid[:, None],
-                               e_du + crit_w[:, None] * b_edelay + e_cc, INF)
-            d0 = d_seed.at[arangeB[:, None], b_ecell].min(e_cost, mode="drop")
-            entry_flag = d0 < d_seed                          # [B, Ncells]
-            # winning entry index per cell (ties -> lowest k, deterministic)
-            d0_at_e = jnp.take_along_axis(
-                jnp.concatenate([d0, jnp.full((B, 1), INF)], axis=1),
-                jnp.minimum(b_ecell, ncells), axis=1)
-            e_won = d0_at_e == e_cost
-            wk = jnp.full((B, ncells), Ko, jnp.int32).at[
-                arangeB[:, None], b_ecell].min(
-                jnp.where(e_won, jnp.arange(Ko, dtype=jnp.int32)[None, :], Ko),
-                mode="drop")
-            edelay_p1 = jnp.concatenate(
-                [b_edelay, jnp.zeros((B, 1))], axis=1)
-            wenter0 = jnp.where(
-                entry_flag,
-                jnp.take_along_axis(edelay_p1, jnp.minimum(wk, Ko), axis=1),
-                0.0)
+            d0, entry_flag, wk, wenter0 = entry_fields(
+                seed_cells, opin_du, cc_flat, crit_w, valid,
+                b_ecell, b_eoidx, b_edelay)
 
         with device_scope("route.dev.relax"):
             if use_pallas:

@@ -435,3 +435,198 @@ def test_resident_batch_step_equals_a_one_group_window():
         assert np.array_equal(np.asarray(got), np.asarray(want)), name
     assert np.asarray(res[2]).any()
     assert int(res[5]) == int(win[-1][SCAL_S_EXEC]) > 0
+
+
+# ---- the cost fields alone (planes.entry_fields, planes.node_cost_field)
+# against the per-(net, cell) gather forms they replaced ----
+
+def _field_graph(kind):
+    """A small PlanesGraph of each relaxation variant: length-1
+    two-way wires, and the published length-4 single-driver ones."""
+    import warnings
+
+    from parallel_eda_tpu.arch.builtin import k6_n10_40nm_arch
+
+    if kind == "directional_l4":
+        arch, n = k6_n10_40nm_arch(chan_width=16), 5
+    else:
+        arch, n = minimal_arch(chan_width=8), 4
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # the 40nm file asks Wilton
+        rr = build_rr_graph(arch, DeviceGrid(n, n, arch.io_capacity))
+    pg = build_planes(rr)
+    assert pg.directional == (kind == "directional_l4")
+    return rr, pg
+
+
+def _entry_case(pg, N, B, Ko=12, O=3):
+    """Seeded wave inputs over ``pg``'s canvas that hold every edge of
+    the entry fields; returns (args, the planted (net, cell) spots)."""
+    ncells = pg.ncells
+    rng = np.random.default_rng(B + ncells)
+    wire = np.where(np.asarray(pg.node_of_cell) < N)[0]
+    ecell = np.stack([rng.choice(wire, Ko, replace=False)
+                      for _ in range(B)]).astype(np.int32)
+    edelay = rng.uniform(1e-11, 9e-11, (B, Ko)).astype(np.float32)
+    eoidx = rng.integers(0, O, (B, Ko)).astype(np.int32)
+    # padded entries at the ncells sentinel (their delays are garbage
+    # on purpose: nothing may read them)
+    ecell[:, Ko - 3:] = ncells
+    ecell[5, :] = ncells                      # a net with no entry
+    seed_cells = rng.random((B, ncells)) < 0.03
+    seed_cells[np.arange(B)[:, None], np.minimum(ecell, ncells - 1)] = False
+    cc_flat = rng.uniform(1e-11, 1e-10, (B, ncells)).astype(np.float32)
+    opin_du = rng.uniform(0.0, 1e-10, (B, O)).astype(np.float32)
+    crit_w = rng.uniform(0.1, 0.99, B).astype(np.float32)
+    valid = np.ones(B, bool)
+    # net 0: entries 0, 1 and 2 of ONE cell at equal cost (no delay
+    # term at crit 0, one OPIN), each with its own delay
+    crit_w[0] = 0.0
+    ecell[0, 1] = ecell[0, 2] = ecell[0, 0]
+    eoidx[0, :3] = 1
+    # net 1: invalid, all-INF costs
+    valid[1] = False
+    # net 2: entry 0 lands on a tree cell and cannot beat it
+    seed_cells[2, ecell[2, 0]] = True
+    # net 3: entry 0's cell lies outside the box (INF congestion);
+    # entries 1 and 2 share a cell and the LATER one is cheaper
+    cc_flat[3, ecell[3, 0]] = np.inf
+    ecell[3, 2] = ecell[3, 1]
+    eoidx[3, 1:3] = 0
+    edelay[3, 1], edelay[3, 2] = 8e-11, 2e-11
+    # net 4: its only OPIN already used (entry cost without the OPIN's)
+    opin_du[4, :] = 0.0
+    spots = {"tie": (0, ecell[0, 0]), "seeded": (2, ecell[2, 0]),
+             "outside": (3, ecell[3, 0]), "later_wins": (3, ecell[3, 1])}
+    args = (seed_cells, opin_du, cc_flat, crit_w, valid, ecell, eoidx,
+            edelay)
+    return tuple(jnp.asarray(a) for a in args), spots
+
+
+@pytest.mark.parametrize("B", [16, 64])
+@pytest.mark.parametrize("kind", ["bidirectional", "directional_l4"])
+def test_entry_fields_equal_the_per_cell_gather(kind, B):
+    """`entry_fields` writes by the entries what the gather form read
+    by the cells: d0, entry_flag, wk and wenter0 bit for bit, on every
+    edge the block has."""
+    from cost_field_refs import entry_fields_gather
+    from parallel_eda_tpu.route.planes import entry_fields
+
+    rr, pg = _field_graph(kind)
+    args, spots = _entry_case(pg, rr.num_nodes, B)
+    want = [np.asarray(a) for a in entry_fields_gather(*args)]
+    got = [np.asarray(a) for a in entry_fields(*args)]
+    for name, g, w in zip(("d0", "entry_flag", "wk", "wenter0"),
+                          got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert np.array_equal(g, w), name
+    d0, flag, wk, wenter0 = got
+    edelay = np.asarray(args[7])
+    Ko = edelay.shape[1]
+    # the edges are live, not vacuous
+    b, c = spots["tie"]
+    assert flag[b, c] and wk[b, c] == 0 and wenter0[b, c] == edelay[0, 0]
+    assert edelay[0, 0] != edelay[0, 1]
+    # (an invalid net's INF costs still 'win' their INF cells in wk)
+    assert not flag[1].any() and not wenter0[1].any()
+    assert np.isinf(d0[1][~np.asarray(args[0])[1]]).all()
+    b, c = spots["seeded"]
+    assert d0[b, c] == 0.0 and not flag[b, c] and wenter0[b, c] == 0.0
+    b, c = spots["outside"]
+    assert np.isinf(d0[b, c]) and wk[b, c] == 0 and wenter0[b, c] == 0.0
+    b, c = spots["later_wins"]
+    assert wk[b, c] == 2 and wenter0[b, c] == edelay[3, 2]
+    assert not flag[5].any() and (wk[5] == Ko).all()
+    # a weight on every flagged cell and nowhere else
+    assert np.array_equal(wenter0 != 0.0, flag)
+    assert flag.sum() > B * (Ko - 3) // 2
+
+
+@pytest.mark.parametrize("B", [16, 64])
+@pytest.mark.parametrize("kind", ["bidirectional", "directional_l4"])
+def test_node_cost_field_equals_the_per_cell_gather(kind, B):
+    """One index vector applied to all nets alike gives the values
+    B * ncells independent element reads gave."""
+    from cost_field_refs import node_cost_field_gather
+    from parallel_eda_tpu.route.planes import node_cost_field
+
+    rr, pg = _field_graph(kind)
+    N = rr.num_nodes
+    rng = np.random.default_rng(B)
+    congj = rng.uniform(1e-11, 1e-10, (B, N)).astype(np.float32)
+    congj[rng.random((B, N)) < 0.2] = np.inf          # outside the box
+    congj_p1 = jnp.asarray(np.concatenate(
+        [congj, np.full((B, 1), np.inf, np.float32)], axis=1))
+    want = np.asarray(node_cost_field_gather(congj_p1, pg.node_of_cell))
+    got = np.asarray(node_cost_field(congj_p1, pg.node_of_cell))
+    assert got.dtype == want.dtype and got.shape == (B, pg.ncells)
+    assert np.array_equal(got, want)
+    # canvas cells no wire covers read the INF column
+    noc = np.asarray(pg.node_of_cell)
+    assert np.isinf(got[:, noc == N]).all()
+    assert np.isfinite(got).any() and np.isinf(got[:, noc < N]).any()
+
+
+def test_directional_route_equals_the_route_under_gathered_fields(
+        monkeypatch):
+    """A whole route on the published length-4 single-driver wires is
+    the route with both cost fields built by the per-(net, cell)
+    gathers, node for node and in iterations, sweeps and waves."""
+    import warnings
+
+    from cost_field_refs import entry_fields_gather, node_cost_field_gather
+    from parallel_eda_tpu.arch.builtin import k6_n10_40nm_arch
+    from parallel_eda_tpu.flow import prepare, run_place_native
+    from parallel_eda_tpu.netlist.generate import generate_circuit
+    from parallel_eda_tpu.route import planes
+
+    arch = k6_n10_40nm_arch(chan_width=32)
+    nl = generate_circuit(num_luts=60, num_inputs=8, num_outputs=8,
+                          K=arch.K, seed=3)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # the 40nm file asks Wilton
+        f = run_place_native(prepare(nl, arch, 32, seed=5), seed=7)
+    assert f.rr.unidir
+
+    programs = (planes.route_window_planes,
+                planes.route_window_planes_fused,
+                planes.route_window_planes_multi,
+                planes.route_batch_resident_planes)
+
+    def route():
+        # the builders are traced into jitted programs: drop what they
+        # hold, before and after
+        for prog in programs:
+            prog.clear_cache()
+        try:
+            return Router(f.rr, RouterOpts(batch_size=16)).route(f.term)
+        finally:
+            for prog in programs:
+                prog.clear_cache()
+
+    res = route()
+    calls = []
+
+    def counted(fn):
+        def wrapped(*a):
+            calls.append(fn.__name__)
+            return fn(*a)
+        return wrapped
+
+    monkeypatch.setattr(planes, "entry_fields", counted(entry_fields_gather))
+    monkeypatch.setattr(planes, "node_cost_field",
+                        counted(node_cost_field_gather))
+    ref = route()
+    monkeypatch.undo()
+    assert {"entry_fields_gather", "node_cost_field_gather"} == set(calls)
+    assert res.success and res.total_waves > res.iterations > 1
+    assert (res.success, res.iterations, res.wirelength,
+            res.total_relax_steps, res.total_relax_steps_useful,
+            res.total_waves, res.total_walk_steps) == (
+        ref.success, ref.iterations, ref.wirelength,
+        ref.total_relax_steps, ref.total_relax_steps_useful,
+        ref.total_waves, ref.total_walk_steps)
+    assert np.array_equal(np.asarray(res.paths), np.asarray(ref.paths))
+    assert np.array_equal(np.asarray(res.sink_delay),
+                          np.asarray(ref.sink_delay))
+    assert np.array_equal(np.asarray(res.occ), np.asarray(ref.occ))
