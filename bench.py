@@ -201,33 +201,23 @@ def build(num_luts: int, chan_width: int, seed: int = 11,
 
 
 def sweep_microbench(args) -> None:
-    """Measure the planes relaxation's per-sweep device cost directly
-    (the 'decide Pallas with data' number): one program, two
-    syncs, reports ms/sweep and derived cell-rate at several grid
-    sizes."""
+    """Measure the planes relaxation's per-sweep device cost directly:
+    one program, two syncs, reports ms/sweep and derived cell-rate at
+    several grid sizes."""
     import jax
     import jax.numpy as jnp
 
     from parallel_eda_tpu.arch.builtin import minimal_arch
-    from parallel_eda_tpu.route.planes import build_planes, planes_relax
+    from parallel_eda_tpu.route.planes import (build_planes, planes_relax,
+                                               xla_bytes_per_cell)
     from parallel_eda_tpu.rr.graph import build_rr_graph
     from parallel_eda_tpu.rr.grid import DeviceGrid
 
     if args.program == "ell":
         raise SystemExit("--sweep_only measures the planes relaxation; "
-                         "--program must be planes or planes_pallas")
-    if args.program == "planes_pallas":
-        from parallel_eda_tpu.route.planes_pallas import (
-            planes_relax_pallas)
+                         "--program must be planes")
     if args.sweep_crop:
-        # crop composes with either backend: XLA cropped program, or
-        # the tile-blocked VMEM Pallas kernel when --program
-        # planes_pallas (so the roofline label below matches what runs)
-        if args.program == "planes_pallas":
-            from parallel_eda_tpu.route.planes_pallas import (
-                planes_relax_cropped_pallas)
-        else:
-            from parallel_eda_tpu.route.planes import planes_relax_cropped
+        from parallel_eda_tpu.route.planes import planes_relax_cropped
 
     rows = []
     # analytic roofline constants (the MFU-style statement for a
@@ -238,20 +228,11 @@ def sweep_microbench(args) -> None:
     # a CPU run reports no roofline share — and an error for a device
     # kind the table does not know
     from parallel_eda_tpu.obs.devprof import peak_hbm_bytes_per_s
-    from parallel_eda_tpu.route.planes_pallas import pallas_interpret
+    from parallel_eda_tpu.serve.batcher import unpacked_lane_occupancy
     peak_bw = peak_hbm_bytes_per_s(jax.devices()[0])
-    # an interpreted Pallas kernel is the interpreter's speed, not the
-    # kernel's: every printed row says which it was
-    interpret = (args.program == "planes_pallas"
-                 and pallas_interpret(None))
 
     nsweeps = 16
-    if args.program == "planes_pallas":
-        # VMEM-resident kernel: HBM sees one load + one store of the
-        # ~6 state canvases for the WHOLE nsweeps relaxation
-        bytes_per_cell_sweep = 2 * 6 * 4.0 / nsweeps
-    else:
-        bytes_per_cell_sweep = 15 * 4.0
+    bytes_per_cell_sweep = float(xla_bytes_per_cell())
     hbm_bound_rate = (peak_bw / bytes_per_cell_sweep
                       if peak_bw else None)
     for nx, W in ((16, 12), (32, 14), (64, 16), (96, 20)):
@@ -274,15 +255,8 @@ def sweep_microbench(args) -> None:
             rng = np.random.default_rng(3)
             ox = jnp.asarray(rng.integers(0, nx - t, B), jnp.int32)
             oy = jnp.asarray(rng.integers(0, nx - t, B), jnp.int32)
-            if args.program == "planes_pallas":
-                fn = jax.jit(lambda d: planes_relax_cropped_pallas(
-                    pg, d, cc, crit, w0, nsweeps, ox, oy, t, t)[0])
-            else:
-                fn = jax.jit(lambda d: planes_relax_cropped(
-                    pg, d, cc, crit, w0, nsweeps, ox, oy, t, t)[0])
-        elif args.program == "planes_pallas":
-            fn = jax.jit(lambda d: planes_relax_pallas(
-                pg, d, cc, crit, w0, nsweeps)[0])
+            fn = jax.jit(lambda d: planes_relax_cropped(
+                pg, d, cc, crit, w0, nsweeps, ox, oy, t, t)[0])
         else:
             fn = jax.jit(lambda d: planes_relax(pg, d, cc, crit, w0,
                                                 nsweeps)[0])
@@ -301,24 +275,16 @@ def sweep_microbench(args) -> None:
             cells = B * pg.ncells
         util = (cells / dt / hbm_bound_rate
                 if hbm_bound_rate else None)
-        # kernel-layout rider: what the packed planner would run at this
-        # shape (mirrors Router._plan_block_nets / route.kernel.* gauges)
-        from parallel_eda_tpu.route.planes_pallas import (
-            auto_block_nets, packed_layout, unpacked_lane_occupancy)
+        # kernel-layout rider (mirrors Router._plan_block_nets /
+        # route.kernel.* gauges)
         if args.sweep_crop:
             t = min(args.sweep_crop, nx - 1)
             shx, shy = (W, t, t + 1), (W, t + 1, t)
         else:
             shx, shy = pg.shape_x, pg.shape_y
-        if args.program == "planes_pallas":
-            g = auto_block_nets(shx, shy, B)
-            kernel = {"variant": "pallas_packed", "block_nets": g,
-                      "lane_occupancy": round(
-                          packed_layout(shx, shy).lane_occupancy(g), 4)}
-        else:
-            kernel = {"variant": "xla", "block_nets": 1,
-                      "lane_occupancy": round(
-                          unpacked_lane_occupancy(shx, shy), 4)}
+        kernel = {"variant": "xla",
+                  "lane_occupancy": round(
+                      unpacked_lane_occupancy(shx, shy), 4)}
         rows.append({"grid": f"{nx}x{nx}", "W": W, "cells": pg.ncells,
                      "ms_per_sweep": round(dt * 1e3, 3),
                      "cell_rate_G": round(cells / dt / 1e9, 3),
@@ -327,15 +293,12 @@ def sweep_microbench(args) -> None:
                          if hbm_bound_rate else None),
                      "bw_utilization": (round(util, 4)
                                         if util is not None else None),
-                     "interpret": interpret,
                      "kernel": kernel})
-        note = ("VMEM-resident roofline" if args.program ==
-                "planes_pallas" else "HBM roofline of the XLA lowering")
-        share = (f"{100 * util:.1f}% of the {note}" if util is not None
+        share = (f"{100 * util:.1f}% of the HBM roofline of the XLA "
+                 f"lowering" if util is not None
                  else "no roofline share off the chip")
         log(f"sweep {nx}x{nx} W={W} B={B}: {dt * 1e3:.2f} ms/sweep, "
-            f"{cells / dt / 1e9:.2f} Gcell/s ({share})"
-            f"{' [pallas INTERPRETED]' if interpret else ''}")
+            f"{cells / dt / 1e9:.2f} Gcell/s ({share})")
     emit(args, {
         "metric": "planes_ms_per_sweep",
         "value": rows[-1]["ms_per_sweep"] if rows else -1.0,
@@ -344,7 +307,6 @@ def sweep_microbench(args) -> None:
         "detail": {"platform": jax.devices()[0].platform,
                    "batch": args.batch, "program": args.program,
                    "sweep_crop": args.sweep_crop,
-                   "interpret": interpret,
                    "rows": rows}})
 
 
@@ -444,9 +406,8 @@ def main():
     ap.add_argument("--chan_width", type=int, default=12)
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--program", default="planes",
-                    choices=["planes", "planes_pallas", "ell"],
-                    help="device search program (planes_pallas = the "
-                         "VMEM-resident Pallas sweep kernel)")
+                    choices=["planes", "ell"],
+                    help="device search program")
     ap.add_argument("--scale", action="store_true",
                     help="the at-scale crossover config: "
                          "a >=1200-LUT circuit, full negotiation on both "
@@ -514,13 +475,9 @@ def main():
     ap.add_argument("--plane_dtype", default="f32",
                     choices=("f32", "bf16"),
                     help="distance/backtrack plane storage dtype "
-                         "(bf16 halves the modeled plane traffic; "
-                         "guarded modes stay QoR-bit-exact)")
-    ap.add_argument("--dtype_guard", default="window",
-                    choices=("window", "route", "off"),
-                    help="bf16 exactness guard: per-window oracle "
-                         "compare, until-first-clean-window, or off "
-                         "(perf mode, commits bf16)")
+                         "(bf16 halves the modeled plane traffic and "
+                         "is the dtype the route commits: a different "
+                         "result, not a faster f32 one)")
     ap.add_argument("--fused_dispatch", action="store_true",
                     help="one ragged packed window program walking "
                          "every populated crop rung instead of one "
@@ -586,7 +543,7 @@ def main():
     router = Router(rr, RouterOpts(
         batch_size=args.batch, program=args.program,
         sweep_budget_div=args.budget_div, pipeline=not args.sync,
-        plane_dtype=args.plane_dtype, dtype_guard=args.dtype_guard,
+        plane_dtype=args.plane_dtype,
         fused_dispatch=args.fused_dispatch))
     from parallel_eda_tpu.obs import (compile_seconds, get_metrics,
                                       reset_compile_seconds)
@@ -789,11 +746,9 @@ def main():
             },
             # kernel-layout ledger (route.kernel.* gauges, set by the
             # router's block planner for the dominant window shape):
-            # how many nets each grid step packs and the model-side
-            # lane occupancy / HBM traffic that implies
+            # the model-side lane occupancy / HBM traffic of the
+            # one-net-per-step XLA layout
             "kernel": {
-                "packed_block_size": mv.get(
-                    "route.kernel.packed_block_size"),
                 "lane_occupancy": mv.get("route.kernel.lane_occupancy"),
                 "bytes_per_sweep": mv.get(
                     "route.kernel.bytes_per_sweep"),

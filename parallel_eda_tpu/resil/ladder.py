@@ -1,15 +1,10 @@
 """The graceful-degradation ladder.
 
-Six dimensions, each an ordered list of execution levels, fastest
-first (all bit-identical except "dtype", whose levels are
-QoR-identical under the router's shadow-oracle guard):
+Four dimensions, each an ordered list of execution levels, fastest
+first, all bit-identical in QoR:
 
-  kernel:   pallas_packed (G>1) -> pallas_g1 (G=1) -> xla
   pipeline: pipelined -> sync
   program:  aot -> jit
-  dtype:    bf16 -> f32   (reduced-precision planes; stepped when a
-            window summary leaves the declared ulp band of the f32
-            oracle — router._dtype_band_ok)
   dispatch: fused -> per_rung   (one ragged packed dispatch per
             window vs one dispatch per populated crop rung)
   mesh:     pallas_halo -> ppermute -> single_chip   (multi-chip
@@ -20,15 +15,14 @@ QoR-identical under the router's shadow-oracle guard):
             engages on TPU backends; elsewhere ppermute is the top
             working rung.  Inert unless RouterOpts.mesh_shards > 1.
 
-"kernel" and "program" descend *per dispatch-variant* inside
-``DispatchGuard`` (quarantine picks the rung); the ladder records
-every such step.  "pipeline", "dtype", "dispatch", and floor
-overrides for the other two are *global*: the service steps them when
-a whole job attempt is poisoned, the router's dtype guard steps
-"dtype" on a band violation, and the router consults ``level()`` when
-building a dispatch chain.  The "dtype"/"dispatch" levels are inert
-unless the matching RouterOpts knob opted in (plane_dtype="bf16" /
-fused_dispatch=True) — level 0 names the opt-in mode, not a default.
+"program" descends *per dispatch-variant* inside ``DispatchGuard``
+(quarantine picks the rung); the ladder records every such step.
+"pipeline", "dispatch", and a floor override for "program" are
+*global*: the service steps them when a whole job attempt is
+poisoned, and the router consults ``level()`` when building a
+dispatch chain.  The "dispatch" levels are inert unless
+RouterOpts.fused_dispatch opted in — level 0 names the opt-in mode,
+not a default.
 Every step is observable — the ``route.resil.degradation_steps``
 counter, per-dimension ``route.resil.level.<dim>`` gauges, and a
 trace instant.
@@ -40,10 +34,8 @@ from ..obs.metrics import get_metrics
 from ..obs.trace import get_tracer
 
 DIMS: Dict[str, tuple] = {
-    "kernel": ("pallas_packed", "pallas_g1", "xla"),
     "pipeline": ("pipelined", "sync"),
     "program": ("aot", "jit"),
-    "dtype": ("bf16", "f32"),
     "dispatch": ("fused", "per_rung"),
     "mesh": ("pallas_halo", "ppermute", "single_chip"),
 }
@@ -52,11 +44,6 @@ DIMS: Dict[str, tuple] = {
 _LABEL_DIM = {
     "aot": "program",
     "jit": "program",
-    "pallas_packed": "kernel",
-    "pallas_g1": "kernel",
-    "xla": "kernel",
-    "bf16": "dtype",
-    "f32": "dtype",
     "fused": "dispatch",
     "per_rung": "dispatch",
     "pallas_halo": "mesh",

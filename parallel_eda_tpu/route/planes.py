@@ -824,8 +824,8 @@ def _sweep_costs(gm: PlanesGeom, crit_c, cc_x, cc_y):
 def _sweep_once(gm: PlanesGeom, s, crit_c, cc_x, cc_y, costs):
     """One relaxation sweep (2 x-scans, turn into y, 2 y-scans, turn
     into x) over the (dist, pred, wenter) state — THE shared body of
-    the XLA programs (planes_relax / planes_relax_cropped) and the
-    Pallas VMEM-resident kernel (planes_pallas.py).  Scan-neighbor
+    planes_relax, planes_relax_cropped and the row-sharded
+    planes_shard.planes_relax_sharded.  Scan-neighbor
     strides use gm.stride_x (the GLOBAL flat-index stride), so pred
     payloads stay in global cell-id space under cropping."""
     cfx, cbx, cfy, cby, wfx, wbx, wfy, wby = costs
@@ -857,15 +857,13 @@ def _sweep_once(gm: PlanesGeom, s, crit_c, cc_x, cc_y, costs):
 
 # Storage dtypes of the distance/backtrack planes.  "f32" is the
 # bit-exact oracle.  "bf16" halves the bytes every sweep's loop-carried
-# state moves (and doubles effective lane width in the packed layout):
-# the dist/wenter canvases are CARRIED in bfloat16 between sweeps while
-# every sweep body still runs in f32 — the wavefront-min reduction (the
-# min-plus scans and turn folds) accumulates in f32 and only the
-# per-sweep requantization rounds.  pred stays int32 (exact global cell
+# state moves: the dist/wenter canvases are CARRIED in bfloat16 between
+# sweeps while every sweep body still runs in f32 — the wavefront-min
+# reduction (the min-plus scans and turn folds) accumulates in f32 and
+# only the per-sweep requantization rounds.  pred stays int32 (exact global cell
 # indices) and crit stays f32; the congestion input is quantized ONCE
-# through the plane dtype (see planes_relax) so the XLA and Pallas
-# lowerings see identical costs and remain bit-identical to each other
-# in either mode.
+# through the plane dtype (see planes_relax), the third loop-carried
+# set the byte model counts at the storage width.
 PLANE_DTYPES = ("f32", "bf16")
 
 
@@ -879,18 +877,25 @@ def plane_jnp_dtype(plane_dtype: str):
 
 
 def plane_itemsize(plane_dtype: str) -> int:
-    """Storage bytes per plane cell — the dtype-aware byte-budget and
-    modeled-traffic multiplier (PackedLayout / kernel_bench / devprof
-    all derive from this one function)."""
+    """Storage bytes per plane cell — the multiplier of the modeled
+    sweep traffic (xla_bytes_per_cell)."""
     return 2 if plane_dtype == "bf16" else 4
+
+
+def xla_bytes_per_cell(itemsize: int = 4) -> int:
+    """Modeled HBM bytes one USEFUL cell moves per XLA sweep: ~15
+    canvas traversals, of which the three loop-carried storage sets
+    (dist, wenter, congestion) take the plane dtype while the scan and
+    turn intermediates XLA materialises stay f32 (60 B/cell in f32,
+    54 in bf16).  A model no chip run has checked; it feeds the
+    route.kernel.bytes_per_sweep gauge."""
+    return 3 * int(itemsize) + 12 * 4
 
 
 def quantize_plane_state(s, plane_dtype: str):
     """(dx, dy, predx, predy, wx, wy) -> storage dtypes: the dist and
     wenter payloads take the plane dtype (round-to-nearest), pred stays
-    int32.  A no-op cast when the state already carries the dtype, so
-    the Pallas kernels (whose refs are already storage-dtype) and the
-    XLA programs (f32 inputs) quantize identically."""
+    int32.  A no-op cast when the state already carries the dtype."""
     dt = plane_jnp_dtype(plane_dtype)
     dx, dy, px, py, wx, wy = s
     return (dx.astype(dt), dy.astype(dt), px, py,
@@ -1007,10 +1012,8 @@ def planes_relax(pg: PlanesGraph, d0_flat, cc_flat, crit_c, wenter0,
     cc_y = cshard(cc_flat[:, ncx:].reshape(B, W, NXp1, NY))
     if plane_dtype != "f32":
         # quantize the congestion input ONCE through the plane dtype
-        # (round trip back to f32 for the sweep body): the Pallas
-        # lowering stores its cc refs in the storage dtype, so both
-        # lowerings must see the same rounded costs to stay
-        # bit-identical to each other in reduced-precision mode
+        # (round trip back to f32 for the sweep body): the mode's
+        # costs are the ones a storage-dtype congestion plane holds
         dt = plane_jnp_dtype(plane_dtype)
         cc_x = cc_x.astype(dt).astype(jnp.float32)
         cc_y = cc_y.astype(dt).astype(jnp.float32)
@@ -1047,9 +1050,9 @@ def planes_relax(pg: PlanesGraph, d0_flat, cc_flat, crit_c, wenter0,
 @device_scope("route.dev.relax.crop")
 def crop_state(pg: PlanesGraph, d0_flat, cc_flat, wenter0, ox, oy,
                cnx: int, cny: int):
-    """Shared crop scaffolding of the two cropped programs (XLA and
-    Pallas): reshape the [B, Ncells] flats into canvases and slice each
-    net's (cnx, cny) tile at its origin.  Returns (full canvases
+    """Crop scaffolding of planes_relax_cropped: reshape the
+    [B, Ncells] flats into canvases and slice each net's (cnx, cny)
+    tile at its origin.  Returns (full canvases
     (dxf, dyf, wxf, wyf), tiles (dx, dy, ccx, ccy, wx, wy))."""
     B = d0_flat.shape[0]
     W, NX, NYp1 = pg.shape_x
@@ -1096,43 +1099,6 @@ def scatter_state(gm_full: PlanesGeom, fulls, tiles, ox, oy):
     return (flat(put(dxf, dx), put(dyf, dy)),
             flat(put(idxx_f, predx), put(idxy_f, predy)),
             flat(put(wxf, wx), put(wyf, wy)))
-
-
-# ---------------------------------------------------------------------------
-# Packed canvas storage (lane folding) — shared with the Pallas kernels.
-#
-# The packed kernels store each net's canvases as ONE row: the track dim
-# W and the spatial dims fold into the minor axis, with the trailing Y
-# extent padded to a lane multiple first, so a block of G nets becomes a
-# [G, row] array whose (8, 128) f32 vector registers carry G nets' rows
-# at high occupancy.  The one-net-per-step [1, W, X, Y] layout instead
-# tiles (X, Y) onto (8, 128): a bench-sized Y extent (~13) fills a
-# sliver of the 128 lanes.
-#
-# The pad columns are storage-only: compute always slices back to the
-# unpadded (W, X, Y) canvas before the sweep body runs, so the fold
-# cannot perturb numerics.  The XLA program deliberately KEEPS the
-# unpadded layout: padding an associative_scan axis changes the fold's
-# combine-tree shape and therefore the float associativity of the
-# min-plus reduction — the two lowerings would no longer be
-# bit-comparable (and the pad cells could leak turn candidates).
-# ---------------------------------------------------------------------------
-
-
-def fold_canvas(a, pad_y: int = 0):
-    """[B, ..., Y] -> [B, prod(...) * (Y + pad_y)]: pad the trailing
-    axis with storage-only columns, then flatten each net to one row."""
-    if pad_y:
-        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad_y)])
-    return a.reshape(a.shape[0], -1)
-
-
-def unfold_canvas(a2, shape, pad_y: int = 0):
-    """Inverse of fold_canvas: [B, row] -> [B, *shape], pad dropped."""
-    B = a2.shape[0]
-    padded = tuple(shape[:-1]) + (shape[-1] + pad_y,)
-    a = a2.reshape((B,) + padded)
-    return a[..., :shape[-1]] if pad_y else a
 
 
 def planes_relax_cropped(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
@@ -1319,9 +1285,9 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                direct_oidx_all, direct_ipin_all, direct_delay_all,
                sel, valid, force, full_bb,
                nsweeps: int, max_len: int, num_waves: int, group: int,
-               doubling: bool, mesh, use_pallas: bool = False,
+               doubling: bool, mesh,
                crop_tile=None, bb0_all=None, widen_ok=None,
-               pallas_g1: bool = False, plane_dtype: str = "f32"):
+               plane_dtype: str = "f32"):
     """One fused batch step (traceable body shared by the standalone
     per-batch wrapper and the window program): rip up the selected nets,
     re-route each against the occupancy view of everyone-but-itself with
@@ -1462,21 +1428,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                 b_ecell, b_eoidx, b_edelay)
 
         with device_scope("route.dev.relax"):
-            if use_pallas:
-                if crop_tile is not None:
-                    from .planes_pallas import planes_relax_cropped_pallas
-                    dist, pred, wenter, rst = planes_relax_cropped_pallas(
-                        pg, d0, cc_flat, crit_c, wenter0, nsweeps,
-                        crop_ox, crop_oy, cnx_t, cny_t,
-                        block_nets=1 if pallas_g1 else None,
-                        plane_dtype=plane_dtype)
-                else:
-                    from .planes_pallas import planes_relax_pallas
-                    dist, pred, wenter, rst = planes_relax_pallas(
-                        pg, d0, cc_flat, crit_c, wenter0, nsweeps,
-                        block_nets=1 if pallas_g1 else None,
-                        plane_dtype=plane_dtype)
-            elif crop_tile is not None:
+            if crop_tile is not None:
                 dist, pred, wenter, rst = planes_relax_cropped(
                     pg, d0, cc_flat, crit_c, wenter0, nsweeps,
                     crop_ox, crop_oy, cnx_t, cny_t,
@@ -1702,8 +1654,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
 @functools.partial(
     jax.jit,
     static_argnames=("nsweeps", "max_len", "num_waves", "group",
-                     "doubling", "mesh", "use_pallas", "crop_tile",
-                     "plane_dtype"),
+                     "doubling", "mesh", "crop_tile", "plane_dtype"),
     donate_argnames=("occ", "paths", "sink_delay", "all_reached", "bb"))
 def route_batch_resident_planes(
         pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
@@ -1714,7 +1665,7 @@ def route_batch_resident_planes(
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel, valid, full_bb,
         nsweeps: int, max_len: int, num_waves: int, group: int,
-        doubling: bool = False, mesh=None, use_pallas: bool = False,
+        doubling: bool = False, mesh=None,
         crop_tile=None, bb0_all=None, plane_dtype: str = "f32"):
     """Standalone one-batch wrapper of _step_core (resident-state
     contract of search.route_batch_resident; the host picked the nets,
@@ -1732,7 +1683,7 @@ def route_batch_resident_planes(
         sink_uid_all, uid_cell, uid_ipin, uid_delay,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel, valid, jnp.bool_(True), full_bb,
-        nsweeps, max_len, num_waves, group, doubling, mesh, use_pallas,
+        nsweeps, max_len, num_waves, group, doubling, mesh,
         crop_tile, bb0_all, plane_dtype=plane_dtype)
     return (paths, sink_delay, all_reached, bb, occ, st[0])
 
@@ -1786,8 +1737,7 @@ def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
 WINDOW_STATIC_ARGNAMES = ("K_iters", "nsweeps", "max_len", "num_waves",
                           "group", "doubling", "topk", "n_colors",
                           "mesh", "sta_depth", "crit_exp", "max_crit",
-                          "use_sdc", "use_pallas", "crop_tile",
-                          "pallas_g1", "plane_dtype")
+                          "use_sdc", "crop_tile", "plane_dtype")
 
 
 def _window_body(
@@ -1804,9 +1754,9 @@ def _window_body(
         n_colors: int = 5, mesh=None,
         tdev=None, req_seed=None, sta_depth: int = 0,
         crit_exp: float = 1.0, max_crit: float = 0.99,
-        use_sdc: bool = False, use_pallas: bool = False,
+        use_sdc: bool = False,
         crop_tile=None, bb0_all=None, widen_ok=None,
-        pallas_g1: bool = False, plane_dtype: str = "f32"):
+        plane_dtype: str = "f32"):
     """A WINDOW of K_iters complete PathFinder iterations as ONE device
     program: per iteration, every batch group in sel_plan [G, B] runs the
     fused rip-up/route/commit step (clean nets no-op via the device-side
@@ -1869,8 +1819,7 @@ def _window_body(
                     direct_oidx_all, direct_ipin_all, direct_delay_all,
                     sel_g, valid_g, force, full_bb,
                     nsweeps, max_len, num_waves, group, doubling, mesh,
-                    use_pallas, crop_tile, bb0_all, widen_ok, pallas_g1,
-                    plane_dtype)
+                    crop_tile, bb0_all, widen_ok, plane_dtype)
                 with device_scope("route.dev.commit"):
                     return (occ2, paths2, sink_delay2, all_reached2, bb2,
                             nr + n_act, ng + 1, led2 + led_g)
@@ -1994,9 +1943,9 @@ def route_window_planes(
         n_colors: int = 5, mesh=None,
         tdev=None, req_seed=None, sta_depth: int = 0,
         crit_exp: float = 1.0, max_crit: float = 0.99,
-        use_sdc: bool = False, use_pallas: bool = False,
+        use_sdc: bool = False,
         crop_tile=None, bb0_all=None, widen_ok=None,
-        pallas_g1: bool = False, plane_dtype: str = "f32"):
+        plane_dtype: str = "f32"):
     """One window RUNG as its own jit program (contract: _window_body's
     docstring) — the per-rung dispatch shape the Router's crop ladder
     used before the fused program below, kept as the watchdog fallback
@@ -2011,8 +1960,7 @@ def route_window_planes(
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
         K_iters, nsweeps, max_len, num_waves, group, doubling, topk,
         n_colors, mesh, tdev, req_seed, sta_depth, crit_exp, max_crit,
-        use_sdc, use_pallas, crop_tile, bb0_all, widen_ok, pallas_g1,
-        plane_dtype)
+        use_sdc, crop_tile, bb0_all, widen_ok, plane_dtype)
 
 
 # the fused program's static argnames: the per-rung statics
@@ -2039,8 +1987,7 @@ def _fused_ladder(
         K_iters: int, max_len: int, rung_desc, topk: int,
         n_colors: int, mesh, tdev, req_seed, sta_depth: int,
         crit_exp: float, max_crit: float, use_sdc: bool,
-        use_pallas: bool, bb0_all, widen_oks,
-        pallas_g1: bool, plane_dtype: str):
+        bb0_all, widen_oks, plane_dtype: str):
     """The traced body shared by route_window_planes_fused (one job)
     and route_window_planes_multi (one job per co-admitted tenant):
     walk the ragged ``rung_desc`` descriptor table, threading the
@@ -2065,8 +2012,8 @@ def _fused_ladder(
             it0, force_until,
             K_iters, nsweeps, max_len, num_waves, group, doubling,
             topk, n_colors, mesh, tdev, req_seed, sta_depth, crit_exp,
-            max_crit, use_sdc, use_pallas, crop_tile, bb0_all,
-            widen_oks[r], pallas_g1, plane_dtype)
+            max_crit, use_sdc, crop_tile, bb0_all, widen_oks[r],
+            plane_dtype)
         (occ, acc, paths, sink_delay, all_reached, bb) = out[:6]
         crit_all = out[13]
         scals.append(out[22])
@@ -2093,9 +2040,8 @@ def route_window_planes_fused(
         n_colors: int = 5, mesh=None,
         tdev=None, req_seed=None, sta_depth: int = 0,
         crit_exp: float = 1.0, max_crit: float = 0.99,
-        use_sdc: bool = False, use_pallas: bool = False,
-        bb0_all=None, widen_oks=None,
-        pallas_g1: bool = False, plane_dtype: str = "f32"):
+        use_sdc: bool = False,
+        bb0_all=None, widen_oks=None, plane_dtype: str = "f32"):
     """The WHOLE window dispatch ladder as ONE device program: walk the
     ragged ``rung_desc`` descriptor table — one static
     (crop_tile, nsweeps, num_waves, group, doubling) tuple per
@@ -2127,15 +2073,14 @@ def route_window_planes_fused(
         sel_plans, valid_plans, full_bb,
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
         K_iters, max_len, rung_desc, topk, n_colors, mesh, tdev,
-        req_seed, sta_depth, crit_exp, max_crit, use_sdc, use_pallas,
-        bb0_all, widen_oks, pallas_g1, plane_dtype)
+        req_seed, sta_depth, crit_exp, max_crit, use_sdc,
+        bb0_all, widen_oks, plane_dtype)
 
 
 # the multi-job program's static argnames: one (K_iters, max_len,
 # rung_desc) triple per co-admitted job rides the ``job_statics``
 # descriptor, everything else is shared grid-level configuration
 MULTI_WINDOW_STATIC_ARGNAMES = ("job_statics", "n_colors",
-                                "use_pallas", "pallas_g1",
                                 "plane_dtype")
 
 
@@ -2146,7 +2091,6 @@ MULTI_WINDOW_STATIC_ARGNAMES = ("job_statics", "n_colors",
 def route_window_planes_multi(
         pg: PlanesGraph, dev: DeviceRRGraph, job_states, job_dynamics,
         job_statics=(), n_colors: int = 5,
-        use_pallas: bool = False, pallas_g1: bool = False,
         plane_dtype: str = "f32"):
     """Continuous-batching window dispatch: the fused window ladders of
     EVERY co-admitted job as ONE device program on the shared device
@@ -2183,8 +2127,8 @@ def route_window_planes_multi(
             sel_plans, valid_plans, full_bb,
             pres0, pres_mult, max_pres, acc_fac, it0, force_until,
             K_iters, max_len, rung_desc, topk, n_colors, None, None,
-            None, 0, 1.0, 0.99, False, use_pallas, bb0_all, widen_oks,
-            pallas_g1, plane_dtype))
+            None, 0, 1.0, 0.99, False, bb0_all, widen_oks,
+            plane_dtype))
     return tuple(outs)
 
 

@@ -47,7 +47,7 @@ Two transport implementations ride the resil ladder's "mesh" rungs:
   LAG 2 (sweep t consumes boundary columns produced at the end of
   sweep t-2), so the transfer issued right after sweep t-1's columns
   exist has ALL of sweep t's compute to hide behind.  On TPU the
-  transport is planes_pallas.remote_slab_permute (double-buffered
+  transport is remote_slab_permute below (double-buffered
   ``pltpu.make_async_remote_copy`` neighbor sends); elsewhere the same
   lag-2 schedule runs over ppermute so the rung's numerics are
   CI-testable.  Lag-2 staleness means one globally-stable sweep no
@@ -266,6 +266,92 @@ def _geom_blocks(pg: PlanesGraph, s: int, kx: int) -> PlanesGeom:
         group_tracks=pg.group_tracks)
 
 
+def remote_slab_permute(slab, axis_name, n_shards, fwd=True):
+    """Halo-slab neighbor exchange over the TPU interconnect (RDMA).
+
+    Transport for the mesh ladder's top rung ("pallas_halo"): inside
+    planes_relax_sharded's shard_map body each device
+    pushes its boundary dist slab ([B, W, 1-or-2, Y]) directly into the
+    neighbor's output buffer with ``pltpu.make_async_remote_copy`` —
+    a one-hop ICI DMA instead of the collective-scheduled
+    ``lax.ppermute`` the middle rung uses.  The overlap itself lives in
+    the lag-2 schedule below: the halo installed before sweep k
+    was extracted before sweep k-1 ran, so two exchange generations are
+    in flight at once and this DMA hides behind the interior sub-sweep
+    (route.mesh.overlap_frac models the hide).
+
+    Semantics match the non-wrapping ``lax.ppermute`` shift exactly:
+    ``fwd=True`` sends shard i -> i+1 (the last shard sends nothing),
+    ``fwd=False`` sends i -> i-1 (the first sends nothing), and an edge
+    shard with no inbound neighbor returns zeros — planes_shard masks
+    those halos to +inf by row index, so the two transports stay
+    bit-identical and rung demotion cannot move QoR.
+
+    TPU-only (callers gate on ``jax.default_backend() == "tpu"``): the
+    remote-DMA primitives have no interpret-mode lowering, so on CPU
+    hosts the ppermute rung is the top of the mesh ladder.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(x_ref, o_ref, send_sem, recv_sem):
+        me = jax.lax.axis_index(axis_name)
+        if fwd:
+            neighbor, sender = me + 1, me - 1
+            sends, recvs = me < n_shards - 1, me > 0
+        else:
+            neighbor, sender = me - 1, me + 1
+            sends, recvs = me > 0, me < n_shards - 1
+        logical = pltpu.DeviceIdType.LOGICAL
+        # handshake: a receiver tells its sender it has entered the
+        # kernel (its output buffer is live) before the sender pushes
+        # into it; each sender consumes exactly the one signal it is
+        # sent, so the barrier semaphore is back at zero on exit
+        barrier = pltpu.get_barrier_semaphore()
+
+        @pl.when(recvs)
+        def _ready():
+            pltpu.semaphore_signal(barrier, 1, device_id=sender,
+                                   device_id_type=logical)
+
+        @pl.when(sends)
+        def _await_ready():
+            pltpu.semaphore_wait(barrier, 1)
+
+        copy = pltpu.make_async_remote_copy(
+            src_ref=x_ref, dst_ref=o_ref,
+            send_sem=send_sem, recv_sem=recv_sem,
+            device_id=neighbor, device_id_type=logical)
+
+        @pl.when(jnp.logical_not(recvs))
+        def _zero_edge():
+            o_ref[...] = jnp.zeros_like(o_ref[...])
+
+        @pl.when(sends)
+        def _start():
+            copy.start()
+
+        @pl.when(sends)
+        def _wait_send():
+            copy.wait_send()
+
+        @pl.when(recvs)
+        def _wait_recv():
+            copy.wait_recv()
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(slab.shape, slab.dtype),
+        scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
+        compiler_params=pltpu.CompilerParams(
+            has_side_effects=True,
+            # fwd/bwd exchanges of one sweep overlap; distinct barrier
+            # semaphores keep their handshakes separate
+            collective_id=0 if fwd else 1,
+        ),
+    )(slab)
+
+
 def planes_relax_sharded(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
                          wenter0, nsweeps: int, rmesh: RowMesh,
                          plane_dtype: str = "f32"):
@@ -315,8 +401,6 @@ def planes_relax_sharded(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
     bwd = [(i, i - 1) for i in range(1, s)]      # -> left neighbor
     if rmesh.impl == "pallas_halo" \
             and jax.default_backend() == "tpu":
-        from .planes_pallas import remote_slab_permute
-
         def _send(slab, to_right: bool):
             return remote_slab_permute(slab, ROW_AXIS, s,
                                        fwd=to_right)

@@ -82,7 +82,8 @@ class RouterOpts:
     # over [B, W, X, Y] wire grids (route/planes.py — no gathers in the
     # sweep loop, the round-3 work-efficiency kernel); "ell" = the
     # gather-based pull Bellman-Ford over the ELL edge table
-    # (route/search.py; any-graph fallback + cross-validation oracle)
+    # (route/search.py; any-graph fallback + cross-validation oracle).
+    # Any other value is refused at Router construction
     program: str = "planes"
     # sinks per wave: 1 = exact VPR incremental tree reuse
     # (route_tree_timing.c); 0 = ALL sinks in one wave — every sink is
@@ -194,30 +195,24 @@ class RouterOpts:
     # Resilience runtime (resil.Resilience, duck-typed: .plan/.guard/
     # .ladder).  When set, every window dispatch runs under the
     # watchdog guard through a chain of bit-identical rungs (AOT ->
-    # jit -> Pallas G=1 -> XLA) with retry/backoff/quarantine, and
-    # fault-injection sites are armed.  None = off (the default path
-    # is byte-for-byte the non-resil dispatch)
+    # jit) with retry/backoff/quarantine, and fault-injection sites
+    # are armed.  None = off (the default path is byte-for-byte the
+    # non-resil dispatch)
     resil: Optional[object] = None
-    # Reduced-precision distance planes (planes.PLANE_DTYPES): "f32"
-    # is the bit-exact oracle; "bf16" stores and relaxes the distance/
-    # backtrack planes at half width (f32 accumulation inside every
-    # sweep — planes._run_relax), halving the bytes each relaxation
-    # sweep moves.  How bf16 results are USED depends on dtype_guard.
+    # Distance-plane storage dtype (planes.PLANE_DTYPES), and the dtype
+    # the route COMMITS: "f32" is the bit-exact oracle; "bf16" stores
+    # and relaxes the distance/backtrack planes at half width (f32
+    # accumulation inside every sweep — planes._run_relax), halving
+    # the bytes each relaxation sweep moves.  A bf16 route is legal
+    # but is a DIFFERENT result (the benchmark's negative control of
+    # `correct`), not a faster f32 one.
     plane_dtype: str = "f32"
-    # Exactness guard for plane_dtype="bf16" (inert under f32):
-    #   "window": every window also runs a bf16 shadow replay on
-    #     non-donated state copies; the committed path stays the f32
-    #     oracle (QoR bit-exact BY CONSTRUCTION) and the shadow's
-    #     packed summary is compared at the window stall
-    #     (_dtype_band_ok).  A divergence beyond the declared ulp band
-    #     demotes dtype via the resil ladder ("dtype": bf16 -> f32),
-    #     counts route.kernel.dtype_demotions, and stops shadowing.
-    #   "route": same shadow compare, but only until the first clean
-    #     window — a per-route spot check instead of per-window.
-    #   "off": COMMIT the bf16 relaxation directly (the perf mode —
-    #     no oracle, no shadow cost; QoR parity is enforced by the
-    #     parity suite + the CI corpus wirelength gate instead).
-    dtype_guard: str = "window"
+    # Vestigial: the one accepted value is "off" (anything else is a
+    # ValueError at route start).  The shadow-replay guards it once
+    # selected are gone; the field stays only because
+    # benchmark/tools/control_runs.py and tests/benchmark/ pass the
+    # key (ROADMAP.md Queue 3 names the remedy).
+    dtype_guard: str = "off"
     # Ragged fused dispatch: walk the whole crop-ladder of a window
     # (every populated size-class rung) inside ONE device program
     # (planes.route_window_planes_fused) instead of one dispatch per
@@ -234,9 +229,9 @@ class RouterOpts:
     # only the boundary halo columns between sweeps.  1 = single-chip
     # (default).  N > 1 needs N visible devices (on CPU hosts set
     # XLA_FLAGS=--xla_force_host_platform_device_count=N before jax
-    # initializes) and program="planes" — the packed Pallas program
-    # and the legacy (net, node) GSPMD mesh are mutually exclusive
-    # with it.  Rides the resil ladder's "mesh" dimension
+    # initializes) and program="planes" — the legacy (net, node)
+    # GSPMD mesh is mutually exclusive with it.  Rides the resil
+    # ladder's "mesh" dimension
     # (pallas_halo -> ppermute -> single_chip): the overlapped
     # remote-DMA transport engages on TPU backends, ppermute is the
     # portable rung, and a lost mesh member (backend.loss) demotes to
@@ -677,41 +672,6 @@ class WindowDispatchRequest:
         self.span_args = span_args
 
 
-# bf16 shadow-oracle acceptance band (RouterOpts.dtype_guard): the
-# fraction of per-net status words allowed to disagree with the f32
-# oracle, and the relative tolerance on the scalar congestion summary
-DTYPE_GUARD_STATUS_FRAC = 0.02
-DTYPE_GUARD_SCAL_RTOL = 0.05
-
-
-def _dtype_band_ok(status_f32, scal_f32, status_bf16, scal_bf16,
-                   status_frac: Optional[float] = None,
-                   scal_rtol: Optional[float] = None) -> bool:
-    """Band compare of a window's bf16 shadow summary against the
-    committed f32 oracle — the dtype-guard decision point (module
-    level so the parity suite can monkeypatch a forced violation).
-    The per-net status words may disagree on a small fraction of nets
-    (a half-ulp cost tie breaking the other way re-colors a net
-    without changing the negotiation outcome) and the scalar summary
-    (N_OVER, OVER_TOTAL, NROUTES, NEXEC, MAX_SPAN) must agree to a
-    relative tolerance with an absolute floor of 1.  The executed-trip
-    counters (S_EXEC, S_USEFUL) are excluded on purpose: reaching the
-    relaxation fixpoint a sweep earlier or later is a legitimate
-    reduced-precision outcome, not a divergence."""
-    if status_frac is None:
-        status_frac = DTYPE_GUARD_STATUS_FRAC
-    if scal_rtol is None:
-        scal_rtol = DTYPE_GUARD_SCAL_RTOL
-    st_a = np.asarray(status_f32)
-    st_b = np.asarray(status_bf16)
-    if st_a.size and float((st_a != st_b).mean()) > status_frac:
-        return False
-    a = np.asarray(scal_f32, dtype=np.float64)[:5]
-    b = np.asarray(scal_bf16, dtype=np.float64)[:5]
-    tol = np.maximum(1.0, scal_rtol * np.abs(a))
-    return bool((np.abs(a - b) <= tol).all())
-
-
 # how many overused rr-node ids each window's congestion record lists
 _CONGESTION_TOPK = 8
 
@@ -788,13 +748,11 @@ class Router:
         # path-length / BF-step bound: a bb-confined path can wind, give slack
         self.max_len = 4 * (nx + ny) + 64
         self.pg = None
-        self.use_pallas = self.opts.program == "planes_pallas"
-        if self.use_pallas and mesh is not None:
+        if self.opts.program not in ("planes", "ell"):
             raise ValueError(
-                "program='planes_pallas' does not support mesh sharding "
-                "yet (the Pallas kernel is single-device VMEM-resident); "
-                "use program='planes' for sharded runs")
-        if self.opts.program in ("planes", "planes_pallas"):
+                "program must be 'planes' or 'ell' "
+                f"(got {self.opts.program!r})")
+        if self.opts.program == "planes":
             from .planes import build_planes
             if rr.wire_switch_of_track is None:
                 raise ValueError(
@@ -814,13 +772,6 @@ class Router:
                     "mesh_shards > 1 and a legacy (net, node) mesh are "
                     "mutually exclusive — the halo-exchange sharding "
                     "owns the device mesh")
-            if self.use_pallas:
-                raise ValueError(
-                    "program='planes_pallas' does not support "
-                    "mesh_shards > 1 (the packed kernel is "
-                    "single-device VMEM-resident); use "
-                    "program='planes' — the sharded pallas_halo rung "
-                    "engages on TPU backends")
             if self.pg is None:
                 raise ValueError(
                     "mesh_shards > 1 needs a planes program "
@@ -886,9 +837,7 @@ class Router:
         resil ladder's "mesh" dimension (None = the single-chip
         floor).  Level 0 (pallas_halo, the overlapped remote-DMA
         transport) only engages where that transport exists — TPU
-        backends; elsewhere ppermute is the top working rung, the
-        same off-accelerator skip the kernel dimension applies to
-        pallas rungs."""
+        backends; elsewhere ppermute is the top working rung."""
         if self._row_meshes is None or self._mesh_lost:
             return None
         lvl = 0 if lad is None else lad.level("mesh")
@@ -945,9 +894,9 @@ class Router:
         transport rung first, then (for pallas_halo) the portable
         ppermute transport, then the single-chip floor.  All rungs are
         route-level QoR-identical (the sharded fixpoint equals the
-        single-device one; see planes_shard).  The AOT library and
-        Pallas kernel rungs never appear here — both are rejected with
-        mesh_shards > 1 at construction."""
+        single-device one; see planes_shard).  The AOT library rung
+        never appears here — the library is not loaded with
+        mesh_shards > 1."""
         from ..resil.watchdog import Rung
         from .planes import route_window_planes
 
@@ -982,9 +931,9 @@ class Router:
         chain of BIT-IDENTICAL execution rungs, fastest first, handed
         to DispatchGuard.run (retry with capped backoff, per-variant
         quarantine, descent).  Rung set per the degradation ladder:
-        AOT library -> live jit -> Pallas G=1 -> XLA.  Each rung notes
-        its own variant key so route.dispatch.{compiles,cache_hits}
-        stays honest about which program actually ran."""
+        AOT library -> live jit.  Each rung notes its own variant key
+        so route.dispatch.{compiles,cache_hits} stays honest about
+        which program actually ran."""
         from ..resil.watchdog import Rung
         from .planes import _as_row_mesh, route_window_planes
         rm = _as_row_mesh(wp_args[-1])
@@ -1012,24 +961,6 @@ class Router:
             return route_window_planes(*wp_args, **wp_kwargs)
 
         rungs.append(Rung("jit", run_jit))
-        if self.use_pallas and ladder.level("kernel") <= 1:
-            key_g1 = vkey + ("pallas_g1",)
-
-            def run_g1():
-                _note_dispatch_variant(key_g1)
-                return route_window_planes(
-                    *wp_args, **{**wp_kwargs, "pallas_g1": True})
-
-            rungs.append(Rung("pallas_g1", run_g1))
-        if self.use_pallas:
-            key_xla = vkey + ("xla",)
-
-            def run_xla():
-                _note_dispatch_variant(key_xla)
-                return route_window_planes(
-                    *wp_args, **{**wp_kwargs, "use_pallas": False})
-
-            rungs.append(Rung("xla", run_xla))
         return resil_rt.guard.run(vkey, rungs)
 
     def _guarded_dispatch_fused(self, resil_rt, vkey, f_args, f_kwargs,
@@ -1039,10 +970,7 @@ class Router:
         sequential per-rung dispatch loop (the ladder's "dispatch"
         dimension; bit-identical by construction — the fallback walks
         the SAME planned rungs in the same threading order the fused
-        program unrolls on device).  Kernel-dimension descent
-        (pallas_g1/xla) is left to the per-rung chain: a window that
-        exhausts this chain retries per-rung, where _guarded_dispatch's
-        usual rungs apply."""
+        program unrolls on device)."""
         from ..resil.watchdog import Rung
         from .planes import _as_row_mesh, route_window_planes_fused
         ladder = resil_rt.ladder
@@ -1224,7 +1152,6 @@ class Router:
         if kernel_plans:
             dom = max(kernel_plans, key=lambda kp: kp.get("nets", 0))
             reg.set_gauges({
-                "route.kernel.packed_block_size": dom["block_nets"],
                 "route.kernel.lane_occupancy": dom["lane_occupancy"],
                 "route.kernel.bytes_per_sweep": dom["bytes_per_sweep"],
             })
@@ -1454,27 +1381,17 @@ class Router:
 
     def _plan_block_nets(self, tile, nnets: int, nsw: int,
                          plane_dtype: str = "f32") -> dict:
-        """Kernel-layout plan for one dispatch (companion of
-        _plan_groups): the SAME VMEM-budget math the packed Pallas
-        wrappers apply (planes_pallas.auto_block_nets), so the
-        route.kernel.* gauges report the block size / occupancy the
-        kernel actually chose for this rung.  For the XLA program the
-        row reports the unpadded one-net-per-step layout instead, with
-        the matching HBM traffic model (per-sweep canvas traversals vs
-        the VMEM-resident kernel's one load+store per relaxation).
-        Both byte models are dtype-aware (planes_pallas.
-        packed_bytes_per_cell / xla_bytes_per_cell): bf16 planes halve
-        the streamed plane bytes while the int32 pred traffic stays
-        full-width, and the VMEM budget packs more nets per block
-        (auto_block_nets itemsize).  Nothing here is cached — a Router
-        reused across route() calls with a different plane_dtype
-        re-plans from scratch every dispatch."""
-        from .planes import plane_itemsize
-        from .planes_pallas import (auto_block_nets,
-                                    packed_bytes_per_cell,
-                                    packed_layout,
-                                    unpacked_lane_occupancy,
-                                    xla_bytes_per_cell)
+        """Modeled layout row of one dispatch (companion of
+        _plan_groups; feeds the route.kernel.* gauges, the
+        route.kernel trace spans and devprof's modeled side): the
+        unpadded one-net-per-step layout's vector-register occupancy
+        and the XLA relaxation's modeled HBM bytes per sweep
+        (planes.xla_bytes_per_cell, dtype-aware).  Nothing here is
+        cached — a Router reused across route() calls with a different
+        plane_dtype re-plans from scratch every dispatch."""
+        from ..serve.batcher import (packed_layout,
+                                     unpacked_lane_occupancy)
+        from .planes import plane_itemsize, xla_bytes_per_cell
 
         W, NX, NYp1 = self.pg.shape_x
         _, NXp1, NY = self.pg.shape_y
@@ -1483,25 +1400,15 @@ class Router:
             shx, shy = (W, cnx, cny + 1), (W, cnx + 1, cny)
         else:
             shx, shy = (W, NX, NYp1), (W, NXp1, NY)
-        lay = packed_layout(shx, shy)
         n = max(1, int(nnets))
-        isz = plane_itemsize(plane_dtype)
-        if self.use_pallas:
-            g = auto_block_nets(shx, shy, n, itemsize=isz)
-            plan = dict(variant="pallas_packed", block_nets=g,
-                        lane_occupancy=round(lay.lane_occupancy(g), 4),
-                        bytes_per_sweep=int(
-                            packed_bytes_per_cell(isz)
-                            * lay.padded_cells * n / max(1, nsw)))
-        else:
-            plan = dict(variant="xla", block_nets=1,
-                        lane_occupancy=round(
-                            unpacked_lane_occupancy(shx, shy), 4),
-                        bytes_per_sweep=int(
-                            xla_bytes_per_cell(isz) * lay.cells * n))
-        plan.update(tile=(None if tile is None else list(tile)),
-                    nets=n, nsweeps=int(nsw), plane_dtype=plane_dtype)
-        return plan
+        return dict(
+            variant="xla",
+            lane_occupancy=round(unpacked_lane_occupancy(shx, shy), 4),
+            bytes_per_sweep=int(
+                xla_bytes_per_cell(plane_itemsize(plane_dtype))
+                * packed_layout(shx, shy).cells * n),
+            tile=(None if tile is None else list(tile)),
+            nets=n, nsweeps=int(nsw), plane_dtype=plane_dtype)
 
     # escalating sync schedule: window sizes between host round trips
     # (a host round trip costs a sync; the values were tuned on an
@@ -1587,9 +1494,8 @@ class Router:
         fin_save = None
         force_all_next = False
         widx = 0
-        # crop composes with the Pallas program (tile-blocked VMEM
-        # kernel, planes_relax_cropped_pallas); only the spatially
-        # sharded mesh path keeps full canvases (crops are net-local)
+        # only the spatially sharded mesh path keeps full canvases
+        # (crops are net-local)
         crop_forced = None
         if "x" in crop and self.mesh is None:
             cwf, chf = (int(v) for v in crop.split("x"))
@@ -1684,29 +1590,21 @@ class Router:
         rid = next(_ROUTE_IDS)      # shared by every span of this route
         ctl = None      # the open route.pipeline.control span, if any
         disp_total = reg.gauge("route.pipeline.dispatch_ms_total")
-        # reduced-precision plane config (RouterOpts.plane_dtype /
-        # dtype_guard): guarded bf16 commits the f32 oracle every
-        # window and replays a bf16 shadow on non-donated state copies
-        # (QoR is bit-exact BY CONSTRUCTION; the shadow only validates
-        # the band); dtype_guard="off" commits bf16 directly.  A band
-        # violation demotes the route to f32 through the resil ladder's
-        # "dtype" dimension and counts route.kernel.dtype_demotions.
-        pd_req = str(opts.plane_dtype)
-        if pd_req not in PLANE_DTYPES:
+        # the plane dtype named by opts.plane_dtype is the dtype every
+        # window of this route commits
+        pd = str(opts.plane_dtype)
+        if pd not in PLANE_DTYPES:
             raise ValueError(
                 f"plane_dtype must be one of {PLANE_DTYPES} "
                 f"(got {opts.plane_dtype!r})")
-        guard_mode = str(opts.dtype_guard)
-        if guard_mode not in ("window", "route", "off"):
+        if opts.dtype_guard != "off":
             raise ValueError(
-                "dtype_guard must be 'window', 'route', or 'off' "
-                f"(got {opts.dtype_guard!r})")
+                "dtype_guard must be 'off', its one remaining value "
+                f"(got {opts.dtype_guard!r}): plane_dtype names the "
+                "dtype that is committed")
         resil_rt = getattr(opts, "resil", None)
         lad = resil_rt.ladder if resil_rt is not None else None
-        dtype_demoted = lad is not None and lad.level("dtype") > 0
-        dtype_validated = False     # guard="route" first-clean-window
-        reg.gauge("route.kernel.plane_dtype").set(
-            "bf16" if pd_req == "bf16" and not dtype_demoted else "f32")
+        reg.gauge("route.kernel.plane_dtype").set(pd)
         # cumulative pipeline accounting (drives the
         # route.pipeline.overlap_frac gauge): host seconds spent on
         # plan/stage/bookkeeping work, and the subset performed while
@@ -1756,9 +1654,8 @@ class Router:
             # of the ELL path's narrow/wide split, generalized to a
             # ladder.  The ladder is a fixed function of the grid, so
             # the compiled window-program variants stay O(log grid);
-            # the unsharded XLA AND Pallas programs both crop, only the
-            # spatial mesh path keeps full canvases (crops are
-            # net-local).  dispatch = [(subset, tile or None), ...],
+            # the unsharded program crops, only the spatial mesh path
+            # keeps full canvases (crops are net-local).  dispatch = [(subset, tile or None), ...],
             # smallest tiles first, full canvas last.
             if crop_forced is not None and len(dirty):
                 Lm = self.pg.max_span
@@ -1792,17 +1689,9 @@ class Router:
                        else self._staging.put(stg + "widen",
                                               budget_full))
 
-            # per-window dtype/dispatch resolution (re-checked every
-            # window: a mid-route demotion or a service-side ladder
-            # step takes effect at the next window boundary)
-            shadow_now = (pd_req == "bf16"
-                          and guard_mode in ("window", "route")
-                          and not dtype_demoted and not dtype_validated
-                          and (lad is None or lad.level("dtype") == 0))
-            pd_main = ("bf16" if pd_req == "bf16"
-                       and guard_mode == "off" and not dtype_demoted
-                       and (lad is None or lad.level("dtype") == 0)
-                       else "f32")
+            # per-window dispatch resolution (re-checked every window:
+            # a service-side ladder step takes effect at the next
+            # window boundary)
             fused_now = (bool(opts.fused_dispatch) and self.mesh is None
                          and (lad is None
                               or lad.level("dispatch") == 0))
@@ -1821,16 +1710,6 @@ class Router:
                 # crop ladder is single-device VMEM machinery — the
                 # row mesh splits the canvas across chips instead
                 dispatch = [(dirty, None)]
-            sh_stash = []
-            sh_state = None
-            if shadow_now:
-                # window-entry copies for the bf16 shadow replay:
-                # NON-donated (the main dispatch donates the
-                # originals), so the shadow can re-walk the same rungs
-                # after the committed window is in flight
-                sh_state = (occ + 0, acc + 0, paths + 0,
-                            sink_delay + 0, all_reached | False,
-                            bb + 0, crit_d + 0)
 
             def plan_rung(sub, tile, ri):
                 """Host planning for one rung of this window's dispatch
@@ -1905,7 +1784,7 @@ class Router:
                          else min(Smax, _pow2_at_least(
                              math.ceil(maxfan / grp_w) + 1)))
                 kplan = self._plan_block_nets(tile, len(sub), nsw,
-                                              plane_dtype=pd_main)
+                                              plane_dtype=pd)
                 if rm_now is not None:
                     # per-chip cost truth for devprof + the halo
                     # ledger: bytes one sweep's exchange moves at this
@@ -1918,10 +1797,10 @@ class Router:
                         kplan, mesh_shards=rm_now.n_shards,
                         mesh_impl=rm_now.impl,
                         halo_bytes_per_sweep=halo_bytes_per_sweep(
-                            self.pg, bw, rm_now.n_shards, pd_main),
+                            self.pg, bw, rm_now.n_shards, pd),
                         mesh_overlap_frac=modeled_overlap_frac(
                             self.pg, bw, rm_now.n_shards, rm_now.impl,
-                            pd_main))
+                            pd))
                 # staged, hash-skipped plan uploads: identical plans
                 # (endgame windows redispatch the same few dirty nets)
                 # reuse the staged device buffer outright, and fresh
@@ -1967,9 +1846,8 @@ class Router:
                     None if self._mesh_lost else mesh_now)
 
             def rung_kwargs(p):
-                return dict(use_pallas=self.use_pallas,
-                            crop_tile=p["tile"], bb0_all=bb0_d,
-                            widen_ok=p["wok"], plane_dtype=pd_main,
+                return dict(crop_tile=p["tile"], bb0_all=bb0_d,
+                            widen_ok=p["wok"], plane_dtype=pd,
                             **sta_kw)
 
             def window_call(p, esc, pres_in, ri):
@@ -1981,9 +1859,8 @@ class Router:
                 # cache hit
                 vkey = (p["tile"], K, p["nsw"], L, p["waves"],
                         p["grp_w"], p["doubling"], p["sel_shape"][0],
-                        p["sel_shape"][1], p["wok"] is None,
-                        self.use_pallas, mesh_vk,
-                        bool(sta_kw), R, Smax, N, pd_main)
+                        p["sel_shape"][1], p["wok"] is None, mesh_vk,
+                        bool(sta_kw), R, Smax, N, pd)
                 wp_args = rung_args(
                     p, (occ, acc, paths, sink_delay, all_reached, bb,
                         crit_d), esc, pres_in)
@@ -1995,21 +1872,14 @@ class Router:
                     (p["tile"], K, p["nsw"], L, p["waves"],
                      p["grp_w"]), p["kplan"],
                     route_window_planes, wp_args, wp_kwargs)
-                if shadow_now:
-                    # the bf16 shadow replays this exact dispatch on
-                    # its own state copies after the window commits
-                    # (only positions 2-7/10 — the donated state — are
-                    # swapped; plans/tables are reused, not donated)
-                    sh_stash.append((route_window_planes, wp_args,
-                                     wp_kwargs, vkey))
                 with dispatching(window=widx, route=rid, rung=ri):
                     if resil_rt is not None \
                             and resil_rt.guard is not None:
                         # guarded dispatch: watchdog + retry/backoff
                         # over a chain of bit-identical rungs (AOT ->
-                        # jit -> Pallas G=1 -> XLA), each noting the
-                        # variant it runs; injected faults fire before
-                        # the call so donated buffers survive retries
+                        # jit), each noting the variant it runs;
+                        # injected faults fire before the call so
+                        # donated buffers survive retries
                         return self._guarded_dispatch(
                             resil_rt, vkey, wp_args, wp_kwargs)
                     _note_dispatch_variant(vkey)
@@ -2103,22 +1973,17 @@ class Router:
                     K, L)
                 f_kwargs = dict(
                     rung_desc=rung_desc, topk=min(4096, N),
-                    n_colors=5, mesh=mesh_now,
-                    use_pallas=self.use_pallas, bb0_all=bb0_d,
-                    widen_oks=widen_oks, plane_dtype=pd_main,
+                    n_colors=5, mesh=mesh_now, bb0_all=bb0_d,
+                    widen_oks=widen_oks, plane_dtype=pd,
                     **sta_kw)
                 vkey = ("fused", rung_desc, K, L,
                         tuple(p["sel_shape"] for p in plans),
-                        widen_oks is None, self.use_pallas,
-                        mesh_vk, bool(sta_kw),
-                        R, Smax, N, pd_main)
+                        widen_oks is None, mesh_vk, bool(sta_kw),
+                        R, Smax, N, pd)
                 dom = max(kplans, key=lambda kp: kp.get("nets", 0))
                 get_devprof().note_variant(
                     ("fused", rung_desc, K, L), dom,
                     route_window_planes_fused, f_args, f_kwargs)
-                if shadow_now:
-                    sh_stash.append((route_window_planes_fused,
-                                     f_args, f_kwargs, vkey))
 
                 def run_per_rung_fb():
                     # ladder "dispatch" fallback: the SAME planned
@@ -2248,39 +2113,12 @@ class Router:
             out, last_tile = outs[-1]
             force_all_next = False
             # one relaxation dispatch per window when fused, one per
-            # populated crop rung otherwise (main committed path; the
-            # bf16 shadow's validation dispatches are not relaxation
-            # work and are counted by its own demotion telemetry)
+            # populated crop rung otherwise
             reg.set_gauges({
                 "route.kernel.fused_rungs": len(dispatch),
                 "route.kernel.dispatches_per_window":
                     1 if fused_now else len(dispatch),
             })
-
-            # ---- bf16 shadow-oracle replay (dtype_guard): re-walk the
-            # SAME stashed dispatches on the non-donated window-entry
-            # copies with plane_dtype="bf16"; only the donated state
-            # positions (2-7, crit at 10) are swapped — the staged
-            # plans/tables are reused, the programs never donate them.
-            # Its packed summary is compared at the stall below ----
-            sh_out = None
-            if sh_stash:
-                s_st = sh_state
-                for s_fn, a_r, kw_r, s_vk in sh_stash:
-                    with dispatching(window=widx, route=rid,
-                                     shadow=True):
-                        _note_dispatch_variant(s_vk + ("shadow_bf16",))
-                        s_out = s_fn(
-                            *(a_r[:2] + s_st[:6] + a_r[8:10]
-                              + (s_st[6],) + a_r[11:]),
-                            **{**kw_r, "plane_dtype": "bf16"})
-                    retire.append(s_st)
-                    s_st = tuple(s_out[:6]) + (s_out[13],)
-                    sh_out = s_out
-                retire.append(s_st)
-                for a in (sh_out[21], sh_out[22]):
-                    if hasattr(a, "copy_to_host_async"):
-                        a.copy_to_host_async()
 
             # ---- overlapped host stage: consume the PREVIOUS window's
             # summary (its bookkeeping was deferred to here, where this
@@ -2305,29 +2143,6 @@ class Router:
                 dmax_hist = (np.asarray(out[14])  # graftlint: ignore[pipeline-sync]
                              if analyzer is not None
                              else None)
-                if sh_out is not None:
-                    # waiting for the bf16 shadow is the guard's cost
-                    # (it queued behind the committed window, so this
-                    # read is usually already streamed)
-                    s_status = np.asarray(sh_out[21])  # graftlint: ignore[pipeline-sync]
-                    s_scal = np.asarray(sh_out[22])    # graftlint: ignore[pipeline-sync]
-            if sh_out is not None:
-                # the dtype-guard decision point: band-compare the
-                # bf16 shadow's packed summary against the committed
-                # f32 oracle
-                if _dtype_band_ok(status_np, scal_np, s_status,
-                                  s_scal):
-                    if guard_mode == "route":
-                        # per-route spot check: one clean window
-                        # validates the dtype for the rest of the route
-                        dtype_validated = True
-                else:
-                    dtype_demoted = True
-                    reg.counter("route.kernel.dtype_demotions").inc()
-                    reg.gauge("route.kernel.plane_dtype").set("f32")
-                    if lad is not None:
-                        lad.step("dtype", "bf16 window summary left "
-                                 "the declared ulp band")
             t_st1 = time.perf_counter()
             win.__exit__(None, None, None)
             # the host's control step: from this window's summary to

@@ -87,8 +87,7 @@ def _shared_key(req: WindowDispatchRequest):
     topk is deliberately NOT here — it tracks each job's net count and
     rides the per-job statics, so a tiny job fuses with a big one."""
     kw = req.f_kwargs
-    return (kw.get("n_colors"), bool(kw.get("use_pallas")),
-            kw.get("plane_dtype"))
+    return (kw.get("n_colors"), kw.get("plane_dtype"))
 
 
 def _split_request(req: WindowDispatchRequest):
@@ -161,7 +160,6 @@ class FusedSliceRunner:
                   tuple(states), tuple(dyns))
         m_kwargs = dict(job_statics=tuple(statics),
                         n_colors=kw0["n_colors"],
-                        use_pallas=kw0["use_pallas"],
                         plane_dtype=kw0["plane_dtype"])
         # the canonicalized pack shape IS the variant key: the sorted
         # multiset of member window keys — same members, same key,

@@ -197,9 +197,8 @@ def main():
               f"{res.total_relax_steps_wasted} wasted "
               f"({res.total_relax_steps_cropped} in cropped tiles)")
         kv = get_metrics().values("route.kernel.")
-        if kv.get("route.kernel.packed_block_size") is not None:
-            print(f"- kernel layout: {kv['route.kernel.packed_block_size']} "
-                  f"nets/block, lane occupancy "
+        if kv.get("route.kernel.lane_occupancy") is not None:
+            print(f"- kernel layout: lane occupancy "
                   f"{kv.get('route.kernel.lane_occupancy')}, "
                   f"~{kv.get('route.kernel.bytes_per_sweep')} modeled "
                   f"HBM bytes/sweep (dominant window shape)")

@@ -187,44 +187,11 @@ def test_route_window_program_compiles_for_every_bench_variant(one_chip):
         _fits_hbm(compiled)
 
 
-# ---- the packed Pallas kernels, asked for real ---------------------
-
-_PALLAS_REFUSAL = (
-    "Mosaic refuses lax.associative_scan inside the kernel "
-    "(planes.py _minplus_scan): 'vector types must have positive "
-    "constant sizes but got 16, 12, 0, 9' — ROADMAP Queue 1 item 2; "
-    "flip this when the kernel compiles")
-
-
-@pytest.mark.xfail(strict=True, reason=_PALLAS_REFUSAL)
-def test_planes_relax_pallas_compiles(one_chip):
-    from parallel_eda_tpu.route.planes_pallas import planes_relax_pallas
-
-    pg = _planes(8, 12)
-    fn = jax.jit(functools.partial(planes_relax_pallas, interpret=False),
-                 static_argnames=("nsweeps",))
-    fn.lower(*_relax_avatars(pg, 16, one_chip), nsweeps=12).compile()
-
-
-@pytest.mark.xfail(strict=True, reason=_PALLAS_REFUSAL)
-def test_planes_relax_cropped_pallas_compiles(one_chip):
-    from parallel_eda_tpu.route.planes_pallas import (
-        planes_relax_cropped_pallas)
-
-    pg = _planes(8, 12)
-    origin = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
-    fn = jax.jit(
-        functools.partial(planes_relax_cropped_pallas, interpret=False),
-        static_argnames=("nsweeps", "cnx", "cny"))
-    fn.lower(*_relax_avatars(pg, 16, one_chip), nsweeps=12,
-             ox=origin, oy=origin, cnx=4, cny=4).compile()
-
-
 # ---- the paths that exist only across chips ------------------------
 
 def test_remote_slab_permute_compiles_on_four_chip_mesh(row_mesh):
-    from parallel_eda_tpu.route.planes_pallas import remote_slab_permute
-    from parallel_eda_tpu.route.planes_shard import ROW_AXIS
+    from parallel_eda_tpu.route.planes_shard import (ROW_AXIS,
+                                                     remote_slab_permute)
 
     n = row_mesh.devices.size
     pg = _planes(ROUTE_NX, ROUTE_W)

@@ -268,6 +268,53 @@ def test_fused_service_join_leave_parity():
     assert all(0.0 <= e["lane_occupancy"] <= 1.0 for e in rb["events"])
 
 
+def test_multi_window_equals_each_jobs_fused_window():
+    """ONE window of route_window_planes_multi over two co-admitted
+    jobs equals each job's route_window_planes_fused dispatched alone,
+    element for element of the 24-tuple: every job's ladder is an
+    independent subgraph of the one program.  The requests are the
+    first ones each job's route_gen yields, split as
+    FusedSliceRunner._dispatch_multi splits them."""
+    import jax
+
+    from parallel_eda_tpu.flow import synth_flow
+    from parallel_eda_tpu.route.planes import (route_window_planes_fused,
+                                               route_window_planes_multi)
+    from parallel_eda_tpu.serve.fused import _shared_key, _split_request
+
+    flows = [synth_flow(num_luts=10, seed=s) for s in (1, 2)]
+    router = Router(flows[0].rr, RouterOpts(
+        batch_size=32, sink_group=0, fused_dispatch=True))
+
+    def first_request(term, prefix):
+        router._staging_prefix = prefix
+        return next(router.route_gen(term))
+
+    solo = []
+    for i, f in enumerate(flows):
+        req = first_request(f.term, f"solo{i}:")
+        solo.append(route_window_planes_fused(*req.f_args,
+                                              **req.f_kwargs))
+    # fresh requests: the solo dispatches donated their state
+    reqs = [first_request(f.term, f"multi{i}:")
+            for i, f in enumerate(flows)]
+    assert _shared_key(reqs[0]) == _shared_key(reqs[1])
+    states, dyns, statics = zip(*(_split_request(r) for r in reqs))
+    assert statics[0] != statics[1]           # two different ladders
+    kw0 = reqs[0].f_kwargs
+    outs = route_window_planes_multi(
+        router.pg, router.dev, tuple(states), tuple(dyns),
+        job_statics=tuple(statics), n_colors=kw0["n_colors"],
+        plane_dtype=kw0["plane_dtype"])
+    jax.block_until_ready(outs)
+    for got, want in zip(outs, solo):
+        assert len(got) == len(want) == 24
+        assert np.asarray(want[4]).any()      # nets were routed
+        for k, (a, b) in enumerate(zip(got, want)):
+            assert np.array_equal(np.asarray(a), np.asarray(b),
+                                  equal_nan=True), k
+
+
 # ---- flow_doctor rebatch rules (crafted summaries, no jax) ---------
 
 def _doctor():

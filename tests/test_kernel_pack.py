@@ -1,18 +1,20 @@
-"""Lane-packed kernel blocks == one-net-per-step path, bit-for-bit.
+"""The planes relaxation is PER-NET, and plane_dtype names what commits.
 
-The packed kernels (planes_pallas, block of G nets per grid step,
-canvases folded + lane-padded) slice every canvas back to its unpadded
-shape before the shared sweep body runs, so for ANY block size the
-results must equal the legacy layout (block_nets=1, lane_mult=1)
-EXACTLY — same lowering, same shapes inside the body, same fold order.
-Covers odd batch remainders (inert pad nets), directional archs, and
-two crop-ladder rungs.  Interpret mode (no TPU in the test env).
+First half: net independence of the XLA relaxation, the property
+`route_window_planes_multi` and `serve/fused.py` rest on — a batch
+relaxes each net on its own canvas against its own congestion view, so
+`planes_relax` / `planes_relax_cropped` over a batch equal each net
+relaxed alone, bit for bit (a converged net's extra trips while a
+batchmate is still improving are identities).  Covers both stencil
+kinds (bidirectional, directional) and two crop-ladder rungs.  The
+host-side block-planning arithmetic (`serve/batcher.py`) is checked
+beside it.
 
-The second half extends the same bit-exactness contract to the PR-11
-kernel modes at full routing fidelity: guarded bf16 planes and the
-fused ragged window dispatch must reproduce the f32 per-rung route
-exactly, and a forced ulp-band violation must demote through the resil
-ladder's dtype dimension without changing QoR.
+Second half, at full routing fidelity: the fused ragged window dispatch
+reproduces the f32 per-rung route exactly on every graph kind;
+``plane_dtype="bf16"`` COMMITS bf16 — a legal route whose wirelength
+recount holds, per-rung and fused; and the option values that selected
+the deleted Pallas lowering and the shadow guards are refused by name.
 """
 
 import jax.numpy as jnp
@@ -20,13 +22,14 @@ import numpy as np
 import pytest
 
 from parallel_eda_tpu.arch.builtin import minimal_arch, unidir_arch
-from parallel_eda_tpu.route.planes import build_planes
-from parallel_eda_tpu.route.planes_pallas import (
-    VMEM_BUDGET_BYTES, auto_block_nets, packed_layout,
-    planes_relax_cropped_pallas, planes_relax_pallas,
-    unpacked_lane_occupancy)
+from parallel_eda_tpu.route.planes import (build_planes, planes_relax,
+                                           planes_relax_cropped)
 from parallel_eda_tpu.rr.graph import CHANX, CHANY, build_rr_graph
 from parallel_eda_tpu.rr.grid import DeviceGrid
+from parallel_eda_tpu.serve.batcher import (VMEM_BUDGET_BYTES,
+                                            auto_block_nets,
+                                            packed_layout,
+                                            unpacked_lane_occupancy)
 
 
 def _instance(arch, nx, ny, B, seed):
@@ -62,27 +65,44 @@ def _assert_identical(a, b):
             assert np.array_equal(x, y)
 
 
-@pytest.mark.parametrize("arch,nx,ny,B,G,seed", [
-    (minimal_arch(chan_width=6), 4, 4, 5, 4, 0),     # odd remainder
-    (minimal_arch(chan_width=6), 5, 4, 4, 2, 1),
-    (unidir_arch(chan_width=6, length=2), 5, 4, 3, 2, 3),  # directional
+def _assert_nets_relax_alone(batch, relax_one, B):
+    """``batch`` = (dist, pred, wenter, stats) of the whole batch;
+    ``relax_one(sl)`` relaxes the nets of slice ``sl`` alone.  The
+    per-net outputs must match bit for bit, and the batch's sweep count
+    is the slowest member's (stats are per-dispatch maxima)."""
+    trips = []
+    for b in range(B):
+        sl = slice(b, b + 1)
+        alone = relax_one(sl)
+        _assert_identical([np.asarray(t)[sl] for t in batch[:3]],
+                          alone[:3])
+        trips.append(int(alone[3][0]))
+    assert np.isfinite(np.asarray(batch[0])).any()
+    assert int(batch[3][0]) == max(trips)
+
+
+@pytest.mark.parametrize("plane_dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("arch,nx,ny,B,seed", [
+    (minimal_arch(chan_width=6), 4, 4, 5, 0),
+    (minimal_arch(chan_width=6), 5, 4, 4, 1),
+    (unidir_arch(chan_width=6, length=2), 5, 4, 3, 3),   # directional
 ])
-def test_packed_full_matches_one_net_per_step(arch, nx, ny, B, G, seed):
+def test_relax_net_independent(arch, nx, ny, B, seed, plane_dtype):
+    """Both storage dtypes: the bf16 loop requantizes per net too."""
     _, pg, d0, cc, crit, w0 = _instance(arch, nx, ny, B, seed)
-    ref = planes_relax_pallas(pg, d0, cc, crit, w0, 12, interpret=True,
-                              block_nets=1, lane_mult=1)
-    packed = planes_relax_pallas(pg, d0, cc, crit, w0, 12,
-                                 interpret=True, block_nets=G,
-                                 lane_mult=8)
-    _assert_identical(ref, packed)
-    # the auto-planned default takes the packed path too
-    auto = planes_relax_pallas(pg, d0, cc, crit, w0, 12, interpret=True)
-    _assert_identical(ref, auto)
+    batch = planes_relax(pg, d0, cc, crit, w0, 12,
+                         plane_dtype=plane_dtype)
+    _assert_nets_relax_alone(
+        batch,
+        lambda sl: planes_relax(pg, d0[sl], cc[sl], crit[sl], w0[sl], 12,
+                                plane_dtype=plane_dtype),
+        B)
 
 
-@pytest.mark.parametrize("cnx,cny,G", [(6, 6, 2), (8, 5, 4)])
-def test_packed_cropped_matches_one_net_per_step(cnx, cny, G):
-    """Two crop-ladder rungs (square + rectangular), odd B vs G."""
+@pytest.mark.parametrize("cnx,cny", [(6, 6), (8, 5)])
+def test_relax_cropped_net_independent(cnx, cny):
+    """Two crop-ladder rungs (square + rectangular), each net's tile at
+    its own origin."""
     arch = minimal_arch(chan_width=8)
     grid = DeviceGrid(12, 10, arch.io_capacity)
     rr = build_rr_graph(arch, grid)
@@ -118,60 +138,14 @@ def test_packed_cropped_matches_one_net_per_step(cnx, cny, G):
     w0 = jnp.zeros((B, pg.ncells), jnp.float32)
     oxj, oyj = jnp.asarray(ox), jnp.asarray(oy)
 
-    ref = planes_relax_cropped_pallas(pg, d0, cc, crit, w0, 24, oxj,
-                                      oyj, cnx, cny, interpret=True,
-                                      block_nets=1, lane_mult=1)
-    packed = planes_relax_cropped_pallas(pg, d0, cc, crit, w0, 24, oxj,
-                                         oyj, cnx, cny, interpret=True,
-                                         block_nets=G, lane_mult=8)
-    _assert_identical(ref, packed)
-
-
-@pytest.mark.kernelbench
-def test_kernel_bench_quick_check(tmp_path):
-    """tools/kernel_bench.py --quick writes a ledger that its own
-    --check validator accepts — including the >= 50% lane-occupancy
-    floor on every packed-variant row."""
-    import importlib.util
-    from pathlib import Path
-
-    tool = Path(__file__).resolve().parent.parent / "tools" / \
-        "kernel_bench.py"
-    spec = importlib.util.spec_from_file_location("kernel_bench", tool)
-    kb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(kb)
-
-    out = tmp_path / "kernel_ledger.json"
-    assert kb.main(["--quick", "--out", str(out)]) == 0
-    assert kb.main(["--check", str(out)]) == 0
-    import json
-    doc = json.loads(out.read_text())
-    packed = [r for r in doc["rows"]
-              if r["variant"].startswith("pallas_packed")]
-    assert packed and all(r["lane_occupancy"] >= 0.5 for r in packed)
-    assert all(r["bytes_per_sweep"] > 0 for r in doc["rows"])
-    # --quick benches f32 AND bf16 rows by default, and the bf16
-    # packed full-canvas byte model lands under the 0.6x-of-f32
-    # acceptance bar check_ledger enforces
-    bps = {r["plane_dtype"]: r["bytes_per_sweep"] * r["sweeps_executed"]
-           for r in doc["rows"] if r["variant"] == "pallas_packed"}
-    assert set(bps) == {"f32", "bf16"}
-    assert bps["bf16"] <= kb.BF16_PACKED_BYTES_RATIO_MAX * bps["f32"]
-    assert set(doc.get("dispatch_overhead", {})) == {"f32", "bf16"}
-    # a bf16 model that saves no bytes must fail the gate
-    inflated = json.loads(json.dumps(doc))
-    for r in inflated["rows"]:
-        if r["variant"] == "pallas_packed" \
-                and r["plane_dtype"] == "bf16":
-            r["bytes_per_sweep"] = bps["f32"]
-    bad = tmp_path / "bad_ratio.json"
-    bad.write_text(json.dumps(inflated))
-    assert kb.main(["--check", str(bad)]) != 0
-    # a corrupted ledger must fail the gate
-    doc["rows"][0].pop("roofline_fraction")
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps(doc))
-    assert kb.main(["--check", str(bad)]) != 0
+    batch = planes_relax_cropped(pg, d0, cc, crit, w0, 24, oxj, oyj,
+                                 cnx, cny)
+    _assert_nets_relax_alone(
+        batch,
+        lambda sl: planes_relax_cropped(pg, d0[sl], cc[sl], crit[sl],
+                                        w0[sl], 24, oxj[sl], oyj[sl],
+                                        cnx, cny),
+        B)
 
 
 def test_block_planning_model():
@@ -192,15 +166,12 @@ def test_block_planning_model():
 
 
 # --------------------------------------------------------------------
-# Full-route parity for the PR-11 kernel modes: reduced-precision
-# planes (guarded) and the fused ragged window program are PERFORMANCE
-# knobs — occ/paths/wirelength must stay bit-identical to the f32
-# per-rung baseline on every arch family.  Flows and the f32 baseline
-# route are cached at module scope so each mode pays one route, not
-# three.
+# Full-route checks.  Flows and the f32 per-rung baseline route are
+# cached at module scope so each mode pays one route.
 
 _FLOWS: dict = {}
 _BASE: dict = {}
+_GRAPHS = ["bench", "unidir", "random7"]
 
 
 def _flow(name):
@@ -213,8 +184,8 @@ def _flow(name):
                 arch=unidir_arch(chan_width=14, length=2))
         elif name == "random7":
             # a second generate_circuit draw: different seed, different
-            # topology — guards against a parity result that only holds
-            # for one routing instance
+            # topology — guards against a result that only holds for
+            # one routing instance
             _FLOWS[name] = synth_flow(
                 num_luts=18, num_inputs=6, num_outputs=6,
                 chan_width=10, seed=7)
@@ -248,51 +219,59 @@ def _assert_route_parity(name, kw):
 
 
 @pytest.mark.parametrize("kw", [
-    dict(plane_dtype="bf16"),                        # per-window guard
-    dict(plane_dtype="bf16", dtype_guard="route"),   # first-clean-window
     dict(fused_dispatch=True),                       # 1 dispatch/window
-    dict(plane_dtype="bf16", fused_dispatch=True),   # both at once
-], ids=["bf16_window", "bf16_route", "fused", "fused_bf16"])
+], ids=["fused"])
 def test_route_parity_bench_arch(kw):
     _assert_route_parity("bench", kw)
 
 
 @pytest.mark.parametrize("name", ["unidir", "random7"])
 def test_route_parity_other_archs(name):
-    """Directional wiring and a second random circuit, with both PR-11
-    knobs on simultaneously."""
-    _assert_route_parity(name,
-                         dict(plane_dtype="bf16", fused_dispatch=True))
+    """Directional wiring and a second random circuit through the fused
+    ragged window program."""
+    _assert_route_parity(name, dict(fused_dispatch=True))
 
 
-def test_forced_band_violation_demotes_dtype(monkeypatch):
-    """A bf16 window summary that leaves the declared ulp band must
-    demote the route to f32: the demotion counter fires once, the
-    plane_dtype gauge flips, the resil ladder's dtype dimension steps —
-    and QoR is still the f32 oracle's, because guarded mode never
-    committed a bf16 result in the first place."""
+@pytest.mark.parametrize("fused", [False, True],
+                         ids=["per_rung", "fused"])
+@pytest.mark.parametrize("name", _GRAPHS)
+def test_bf16_route_commits_and_is_legal(name, fused):
+    """plane_dtype="bf16" is the dtype that is committed: the route
+    converges, check_route (the independent legality oracle) accepts
+    its trees against its own occupancy, and the oracle's wirelength
+    recount equals the router's.  No parity with f32 is asked: bf16 is
+    a different result, not a faster f32 one."""
     from parallel_eda_tpu.obs import (MetricsRegistry, get_metrics,
                                       set_metrics)
-    from parallel_eda_tpu.resil import Resilience, ResilOpts
-    from parallel_eda_tpu.route import Router, RouterOpts
-    from parallel_eda_tpu.route import router as router_mod
-
-    monkeypatch.setattr(router_mod, "_dtype_band_ok",
-                        lambda *a, **k: False)
+    from parallel_eda_tpu.route import Router, RouterOpts, check_route
+    f = _flow(name)
     old = get_metrics()
     reg = set_metrics(MetricsRegistry())
     try:
-        rt = Resilience(ResilOpts())
-        f = _flow("bench")
         res = Router(f.rr, RouterOpts(
             batch_size=32, plane_dtype="bf16",
-            resil=rt)).route(f.term)
-        assert res.success
-        base = _baseline("bench")
-        assert np.array_equal(base.paths, res.paths)
-        assert base.wirelength == res.wirelength
-        assert reg.counter("route.kernel.dtype_demotions").value == 1
-        assert reg.gauge("route.kernel.plane_dtype").value == "f32"
-        assert rt.ladder.level("dtype") == 1
+            fused_dispatch=fused)).route(f.term)
+        assert reg.gauge("route.kernel.plane_dtype").value == "bf16"
     finally:
         set_metrics(old)
+    assert res.success
+    judged = check_route(f.rr, f.term, res.paths, occ=res.occ)
+    assert judged["wirelength"] == res.wirelength > 0
+
+
+@pytest.mark.parametrize("kw,names", [
+    (dict(program="planes_pallas"), ("'planes'", "'ell'")),
+    (dict(dtype_guard="window"), ("dtype_guard", "'off'")),
+    (dict(dtype_guard="route"), ("dtype_guard", "'off'")),
+], ids=["planes_pallas", "guard_window", "guard_route"])
+def test_deleted_option_values_are_refused(kw, names):
+    """The values that selected the Pallas lowering and the shadow
+    guards raise a ValueError that names what is accepted — at Router
+    construction (program) or at route start (dtype_guard) — and never
+    fall through to another program or to a silent f32 commit."""
+    from parallel_eda_tpu.route import Router, RouterOpts
+    f = _flow("bench")
+    with pytest.raises(ValueError) as ei:
+        Router(f.rr, RouterOpts(batch_size=32, **kw)).route(f.term)
+    for n in names:
+        assert n in str(ei.value)

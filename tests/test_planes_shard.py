@@ -12,9 +12,15 @@ Three layers, mirroring tests/test_kernel_pack.py's parity discipline:
   later, and the strict-< update keeps whichever arrived first — the
   router's per-(net,node) jitter makes shortest paths unique, which
   is why ROUTE-level parity below is exact.
+* window parity — ONE window of the per-rung program
+  (route_window_planes, the `_window_body` every planes program
+  shares) under a two-shard RowMesh and under the GSPMD `net` mesh
+  must leave the one-device window's state and ledger, bit for bit;
+  tier-1 runs these (the whole-route gates below are `slow`).
 * route parity — a mesh-sharded Router run must produce bit-identical
   paths/occ/wirelength to the single-device baseline (incl. fused
-  dispatch and bf16 planes), and the halo ledger must be populated.
+  dispatch), a bf16 one must equal the single-device bf16 route, and
+  the halo ledger must be populated.
 * degradation — an injected backend.loss must land the resilience
   ladder's "mesh" dimension on the single_chip floor and still finish
   bit-identical.
@@ -23,8 +29,7 @@ The mesh layers need >= 4 visible devices
 (XLA_FLAGS=--xla_force_host_platform_device_count=4, as the CI
 mesh-smoke job sets); on a stock 1-device tier-1 host they skip.
 The model/validation layers (make_mesh argument checking, the
-dtype-aware halo byte model, fold/unfold at shard boundaries, the
-corpus n_shards field, flow_doctor's mesh-consistency rule) run
+dtype-aware halo byte model, the corpus n_shards field, flow_doctor's mesh-consistency rule) run
 everywhere.
 """
 
@@ -40,9 +45,8 @@ from parallel_eda_tpu.arch.builtin import minimal_arch, unidir_arch
 from parallel_eda_tpu.flow import synth_flow
 from parallel_eda_tpu.obs import MetricsRegistry, get_metrics, set_metrics
 from parallel_eda_tpu.route import Router, RouterOpts, check_route
-from parallel_eda_tpu.route.planes import (build_planes, fold_canvas,
-                                           plane_itemsize, planes_relax,
-                                           unfold_canvas)
+from parallel_eda_tpu.route.planes import (build_planes, plane_itemsize,
+                                           planes_relax)
 from parallel_eda_tpu.route.planes_shard import (halo_bytes_per_sweep,
                                                  make_row_mesh,
                                                  modeled_overlap_frac,
@@ -164,6 +168,52 @@ def test_kernel_parity_unidir_arch():
                           equal_nan=True)
 
 
+# ---- one window of the per-rung program under each mesh ------------
+
+def _one_window(mesh=None, shard=None):
+    """__graft_entry__'s tiny problem through ONE one-iteration window
+    of route_window_planes (one forced group): the state 6-tuple and
+    the packed scal ledger."""
+    import __graft_entry__ as graft
+    from parallel_eda_tpu.route.planes import route_window_planes
+
+    p = graft.planes_step_problem()
+    occ, acc, paths, sink_delay, all_reached, bb = p["state"]
+    dev = p["dev"] if shard is None else shard(p["dev"])
+    out = route_window_planes(
+        p["pg"], dev, occ, acc, paths, sink_delay, all_reached, bb,
+        *p["nets"], p["sel"][None], p["valid"][None], p["full_bb"],
+        jnp.float32(0.5), jnp.float32(1.0), jnp.float32(1.0),
+        jnp.float32(0.0), jnp.int32(0), jnp.int32(1),
+        1, p["nsweeps"], p["max_len"], p["num_waves"], p["group"],
+        True, topk=64, mesh=mesh)
+    return out[:6] + (out[22],)
+
+
+def _assert_window_equal(got):
+    if "window" not in _BASE:
+        _BASE["window"] = _one_window()
+    want = _BASE["window"]
+    assert np.asarray(want[4]).any()          # nets were routed
+    for name, a, b in zip(("occ", "acc", "paths", "sink_delay",
+                           "all_reached", "bb", "scal"), got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b),
+                              equal_nan=True), name
+
+
+@needs_mesh
+def test_window_under_gspmd_net_mesh_equals_one_device():
+    from parallel_eda_tpu.parallel.shard import make_mesh, shard_graph
+    mesh = make_mesh(2, shape=(2, 1))
+    _assert_window_equal(
+        _one_window(mesh, lambda dev: shard_graph(dev, mesh)))
+
+
+@needs_mesh
+def test_window_under_two_shard_row_mesh_equals_one_device():
+    _assert_window_equal(_one_window(make_row_mesh(2, "ppermute")))
+
+
 # ---- route parity (needs a mesh) -----------------------------------
 
 def _assert_route_parity(**kw):
@@ -210,7 +260,18 @@ def test_route_parity_mesh2():
 @needs_mesh
 @pytest.mark.slow
 def test_route_parity_mesh3_bf16():
-    _assert_route_parity(mesh_shards=3, plane_dtype="bf16")
+    """bf16 commits bf16, so the reference is the single-device bf16
+    route, not the f32 baseline."""
+    f = _flow()
+    ref = Router(f.rr, RouterOpts(batch_size=32,
+                                  plane_dtype="bf16")).route(f.term)
+    res = Router(f.rr, RouterOpts(batch_size=32, plane_dtype="bf16",
+                                  mesh_shards=3)).route(f.term)
+    assert ref.success and res.success
+    assert res.wirelength == ref.wirelength
+    assert np.array_equal(np.asarray(ref.paths), np.asarray(res.paths))
+    assert np.array_equal(np.asarray(ref.occ), np.asarray(res.occ))
+    check_route(f.rr, f.term, res.paths, occ=res.occ)
 
 
 @needs_mesh
@@ -293,13 +354,6 @@ def test_ladder_has_mesh_dimension():
         assert _LABEL_DIM[label] == "mesh"
 
 
-def test_router_rejects_mesh_with_packed_kernel():
-    f = _flow()
-    with pytest.raises(ValueError, match="mesh_shards"):
-        Router(f.rr, RouterOpts(batch_size=32, mesh_shards=2,
-                                program="planes_pallas"))
-
-
 def test_router_rejects_mesh_with_legacy_mesh():
     from parallel_eda_tpu.parallel.shard import make_mesh
     f = _flow()
@@ -344,55 +398,6 @@ def test_make_mesh_both_axis_orders():
     assert m.shape[NET] == n and m.shape[NODE] == 1
     m = make_mesh(n, shape=(1, n))
     assert m.shape[NET] == 1 and m.shape[NODE] == n
-
-
-# ---- fold/unfold at shard boundaries (satellite) --------------------
-
-def test_fold_unfold_roundtrip_non_lane_multiple():
-    rng = np.random.default_rng(0)
-    for shape in ((3, 5, 7, 13), (2, 6, 9, 11), (4, 1, 5, 3)):
-        a = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-        for pad_y in (0, 3, (-shape[-1]) % 8, 128 - shape[-1]):
-            folded = fold_canvas(a, pad_y)
-            assert folded.shape == (
-                shape[0],
-                int(np.prod(shape[1:-1])) * (shape[-1] + pad_y))
-            back = unfold_canvas(folded, shape[1:], pad_y)
-            assert np.array_equal(np.asarray(back), np.asarray(a))
-
-
-def test_fold_pad_columns_are_storage_only():
-    """Garbage written into the pad columns must vanish on unfold."""
-    rng = np.random.default_rng(1)
-    shape = (3, 4, 6, 13)
-    pad_y = 3
-    a = rng.normal(size=shape).astype(np.float32)
-    folded = np.asarray(fold_canvas(jnp.asarray(a), pad_y)).copy()
-    view = folded.reshape(shape[0], shape[1], shape[2],
-                          shape[3] + pad_y)
-    view[..., shape[3]:] = np.nan
-    back = unfold_canvas(jnp.asarray(folded), shape[1:], pad_y)
-    assert np.array_equal(np.asarray(back), a)
-
-
-def test_fold_unfold_ragged_shard_block():
-    """A shard boundary falling on a non-lane-multiple row: the last
-    row block of a padded canvas is RAGGED (NX + 2 not divisible by
-    n_shards), and its fold/unfold must still round-trip — the packed
-    storage must not assume lane-multiple X extents."""
-    pg = _small_pg()
-    W, NX, NYp1 = pg.shape_x
-    s = 3
-    kx = row_block_cols(pg, s)
-    assert (NX + 2) % s != 0    # the fixture exercises the ragged case
-    rng = np.random.default_rng(2)
-    a = rng.normal(size=(2, W, s * kx, NYp1)).astype(np.float32)
-    for i in range(s):
-        blk = jnp.asarray(a[:, :, i * kx:(i + 1) * kx, :])
-        pad_y = (-NYp1) % 8
-        back = unfold_canvas(fold_canvas(blk, pad_y),
-                             (W, kx, NYp1), pad_y)
-        assert np.array_equal(np.asarray(back), np.asarray(blk))
 
 
 # ---- corpus n_shards field (satellite) ------------------------------

@@ -255,23 +255,20 @@ def test_guard_injected_hang_counts_as_timeout_then_retries():
 
 def test_ladder_levels_records_and_floor():
     lad = DegradationLadder()
-    assert lad.snapshot() == {"kernel": "pallas_packed",
-                              "pipeline": "pipelined",
+    assert lad.snapshot() == {"pipeline": "pipelined",
                               "program": "aot",
-                              "dtype": "bf16",
                               "dispatch": "fused",
                               "mesh": "pallas_halo"}
     assert lad.step("pipeline", reason="poisoned dispatch")
     assert lad.level("pipeline") == 1
     assert lad.name("pipeline") == "sync"
     assert not lad.step("pipeline", reason="again")   # at the floor
-    lad.record("pallas_packed", reason="quarantined")
+    lad.record("aot", reason="quarantined")
     v = _vals()
     assert v["route.resil.level.pipeline"] == 1
-    assert v["route.resil.level.kernel"] == 0
+    assert v["route.resil.level.program"] == 0
     assert v["route.resil.degradation_steps"] == 2
-    assert set(DIMS) == {"kernel", "pipeline", "program", "dtype",
-                         "dispatch", "mesh"}
+    assert set(DIMS) == {"pipeline", "program", "dispatch", "mesh"}
 
 
 # ---- queue backoff vs deadline (fake clock; no jax) ----------------

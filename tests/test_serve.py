@@ -212,37 +212,27 @@ def test_batcher_strict_demux():
 
 
 def test_batcher_cross_job_relax_parity():
-    """Folding two jobs' nets into ONE packed relaxation batch changes
-    nothing, net for net: canvases are per-net, so the packed kernel is
-    job-agnostic — the property that makes cross-job lane packing
-    QoR-neutral by construction."""
+    """Folding two jobs' nets into ONE relaxation batch changes
+    nothing, net for net: canvases are per-net, so the relaxation is
+    job-agnostic — the property that makes cross-job packing (and the
+    multi-job window program) QoR-neutral by construction."""
     from parallel_eda_tpu.arch.builtin import minimal_arch
-    from parallel_eda_tpu.route.planes_pallas import (auto_block_nets,
-                                                      planes_relax_pallas)
+    from parallel_eda_tpu.route.planes import planes_relax
     from tests.test_kernel_pack import _assert_identical, _instance
 
     arch = minimal_arch(chan_width=6)
     _, pg, d0, cc, crit, w0 = _instance(arch, 4, 4, 7, seed=11)
     # nets 0..2 belong to job A, 3..6 to job B (same device graph)
     slA, slB = slice(0, 3), slice(3, 7)
-    soloA = planes_relax_pallas(pg, d0[slA], cc[slA], crit[slA],
-                                w0[slA], 12, interpret=True,
-                                block_nets=1, lane_mult=1)
-    soloB = planes_relax_pallas(pg, d0[slB], cc[slB], crit[slB],
-                                w0[slB], 12, interpret=True,
-                                block_nets=1, lane_mult=1)
-    G = auto_block_nets(pg.shape_x, pg.shape_y, 7)
-    shared = planes_relax_pallas(pg, d0, cc, crit, w0, 12,
-                                 interpret=True, block_nets=G,
-                                 lane_mult=8)
-    # stats (index 2+) are per-dispatch maxima, not per-net — compare
-    # the per-net outputs (dist, winner)
-    _assert_identical([np.asarray(shared[0])[slA],
-                       np.asarray(shared[1])[slA]],
-                      [soloA[0], soloA[1]])
-    _assert_identical([np.asarray(shared[0])[slB],
-                       np.asarray(shared[1])[slB]],
-                      [soloB[0], soloB[1]])
+    soloA = planes_relax(pg, d0[slA], cc[slA], crit[slA], w0[slA], 12)
+    soloB = planes_relax(pg, d0[slB], cc[slB], crit[slB], w0[slB], 12)
+    shared = planes_relax(pg, d0, cc, crit, w0, 12)
+    # stats (index 3) are per-dispatch maxima, not per-net — compare
+    # the per-net outputs (dist, pred, wenter)
+    for sl, solo in ((slA, soloA), (slB, soloB)):
+        _assert_identical([np.asarray(t)[sl] for t in shared[:3]],
+                          solo[:3])
+    assert int(shared[3][0]) == max(int(soloA[3][0]), int(soloB[3][0]))
 
 
 # ---- runstore v2 + observatory tenant grouping ---------------------
@@ -306,7 +296,7 @@ def test_library_static_split():
     # the constant matches the live signature
     assert set(WINDOW_STATIC_ARGNAMES) <= set(names)
     args = tuple(f"v_{n}" for n in names)
-    kwargs = {"use_pallas": True, "crop_tile": (8, 8), "bb0_all": "bb0"}
+    kwargs = {"use_sdc": True, "crop_tile": (8, 8), "bb0_all": "bb0"}
     dyn_args, dyn_kwargs = lib._split_dynamic(
         route_window_planes, args, kwargs)
     assert len(dyn_args) == len(names) - sum(

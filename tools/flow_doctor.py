@@ -437,15 +437,11 @@ def check_resil(doc: dict) -> tuple:
     inj = g("injections")
     wdt = g("watchdog_timeouts")
     derr = g("dispatch_errors")
-    # a bf16 window summary leaving the declared ulp band steps the
-    # dtype ladder dimension (router._dtype_band_ok) — a legitimate,
-    # counted cause for a degradation step
-    dtyped = vals.get("route.kernel.dtype_demotions") or 0
     # a lost mesh member demotes the mesh ladder dimension to
-    # single_chip (router._mesh_demote) — like dtype_demotions, a
-    # legitimate, counted cause for quarantine/degradation steps
+    # single_chip (router._mesh_demote) — a legitimate, counted cause
+    # for quarantine/degradation steps
     meshd = vals.get("route.mesh.mesh_demotions") or 0
-    causes = inj + wdt + derr + dtyped + meshd
+    causes = inj + wdt + derr + meshd
     q = g("quarantined_variants")
     ret = g("retries")
     cap = g("retry_cap")

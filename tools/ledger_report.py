@@ -115,10 +115,6 @@ def validate(doc) -> list:
             errs.append(
                 f"route.kernel.dispatches_per_window {dpw} exceeds the "
                 f"populated-rung count route.kernel.fused_rungs {fr}")
-    dem = values.get("route.kernel.dtype_demotions")
-    if dem is not None and not (
-            isinstance(dem, (int, float)) and dem >= 0):
-        errs.append(f"bad route.kernel.dtype_demotions {dem!r}")
     pd = values.get("route.kernel.plane_dtype")
     if pd is not None and pd not in ("f32", "bf16"):
         errs.append(f"bad route.kernel.plane_dtype {pd!r}")

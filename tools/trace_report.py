@@ -375,13 +375,10 @@ def summarize(doc) -> str:
         occs = [e["args"]["lane_occupancy"] for e in kernels
                 if isinstance(e.get("args", {}).get("lane_occupancy"),
                               (int, float))]
-        gmax = max((e.get("args", {}).get("block_nets", 0)
-                    for e in kernels), default=0)
         variants = sorted({e.get("args", {}).get("variant", "?")
                            for e in kernels})
         line = (f"kernel layout: {len(kernels)} window plan(s), "
-                f"variants {'/'.join(variants)}, "
-                f"block_nets<= {gmax}")
+                f"variants {'/'.join(variants)}")
         if occs:
             line += (f", lane occupancy {min(occs):.3f}"
                      f"..{max(occs):.3f} "
