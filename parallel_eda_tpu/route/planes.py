@@ -62,12 +62,16 @@ import numpy as np
 from flax import struct
 from jax import lax
 
+from ..obs import get_metrics
 from ..obs.trace import device_scope
 from ..rr.graph import CHANX, CHANY, RRGraph, unidir_exit_point
 from .device_graph import DeviceRRGraph
 from .search import JITTER_EPS, congestion_cost, usage_from_paths
 
 INF = jnp.inf
+# PlanesTerminals.uid_pcrank where a pin does not hear a cell: past
+# every rank, so the device needs no K
+RANK_PAD = np.iinfo(np.int32).max
 
 
 # ---------------------------------------------------------------------------
@@ -276,22 +280,40 @@ class PlanesTerminals:
 
     SOURCE side: the net's source-class OPINs and every OPIN->wire edge as
     (wire cell, opin index, exact edge delay).  SINK side: every
-    (wire -> IPIN -> SINK) two-edge hop as (wire cell, ipin node, exact
-    total delay) — FACTORIZED by unique sink node: the candidate tables
-    are stored once per distinct SINK rr-node ([U, K], U ~ #blocks) and
-    every (net, sink) slot holds only an int32 index into them.  This
-    removes the [R, S, K] dense term that dominated the Titan-scale
-    memory model (BENCHMARKS.md; the reference's per-node fan-in lists,
-    init.cxx:85, are the same sharing).  All host numpy; the Router
-    uploads them once per route() call and keeps them device-resident."""
+    (wire -> IPIN -> SINK) two-edge hop, stored once per distinct SINK
+    rr-node (U ~ #blocks; every (net, sink) slot holds an int32 row
+    index) and FACTORED into the sink's distinct wire cells x its input
+    pins: a cluster's equivalent pins hear the same few tracks, so the K
+    hops of a sink name only C ~ K / 5 cells and P pins, and the wave
+    reads a distance once a CELL and a pin's cost once a PIN (on the
+    chip an element read out of a per-net canvas costs about 10 ns,
+    PERF.md PR 29).  Pin p hears cell slot c iff ``uid_pcrank[u, p, c]``
+    is not RANK_PAD; the rank is the hop's position k in the pin-major
+    enumeration (the sink's IPINs in in-edge order, then each IPIN's
+    in-edges), which is the order equal costs are broken in.
+
+    Sizes from the shapes, a unique sink: (C + P) * 4 + P * C * 8 bytes
+    against the K * 12 of a flat (cell, pin, delay) list.  At the
+    benchmark's k6_N10 shape (K 1,320, C 256, P 33) that is 69 KB
+    against 16 KB, 15 MB for its 227 sinks; at U = 10^4 unique sinks of
+    that shape 690 MB against 160 MB, about 4x.  On a graph whose pins
+    share no track (C = K) the dense table is P times the flat list and
+    the form saves no read.  All host numpy; the Router uploads the
+    tables once per route() call and keeps them device-resident."""
     opin_node: np.ndarray       # int32 [R, O] source-class OPINs (pad N)
     entry_cell: np.ndarray      # int32 [R, Ko] wire cell (pad Ncells)
     entry_oidx: np.ndarray      # int32 [R, Ko] index into opin_node (pad 0)
     entry_delay: np.ndarray     # f32  [R, Ko] edge delay OPIN -> wire
     sink_uid: np.ndarray        # int32 [R, S] unique-sink row (pad U)
-    uid_cell: np.ndarray        # int32 [U+1, K] wire cell (pad Ncells)
-    uid_ipin: np.ndarray        # int32 [U+1, K] IPIN node (pad N)
-    uid_delay: np.ndarray       # f32  [U+1, K] delay wire->IPIN->SINK
+    uid_ucell: np.ndarray       # int32 [U+1, C] distinct wire cells,
+    #                             ascending (pad Ncells)
+    uid_upin: np.ndarray        # int32 [U+1, P] IPIN nodes, in-edge
+    #                             order (pad N)
+    uid_pcdel: np.ndarray       # f32  [U+1, P, C] delay wire->IPIN->SINK
+    #                             (0 where the pin does not hear the cell)
+    uid_pcrank: np.ndarray      # int32 [U+1, P, C] rank k of the hop
+    #                             (RANK_PAD where there is none)
+    sink_cands: int             # K: most hops any sink has
     # dedicated direct connections (OPIN->IPIN edges, t_direct_inf):
     # per (net, sink) the best source-class OPIN that directly drives
     # one of the sink's IPINs (-1 = none) — the planes wave compares
@@ -379,14 +401,40 @@ def build_planes_terminals(rr: RRGraph, source: np.ndarray,
     u_of_2 = u_of_1[p_of_2]
     k2, cand_cnt = _within(u_of_2, U)
     K = max(1, int(cand_cnt.max()) if U else 1)
-    # one pad row at U: cell=ncells / ipin=N / delay=0 — candidate
-    # extraction on a pad slot sees only INF-distance candidates
-    u_cell = np.full((U + 1, K), ncells, dtype=np.int32)
-    u_ipin = np.full((U + 1, K), N, dtype=np.int32)
-    u_del = np.zeros((U + 1, K), dtype=np.float32)
-    u_cell[u_of_2, k2] = cell_of_node[wires2]
-    u_ipin[u_of_2, k2] = ipins[p_of_2]
-    u_del[u_of_2, k2] = wtot
+    pin_of_1, pin_cnt = _within(u_of_1, U)
+    P = max(1, int(pin_cnt.max()) if U else 1)
+    cells2 = cell_of_node[wires2].astype(np.int64)
+    ucells, cell_of_2 = np.unique(u_of_2 * (ncells + 1) + cells2,
+                                  return_inverse=True)
+    u_of_c = ucells // (ncells + 1)
+    slot_of_c, cell_cnt = _within(u_of_c, U)
+    C = max(1, int(cell_cnt.max()) if U else 1)
+    # one pad row at U, and pad slots in every shorter row: cell=ncells
+    # / ipin=N / delay=0 / no rank -- extraction on a pad slot sees
+    # only INF-distance candidates
+    u_ucell = np.full((U + 1, C), ncells, dtype=np.int32)
+    u_upin = np.full((U + 1, P), N, dtype=np.int32)
+    u_pcdel = np.zeros((U + 1, P, C), dtype=np.float32)
+    u_pcrank = np.full((U + 1, P, C), RANK_PAD, dtype=np.int32)
+    u_ucell[u_of_c, slot_of_c] = ucells % (ncells + 1)
+    u_upin[u_of_1, pin_of_1] = ipins
+    pslot, cslot = pin_of_1[p_of_2], slot_of_c[cell_of_2]
+    # a (pin, cell) pair holds ONE hop.  Several OPIN -> IPIN edges of
+    # one pin all sit on the pad cell (an OPIN has no cell) and can
+    # never win on cost: the lowest rank stands for them, as it does
+    # among equal costs.  Two edges from one WIRE into one pin would
+    # need two delays in one slot
+    _, first = np.unique((u_of_2 * P + pslot) * C + cslot,
+                         return_index=True)
+    if (np.delete(cells2, first) < ncells).any():
+        raise ValueError("parallel wire -> IPIN edges: the sink tables "
+                         "hold one hop per (pin, cell)")
+    held = (u_of_2[first], pslot[first], cslot[first])
+    u_pcdel[held] = wtot[first]
+    u_pcrank[held] = k2[first]
+    get_metrics().set_gauges({"route.sink_pick.cands_per_sink": K,
+                              "route.sink_pick.cells_per_sink": C,
+                              "route.sink_pick.pins_per_sink": P})
 
     sink_uid = np.full(R * S, U, dtype=np.int32)
     sink_uid[valid] = inv.astype(np.int32)
@@ -440,7 +488,8 @@ def build_planes_terminals(rr: RRGraph, source: np.ndarray,
                             direct_ipin[r, s] = ip
                             direct_delay[r, s] = dd
     return PlanesTerminals(opin_node, entry_cell, entry_oidx, entry_delay,
-                           sink_uid.reshape(R, S), u_cell, u_ipin, u_del,
+                           sink_uid.reshape(R, S), u_ucell, u_upin,
+                           u_pcdel, u_pcrank, K,
                            direct_oidx, direct_ipin, direct_delay)
 
 
@@ -1276,12 +1325,70 @@ def entry_fields(seed_cells, opin_du, cc_flat, crit_w, valid,
     return d0, entry_flag, wk, wenter0
 
 
+def sink_pin_costs(congj_p1, sink_tabs):
+    """The node costs of every sink's input pins, [B, S, P]: once a
+    step, one element read a PIN (each of a sink's hops through the pin
+    shares it)."""
+    b_upin = sink_tabs[1]
+    B, S, P = b_upin.shape
+    return jnp.take_along_axis(
+        congj_p1, b_upin.reshape(B, -1), axis=1).reshape(B, S, P)
+
+
+def sink_pick(dist, pin_congj, crit_w, cw, sink_tabs):
+    """A wave's cheapest (wire cell -> IPIN -> SINK) hop of every sink
+    slot, from the batch's factored sink tables ``sink_tabs`` =
+    (ucell [B, S, C], upin [B, S, P], pcdel and pcrank [B, S, P, C];
+    PlanesTerminals) and the relaxed distances ``dist`` [B, ncells]:
+
+    sink_dist [B, S]   min over the sink's hops of
+                       dist[cell] + crit_w * delay + cw * pin cost
+    ent_cell, ent_ipin, ent_wdel   the winning hop's cell, IPIN node and
+                       delay; equal costs -> the lowest rank, i.e. the
+                       first in the pin-major enumeration
+
+    A distance is read once per distinct CELL of a sink, [B, S, C]
+    element reads, and the hops are formed dense over (pin, cell) and
+    reduced in one pass, with no second gather."""
+    b_ucell, b_upin, b_pcdel, b_pcrank = sink_tabs
+    B, S, P, C = b_pcrank.shape
+    dist_p1 = jnp.concatenate([dist, jnp.full((B, 1), INF)], axis=1)
+    dist_c = jnp.take_along_axis(
+        dist_p1, b_ucell.reshape(B, -1), axis=1).reshape(B, S, C)
+    cand = jnp.where(
+        b_pcrank < RANK_PAD,
+        dist_c[:, :, None, :] + crit_w[:, None, None, None] * b_pcdel
+        + cw[:, None, None, None] * pin_congj[:, :, :, None], INF)
+    slots = jnp.broadcast_to(
+        jnp.arange(P * C, dtype=jnp.int32).reshape(P, C), (B, S, P, C))
+
+    def first(a, b):
+        # the cheaper hop; of two at one cost, the lower rank.  Slots
+        # order what is left, the hop-less pairs (INF, RANK_PAD): a
+        # sink without a hop lands on slot 0, whose entries are pads
+        (ca, ra, sa), (cb, rb, sb) = a, b
+        a_first = (ca < cb) | ((ca == cb)
+                               & ((ra < rb) | ((ra == rb) & (sa < sb))))
+        return tuple(jnp.where(a_first, x, y) for x, y in zip(a, b))
+
+    sink_dist, _, pc = lax.reduce(
+        (cand, b_pcrank, slots),
+        (jnp.float32(INF), jnp.int32(RANK_PAD), jnp.int32(P * C)),
+        first, (2, 3))                                         # [B, S]
+    pc = pc[:, :, None]
+    ent_cell = jnp.take_along_axis(b_ucell, pc % C, axis=2)[:, :, 0]
+    ent_ipin = jnp.take_along_axis(b_upin, pc // C, axis=2)[:, :, 0]
+    ent_wdel = jnp.take_along_axis(b_pcdel.reshape(B, S, P * C), pc,
+                                   axis=2)[:, :, 0]
+    return sink_dist, ent_cell, ent_ipin, ent_wdel
+
+
 def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                paths, sink_delay, all_reached, bb,
                source_all, sinks_all, crit_all,
                opin_node_all, entry_cell_all, entry_oidx_all,
                entry_delay_all,
-               sink_uid_all, uid_cell, uid_ipin, uid_delay,
+               sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
                direct_oidx_all, direct_ipin_all, direct_delay_all,
                sel, valid, force, full_bb,
                nsweeps: int, max_len: int, num_waves: int, group: int,
@@ -1320,9 +1427,8 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
         b_eoidx = entry_oidx_all[sel]
         b_edelay = entry_delay_all[sel]
         b_uid = sink_uid_all[sel]                    # [B, S]
-        b_scell = uid_cell[b_uid]                    # [B, S, K]
-        b_sipin = uid_ipin[b_uid]
-        b_swdel = uid_delay[b_uid]
+        sink_tabs = (uid_ucell[b_uid], uid_upin[b_uid],  # [B, S, C], P
+                     uid_pcdel[b_uid], uid_pcrank[b_uid])   # [B, S, P, C]
         b_doidx = direct_oidx_all[sel]               # [B, S] (-1 = none)
         b_dipin = direct_ipin_all[sel]
         b_ddel = direct_delay_all[sel]
@@ -1342,9 +1448,8 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             b_ecell = c(b_ecell, "net", None)
             b_eoidx = c(b_eoidx, "net", None)
             b_edelay = c(b_edelay, "net", None)
-            b_scell = c(b_scell, "net", None, None)
-            b_sipin = c(b_sipin, "net", None, None)
-            b_swdel = c(b_swdel, "net", None, None)
+            sink_tabs = tuple(c(t, "net", *(None,) * (t.ndim - 1))
+                              for t in sink_tabs)
             b_doidx = c(b_doidx, "net", None)
             b_dipin = c(b_dipin, "net", None)
             b_ddel = c(b_ddel, "net", None)
@@ -1352,7 +1457,6 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
         arangeB = jnp.arange(B)
         O = b_opin.shape[1]
         Ko = b_ecell.shape[1]
-        K = b_scell.shape[2]
 
         # device-side reroute predicate: skip clean nets unless forced
         over_now = jnp.append(occ > dev.capacity, False)
@@ -1386,8 +1490,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             cc_flat_base = c(cc_flat_base, "net", None)
         opin_congj = jnp.take_along_axis(
             congj_p1, jnp.clip(b_opin, 0, N), axis=1)              # [B, O]
-        ipin_congj = jnp.take_along_axis(
-            congj_p1, b_sipin.reshape(B, -1), axis=1).reshape(B, S, K)
+        pin_congj = sink_pin_costs(congj_p1, sink_tabs)           # [B, S, P]
 
         # initial tree: empty in cell space; SOURCE entries come via opin_du
         seed0 = jnp.zeros((B, ncells), bool)
@@ -1446,21 +1549,8 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             st = st.at[:2].add(rst)
 
         with device_scope("route.dev.sink_pick"):
-            # --- sink extraction from the per-net candidate tables ---
-            dist_p1 = jnp.concatenate([dist, jnp.full((B, 1), INF)], axis=1)
-            cand = (jnp.take_along_axis(
-                dist_p1, b_scell.reshape(B, -1), axis=1).reshape(B, S, K)
-                + crit_w[:, None, None] * b_swdel
-                + cw[:, None, None] * ipin_congj)
-            kstar = jnp.argmin(cand, axis=2)                       # [B, S]
-            sink_dist = jnp.take_along_axis(cand, kstar[:, :, None],
-                                            axis=2)[:, :, 0]
-            ent_cell = jnp.take_along_axis(b_scell, kstar[:, :, None],
-                                           axis=2)[:, :, 0]
-            ent_ipin = jnp.take_along_axis(b_sipin, kstar[:, :, None],
-                                           axis=2)[:, :, 0]
-            ent_wdel = jnp.take_along_axis(b_swdel, kstar[:, :, None],
-                                           axis=2)[:, :, 0]
+            sink_dist, ent_cell, ent_ipin, ent_wdel = sink_pick(
+                dist, pin_congj, crit_w, cw, sink_tabs)
 
             # --- dedicated direct candidate (OPIN->IPIN->SINK, bypassing
             # the fabric): competes with the relaxation candidates; the
@@ -1661,7 +1751,7 @@ def route_batch_resident_planes(
         paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_cell, uid_ipin, uid_delay,
+        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel, valid, full_bb,
         nsweeps: int, max_len: int, num_waves: int, group: int,
@@ -1680,7 +1770,7 @@ def route_batch_resident_planes(
         pg, dev, occ, acc, pres_fac, paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_cell, uid_ipin, uid_delay,
+        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel, valid, jnp.bool_(True), full_bb,
         nsweeps, max_len, num_waves, group, doubling, mesh,
@@ -1745,7 +1835,7 @@ def _window_body(
         paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_cell, uid_ipin, uid_delay,
+        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel_plan, valid_plan, full_bb,
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
@@ -1815,7 +1905,8 @@ def _window_body(
                     source_all, sinks_all, crit_all,
                     opin_node_all, entry_cell_all, entry_oidx_all,
                     entry_delay_all,
-                    sink_uid_all, uid_cell, uid_ipin, uid_delay,
+                    sink_uid_all, uid_ucell, uid_upin, uid_pcdel,
+                    uid_pcrank,
                     direct_oidx_all, direct_ipin_all, direct_delay_all,
                     sel_g, valid_g, force, full_bb,
                     nsweeps, max_len, num_waves, group, doubling, mesh,
@@ -1934,7 +2025,7 @@ def route_window_planes(
         paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_cell, uid_ipin, uid_delay,
+        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel_plan, valid_plan, full_bb,
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
@@ -1954,7 +2045,7 @@ def route_window_planes(
         pg, dev, occ, acc, paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_cell, uid_ipin, uid_delay,
+        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel_plan, valid_plan, full_bb,
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
@@ -1980,7 +2071,7 @@ def _fused_ladder(
         paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_cell, uid_ipin, uid_delay,
+        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel_plans, valid_plans, full_bb,
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
@@ -2003,8 +2094,8 @@ def _fused_ladder(
             pg, dev, occ, acc, paths, sink_delay, all_reached, bb,
             source_all, sinks_all, crit_all,
             opin_node_all, entry_cell_all, entry_oidx_all,
-            entry_delay_all, sink_uid_all, uid_cell, uid_ipin,
-            uid_delay, direct_oidx_all, direct_ipin_all,
+            entry_delay_all, sink_uid_all, uid_ucell, uid_upin,
+            uid_pcdel, uid_pcrank, direct_oidx_all, direct_ipin_all,
             direct_delay_all,
             sel_plans[r], valid_plans[r], full_bb,
             pres0, pres_mult, max_pres,
@@ -2032,7 +2123,7 @@ def route_window_planes_fused(
         paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_cell, uid_ipin, uid_delay,
+        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel_plans, valid_plans, full_bb,
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
@@ -2068,7 +2159,7 @@ def route_window_planes_fused(
         pg, dev, occ, acc, paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
         opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_cell, uid_ipin, uid_delay,
+        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
         direct_oidx_all, direct_ipin_all, direct_delay_all,
         sel_plans, valid_plans, full_bb,
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,

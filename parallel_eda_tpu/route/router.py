@@ -2478,8 +2478,8 @@ class Router:
                 np.asarray(self.pg.cell_of_node), self.pg.ncells)
             self._pt = tuple(jnp.asarray(a) for a in (
                 pt.opin_node, pt.entry_cell, pt.entry_oidx,
-                pt.entry_delay, pt.sink_uid, pt.uid_cell,
-                pt.uid_ipin, pt.uid_delay, pt.direct_oidx,
+                pt.entry_delay, pt.sink_uid, pt.uid_ucell,
+                pt.uid_upin, pt.uid_pcdel, pt.uid_pcrank, pt.direct_oidx,
                 pt.direct_ipin, pt.direct_delay))
             self._pt_key = id(term)
             self._pt_ref = term          # keep id(term) alive

@@ -95,15 +95,14 @@ def _split_request(req: WindowDispatchRequest):
     program's per-job (state, dynamics, statics) triple.  The layout
     mirrors the f_args construction in Router._route_planes_windows:
     [0] pg [1] dev [2:8] donated state [8] source [9] sinks [10] crit
-    [11:22] terminal tables [22] sel plans [23] valid plans
-    [24] full_bb [25:31] scalars [31] K [32] L."""
+    [11:-11] terminal tables, then the eleven that follow them:
+    sel plans, valid plans, full_bb, six scalars, K, L."""
     a = req.f_args
     kw = req.f_kwargs
     state = (a[2], a[3], a[4], a[5], a[6], a[7], a[10])
-    dyn = (a[8], a[9], tuple(a[11:22]), a[22], a[23], a[24],
-           a[25], a[26], a[27], a[28], a[29], a[30],
+    dyn = (a[8], a[9], tuple(a[11:-11]), *a[-11:-2],
            kw.get("bb0_all"), kw.get("widen_oks"))
-    static = (a[31], a[32], kw["rung_desc"], kw["topk"])
+    static = (a[-2], a[-1], kw["rung_desc"], kw["topk"])
     return state, dyn, static
 
 

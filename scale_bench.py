@@ -369,13 +369,14 @@ def main():
     N = f.rr.num_nodes
     nc = pg.ncells
     Bt = args.batch
-    U, K = pt.uid_cell.shape
+    U, P, C = pt.uid_pcrank.shape
     U -= 1                               # drop the pad row
+    K = pt.sink_cands
     span0 = int(((f.term.bb_xmax - f.term.bb_xmin)
                  + (f.term.bb_ymax - f.term.bb_ymin)).max())
     L_bb = path_budget(span0, 4 * (f.rr.grid.nx + f.rr.grid.ny) + 64)
 
-    def model(R_, S_, nc_, N_, U_, K_, L_):
+    def model(R_, S_, nc_, N_, U_, C_, L_):
         return [
             ("planes dist/pred/w (per batch)", "3*B*Ncells*4",
              3 * Bt * nc_ * 4),
@@ -383,20 +384,21 @@ def main():
             ("occ/acc/history", "N*8", N_ * 8),
             ("paths (bb-adaptive L)", "R*S*L_bb*4", R_ * S_ * L_ * 4),
             ("sink uid index", "R*S*4", R_ * S_ * 4),
-            ("unique-sink tables", "U*K*12", U_ * K_ * 12),
+            ("unique-sink tables (cells x pins)", "U*(P*C*8+(P+C)*4)",
+             U_ * (P * C_ * 8 + (P + C_) * 4)),
             ("planes masks/delays (static)", "~12*Ncells*4", 12 * nc_ * 4),
         ]
 
     print("\n## Memory model (resident device state)\n")
     print("The two round-3 Titan blockers are closed: sink tables are "
-          "factorized by unique sink node ([U, K] + int32 index, was "
+          "factorized by unique sink node ([U, P, C] + int32 index, was "
           "[R, S, K]*12B) and the path store's L is the circuit's "
           "largest bb half-perimeter (regrown on demand), not the "
           "device's.\n")
     print("| structure | formula | this circuit |")
     print("|---|---|---|")
     total = 0
-    for name, formula, b in model(R, S, nc, N, U, K, L_bb):
+    for name, formula, b in model(R, S, nc, N, U, C, L_bb):
         total += b
         print(f"| {name} | {formula} | {b/1e6:.1f} MB |")
     print(f"| **total** | | **{total/1e6:.1f} MB** |")
@@ -413,18 +415,20 @@ def main():
     R_t = int(1.0e5)
     S_t = 8
     U_t = int(1.2e5)
-    # per-sink candidate count scales with channel width (wire->IPIN
-    # fan-in ~ Fc_in * W per adjacent channel): extrapolate from the
-    # measured fixture K
+    # per-sink candidate and distinct-cell counts scale with channel
+    # width (wire->IPIN fan-in ~ Fc_in * W per adjacent channel), the
+    # pin count does not: extrapolate from the measured fixture K, C
     K_t = max(K, int(round(K * W_t / f.rr.chan_width)))
+    C_t = max(C, int(round(C * W_t / f.rr.chan_width)))
     L_t = 512
     print(f"\nTitan proxy (1e6 rr nodes, 1e5 nets, 300x300 W=80, "
-          f"fanout-class S=8, L_bb=512, K={K_t} extrapolated from the "
-          f"fixture's K={K} at W={f.rr.chan_width}):\n")
+          f"fanout-class S=8, L_bb=512, C={C_t} cells x P={P} pins a "
+          f"sink extrapolated from the fixture's C={C} at "
+          f"W={f.rr.chan_width}):\n")
     print("| structure | bytes |")
     print("|---|---|")
     tot = 0
-    for name, formula, b in model(R_t, S_t, nc_t, N_t, U_t, K_t, L_t):
+    for name, formula, b in model(R_t, S_t, nc_t, N_t, U_t, C_t, L_t):
         tot += b
         print(f"| {name} | {b/1e9:.2f} GB |")
     print(f"| **total** | **{tot/1e9:.2f} GB** |")

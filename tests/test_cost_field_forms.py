@@ -11,12 +11,19 @@ import numpy as np
 import pytest
 
 from cost_field_refs import entry_fields_gather, node_cost_field_gather
-from parallel_eda_tpu.route.planes import entry_fields, node_cost_field
+from parallel_eda_tpu.route.planes import (entry_fields, node_cost_field,
+                                           sink_pick, sink_pin_costs)
+from sink_pick_refs import sink_pick_flat, sink_pin_costs_flat
 
 # (B, Ko, ncells, N) of the benchmark's three cells
 SHAPES = {"route_relaxed": (64, 160, 20240, 29656),
           "route_k6n10_relaxed": (64, 24, 16896, 13560),
           "route_tight": (64, 128, 16192, 25608)}
+# (S, K, C, P) of the same cells' sink tables: sink slots a net, flat
+# candidates a sink, distinct wire cells and pins among them
+SINK_SHAPES = {"route_relaxed": (8, 400, 80, 10),
+               "route_k6n10_relaxed": (7, 1320, 256, 33),
+               "route_tight": (8, 320, 64, 10)}
 
 
 def gather_index_rows(fn, *avals):
@@ -69,3 +76,79 @@ def test_node_cost_field_gathers_one_row_a_cell(cell):
     ref = gather_index_rows(node_cost_field_gather,
                             *_node_avals(B, ncells, N))
     assert ref == [B * ncells], ref
+
+
+def _sink_avals(cell):
+    """((dist, congj_p1, crit_w, cw), factored tables, flat tables)."""
+    B, _, ncells, N = SHAPES[cell]
+    S, K, C, P = SINK_SHAPES[cell]
+    s, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
+    wave = (s((B, ncells), f32), s((B, N + 1), f32), s((B,), f32),
+            s((B,), f32))
+    fact = (s((B, S, C), i32), s((B, S, P), i32), s((B, S, P, C), f32),
+            s((B, S, P, C), i32))
+    flat = (s((B, S, K), i32), s((B, S, K), i32), s((B, S, K), f32))
+    return wave, fact, flat
+
+
+@pytest.mark.parametrize("cell", sorted(SHAPES))
+def test_sink_pick_gathers_a_distance_per_cell_of_a_sink(cell):
+    """Once a wave: B * S * C element reads of the distances (a fifth of
+    the candidates), and B * S more for the winner's three fields."""
+    B = SHAPES[cell][0]
+    S, K, C, P = SINK_SHAPES[cell]
+    (dist, _, crit_w, cw), fact, flat = _sink_avals(cell)
+    s = jax.ShapeDtypeStruct
+    rows = gather_index_rows(sink_pick, dist, s((B, S, P), jnp.float32),
+                             crit_w, cw, fact)
+    assert sorted(rows) == [B * S] * 3 + [B * S * C], rows
+    ref = gather_index_rows(sink_pick_flat, dist,
+                            s((B, S, K), jnp.float32), crit_w, cw, flat)
+    assert sorted(ref) == [B * S] * 4 + [B * S * K], ref
+    assert K >= 5 * C
+
+
+@pytest.mark.parametrize("cell", sorted(SHAPES))
+def test_sink_pin_costs_gather_a_cost_per_pin_of_a_sink(cell):
+    """Once a step: B * S * P element reads of the node costs."""
+    B = SHAPES[cell][0]
+    S, K, C, P = SINK_SHAPES[cell]
+    (_, congj_p1, _, _), fact, flat = _sink_avals(cell)
+    rows = gather_index_rows(sink_pin_costs, congj_p1, fact)
+    assert rows == [B * S * P], rows
+    ref = gather_index_rows(sink_pin_costs_flat, congj_p1, flat)
+    assert ref == [B * S * K], ref
+
+
+def test_a_whole_step_gathers_no_result_of_candidate_size(monkeypatch):
+    """`_step_core` whole (the resident batch step on entry()'s
+    problem): nothing is fetched once per flat candidate; with the flat
+    forms patched in, the distances a wave and the pins' costs a step
+    are."""
+    import __graft_entry__ as graft
+    from parallel_eda_tpu.obs import get_metrics
+    from parallel_eda_tpu.route import planes
+    from sink_pick_refs import flat_forms
+
+    fn, args = graft.entry()
+    p = graft.planes_step_problem()
+    g = get_metrics().values("route.sink_pick.")
+    K, C, P = (g["route.sink_pick.cands_per_sink"],
+               g["route.sink_pick.cells_per_sink"],
+               g["route.sink_pick.pins_per_sink"])
+    B, S = p["sel"].shape[0], p["nets"][1].shape[1]
+    assert K > C > P > 1
+    rows = gather_index_rows(fn, *args)
+    assert B * S * K not in rows
+    assert rows.count(B * S * C) == 1 and rows.count(B * S * P) == 1
+    assert max(rows) < B * S * K
+
+    pin_costs_flat, pick_flat = flat_forms(K, p["dev"].num_nodes)
+    monkeypatch.setattr(planes, "sink_pin_costs", pin_costs_flat)
+    monkeypatch.setattr(planes, "sink_pick", pick_flat)
+    planes.route_batch_resident_planes.clear_cache()
+    try:
+        ref = gather_index_rows(graft.entry()[0], *args)
+    finally:
+        planes.route_batch_resident_planes.clear_cache()
+    assert ref.count(B * S * K) == 2

@@ -441,14 +441,27 @@ def test_resident_batch_step_equals_a_one_group_window():
 # against the per-(net, cell) gather forms they replaced ----
 
 def _field_graph(kind):
-    """A small PlanesGraph of each relaxation variant: length-1
-    two-way wires, and the published length-4 single-driver ones."""
+    """A small rr graph with its PlanesGraph: length-1 two-way wires
+    (``bidirectional``; ``k4n4`` with the benchmark's cluster, ``directs``
+    with two dedicated OPIN -> IPIN connections into one pin), and the
+    published length-4 single-driver ones (``directional_l4``)."""
     import warnings
 
     from parallel_eda_tpu.arch.builtin import k6_n10_40nm_arch
+    from parallel_eda_tpu.arch.model import DirectSpec
 
     if kind == "directional_l4":
         arch, n = k6_n10_40nm_arch(chan_width=16), 5
+    elif kind == "k4n4":
+        arch, n = minimal_arch(K=4, N=4, I=10, io_capacity=2,
+                               chan_width=12), 5
+    elif kind == "directs":
+        arch, n = minimal_arch(chan_width=10), 4
+        arch.directs = [
+            DirectSpec(from_type="clb", from_pin=6, to_type="clb",
+                       to_pin=0, dx=0, dy=1),
+            DirectSpec(from_type="clb", from_pin=7, to_type="clb",
+                       to_pin=0, dx=1, dy=0)]
     else:
         arch, n = minimal_arch(chan_width=8), 4
     with warnings.catch_warnings():
@@ -567,26 +580,36 @@ def test_node_cost_field_equals_the_per_cell_gather(kind, B):
     assert np.isfinite(got).any() and np.isinf(got[:, noc < N]).any()
 
 
-def test_directional_route_equals_the_route_under_gathered_fields(
-        monkeypatch):
-    """A whole route on the published length-4 single-driver wires is
-    the route with both cost fields built by the per-(net, cell)
-    gathers, node for node and in iterations, sweeps and waves."""
+def _placed(kind):
+    """A placed 60-LUT circuit on the published length-4 single-driver
+    wires (``directional_l4``) or on the benchmark's K=4 N=4 cluster
+    with two-way length-1 wires."""
     import warnings
 
-    from cost_field_refs import entry_fields_gather, node_cost_field_gather
     from parallel_eda_tpu.arch.builtin import k6_n10_40nm_arch
     from parallel_eda_tpu.flow import prepare, run_place_native
     from parallel_eda_tpu.netlist.generate import generate_circuit
-    from parallel_eda_tpu.route import planes
 
-    arch = k6_n10_40nm_arch(chan_width=32)
+    if kind == "directional_l4":
+        arch, W = k6_n10_40nm_arch(chan_width=32), 32
+    else:
+        arch, W = minimal_arch(K=4, N=4, I=10, io_capacity=2,
+                               chan_width=12), 12
     nl = generate_circuit(num_luts=60, num_inputs=8, num_outputs=8,
                           K=arch.K, seed=3)
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")     # the 40nm file asks Wilton
-        f = run_place_native(prepare(nl, arch, 32, seed=5), seed=7)
-    assert f.rr.unidir
+        f = run_place_native(prepare(nl, arch, W, seed=5), seed=7)
+    assert f.rr.unidir == (kind == "directional_l4")
+    return f
+
+
+def _assert_route_unmoved_by(f, monkeypatch, refs):
+    """Route ``f``, then again with planes' builders ``refs`` {name:
+    reference} patched in: every reference was traced, and the two
+    routes agree node for node and in iterations, sweeps, waves and
+    walk steps."""
+    from parallel_eda_tpu.route import planes
 
     programs = (planes.route_window_planes,
                 planes.route_window_planes_fused,
@@ -605,20 +628,19 @@ def test_directional_route_equals_the_route_under_gathered_fields(
                 prog.clear_cache()
 
     res = route()
-    calls = []
+    calls = set()
 
-    def counted(fn):
+    def counted(name, fn):
         def wrapped(*a):
-            calls.append(fn.__name__)
+            calls.add(name)
             return fn(*a)
         return wrapped
 
-    monkeypatch.setattr(planes, "entry_fields", counted(entry_fields_gather))
-    monkeypatch.setattr(planes, "node_cost_field",
-                        counted(node_cost_field_gather))
+    for name, fn in refs.items():
+        monkeypatch.setattr(planes, name, counted(name, fn))
     ref = route()
     monkeypatch.undo()
-    assert {"entry_fields_gather", "node_cost_field_gather"} == set(calls)
+    assert calls == set(refs)
     assert res.success and res.total_waves > res.iterations > 1
     assert (res.success, res.iterations, res.wirelength,
             res.total_relax_steps, res.total_relax_steps_useful,
@@ -630,3 +652,247 @@ def test_directional_route_equals_the_route_under_gathered_fields(
     assert np.array_equal(np.asarray(res.sink_delay),
                           np.asarray(ref.sink_delay))
     assert np.array_equal(np.asarray(res.occ), np.asarray(ref.occ))
+
+
+def test_directional_route_equals_the_route_under_gathered_fields(
+        monkeypatch):
+    """A whole route on the published length-4 single-driver wires is
+    the route with both cost fields built by the per-(net, cell)
+    gathers, node for node and in iterations, sweeps and waves."""
+    from cost_field_refs import entry_fields_gather, node_cost_field_gather
+
+    _assert_route_unmoved_by(
+        _placed("directional_l4"), monkeypatch,
+        {"entry_fields": entry_fields_gather,
+         "node_cost_field": node_cost_field_gather})
+
+
+# ---- the sink pick (planes.build_planes_terminals' factored sink
+# tables, planes.sink_pin_costs, planes.sink_pick) against the flat
+# (cell, pin, delay) candidate form it replaced ----
+
+def _seeded_nets(rr, R, S, seed):
+    """R nets of random SINK nodes in S slots -- slot 0 a cluster's
+    (the sinks of most pins), the others clusters' and pads' alike:
+    trailing slots padded, net 3 all pads."""
+    from parallel_eda_tpu.rr.graph import SINK, SOURCE
+
+    rng = np.random.default_rng(seed)
+    all_sinks = np.where(rr.node_type == SINK)[0]
+    pins = np.diff(rr.in_row_ptr)[all_sinks]
+    sinks = rng.choice(all_sinks, (R, S)).astype(np.int64)
+    sinks[:, 0] = rng.choice(all_sinks[pins == pins.max()], R)
+    sinks[:, S - 2:] = -1
+    sinks[3, :] = -1
+    source = rng.choice(np.where(rr.node_type == SOURCE)[0], R)
+    return source.astype(np.int64), sinks
+
+
+@pytest.mark.parametrize("kind", ["k4n4", "directional_l4", "directs"])
+def test_sink_tables_reproduce_the_flat_tables(kind):
+    """Every flat candidate (cell, ipin, delay) of rank k sits at its
+    (pin, cell) slot and no slot holds anything else; pads stay pads;
+    the three gauges say the shapes."""
+    from parallel_eda_tpu.obs import get_metrics
+    from parallel_eda_tpu.route.planes import (RANK_PAD,
+                                               build_planes_terminals)
+    from sink_pick_refs import flat_sink_tables
+
+    rr, pg = _field_graph(kind)
+    N, ncells = rr.num_nodes, pg.ncells
+    source, sinks = _seeded_nets(rr, 24, 6, seed=1)
+    con = np.asarray(pg.cell_of_node)
+    pt = build_planes_terminals(rr, source, sinks, con, ncells)
+    uid, u_cell, u_ipin, u_del = flat_sink_tables(rr, sinks, con, ncells)
+    assert np.array_equal(pt.sink_uid, uid)
+    U1, K = u_cell.shape
+    _, P, C = pt.uid_pcrank.shape
+    assert pt.uid_ucell.shape == (U1, C) and pt.uid_upin.shape == (U1, P)
+    assert pt.uid_pcdel.shape == pt.uid_pcrank.shape == (U1, P, C)
+    g = get_metrics().values("route.sink_pick.")
+    assert (g["route.sink_pick.cands_per_sink"],
+            g["route.sink_pick.cells_per_sink"],
+            g["route.sink_pick.pins_per_sink"]) == (K, C, P)
+    assert pt.sink_cands == K and C < K      # pins share tracks here
+
+    held = pt.uid_pcrank < RANK_PAD
+    dropped = 0
+    for u in range(U1):
+        ps, cs = np.nonzero(held[u])
+        ks = pt.uid_pcrank[u, ps, cs]
+        real = np.nonzero((u_cell[u] < ncells) | (u_ipin[u] < N))[0]
+        # ranks are distinct flat positions of real candidates ...
+        assert len(np.unique(ks)) == len(ks)
+        assert np.isin(ks, real).all()
+        # ... each holding its candidate's cell, pin and delay
+        assert np.array_equal(pt.uid_ucell[u, cs], u_cell[u, ks])
+        assert np.array_equal(pt.uid_upin[u, ps], u_ipin[u, ks])
+        assert np.array_equal(pt.uid_pcdel[u, ps, cs], u_del[u, ks])
+        # a candidate without a slot shares (pin, pad cell) with an
+        # earlier one: several OPINs that drive one pin directly
+        for k in np.setdiff1d(real, ks):
+            assert u_cell[u, k] == ncells
+            twin = ks[(pt.uid_upin[u, ps] == u_ipin[u, k])
+                      & (pt.uid_ucell[u, cs] == ncells)]
+            assert len(twin) == 1 and twin[0] < k
+            dropped += 1
+        # cells ascending and distinct, then pads; pins, then pads
+        # (only OPIN -> IPIN edges sit on the pad cell, in ONE slot)
+        nc = int((pt.uid_ucell[u] < ncells).sum())
+        assert (np.diff(pt.uid_ucell[u, :nc]) > 0).all()
+        assert (pt.uid_ucell[u, nc:] == ncells).all()
+        assert not held[u, :, nc + 1:].any()
+        assert not held[u, :, nc:].any() or kind == "directs"
+        npin = int((pt.uid_upin[u] < N).sum())
+        assert (pt.uid_upin[u, npin:] == N).all()
+        assert not held[u, npin:].any()
+    assert not pt.uid_pcdel[~held].any()
+    assert not held[U1 - 1].any()            # the pad row
+    assert (dropped > 0) == (kind == "directs")
+
+
+def _sink_case(rr, pg, B, seed):
+    """Seeded wave inputs over ``pg``'s canvas for B nets, with the
+    batch's factored and flat sink tables; exact ties planted on nets
+    0 and 1.  Returns (dist, congj_p1, crit_w, cw, fact, flat, spots)."""
+    from parallel_eda_tpu.route.planes import build_planes_terminals
+    from sink_pick_refs import flat_sink_tables
+
+    N, ncells = rr.num_nodes, pg.ncells
+    S = 6
+    source, sinks = _seeded_nets(rr, B, S, seed)
+    con = np.asarray(pg.cell_of_node)
+    pt = build_planes_terminals(rr, source, sinks, con, ncells)
+    uid, u_cell, u_ipin, u_del = flat_sink_tables(rr, sinks, con, ncells)
+    fact = tuple(t[uid] for t in (pt.uid_ucell, pt.uid_upin,
+                                  pt.uid_pcdel, pt.uid_pcrank))
+    flat = tuple(t[uid] for t in (u_cell, u_ipin, u_del))
+    scell, sipin, sdel = flat
+
+    rng = np.random.default_rng(seed + B)
+    dist = rng.uniform(1e-10, 9e-9, (B, ncells)).astype(np.float32)
+    dist[rng.random((B, ncells)) < 0.3] = np.inf      # unreached cells
+    congj = rng.uniform(1e-11, 1e-10, (B, N)).astype(np.float32)
+    congj[rng.random((B, N)) < 0.1] = np.inf          # outside the box
+    crit_w = rng.uniform(0.1, 0.99, B).astype(np.float32)
+    crit_w[4] = 0.0                                   # no timing term
+    dist[2] = np.inf                                  # nothing reached
+
+    def real(b, k):
+        return scell[b, 0, k] < ncells
+
+    # net 0, slot 0: two cells of ONE pin at one distance, the pin the
+    # cheapest by far -- and the earlier candidate on the HIGHER cell,
+    # so no order of cell slots picks it
+    k1, k2 = next(
+        (a, b) for a in range(scell.shape[2])
+        for b in range(a + 1, scell.shape[2])
+        if real(0, a) and real(0, b) and sipin[0, 0, a] == sipin[0, 0, b]
+        and scell[0, 0, a] > scell[0, 0, b])
+    assert sdel[0, 0, k1] == sdel[0, 0, k2]
+    dist[0, scell[0, 0, [k1, k2]]] = 1e-12
+    congj[0, sipin[0, 0, k1]] = 1e-13
+    # net 1, slot 0: two PINS at one cost, each on a cell of its own,
+    # the earlier pin's cell the higher; no timing term, so the pins'
+    # delays cannot part them
+    crit_w[1] = 0.0
+    k3, k4 = next(
+        (a, b) for a in range(scell.shape[2])
+        for b in range(a + 1, scell.shape[2])
+        if real(1, a) and real(1, b) and sipin[1, 0, a] != sipin[1, 0, b]
+        and scell[1, 0, a] > scell[1, 0, b])
+    dist[1, scell[1, 0, [k3, k4]]] = 1e-12
+    congj[1, sipin[1, 0, [k3, k4]]] = 1e-13
+    congj_p1 = np.concatenate(
+        [congj, np.full((B, 1), np.inf, np.float32)], axis=1)
+    spots = {"two_cells": (0, k1, k2), "two_pins": (1, k3, k4)}
+    j = jnp.asarray
+    return (j(dist), j(congj_p1), j(crit_w), j(1.0 - crit_w),
+            tuple(j(t) for t in fact), tuple(j(t) for t in flat), spots)
+
+
+@pytest.mark.parametrize("B", [16, 64])
+@pytest.mark.parametrize("kind", ["bidirectional", "directional_l4"])
+def test_sink_pick_equals_the_flat_pick(kind, B):
+    """The pick over distinct cells x pins gives what the argmin over
+    the flat candidates gave: sink_dist, ent_cell, ent_ipin and ent_wdel
+    bit for bit, exact ties and pad sinks included."""
+    from parallel_eda_tpu.route.planes import sink_pick, sink_pin_costs
+    from sink_pick_refs import sink_pick_flat, sink_pin_costs_flat
+
+    rr, pg = _field_graph(kind)
+    N, ncells = rr.num_nodes, pg.ncells
+    dist, congj_p1, crit_w, cw, fact, flat, spots = _sink_case(
+        rr, pg, B, seed=7)
+    ipin_congj = sink_pin_costs_flat(congj_p1, flat)
+    want = [np.asarray(a) for a in sink_pick_flat(
+        dist, ipin_congj, crit_w, cw, flat)]
+    pin_congj = sink_pin_costs(congj_p1, fact)
+    got = [np.asarray(a) for a in sink_pick(
+        dist, pin_congj, crit_w, cw, fact)]
+    for name, g, w in zip(("sink_dist", "ent_cell", "ent_ipin",
+                           "ent_wdel"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert np.array_equal(g, w), name
+    sink_dist, ent_cell, ent_ipin, ent_wdel = got
+    scell, sipin, sdel = (np.asarray(t) for t in flat)
+    # each pin's cost is the cost its candidates read
+    upin, pcrank = np.asarray(fact[1]), np.asarray(fact[3])
+    assert pin_congj.shape == upin.shape
+    assert np.array_equal(
+        np.asarray(pin_congj),
+        np.take_along_axis(np.asarray(congj_p1),
+                           upin.reshape(B, -1), axis=1).reshape(upin.shape))
+
+    # the edges are live, not vacuous
+    cand = (np.take_along_axis(
+        np.concatenate([np.asarray(dist), np.full((B, 1), np.inf,
+                                                  np.float32)], axis=1),
+        scell.reshape(B, -1), axis=1).reshape(scell.shape)
+        + np.asarray(crit_w)[:, None, None] * sdel
+        + np.asarray(cw)[:, None, None] * np.asarray(ipin_congj))
+    for spot in ("two_cells", "two_pins"):
+        b, ka, kb = spots[spot]
+        assert np.isfinite(sink_dist[b, 0])
+        assert cand[b, 0, ka] == cand[b, 0, kb] == sink_dist[b, 0]
+        assert (cand[b, 0] == sink_dist[b, 0]).sum() == 2
+        # the earlier candidate won though it sits on the later cell
+        assert ent_cell[b, 0] == scell[b, 0, ka] > scell[b, 0, kb]
+        assert ent_ipin[b, 0] == sipin[b, 0, ka]
+    b, ka, kb = spots["two_pins"]
+    assert sipin[b, 0, ka] != sipin[b, 0, kb]
+    # nothing reached: the first candidate stands, at INF
+    assert np.isinf(sink_dist[2]).all()
+    assert np.array_equal(ent_cell[2], scell[2, :, 0])
+    # pad sinks read the pads
+    pad = np.asarray(fact[0])[:, :, 0] == ncells
+    assert pad[3].all() and pad[:, -2:].all() and not pad.all()
+    assert np.isinf(sink_dist[pad]).all()
+    assert (ent_cell[pad] == ncells).all() and (ent_ipin[pad] == N).all()
+    assert not ent_wdel[pad].any()
+    # most real sinks found a finite candidate, through a real pin
+    found = np.isfinite(sink_dist)
+    assert found.sum() > (~pad).sum() // 2
+    assert (ent_cell[found] < ncells).all() and (ent_ipin[found] < N).all()
+    assert (pcrank < np.iinfo(np.int32).max).any(axis=(2, 3))[~pad].all()
+
+
+@pytest.mark.parametrize("kind", ["directional_l4", "bidirectional"])
+def test_route_equals_the_route_under_the_flat_sink_pick(kind,
+                                                         monkeypatch):
+    """A whole route -- on the published length-4 single-driver wires
+    and on two-way length-1 ones -- is the route with the flat
+    candidate pick patched in, node for node and in iterations, sweeps,
+    waves and walk steps."""
+    from parallel_eda_tpu.route.planes import build_planes_terminals
+    from sink_pick_refs import flat_forms
+
+    f = _placed(kind)
+    pg = build_planes(f.rr)
+    pt = build_planes_terminals(f.rr, f.term.source, f.term.sinks,
+                                np.asarray(pg.cell_of_node), pg.ncells)
+    assert pt.sink_cands > pt.uid_ucell.shape[1] > 1
+    pin_costs_flat, pick_flat = flat_forms(pt.sink_cands, f.rr.num_nodes)
+    _assert_route_unmoved_by(
+        f, monkeypatch,
+        {"sink_pin_costs": pin_costs_flat, "sink_pick": pick_flat})
