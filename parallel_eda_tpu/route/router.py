@@ -467,18 +467,33 @@ def _pow2_at_least(x: int) -> int:
     return 1 << max(0, (x - 1).bit_length())
 
 
+def _crop_ladder(nx: int, ny: int, base: int = 8,
+                 full_frac: float = 0.8):
+    """The crop tiles of an nx x ny grid, ascending: base, 2*base,
+    4*base, ... clamped to the grid, stopping at the first rung whose
+    tile covers the grid or whose area reaches ``full_frac`` of the
+    grid area (a crop that big saves nothing over the full canvas, and
+    the full-canvas program is the one the mesh path shards).  A fixed
+    function of the grid: 11x11 has the one rung (8, 8), and a 16x16
+    rung exists from 18x18 up."""
+    ladder = []
+    s = base
+    while True:
+        cw, ch = min(nx, s), min(ny, s)
+        if cw * ch >= full_frac * nx * ny or (cw == nx and ch == ny):
+            return ladder
+        ladder.append((cw, ch))
+        s *= 2
+
+
 def _size_class_buckets(need_w: np.ndarray, need_h: np.ndarray,
                         nx: int, ny: int, min_count: int = 1,
                         base: int = 8, full_frac: float = 0.8):
     """Bin nets into pow-2 size-class crop buckets.
 
     ``need_w``/``need_h`` are the per-net canvas requirements (live bb
-    span + crop margin, in grid cells).  The ladder is base, 2*base,
-    4*base, ... clamped to the grid; it stops at the first rung whose
-    tile covers the grid or whose area reaches ``full_frac`` of the
-    grid area (a crop that big saves nothing over the full canvas, and
-    the full-canvas program is the one the mesh path shards).  Each net
-    gets the SMALLEST rung that fits both of its spans; nets that fit
+    span + crop margin, in grid cells).  Each net gets the SMALLEST
+    rung of ``_crop_ladder`` that fits both of its spans; nets that fit
     no rung take the full canvas.  Rungs holding fewer than
     ``min_count`` nets are merged upward (a near-empty bucket costs a
     whole program launch for a handful of nets).
@@ -488,14 +503,7 @@ def _size_class_buckets(need_w: np.ndarray, need_h: np.ndarray,
     on the full canvas.  Deterministic — pure function of the spans and
     the grid."""
     n = len(need_w)
-    ladder = []
-    s = base
-    while True:
-        cw, ch = min(nx, s), min(ny, s)
-        if cw * ch >= full_frac * nx * ny or (cw == nx and ch == ny):
-            break
-        ladder.append((cw, ch))
-        s *= 2
+    ladder = _crop_ladder(nx, ny, base, full_frac)
     assign = np.full(n, len(ladder), dtype=np.int64)
     for k in range(len(ladder) - 1, -1, -1):
         cw, ch = ladder[k]
@@ -1590,6 +1598,9 @@ class Router:
         rid = next(_ROUTE_IDS)      # shared by every span of this route
         ctl = None      # the open route.pipeline.control span, if any
         disp_total = reg.gauge("route.pipeline.dispatch_ms_total")
+        # nets x windows handed to a cropped rung / to the full canvas
+        crop_nets = reg.counter("route.crop.net_dispatches_cropped_total")
+        full_nets = reg.counter("route.crop.net_dispatches_full_total")
         # the plane dtype named by opts.plane_dtype is the dtype every
         # window of this route commits
         pd = str(opts.plane_dtype)
@@ -1710,6 +1721,9 @@ class Router:
                 # crop ladder is single-device VMEM machinery — the
                 # row mesh splits the canvas across chips instead
                 dispatch = [(dirty, None)]
+            for rung_nets, rung_tile in dispatch:
+                (full_nets if rung_tile is None
+                 else crop_nets).inc(len(rung_nets))
 
             def plan_rung(sub, tile, ri):
                 """Host planning for one rung of this window's dispatch
@@ -1940,7 +1954,8 @@ class Router:
                 plan_sp = span("route.pipeline.plan", cat="route",
                                stage="plan", window=widx, route=rid,
                                rung=0, nets=len(dirty), fused=True,
-                               rungs=len(dispatch))
+                               rungs=len(dispatch),
+                               tiles=[t for _, t in dispatch])
                 plan_sp.__enter__()
                 plans = [plan_rung(sub0, tile, ri)
                          for ri, (sub0, tile) in enumerate(dispatch)]

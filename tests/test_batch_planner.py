@@ -10,7 +10,8 @@ slots are masked, never re-checked)."""
 import numpy as np
 import pytest
 
-from parallel_eda_tpu.route.router import (_median_cut_bins,
+from parallel_eda_tpu.route.router import (_crop_ladder,
+                                           _median_cut_bins,
                                            _order_and_chunk,
                                            _pow2_at_least,
                                            _size_class_buckets,
@@ -178,6 +179,28 @@ class TestSizeClassBuckets:
         b = _size_class_buckets(w.copy(), h.copy(), 32, 32, min_count=3)
         assert a[0] == b[0]
         assert np.array_equal(a[1], b[1])
+
+    @pytest.mark.parametrize("n, ladder, assign", [
+        (11, [(8, 8)], None),               # route_k6n10_relaxed
+        (17, [(8, 8)], None),               # 256 >= 0.8 * 289
+        (18, [(8, 8), (16, 16)], [0, 0, 1, 1]),     # the first 16 rung
+        (19, [(8, 8), (16, 16)], [0, 0, 1, 1]),     # route_scale
+        (22, [(8, 8), (16, 16)], [0, 0, 1, 1]),     # route_relaxed
+        (29, [(8, 8), (16, 16)], [0, 0, 1, 1]),     # clma: 32 clamps to 29
+        (40, [(8, 8), (16, 16), (32, 32)], [0, 0, 1, 2]),
+    ])
+    def test_ladder_of_the_benchmark_grids(self, n, ladder, assign):
+        """The ladder is a function of the grid alone, and span-4 wires
+        (a margin of 2 x 4 cells) decide who fits: a 1-cell box needs 9,
+        so no net fits the 8 rung; boxes up to 8 fit the 16 rung; on a
+        19 to 29 grid the rest, between 16 and the grid, has no rung
+        and takes the full canvas."""
+        assert _crop_ladder(n, n) == ladder
+        box = np.array([1, 8, 9, n])
+        classes, got = _size_class_buckets(box + 2 * 4, box + 2 * 4, n, n)
+        # the 8 rung is never populated
+        assert classes == ladder[1:]
+        assert got.tolist() == (assign or [0, 0, 0, 0])
 
 
 class TestPlanGroupsCompaction:

@@ -130,10 +130,16 @@ def test_planes_relax_cropped_compiles_at_route_tile(one_chip):
     _fits_hbm(compiled)
 
 
-def test_directional_planes_relax_compiles_at_k6n10_canvas(one_chip):
+@pytest.mark.parametrize("n, W, tile", [
+    (11, 64, 8),        # route_k6n10_relaxed
+    (19, 88, 16),       # route_scale: the one populated rung, 16 x 16
+])
+def test_directional_planes_relax_compiles_at_k6n10_canvas(one_chip, n, W,
+                                                           tile):
     """The directional relaxation (unidir graphs: group-min turns) at
-    the canvas of the cell ``route_k6n10_relaxed``: 11 x 11, W = 64 of
-    length-4 single-driver wires, 64 nets -- whole and cropped."""
+    the canvases of the cells ``route_k6n10_relaxed`` (11 x 11, W = 64)
+    and ``route_scale`` (19 x 19, W = 88) of length-4 single-driver
+    wires, 64 nets -- whole and cropped."""
     import warnings
 
     from parallel_eda_tpu.arch.builtin import k6_n10_40nm_arch
@@ -142,11 +148,12 @@ def test_directional_planes_relax_compiles_at_k6n10_canvas(one_chip):
     from parallel_eda_tpu.rr.graph import build_rr_graph
     from parallel_eda_tpu.rr.grid import DeviceGrid
 
-    arch = k6_n10_40nm_arch(chan_width=64)
+    arch = k6_n10_40nm_arch(chan_width=W)
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")     # the file asks for Wilton
         pg = build_planes(build_rr_graph(
-            arch, DeviceGrid(11, 11, arch.io_capacity)))
+            arch, DeviceGrid(n, n, arch.io_capacity)))
+    assert pg.shape_x[:2] == (W, n)
     assert pg.directional and pg.group_tracks == 8 and pg.max_span == 4
     fn = jax.jit(planes_relax, static_argnames=("nsweeps",))
     _fits_hbm(fn.lower(*_relax_avatars(pg, ROUTE_B, one_chip),
@@ -156,7 +163,7 @@ def test_directional_planes_relax_compiles_at_k6n10_canvas(one_chip):
                  static_argnames=("nsweeps", "cnx", "cny"))
     _fits_hbm(fn.lower(*_relax_avatars(pg, ROUTE_B, one_chip),
                        nsweeps=ROUTE_SWEEPS, ox=origin, oy=origin,
-                       cnx=8, cny=8).compile())
+                       cnx=tile, cny=tile).compile())
 
 
 # ---- the whole window program once, small --------------------------

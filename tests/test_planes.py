@@ -583,24 +583,31 @@ def test_node_cost_field_equals_the_per_cell_gather(kind, B):
 def _placed(kind):
     """A placed 60-LUT circuit on the published length-4 single-driver
     wires (``directional_l4``) or on the benchmark's K=4 N=4 cluster
-    with two-way length-1 wires."""
+    with two-way length-1 wires; ``directional_l4_19x19`` is 30 LUTs
+    (35 nets) on those wires on a 19 x 19 grid, the smallest size
+    class whose crop ladder has a 16 x 16 rung."""
     import warnings
 
     from parallel_eda_tpu.arch.builtin import k6_n10_40nm_arch
     from parallel_eda_tpu.flow import prepare, run_place_native
     from parallel_eda_tpu.netlist.generate import generate_circuit
 
+    luts, gen_seed, n = 60, 3, 0
     if kind == "directional_l4":
         arch, W = k6_n10_40nm_arch(chan_width=32), 32
+    elif kind == "directional_l4_19x19":
+        arch, W = k6_n10_40nm_arch(chan_width=24), 24
+        luts, gen_seed, n = 30, 1, 19
     else:
         arch, W = minimal_arch(K=4, N=4, I=10, io_capacity=2,
                                chan_width=12), 12
-    nl = generate_circuit(num_luts=60, num_inputs=8, num_outputs=8,
-                          K=arch.K, seed=3)
+    nl = generate_circuit(num_luts=luts, num_inputs=8, num_outputs=8,
+                          K=arch.K, seed=gen_seed)
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")     # the 40nm file asks Wilton
-        f = run_place_native(prepare(nl, arch, W, seed=5), seed=7)
-    assert f.rr.unidir == (kind == "directional_l4")
+        f = run_place_native(prepare(nl, arch, W, seed=5, nx=n, ny=n),
+                             seed=7)
+    assert f.rr.unidir == kind.startswith("directional_l4")
     return f
 
 
@@ -631,9 +638,9 @@ def _assert_route_unmoved_by(f, monkeypatch, refs):
     calls = set()
 
     def counted(name, fn):
-        def wrapped(*a):
+        def wrapped(*a, **kw):
             calls.add(name)
-            return fn(*a)
+            return fn(*a, **kw)
         return wrapped
 
     for name, fn in refs.items():
@@ -652,6 +659,7 @@ def _assert_route_unmoved_by(f, monkeypatch, refs):
     assert np.array_equal(np.asarray(res.sink_delay),
                           np.asarray(ref.sink_delay))
     assert np.array_equal(np.asarray(res.occ), np.asarray(ref.occ))
+    return res
 
 
 def test_directional_route_equals_the_route_under_gathered_fields(
@@ -665,6 +673,46 @@ def test_directional_route_equals_the_route_under_gathered_fields(
         _placed("directional_l4"), monkeypatch,
         {"entry_fields": entry_fields_gather,
          "node_cost_field": node_cost_field_gather})
+
+
+def test_directional_cropped_route_equals_the_full_canvas_route(
+        monkeypatch):
+    """On a grid with a 16 x 16 crop rung (19 x 19) a route on the
+    length-4 single-driver wires dispatches a cropped rung and the full
+    canvas in every window, and is the route with the full-canvas
+    relaxation in the cropped one's place -- the SAME dispatch, node
+    for node and in iterations, sweeps, waves and walk steps.  (Against
+    ``crop="off"`` the dispatch itself differs: one subset of all the
+    nets where ``auto`` hands each rung its own, so the net groups and
+    with them the negotiation are others; that route is held to be
+    legal and to count no cropped sweep.)"""
+    from parallel_eda_tpu.obs import get_metrics
+    from parallel_eda_tpu.route import planes
+
+    def full_canvas(pg, d0, cc, crit_c, wenter0, nsweeps, ox, oy, cnx,
+                    cny, plane_dtype="f32"):
+        assert (cnx, cny) == (16, 16)
+        return planes.planes_relax(pg, d0, cc, crit_c, wenter0, nsweeps,
+                                   None, plane_dtype)
+
+    f = _placed("directional_l4_19x19")
+    assert (f.grid.nx, f.grid.ny) == (19, 19)
+    res = _assert_route_unmoved_by(
+        f, monkeypatch, {"planes_relax_cropped": full_canvas})
+    check_route(f.rr, f.term, res.paths, res.occ)
+    # not by bypass: a cropped rung was dispatched, and so was the
+    # full canvas
+    assert 0 < res.total_relax_steps_cropped < res.total_relax_steps
+    reg = get_metrics().values("route.crop.")
+    assert reg["route.crop.net_dispatches_cropped_total"] > 0
+    assert reg["route.crop.net_dispatches_full_total"] > 0
+
+    full0 = reg["route.crop.net_dispatches_full_total"]
+    off = Router(f.rr, RouterOpts(batch_size=16, crop="off")).route(f.term)
+    assert off.success and off.total_relax_steps_cropped == 0
+    check_route(f.rr, f.term, off.paths, off.occ)
+    reg = get_metrics().values("route.crop.")
+    assert reg["route.crop.net_dispatches_full_total"] > full0
 
 
 # ---- the sink pick (planes.build_planes_terminals' factored sink
