@@ -1790,20 +1790,30 @@ def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
     every contested node among the still-uncolored).  Nets left after
     n_colors-1 rounds share the last class.
 
+    A path slot finds its column of the conflict matrix U by ONE read
+    of a node-indexed table ``code [N + 1]``, written once a call from
+    what ``top_k`` returns: k < topk where the node is the k-th of the
+    top-K overused, topk (the dump column) where it is overused outside
+    them, topk + 1 where it is clean or the sentinel N.  The same read
+    says whether the slot is overused at all (rrm).  U's columns stand
+    in top_k's order; claim is a min down each column and conflict an
+    any across them, so no order of the columns moves a colour
+    (tests/mis_colors_refs.py keeps the searchsorted form this
+    replaced: fifteen gather rounds over the path store).
+
     Returns (rrm [R], colors [R])."""
     N = dev.num_nodes
     R = paths.shape[0]
     over = jnp.maximum(occ - dev.capacity, 0)
-    over_p1 = jnp.append(over > 0, False)
-    rrm = over_p1[paths].any(axis=(1, 2)) | ~all_reached
     val, ids = lax.top_k(over, topk)
-    ids = jnp.where(val > 0, ids, N)
-    ids_sorted = jnp.sort(ids)
-    flat = paths.reshape(R, -1)
-    pos = jnp.clip(jnp.searchsorted(ids_sorted, flat), 0, topk - 1)
-    hit = (ids_sorted[pos] == flat) & (flat < N)
+    code = jnp.append(jnp.where(over > 0, topk, topk + 1),
+                      topk + 1).astype(jnp.int32)
+    code = code.at[jnp.where(val > 0, ids, N + 1)].set(
+        jnp.arange(topk, dtype=jnp.int32), mode="drop")
+    col = code[paths.reshape(R, -1)]
+    rrm = (col <= topk).any(axis=1) | ~all_reached
     U = jnp.zeros((R, topk + 1), bool).at[
-        jnp.arange(R)[:, None], jnp.where(hit, pos, topk)].set(
+        jnp.arange(R)[:, None], jnp.minimum(col, topk)].set(
         True)[:, :topk]
     U = U & rrm[:, None]
     prio = jnp.arange(R, dtype=jnp.int32)

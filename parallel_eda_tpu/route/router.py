@@ -1601,6 +1601,10 @@ class Router:
         # nets x windows handed to a cropped rung / to the full canvas
         crop_nets = reg.counter("route.crop.net_dispatches_cropped_total")
         full_nets = reg.counter("route.crop.net_dispatches_full_total")
+        # conflict colourings run (one a dispatched _window_body, so one
+        # a rung) / read (one a window: the last rung's summary)
+        mis_calls = reg.counter("route.mis_colors.calls_total")
+        mis_reads = reg.counter("route.mis_colors.read_total")
         # the plane dtype named by opts.plane_dtype is the dtype every
         # window of this route commits
         pd = str(opts.plane_dtype)
@@ -1724,6 +1728,7 @@ class Router:
             for rung_nets, rung_tile in dispatch:
                 (full_nets if rung_tile is None
                  else crop_nets).inc(len(rung_nets))
+            mis_calls.inc(len(dispatch))
 
             def plan_rung(sub, tile, ri):
                 """Host planning for one rung of this window's dispatch
@@ -2226,6 +2231,7 @@ class Router:
             # so it stays at the sync point in BOTH modes (lag-0) ----
             (rrm, colors, dev_wide, unreached, live_w,
              live_h) = unpack_window_status(status_np)
+            mis_reads.inc()
             n_over, over_total = int(scal_np[0]), int(scal_np[1])
             max_span = int(scal_np[4])
             if opts.sweep_budget_div > 1:

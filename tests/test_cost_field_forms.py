@@ -11,8 +11,10 @@ import numpy as np
 import pytest
 
 from cost_field_refs import entry_fields_gather, node_cost_field_gather
-from parallel_eda_tpu.route.planes import (entry_fields, node_cost_field,
-                                           sink_pick, sink_pin_costs)
+from mis_colors_refs import mis_colors_searchsorted
+from parallel_eda_tpu.route.planes import (_mis_colors, entry_fields,
+                                           node_cost_field, sink_pick,
+                                           sink_pin_costs)
 from sink_pick_refs import sink_pick_flat, sink_pin_costs_flat
 
 # (B, Ko, ncells, N) of the benchmark's three cells
@@ -24,23 +26,29 @@ SHAPES = {"route_relaxed": (64, 160, 20240, 29656),
 SINK_SHAPES = {"route_relaxed": (8, 400, 80, 10),
                "route_k6n10_relaxed": (7, 1320, 256, 33),
                "route_tight": (8, 320, 64, 10)}
+# (R, Smax, L, N) of the FOUR cells' path stores
+MIS_SHAPES = {"route_relaxed": (962, 8, 192, 29656),
+              "route_k6n10_relaxed": (1017, 7, 152, 13560),
+              "route_tight": (962, 8, 192, 25608),
+              "route_scale": (2985, 9, 216, 42008)}
+
+
+def _eqns(fn, *avals):
+    """Every equation of ``fn``'s jaxpr, nested jaxprs included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    return list(walk(jax.make_jaxpr(fn)(*avals).jaxpr))
 
 
 def gather_index_rows(fn, *avals):
     """Index rows (one row = one slice fetched) of every ``gather`` in
     ``fn``'s jaxpr, nested jaxprs included."""
-    rows = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "gather":
-                idx = eqn.invars[1].aval.shape
-                rows.append(int(np.prod(idx[:-1])))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*avals).jaxpr)
-    return rows
+    return [int(np.prod(eqn.invars[1].aval.shape[:-1]))
+            for eqn in _eqns(fn, *avals) if eqn.primitive.name == "gather"]
 
 
 def _entry_avals(B, Ko, ncells, O=4):
@@ -118,6 +126,40 @@ def test_sink_pin_costs_gather_a_cost_per_pin_of_a_sink(cell):
     assert rows == [B * S * P], rows
     ref = gather_index_rows(sink_pin_costs_flat, congj_p1, flat)
     assert ref == [B * S * K], ref
+
+
+@pytest.mark.parametrize("cell", sorted(MIS_SHAPES))
+def test_mis_colors_gather_the_path_store_once(cell):
+    """Once a window rung: ONE read of R * Smax * L slots out of the
+    node-indexed table, and nothing that loops or sorts.  The
+    searchsorted form read the store twice in the open and thirteen
+    times inside its search's loop: half of ``route_scale``'s traced
+    slice on the chip (PERF.md, PR 31)."""
+    import types
+
+    R, S, L, N = MIS_SHAPES[cell]
+    s = jax.ShapeDtypeStruct
+    avals = (s((N,), jnp.int32), s((N,), jnp.int32),
+             s((R, S, L), jnp.int32), s((R,), jnp.bool_))
+
+    def form(fn):
+        def call(cap, occ, paths, all_reached):
+            dev = types.SimpleNamespace(num_nodes=N, capacity=cap)
+            return fn(dev, occ, paths, all_reached, min(4096, N), 5)
+        return call
+
+    def names(fn):
+        return {e.primitive.name for e in _eqns(fn, *avals)}
+
+    new = form(_mis_colors)
+    assert gather_index_rows(new, *avals) == [R * S * L]
+    assert not names(new) & {"while", "scan", "sort"}
+    # the guard sees the form it guards against: three reads of the
+    # store, one of them inside the search's loop, and the ids' sort
+    ref = form(mis_colors_searchsorted)
+    assert gather_index_rows(ref, *avals) == [R * S * L] * 3
+    ref_names = names(ref)
+    assert "sort" in ref_names and ref_names & {"while", "scan"}
 
 
 def test_a_whole_step_gathers_no_result_of_candidate_size(monkeypatch):

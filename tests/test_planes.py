@@ -614,8 +614,9 @@ def _placed(kind):
 def _assert_route_unmoved_by(f, monkeypatch, refs):
     """Route ``f``, then again with planes' builders ``refs`` {name:
     reference} patched in: every reference was traced, and the two
-    routes agree node for node and in iterations, sweeps, waves and
-    walk steps."""
+    routes agree node for node, in iterations, sweeps, waves and walk
+    steps, and in the dirty set and colours the host read of every
+    window."""
     from parallel_eda_tpu.route import planes
 
     programs = (planes.route_window_planes,
@@ -634,7 +635,20 @@ def _assert_route_unmoved_by(f, monkeypatch, refs):
             for prog in programs:
                 prog.clear_cache()
 
+    # what the host reads of each window's summary: (rrm, colors)
+    seen = {"res": [], "ref": []}
+    unpack = planes.unpack_window_status
+
+    def recording(side):
+        def wrapped(status):
+            out = unpack(status)
+            seen[side].append((out[0].copy(), out[1].copy()))
+            return out
+        return wrapped
+
+    monkeypatch.setattr(planes, "unpack_window_status", recording("res"))
     res = route()
+    monkeypatch.setattr(planes, "unpack_window_status", recording("ref"))
     calls = set()
 
     def counted(name, fn):
@@ -655,11 +669,26 @@ def _assert_route_unmoved_by(f, monkeypatch, refs):
         ref.success, ref.iterations, ref.wirelength,
         ref.total_relax_steps, ref.total_relax_steps_useful,
         ref.total_waves, ref.total_walk_steps)
+    assert len(seen["res"]) == len(seen["ref"]) == len(res.stats)
+    for (rrm, colors), (rrm_ref, colors_ref) in zip(seen["res"],
+                                                    seen["ref"]):
+        assert np.array_equal(rrm, rrm_ref)
+        assert np.array_equal(colors, colors_ref)
     assert np.array_equal(np.asarray(res.paths), np.asarray(ref.paths))
     assert np.array_equal(np.asarray(res.sink_delay),
                           np.asarray(ref.sink_delay))
     assert np.array_equal(np.asarray(res.occ), np.asarray(ref.occ))
     return res
+
+
+def _mis_counters():
+    """(colourings run, colourings the host read) so far in this
+    process."""
+    from parallel_eda_tpu.obs import get_metrics
+
+    v = get_metrics().values("route.mis_colors.")
+    return (v.get("route.mis_colors.calls_total", 0),
+            v.get("route.mis_colors.read_total", 0))
 
 
 def test_directional_route_equals_the_route_under_gathered_fields(
@@ -697,9 +726,14 @@ def test_directional_cropped_route_equals_the_full_canvas_route(
 
     f = _placed("directional_l4_19x19")
     assert (f.grid.nx, f.grid.ny) == (19, 19)
+    calls0, reads0 = _mis_counters()
     res = _assert_route_unmoved_by(
         f, monkeypatch, {"planes_relax_cropped": full_canvas})
     check_route(f.rr, f.term, res.paths, res.occ)
+    # a colouring ran once a rung and the host read one a window (the
+    # helper routed twice): the cropped rung's were dropped unread
+    calls, reads = _mis_counters()
+    assert calls - calls0 > reads - reads0 == 2 * len(res.stats)
     # not by bypass: a cropped rung was dispatched, and so was the
     # full canvas
     assert 0 < res.total_relax_steps_cropped < res.total_relax_steps
@@ -944,3 +978,129 @@ def test_route_equals_the_route_under_the_flat_sink_pick(kind,
     _assert_route_unmoved_by(
         f, monkeypatch,
         {"sink_pin_costs": pin_costs_flat, "sink_pick": pick_flat})
+
+
+# ---- the conflict colouring (planes._mis_colors: a slot's column of
+# the conflict matrix by one read of a node-indexed table) against the
+# searchsorted form it replaced ----
+
+# case: (overused nodes, topk (None: the graph's node count), every
+# overuse equal, the planted net the case is about)
+MIS_CASES = {
+    "no_overused_node": (0, 64, False, None),
+    "fewer_overused_than_topk": (24, 64, False, None),
+    "more_overused_than_topk": (120, 32, False, "dump_only"),
+    "topk_is_the_node_count": (24, None, False, None),
+    "ties_across_the_topk_boundary": (96, 32, True, None),
+    "an_unreached_net_with_a_clean_path": (24, 64, False,
+                                           "unreached_clean"),
+    "a_net_of_sentinel_slots": (24, 64, False, "all_sentinel"),
+}
+
+
+def _mis_case(kind, case):
+    """(dev, occ, paths, all_reached, topk): 24 seeded nets of 3 sinks
+    x 10 path slots (tails padded with the sentinel N) on ``kind``'s
+    graph, the case's overused nodes all ON paths; net 0 carries the
+    case's plant."""
+    n_over, topk, ties, plant = MIS_CASES[case]
+    rr, _ = _field_graph(kind)
+    dev = to_device(rr)
+    N = rr.num_nodes
+    R, S, L = 24, 3, 10
+    rng = np.random.default_rng(len(case) + 100 * len(kind))
+    paths = rng.integers(0, N, (R, S, L))
+    ln = rng.integers(1, L + 1, (R, S))
+    paths[np.arange(L) >= ln[:, :, None]] = N
+    cap = np.asarray(dev.capacity)
+    hot = rng.choice(np.unique(paths[1:][paths[1:] < N]), n_over,
+                     replace=False)
+    over = np.zeros(N, np.int64)
+    over[hot] = 1 if ties else 1 + rng.permutation(n_over)
+    occ = np.where(over > 0, cap + over, rng.integers(0, 2, N) * cap)
+    reached = np.ones(R, bool)
+    reached[5] = False
+    if plant == "dump_only":
+        # its only overused nodes are the three LEAST overused
+        paths[0] = N
+        paths[0, 0, :3] = hot[np.argsort(over[hot])[:3]]
+    elif plant == "unreached_clean":
+        paths[0] = rng.choice(np.setdiff1d(np.arange(N), hot), (S, L))
+        reached[0] = False
+    elif plant == "all_sentinel":
+        paths[0] = N
+    else:
+        paths[0] = np.where(np.isin(paths[0], hot), N, paths[0])
+    return (dev, jnp.asarray(occ, jnp.int32), jnp.asarray(paths, jnp.int32),
+            jnp.asarray(reached), N if topk is None else topk)
+
+
+@pytest.mark.parametrize("case", sorted(MIS_CASES))
+@pytest.mark.parametrize("kind", ["bidirectional", "directional_l4"])
+def test_mis_colors_equal_the_searchsorted_form(kind, case):
+    """``rrm`` and ``colors`` of the node-indexed table equal the
+    searchsorted form's bit for bit; nets of one colour (but the last,
+    the rest class) share none of the top-K overused nodes."""
+    from jax import lax
+
+    from mis_colors_refs import mis_colors_searchsorted
+    from parallel_eda_tpu.route.planes import _mis_colors
+
+    n_colors = 5
+    dev, occ, paths, reached, topk = _mis_case(kind, case)
+    rrm, colors = _mis_colors(dev, occ, paths, reached, topk, n_colors)
+    rrm_ref, colors_ref = mis_colors_searchsorted(
+        dev, occ, paths, reached, topk, n_colors)
+    assert np.array_equal(rrm, rrm_ref)
+    assert np.array_equal(colors, colors_ref)
+
+    N = dev.num_nodes
+    n_over, _, _, plant = MIS_CASES[case]
+    rrm, colors = np.asarray(rrm), np.asarray(colors)
+    over = np.append(np.maximum(np.asarray(occ - dev.capacity), 0), 0)
+    assert (over > 0).sum() == n_over
+    flat = np.asarray(paths).reshape(len(rrm), -1)
+    assert np.array_equal(rrm, (over[flat] > 0).any(1)
+                          | ~np.asarray(reached))
+    assert (colors[~rrm] == n_colors - 1).all()
+    assert rrm[5] and not reached[5]
+    val, ids = lax.top_k(jnp.asarray(over[:N]), topk)
+    top = np.zeros(N + 1, bool)
+    top[np.asarray(ids)[np.asarray(val) > 0]] = True
+    assert top.sum() == min(n_over, topk)
+    holds = np.zeros((len(rrm), N + 1), bool)
+    holds[np.arange(len(rrm))[:, None], flat] = True
+    holds &= top
+    for c in range(n_colors - 1):
+        assert (holds[rrm & (colors == c)].sum(0) <= 1).all()
+    if n_over:
+        assert (colors[rrm] < n_colors - 1).any()
+    # the plant: dirty through the dump column or the flag alone, it
+    # contests nothing and joins the first class; all sentinels: clean
+    if plant == "all_sentinel":
+        assert not rrm[0] and colors[0] == n_colors - 1
+    elif plant is not None:
+        assert rrm[0] and colors[0] == 0 and not holds[0].any()
+        assert (over[flat[0]] > 0).any() == (plant == "dump_only")
+    else:
+        assert not rrm[0]
+
+
+@pytest.mark.parametrize("kind", ["directional_l4", "bidirectional"])
+def test_route_equals_the_route_under_the_searchsorted_colouring(
+        kind, monkeypatch):
+    """A whole route on both kinds of wire is the route with the
+    searchsorted colouring patched in, node for node, in iterations,
+    sweeps, waves and the colours of every window.  One rung a window
+    here, so every colouring that ran was read (the two-rung count is
+    ``test_directional_cropped_route_equals_the_full_canvas_route``'s)."""
+    from mis_colors_refs import mis_colors_searchsorted
+
+    calls0, reads0 = _mis_counters()
+    res = _assert_route_unmoved_by(
+        _placed(kind), monkeypatch,
+        {"_mis_colors": mis_colors_searchsorted})
+    calls, reads = _mis_counters()
+    # the helper routed twice
+    assert calls - calls0 == reads - reads0 == 2 * len(res.stats)
+    assert len(res.stats) > 1 and res.total_relax_steps_cropped == 0
