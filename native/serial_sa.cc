@@ -120,9 +120,13 @@ extern "C" {
 // Runs the full anneal.  Returns total proposed moves; fills
 // out_stats = {accepted, final_cost, num_temperatures}.
 int64_t serial_sa_place(
-    // tables
+    // tables.  Sites by block TYPE: type_id [NB] is a block's interior
+    // type (-1 for I/O), col_snap [T, nx + 2] the nearest column of a
+    // type, type_h [T] the rows a block of it occupies (its anchors are
+    // the rows 1 + k * type_h: a RAM is 6 rows tall)
     const int32_t* net_blk, const float* net_q, const int32_t* blk_net,
-    const uint8_t* is_io, const int32_t* ring_xy, int32_t NN, int32_t P,
+    const uint8_t* is_io, const int32_t* ring_xy, const int32_t* type_id,
+    const int32_t* col_snap, const int32_t* type_h, int32_t NN, int32_t P,
     int32_t NB, int32_t F, int32_t NRING, int32_t nx, int32_t ny,
     int32_t io_cap,
     // state (modified in place)
@@ -164,18 +168,26 @@ int64_t serial_sa_place(
       np[2] = rng.below(io_cap);
     } else {
       np[0] = pos[b * 3] + rng.below(2 * irl + 1) - irl;
-      np[1] = pos[b * 3 + 1] + rng.below(2 * irl + 1) - irl;
+      int32_t dy = rng.below(2 * irl + 1) - irl;
       if (np[0] < 1) np[0] = 1;
       if (np[0] > nx) np[0] = nx;
-      if (np[1] < 1) np[1] = 1;
-      if (np[1] > ny) np[1] = ny;
+      // onto a site of the block's own type: the nearest column of
+      // the type, and dy tiles up or down counted in whole blocks (a
+      // block of height 1: the column as drawn, the row clipped)
+      const int32_t ty = type_id[b], h = type_h[ty];
+      np[0] = col_snap[ty * (nx + 2) + np[0]];
+      int32_t k = (pos[b * 3 + 1] - 1) / h
+          + (dy < 0 ? -((-dy + h - 1) / h) : (dy + h - 1) / h);
+      if (k < 0) k = 0;
+      if (k > ny / h - 1) k = ny / h - 1;
+      np[1] = 1 + k * h;
       np[2] = 0;
     }
     int32_t src = site_of(t, pos + b * 3, ring[b]);
     int32_t dst = site_of(t, np, nring);
     if (src == dst) return;
     int32_t o = occ[dst];
-    if (o >= 0 && (bool)is_io[o] != (bool)is_io[b]) return;  // type clash
+    if (o >= 0 && type_id[o] != type_id[b]) return;  // type clash
     proposed++;
     // tentatively apply
     int32_t oldp[3] = {pos[b * 3], pos[b * 3 + 1], pos[b * 3 + 2]};

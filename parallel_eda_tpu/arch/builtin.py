@@ -84,13 +84,56 @@ def k6_n10_40nm_arch(chan_width: int = 120) -> Arch:
     return arch
 
 
+def k6_frac_n10_mem32k_40nm_arch(chan_width: int = 120) -> Arch:
+    """VTR 7.0's flagship heterogeneous architecture,
+    ``vtr_flow/arch/timing/k6_frac_N10_mem32K_40nm.xml``, built ON
+    ``k6_n10_40nm_arch``: the routing half (segment, mux, Fc, connection
+    block, switch box) is that one definition, every block type at
+    Fc_in 0.15 / Fc_out 0.10.  The blocks as published: ``io`` 8 a
+    tile; ``clb`` N=10 fracturable logic elements (one 6-LUT or two
+    5-LUTs on shared inputs, two flip-flops, TWO outputs), I=40
+    equivalent inputs, O=20 outputs not equivalent, one clock;
+    ``mult_36`` 4 rows tall, ``a[36]``, ``b[36]`` -> ``out[72]``,
+    columns 4 + 8k; ``memory`` (32 Kb) 6 rows tall, ``addr1[15]``,
+    ``addr2[15]``, ``data[64]``, ``we1``, ``we2`` -> ``out[64]``, one
+    clock, columns 2 + 8k.  A hard block's pins are one class a pin
+    (``make_hard_type``).  NOT as published (the configuration that
+    runs this says so under ``assumed``): block timing (the cluster's is
+    ``k6_n10_arch``'s, the hard blocks' is recalled from the file, not
+    read from it); ``mult_36`` carries the clock pin every hard type
+    of this repo has (its timing graph takes a hard block as
+    registered); the packer fills an FLE with ONE LUT and drives the
+    first of its two outputs (no pb tree: the two-mode pack is
+    ``frac_arch``'s, at test size)."""
+    arch = k6_n10_40nm_arch(chan_width)
+    arch.name = "k6_frac_N10_mem32K_40nm"
+    arch.I = 40
+    arch.block_types = [
+        make_io_type(index=0, capacity=arch.io_capacity),
+        make_clb_type(index=1, K=arch.K, N=arch.N, I=arch.I,
+                      T_comb=261e-12, T_setup=66e-12, T_clk_to_q=124e-12,
+                      output_equivalent=False, outputs_per_ble=2),
+        make_hard_type("mult_36", index=2, num_in=36 + 36, num_out=72,
+                       height=4, T_comb=1.523e-9, T_setup=66e-12,
+                       T_clk_to_q=124e-12),
+        make_hard_type("memory", index=3, num_in=15 + 15 + 64 + 2,
+                       num_out=64, height=6, T_comb=1.234e-9,
+                       T_setup=509e-12, T_clk_to_q=1.234e-9),
+    ]
+    arch.column_types = [ColumnSpec("memory", start=2, repeat=8),
+                         ColumnSpec("mult_36", start=4, repeat=8)]
+    arch.hard_models = {"multiply": "mult_36", "dual_port_ram": "memory"}
+    return arch
+
+
 def k6_n10_mem_arch(addr_bits: int = 6, data_bits: int = 8,
                     mem_start: int = 4, mem_repeat: int = 6) -> Arch:
-    """k6_N10 plus a single-port RAM column type (Stratix-IV-style
-    heterogeneous device: io ring, CLB interior, periodic 'bram' columns;
-    physical_types.h t_type_descriptor + SetupGrid.c column fill).  The
-    'spram' .subckt model maps onto it (pins: addr + data-in + we, then
-    data-out, then clk)."""
+    """A TOY, kept for the tests that use it: k6_N10's length-1
+    bidirectional fixture plus ONE made-up single-port RAM type of
+    height 1 ('bram': addr + data-in + we, then data-out, then clk, at
+    whatever widths the test asks), on periodic columns.  The published
+    heterogeneous architecture is ``k6_frac_n10_mem32k_40nm_arch``.  The
+    'spram' .subckt model maps onto 'bram'."""
     arch = k6_n10_arch()
     arch.name = "k6_N10_mem"
     num_in = addr_bits + data_bits + 1          # addr, din, we

@@ -136,6 +136,11 @@ class BlockType:
     # accessible from all adjacent channels; VPR7's default pin_location
     # "spread" is approximated as omni-side access).
     is_io: bool = False
+    # grid rows the block occupies, from its anchor row upwards (a
+    # <pb_type height=>: k6_frac_N10_mem32K's mult_36 4, memory 6).  Its
+    # pin p lies on row p % height of them; SOURCE and SINK nodes exist
+    # once a block (rr/graph.py)
+    height: int = 1
     # Combinational delay through the block (input pin -> output pin), and
     # sequential setup/clk-to-q.  Stand-ins for VPR7's <pb_type> delay matrix.
     T_comb: float = 400e-12
@@ -283,14 +288,18 @@ def make_clb_type(index: int, K: int, N: int, I: int,
                   T_comb: float = 400e-12,
                   T_setup: float = 60e-12,
                   T_clk_to_q: float = 80e-12,
-                  output_equivalent: bool = True) -> BlockType:
+                  output_equivalent: bool = True,
+                  outputs_per_ble: int = 1) -> BlockType:
     """Build a CLB block type: I input pins (one class), N output pins, 1
     clock pin.  Mirrors the k6_N10 soft logic cluster.  The outputs are
     one class of N equivalent pins, or, with ``output_equivalent``
     false (k6_N10_40nm.xml: ``<output name="O" num_pins="10"
     equivalent="false"/>``, each BLE drives its own pin), N classes of
     one pin after the clock's, so a net leaves by the pin the packer
-    gave it and can never take two."""
+    gave it and can never take two.  ``outputs_per_ble`` 2 is the
+    fracturable cluster's O = 2N (k6_frac_N10: an FLE is one 6-LUT or
+    two 5-LUTs and owns two output pins)."""
+    N = N * outputs_per_ble
     num_pins = I + N + 1
     pin_classes = [
         PinClass(PIN_CLASS_RECEIVER, list(range(0, I))),
@@ -312,21 +321,25 @@ def make_clb_type(index: int, K: int, N: int, I: int,
 
 def make_hard_type(name: str, index: int, num_in: int, num_out: int,
                    T_comb: float = 1.5e-9, T_setup: float = 100e-12,
-                   T_clk_to_q: float = 400e-12) -> BlockType:
+                   T_clk_to_q: float = 400e-12,
+                   height: int = 1) -> BlockType:
     """A hard block type (RAM / DSP column block): num_in data+address
-    input pins (one class), num_out output pins (one class), one clock.
+    input pins, num_out output pins, one clock, ``height`` grid rows.
+    The pins of a hard block are NOT logically equivalent (data bit 3 of
+    a RAM is not bit 7): every pin is a class of its own, so pin p is
+    class p and a net reaches the pin the netlist names.
     Stratix-IV-style heterogeneous tile (physical_types.h
     t_type_descriptor with its own pin classes and timing)."""
     num_pins = num_in + num_out + 1
-    pin_classes = [
-        PinClass(PIN_CLASS_RECEIVER, list(range(0, num_in))),
-        PinClass(PIN_CLASS_DRIVER, list(range(num_in, num_in + num_out))),
-        PinClass(PIN_CLASS_RECEIVER, [num_in + num_out], is_clock=True),
-    ]
-    pin_class_of = [0] * num_in + [1] * num_out + [2]
+    pin_classes = (
+        [PinClass(PIN_CLASS_RECEIVER, [p]) for p in range(num_in)]
+        + [PinClass(PIN_CLASS_DRIVER, [p])
+           for p in range(num_in, num_in + num_out)]
+        + [PinClass(PIN_CLASS_RECEIVER, [num_in + num_out], is_clock=True)])
     return BlockType(
         name=name, index=index, num_pins=num_pins, capacity=1,
-        pin_classes=pin_classes, pin_class_of=pin_class_of, is_io=False,
+        pin_classes=pin_classes, pin_class_of=list(range(num_pins)),
+        is_io=False, height=int(height),
         T_comb=T_comb, T_setup=T_setup, T_clk_to_q=T_clk_to_q,
     )
 

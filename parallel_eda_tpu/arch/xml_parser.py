@@ -353,6 +353,21 @@ def read_arch_xml(path: str) -> Arch:
             t_cq = _f(e.attrib, "max", _f(e.attrib, "value", t_cq))
         return t_comb, t_setup, t_cq
 
+    def _height(pb) -> int:
+        """A top-level <pb_type height=>: the grid rows the block
+        occupies (BlockType.height)."""
+        h = int(float(pb.attrib.get("height", 1)))
+        if h < 1:
+            raise ValueError(f"{path}: pb_type "
+                             f"{pb.attrib.get('name')!r} has height {h}")
+        return h
+
+    if cluster_pb is not None and _height(cluster_pb) != 1:
+        raise ValueError(
+            f"{path}: the logic cluster "
+            f"{cluster_pb.attrib.get('name')!r} has height "
+            f"{_height(cluster_pb)}; only column types (the pb_types "
+            "after it) may be tall")
     arch.K, arch.N, arch.I, arch.io_capacity = K, N, I, io_capacity
     t_comb, t_setup, t_cq = _pb_timing(cluster_pb)
     arch.block_types = [
@@ -377,7 +392,7 @@ def read_arch_xml(path: str) -> Arch:
         arch.block_types.append(make_hard_type(
             name, index=len(arch.block_types), num_in=num_in,
             num_out=num_out, T_comb=ht_comb, T_setup=ht_setup,
-            T_clk_to_q=ht_cq))
+            T_clk_to_q=ht_cq, height=_height(pb)))
         for inner in pb.iter("pb_type"):
             model = inner.attrib.get("blif_model", "")
             toks = model.split(None, 1)

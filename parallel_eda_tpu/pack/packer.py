@@ -426,7 +426,10 @@ def pack_netlist(nl: LogicalNetlist, arch: Arch,
 
     in_base = 0
     out_base = arch.I
-    clk_pin = arch.I + arch.N
+    clk_pin = clb_t.num_pins - 1
+    # a BLE of a fracturable cluster owns several output pins (O = 2N on
+    # k6_frac_N10); one LUT a BLE drives the first of its BLE's
+    out_step = clb_t.num_output_pins // arch.N
     for ci, mem in enumerate(clusters):
         pin_nets = [-1] * clb_t.num_pins
         outs = {bles[m].output for m in mem}
@@ -452,7 +455,7 @@ def pack_netlist(nl: LogicalNetlist, arch: Arch,
             b = bles[m]
             if net_needed_outside(ci, b.output):
                 pin_nets[out_base + oidx] = pnl.add_net(b.output)
-                oidx += 1
+                oidx += out_step
         if clk is not None:
             pin_nets[clk_pin] = pnl.add_net(clk, is_global=True)
         pnl.blocks.append(Block(name=f"clb{ci}", type_name=clb_t.name,

@@ -2,8 +2,8 @@
 
 Equivalent of the reference's post-anneal verification (place.c:253
 check_place + the cost re-derivation at :654-683): every block sits on a
-tile legal for its type, subtile indices are in range, and no two blocks
-share a site.  Called by Placer.place() on its final result (not just
+tile legal for its type (a tall block anchored on its type's rows), subtile
+indices are in range, and no two blocks share a site.  Called by Placer.place() on its final result (not just
 tests), so an annealer bug can never hand an illegal placement to the
 router silently.
 """
@@ -48,6 +48,12 @@ def check_place(pnl: PackedNetlist, grid: DeviceGrid,
     flag(~is_io & interior & (col_t[xc] != tname),
          "block on a column of another type")
     flag(~is_io & (z != 0), "non-io subtile != 0")
+    # a block of height h is anchored at a row 1 + k * h and its h rows
+    # lie inside the grid; with every block of a column the same height
+    # on such anchors, distinct anchors are disjoint footprints
+    h = np.array([pnl.block_type(i).height for i in range(NB)])
+    flag(~is_io & interior & (((y - 1) % h != 0) | (y + h - 1 > grid.ny)),
+         "block off its type's anchor rows")
 
     # site collisions: unique (x, y, z) per block
     key = (x.astype(np.int64) * (grid.ny + 2) + y) \

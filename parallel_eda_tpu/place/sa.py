@@ -94,6 +94,8 @@ class PlaceProblem:
     type_id: jnp.ndarray       # int32 [NB] interior type index
     col_list: jnp.ndarray      # int32 [T, Cmax] interior columns per type
     ncols: jnp.ndarray         # int32 [T]
+    type_h: jnp.ndarray        # int32 [T] rows a block occupies: its
+    #                            anchors are the rows 1 + k * type_h
     col_idx_of_x: jnp.ndarray  # int32 [T, nx+2] nearest own-column index
     # timing model: delta-delay matrices (delay_lookup) padded to one
     # [4, nx+2, ny+2] stack ordered (clb_clb, io_clb, clb_io, io_io)
@@ -201,6 +203,7 @@ def build_place_problem(pnl: PackedNetlist, grid: DeviceGrid,
     Cmax = max(1, max(len(c) for c in cols_by_t.values()))
     col_list = np.zeros((len(itypes), Cmax), dtype=np.int32)
     ncols = np.zeros(len(itypes), dtype=np.int32)
+    type_h = np.array([grid.height_of(t) for t in itypes], dtype=np.int32)
     col_idx_of_x = np.zeros((len(itypes), grid.nx + 2), dtype=np.int32)
     for t, cols in cols_by_t.items():
         ti = tid_of[t]
@@ -232,7 +235,8 @@ def build_place_problem(pnl: PackedNetlist, grid: DeviceGrid,
         net_q=jnp.asarray(net_q), blk_net=jnp.asarray(blk_net),
         is_io=jnp.asarray(is_io), ring_xy=jnp.asarray(ring),
         type_id=jnp.asarray(type_id), col_list=jnp.asarray(col_list),
-        ncols=jnp.asarray(ncols), col_idx_of_x=jnp.asarray(col_idx_of_x),
+        ncols=jnp.asarray(ncols), type_h=jnp.asarray(type_h),
+        col_idx_of_x=jnp.asarray(col_idx_of_x),
         delta=jnp.asarray(delta),
         movable=jnp.asarray(movable), frozen=jnp.asarray(frozen),
         nx=grid.nx, ny=grid.ny, io_cap=grid.io_capacity,
@@ -318,7 +322,11 @@ def _propose(pp: PlaceProblem, pos, ring_idx, key, rlim, M: int):
                   .astype(jnp.int32), 0, nc - 1)
     cx = pp.col_list[tid, ci]
     dy = jax.random.randint(k2b, (M,), -rl, rl + 1)
-    cy = jnp.clip(pos[b, 1] + dy, 1, pp.ny)
+    # the row: dy tiles up or down counted in whole blocks of the type's
+    # height, onto an anchor row 1 + k * h (height 1: pos + dy, clipped)
+    h = pp.type_h[tid]
+    k = (pos[b, 1] - 1) // h + jnp.sign(dy) * ((jnp.abs(dy) + h - 1) // h)
+    cy = 1 + jnp.clip(k, 0, pp.ny // h - 1) * h
 
     # IO target: shift along the perimeter ring (ring distance ~ 2x
     # Manhattan distance for the same rlim), random subtile

@@ -90,12 +90,36 @@ def _tables(pnl: PackedNetlist, grid: DeviceGrid):
     return net_blk, net_q, blk_net, is_io, ring
 
 
+def _type_tables(pnl: PackedNetlist, grid: DeviceGrid, is_io):
+    """Sites by block type: per block its interior type (-1 for I/O),
+    per type the nearest own column of every x and the rows a block
+    occupies (its anchors are the rows 1 + k * height)."""
+    names = sorted({b.type_name for b, io in zip(pnl.blocks, is_io)
+                    if not io})
+    tid = {t: i for i, t in enumerate(names)}
+    type_id = np.array([-1 if io else tid[b.type_name]
+                        for b, io in zip(pnl.blocks, is_io)],
+                       dtype=np.int32)
+    col_snap = np.ones((max(1, len(names)), grid.nx + 2), dtype=np.int32)
+    type_h = np.ones(max(1, len(names)), dtype=np.int32)
+    for t, i in tid.items():
+        cols = np.array([x for x in range(1, grid.nx + 1)
+                         if grid.interior_type_name(x) == t])
+        if not len(cols):
+            raise ValueError(f"block type '{t}' has no columns")
+        xs = np.arange(grid.nx + 2)
+        col_snap[i] = cols[np.abs(cols[None, :] - xs[:, None]).argmin(1)]
+        type_h[i] = grid.height_of(t)
+    return type_id, col_snap, type_h
+
+
 def serial_sa_place(pnl: PackedNetlist, grid: DeviceGrid,
                     pos0: np.ndarray, inner_num: float = 1.0,
                     exit_t_frac: float = 0.005, max_temps: int = 500,
                     seed: int = 0) -> SerialPlaceResult:
     lib = _get_lib()
     net_blk, net_q, blk_net, is_io, ring_xy = _tables(pnl, grid)
+    type_id, col_snap, type_h = _type_tables(pnl, grid, is_io)
     NB = pnl.num_blocks
     NN, P = net_blk.shape
     F = blk_net.shape[1]
@@ -126,6 +150,9 @@ def serial_sa_place(pnl: PackedNetlist, grid: DeviceGrid,
         blk_net.ctypes.data_as(c.c_void_p),
         is_io.ctypes.data_as(c.c_void_p),
         ring_xy.ctypes.data_as(c.c_void_p),
+        type_id.ctypes.data_as(c.c_void_p),
+        col_snap.ctypes.data_as(c.c_void_p),
+        type_h.ctypes.data_as(c.c_void_p),
         c.c_int32(NN), c.c_int32(P), c.c_int32(NB), c.c_int32(F),
         c.c_int32(NRING), c.c_int32(grid.nx), c.c_int32(grid.ny),
         c.c_int32(grid.io_capacity),

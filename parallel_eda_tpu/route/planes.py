@@ -434,7 +434,13 @@ def build_planes_terminals(rr: RRGraph, source: np.ndarray,
     u_pcrank[held] = k2[first]
     get_metrics().set_gauges({"route.sink_pick.cands_per_sink": K,
                               "route.sink_pick.cells_per_sink": C,
-                              "route.sink_pick.pins_per_sink": P})
+                              "route.sink_pick.pins_per_sink": P,
+                              # real (cell, pin) hops over the dense
+                              # [U, P, C] entries: 1 / P where a hard
+                              # block's one-pin sinks sit beside a
+                              # cluster's P equivalent inputs
+                              "route.sink_pick.table_fill":
+                              len(first) / max(1, U * P * C)})
 
     sink_uid = np.full(R * S, U, dtype=np.int32)
     sink_uid[valid] = inv.astype(np.int32)

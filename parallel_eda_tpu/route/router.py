@@ -1601,6 +1601,9 @@ class Router:
         # nets x windows handed to a cropped rung / to the full canvas
         crop_nets = reg.counter("route.crop.net_dispatches_cropped_total")
         full_nets = reg.counter("route.crop.net_dispatches_full_total")
+        # of both, the nets with a terminal on a hard block (0 on a
+        # device of identical clusters)
+        hard_nets = reg.counter("route.hetero.net_dispatches_hard_total")
         # conflict colourings run (one a dispatched _window_body, so one
         # a rung) / read (one a window: the last rung's summary)
         mis_calls = reg.counter("route.mis_colors.calls_total")
@@ -1728,6 +1731,8 @@ class Router:
             for rung_nets, rung_tile in dispatch:
                 (full_nets if rung_tile is None
                  else crop_nets).inc(len(rung_nets))
+                if term.hard is not None:
+                    hard_nets.inc(int(term.hard[rung_nets].sum()))
             mis_calls.inc(len(dispatch))
 
             def plan_rung(sub, tile, ri):

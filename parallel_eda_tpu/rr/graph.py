@@ -241,7 +241,11 @@ def build_rr_graph(arch: Arch, grid: DeviceGrid,
                max_span=int(np.maximum(
                    rr.xhigh - rr.xlow, rr.yhigh - rr.ylow)[is_wire].max(
                        initial=0)) + 1,
-               nodes=rr.num_nodes, edges=rr.num_edges)
+               nodes=rr.num_nodes, edges=rr.num_edges,
+               block_types=len({grid.interior_type_name(x)
+                                for x in range(1, grid.nx + 1)}) + 1,
+               hard_columns=len(grid.col_types),
+               tall_rows=max(grid.type_heights.values(), default=1))
         if rr.unidir:
             exit_min, opin_min = unidir_box_stats(rr)
             get_metrics().set_gauges({"rr.exit_turns_min": exit_min,
@@ -309,15 +313,35 @@ def _build_rr_graph(arch: Arch, grid: DeviceGrid,
             m = arch.segments[seg_of_track[t]].sb_marks()
             sb_of_track[t, :len(m)] = m
 
-    def type_at(x: int, y: int):
-        """Block type on tile (x, y), or None (corner/empty).  Interior
+    def block_at(x: int, y: int):
+        """(block type, anchor row) of the block site covering tile
+        (x, y), or (None, y) on a corner or an empty tile.  Interior
         columns may hold heterogeneous types (grid.col_types,
-        SetupGrid.c column assignment)."""
+        SetupGrid.c column assignment); a type of ``height`` h is
+        anchored every h rows of its column and covers the h rows from
+        its anchor (grid.block_at)."""
         if 1 <= x <= nx and 1 <= y <= ny:
-            return arch.block_type(grid.interior_type_name(x))
+            site = grid.block_at(x, y)
+            if site is None:
+                return None, y
+            return arch.block_type(site[0]), site[1]
         if grid.is_io(x, y):
-            return arch.io_type
-        return None
+            return arch.io_type, y
+        return None, y
+
+    # every tile that holds pins, in node order.  A block's SOURCE and
+    # SINK nodes exist ONCE, at its anchor tile, and span its rows; its
+    # pin p lies on row p % height and reaches the channels beside that
+    # row (the channels run through a hard column).  A block of height 1
+    # is the same rule: all its pins on its one row
+    tiles = []
+    for x in range(nx + 2):
+        for y in range(ny + 2):
+            bt, y0 = block_at(x, y)
+            if bt is not None:
+                tiles.append((x, y, bt, y0, [
+                    p for p in range(bt.num_pins)
+                    if p % bt.height == y - y0]))
 
     ntype: List[int] = []
     xlo: List[int] = []; ylo: List[int] = []
@@ -335,33 +359,30 @@ def _build_rr_graph(arch: Arch, grid: DeviceGrid,
     src_of: Dict = {}; sink_of: Dict = {}
     opin_of: Dict = {}; ipin_of: Dict = {}
 
-    # ---- block-pin nodes (SOURCE/SINK/OPIN/IPIN), per tile/subtile ----
-    for x in range(nx + 2):
-        for y in range(ny + 2):
-            bt = type_at(x, y)
-            if bt is None:
-                continue
-            ncls = len(bt.pin_classes)
-            for z in range(bt.capacity):
-                for k, cls in enumerate(bt.pin_classes):
-                    pc = z * ncls + k
-                    if cls.direction == PIN_CLASS_DRIVER:
-                        src_of[(x, y, z, k)] = add_node(
-                            SOURCE, x, y, x, y, pc, len(cls.pins),
-                            0.0, 0.0, COST_SOURCE)
-                    else:
-                        sink_of[(x, y, z, k)] = add_node(
-                            SINK, x, y, x, y, pc, len(cls.pins),
-                            0.0, 0.0, COST_SINK)
-                for p in range(bt.num_pins):
-                    pc = z * bt.num_pins + p
-                    k = bt.pin_class_of[p]
-                    if bt.pin_classes[k].direction == PIN_CLASS_DRIVER:
-                        opin_of[(x, y, z, p)] = add_node(
-                            OPIN, x, y, x, y, pc, 1, 0.0, 0.0, COST_OPIN)
-                    else:
-                        ipin_of[(x, y, z, p)] = add_node(
-                            IPIN, x, y, x, y, pc, 1, 0.0, 0.0, COST_IPIN)
+    # ---- block-pin nodes (SOURCE/SINK/OPIN/IPIN), per tile/subtile;
+    # the lookups are keyed by the block's ANCHOR (x, y0) ----
+    for x, y, bt, y0, pins in tiles:
+        ncls = len(bt.pin_classes)
+        for z in range(bt.capacity):
+            for k, cls in enumerate(bt.pin_classes if y == y0 else ()):
+                pc = z * ncls + k
+                if cls.direction == PIN_CLASS_DRIVER:
+                    src_of[(x, y, z, k)] = add_node(
+                        SOURCE, x, y, x, y + bt.height - 1, pc,
+                        len(cls.pins), 0.0, 0.0, COST_SOURCE)
+                else:
+                    sink_of[(x, y, z, k)] = add_node(
+                        SINK, x, y, x, y + bt.height - 1, pc,
+                        len(cls.pins), 0.0, 0.0, COST_SINK)
+            for p in pins:
+                pc = z * bt.num_pins + p
+                k = bt.pin_class_of[p]
+                if bt.pin_classes[k].direction == PIN_CLASS_DRIVER:
+                    opin_of[(x, y0, z, p)] = add_node(
+                        OPIN, x, y, x, y, pc, 1, 0.0, 0.0, COST_OPIN)
+                else:
+                    ipin_of[(x, y0, z, p)] = add_node(
+                        IPIN, x, y, x, y, pc, 1, 0.0, 0.0, COST_IPIN)
 
     # ---- wire nodes ----
     # chanx_wire[y][t, x] / chany_wire[x][t, y]: node covering that position
@@ -421,21 +442,19 @@ def _build_rr_graph(arch: Arch, grid: DeviceGrid,
         e_src.append(s); e_dst.append(d); e_sw.append(sw)
 
     # ---- SOURCE->OPIN, IPIN->SINK (delayless) ----
-    for x in range(nx + 2):
-        for y in range(ny + 2):
-            bt = type_at(x, y)
-            if bt is None:
-                continue
-            for z in range(bt.capacity):
-                for k, cls in enumerate(bt.pin_classes):
-                    if cls.direction == PIN_CLASS_DRIVER:
-                        s = src_of[(x, y, z, k)]
-                        for p in cls.pins:
-                            add_edge(s, opin_of[(x, y, z, p)], delayless)
-                    else:
-                        snk = sink_of[(x, y, z, k)]
-                        for p in cls.pins:
-                            add_edge(ipin_of[(x, y, z, p)], snk, delayless)
+    for x, y, bt, y0, _ in tiles:
+        if y != y0:
+            continue
+        for z in range(bt.capacity):
+            for k, cls in enumerate(bt.pin_classes):
+                if cls.direction == PIN_CLASS_DRIVER:
+                    s = src_of[(x, y, z, k)]
+                    for p in cls.pins:
+                        add_edge(s, opin_of[(x, y, z, p)], delayless)
+                else:
+                    snk = sink_of[(x, y, z, k)]
+                    for p in cls.pins:
+                        add_edge(ipin_of[(x, y, z, p)], snk, delayless)
 
     # ---- pin <-> channel edges ----
     def starting_tracks(kind: str, ci: int, pos: int) -> List[int]:
@@ -456,68 +475,65 @@ def _build_rr_graph(arch: Arch, grid: DeviceGrid,
                 out.append(t)
         return out
 
-    for x in range(nx + 2):
-        for y in range(ny + 2):
-            bt = type_at(x, y)
-            if bt is None:
-                continue
-            adj = _adjacent_channels(grid, x, y)
-            for z in range(bt.capacity):
-                for p in range(bt.num_pins):
-                    k = bt.pin_class_of[p]
-                    cls = bt.pin_classes[k]
-                    is_out = cls.direction == PIN_CLASS_DRIVER
-                    node = (opin_of if is_out else ipin_of)[(x, y, z, p)]
-                    fc = arch.fc_frac(W, is_out, type_name=bt.name, pin=p)
-                    pin_ptc = z * bt.num_pins + p
-                    for side, (kind, ci, pos) in enumerate(adj):
-                        if unidir:
-                            # single-driver wires: an OPIN drives only
-                            # wires that START at its position, an IPIN
-                            # hears any wire passing it; Fc of W, spread
-                            # over the candidates
-                            cands = (starting_tracks(kind, ci, pos)
-                                     if is_out else list(range(W)))
-                            if not cands:
-                                continue
-                            fc_abs = min(len(cands),
-                                         max(1, int(round(fc * W))))
-                            for i in _spread_picks(len(cands), fc_abs,
-                                                   4 * pin_ptc + side):
-                                t = cands[i]
-                                wire = int(chanx_wire[ci][t, pos]
-                                           if kind == "x"
-                                           else chany_wire[ci][t, pos])
-                                if is_out:
-                                    add_edge(node, wire, arch.segments[
-                                        seg_of_track[t]].opin_switch)
-                                else:
-                                    add_edge(wire, node, arch.ipin_switch)
+    for x, y, bt, y0, pins in tiles:
+        adj = _adjacent_channels(grid, x, y)
+        for z in range(bt.capacity):
+            for p in pins:
+                k = bt.pin_class_of[p]
+                cls = bt.pin_classes[k]
+                is_out = cls.direction == PIN_CLASS_DRIVER
+                node = (opin_of if is_out else ipin_of)[(x, y0, z, p)]
+                fc = arch.fc_frac(W, is_out, type_name=bt.name, pin=p)
+                pin_ptc = z * bt.num_pins + p
+                for side, (kind, ci, pos) in enumerate(adj):
+                    if unidir:
+                        # single-driver wires: an OPIN drives only
+                        # wires that START at its position, an IPIN
+                        # hears any wire passing it; Fc of W, spread
+                        # over the candidates
+                        cands = (starting_tracks(kind, ci, pos)
+                                 if is_out else list(range(W)))
+                        if not cands:
                             continue
-                        for t in _fc_tracks(pin_ptc, side, W, fc):
-                            wire = (chanx_wire[ci][t, pos] if kind == "x"
-                                    else chany_wire[ci][t, pos])
-                            if wire < 0:
-                                continue
+                        fc_abs = min(len(cands),
+                                     max(1, int(round(fc * W))))
+                        for i in _spread_picks(len(cands), fc_abs,
+                                               4 * pin_ptc + side):
+                            t = cands[i]
+                            wire = int(chanx_wire[ci][t, pos]
+                                       if kind == "x"
+                                       else chany_wire[ci][t, pos])
                             if is_out:
-                                sw = arch.segments[seg_of_track[t]].opin_switch
-                                add_edge(node, int(wire), sw)
+                                add_edge(node, wire, arch.segments[
+                                    seg_of_track[t]].opin_switch)
                             else:
-                                add_edge(int(wire), node, arch.ipin_switch)
+                                add_edge(wire, node, arch.ipin_switch)
+                        continue
+                    for t in _fc_tracks(pin_ptc, side, W, fc):
+                        wire = (chanx_wire[ci][t, pos] if kind == "x"
+                                else chany_wire[ci][t, pos])
+                        if wire < 0:
+                            continue
+                        if is_out:
+                            sw = arch.segments[seg_of_track[t]].opin_switch
+                            add_edge(node, int(wire), sw)
+                        else:
+                            add_edge(int(wire), node, arch.ipin_switch)
 
     # ---- dedicated direct connections (<directlist>,
     # physical_types.h t_direct_inf): OPIN -> IPIN of the offset
     # neighbour through a private wire, bypassing the fabric ----
+    # (between block ANCHORS: the offset is in tiles)
     for d in arch.directs:
         sw = d.switch if d.switch >= 0 else delayless
         for x in range(nx + 2):
             for y in range(ny + 2):
-                bt = type_at(x, y)
-                if bt is None or bt.name != d.from_type:
+                bt, y0 = block_at(x, y)
+                if bt is None or bt.name != d.from_type or y != y0:
                     continue
                 tx, ty = x + d.dx, y + d.dy
-                tt = type_at(tx, ty)
-                if tt is None or tt.name != d.to_type:
+                tt, ty0 = block_at(tx, ty)
+                if tt is None or tt.name != d.to_type or ty != ty0:
                     continue
                 for z in range(bt.capacity):
                     src_n = opin_of.get((x, y, z, d.from_pin))
@@ -836,7 +852,9 @@ def _check_unidir_box(rr: RRGraph, arch: Optional[Arch]) -> None:
               else arch.block_type(rr.grid.interior_type_name(x)))
         fc = arch.fc_frac(rr.chan_width, True, type_name=bt.name, pin=p)
         want = max(1, int(round(fc * rr.chan_width)))
-        for kind, f, pos_ in _adjacent_channels(rr.grid, x, y):
+        # the key is the block's anchor; the pin's own row is the node's
+        for kind, f, pos_ in _adjacent_channels(rr.grid, x,
+                                                int(rr.ylow[o])):
             kx = kind == "x"
             n = starts.get((kx, f, pos_), 0)
             if n == 0:

@@ -29,9 +29,31 @@ class DeviceGrid:
     # interior column x (1..nx) -> block type name; missing = "clb"
     # (heterogeneous columns, SetupGrid.c t_grid_loc_def col semantics)
     col_types: Dict[int, str] = field(default_factory=dict)
+    # block type name -> rows a block of it occupies; missing = 1.  A
+    # block of height h is ANCHORED at a row 1 + k * h of its column and
+    # occupies the h rows from there; a column holds ny // h of them,
+    # and the ny % h rows above the last stay empty
+    type_heights: Dict[str, int] = field(default_factory=dict)
 
     def interior_type_name(self, x: int) -> str:
         return self.col_types.get(x, "clb")
+
+    def height_of(self, name: str) -> int:
+        return self.type_heights.get(name, 1)
+
+    def anchor_rows(self, name: str) -> List[int]:
+        """The rows a block of type ``name`` can be anchored at."""
+        h = self.height_of(name)
+        return [1 + k * h for k in range(self.ny // h)]
+
+    def block_at(self, x: int, y: int) -> Optional[Tuple[str, int]]:
+        """(type name, anchor row) of the block site that covers
+        interior tile (x, y); None on a tile no site covers (the rows
+        left over above a column's last tall block)."""
+        name = self.interior_type_name(x)
+        h = self.height_of(name)
+        y0 = 1 + (y - 1) // h * h
+        return (name, y0) if y0 + h - 1 <= self.ny else None
 
     @property
     def width(self) -> int:
@@ -71,10 +93,20 @@ class DeviceGrid:
                 if self.interior_type_name(x) == "clb"]
 
     def sites_of_type(self, name: str) -> List[Tuple[int, int]]:
-        """Interior tile coordinates holding blocks of ``name``."""
-        return [(x, y) for y in range(1, self.ny + 1)
+        """Interior anchor tiles of the block sites of type ``name``."""
+        return [(x, y) for y in self.anchor_rows(name)
                 for x in range(1, self.nx + 1)
                 if self.interior_type_name(x) == name]
+
+
+def make_grid(arch: Arch, nx: int, ny: int) -> DeviceGrid:
+    """The device of ``arch`` at nx x ny interior tiles: its typed
+    columns and its block types' heights."""
+    return DeviceGrid(nx, ny, arch.io_capacity,
+                      col_types=assign_columns(arch, nx),
+                      type_heights={t.name: t.height
+                                    for t in arch.block_types
+                                    if t.height != 1})
 
 
 def assign_columns(arch: Arch, n: int) -> Dict[int, str]:
@@ -102,6 +134,8 @@ def size_grid(num_clb: int, num_io: int, arch: Arch,
             raise ValueError(f"netlist needs '{t}' blocks but the arch "
                              f"has no {t} columns")
 
+    heights = {t.name: t.height for t in arch.block_types}
+
     def capacities(w: int, h: int):
         cols = assign_columns(arch, w)
         n_hard_cols: Dict[str, int] = {}
@@ -110,8 +144,9 @@ def size_grid(num_clb: int, num_io: int, arch: Arch,
             if t is not None:
                 n_hard_cols[t] = n_hard_cols.get(t, 0) + 1
         clb_cols = w - sum(n_hard_cols.values())
-        return cols, clb_cols * h, {t: c * h for t, c in
-                                    n_hard_cols.items()}
+        # a column holds h // height blocks of its type
+        return cols, clb_cols * h, {t: c * (h // heights.get(t, 1))
+                                    for t, c in n_hard_cols.items()}
 
     def fits(n: int) -> bool:
         _, clb_cap, hard_cap = capacities(n, n)
@@ -120,16 +155,14 @@ def size_grid(num_clb: int, num_io: int, arch: Arch,
         return all(hard_cap.get(t, 0) >= c for t, c in hard_counts.items())
 
     if nx and ny:
-        g = DeviceGrid(nx, ny, arch.io_capacity,
-                       col_types=assign_columns(arch, nx))
+        g = make_grid(arch, nx, ny)
     else:
         n = max(1,
                 math.ceil(math.sqrt(max(1, num_clb))),
                 math.ceil(num_io / (4 * max(1, arch.io_capacity))))
         while not fits(n):
             n += 1
-        g = DeviceGrid(n, n, arch.io_capacity,
-                       col_types=assign_columns(arch, n))
+        g = make_grid(arch, n, n)
     cols, clb_cap, hard_cap = capacities(g.nx, g.ny)
     if clb_cap < num_clb:
         raise ValueError(f"grid {g.nx}x{g.ny} too small for {num_clb} CLBs")

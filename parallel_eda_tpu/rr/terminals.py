@@ -11,11 +11,12 @@ bb_factor-expanded bounding box the router restricts its search to
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List
+from typing import List, Optional
 
 import numpy as np
 
 from ..netlist.packed import PackedNetlist
+from ..obs import get_metrics
 from .graph import RRGraph
 
 
@@ -30,6 +31,10 @@ class NetTerminals:
     bb_xmax: np.ndarray
     bb_ymin: np.ndarray
     bb_ymax: np.ndarray
+    # [R] bool: the net has a terminal on a block of a typed column (a
+    # RAM, a multiplier); None where the terminals were not built from
+    # a placed netlist
+    hard: Optional[np.ndarray] = None
 
     @property
     def num_nets(self) -> int:
@@ -43,7 +48,8 @@ class NetTerminals:
 def net_terminals(pnl: PackedNetlist, rr: RRGraph, pos: np.ndarray,
                   bb_factor: int = 3) -> NetTerminals:
     """``pos`` is [num_blocks, 3] (x, y, subtile).  bb_factor default mirrors
-    SetupVPR.c:337."""
+    SetupVPR.c:337.  Sets the gauge ``route.hetero.nets_hard``: the
+    routed nets with a terminal on a block of a typed column."""
     routable = pnl.routed_nets
     R = len(routable)
     Smax = max((pnl.nets[i].num_sinks for i in routable), default=1)
@@ -54,6 +60,8 @@ def net_terminals(pnl: PackedNetlist, rr: RRGraph, pos: np.ndarray,
     num_sinks = np.zeros(R, dtype=np.int32)
     bbx0 = np.zeros(R, dtype=np.int32); bbx1 = np.zeros(R, dtype=np.int32)
     bby0 = np.zeros(R, dtype=np.int32); bby1 = np.zeros(R, dtype=np.int32)
+    col_types = set(rr.grid.col_types.values())
+    hard = np.zeros(R, dtype=bool)
 
     for r, ni in enumerate(routable):
         net = pnl.nets[ni]
@@ -61,23 +69,29 @@ def net_terminals(pnl: PackedNetlist, rr: RRGraph, pos: np.ndarray,
         x, y, z = (int(v) for v in pos[net.driver.block])
         k = bt.pin_class_of[net.driver.pin]
         source[r] = rr.src_of[(x, y, z, k)]
-        xs, ys = [x], [y]
+        # a block's position is its anchor; the box holds every row it
+        # occupies (its pins are spread over them)
+        xs, ys = [x], [y, y + bt.height - 1]
         for s, pin in enumerate(net.sinks):
             bt_s = pnl.block_type(pin.block)
             sx, sy, sz = (int(v) for v in pos[pin.block])
             ks = bt_s.pin_class_of[pin.pin]
             sinks[r, s] = rr.sink_of[(sx, sy, sz, ks)]
-            xs.append(sx); ys.append(sy)
+            xs.append(sx); ys += [sy, sy + bt_s.height - 1]
         num_sinks[r] = net.num_sinks
+        hard[r] = any(pnl.blocks[p.block].type_name in col_types
+                      for p in [net.driver] + net.sinks)
         bbx0[r] = max(0, min(xs) - bb_factor)
         bbx1[r] = min(nx + 1, max(xs) + bb_factor)
         bby0[r] = max(0, min(ys) - bb_factor)
         bby1[r] = min(ny + 1, max(ys) + bb_factor)
 
+    get_metrics().set_gauges({"route.hetero.nets_hard": int(hard.sum())})
     return NetTerminals(
         net_ids=np.array(routable, dtype=np.int32),
         source=source, sinks=sinks, num_sinks=num_sinks,
         bb_xmin=bbx0, bb_xmax=bbx1, bb_ymin=bby0, bb_ymax=bby1,
+        hard=hard,
     )
 
 
@@ -103,4 +117,5 @@ def subset_terminals(term: NetTerminals, frac: float,
         net_ids=term.net_ids[idx], source=term.source[idx],
         sinks=term.sinks[idx], num_sinks=term.num_sinks[idx],
         bb_xmin=term.bb_xmin[idx], bb_xmax=term.bb_xmax[idx],
-        bb_ymin=term.bb_ymin[idx], bb_ymax=term.bb_ymax[idx])
+        bb_ymin=term.bb_ymin[idx], bb_ymax=term.bb_ymax[idx],
+        hard=None if term.hard is None else term.hard[idx])

@@ -120,6 +120,8 @@ def build_timing_graph(nl: LogicalNetlist, pnl: PackedNetlist,
     out_tnode = np.full(n_prims, -1, dtype=np.int32)
     in_tnode = np.full(n_prims, -1, dtype=np.int32)   # ff.IN / outpad.IN
     tnode_prim = []
+    hard_in: dict = {}      # hard prim -> {input net: tnode}
+    hard_out: dict = {}     # hard prim -> {output net: tnode}
 
     def new_tnode(p):
         tnode_prim.append(p)
@@ -130,11 +132,19 @@ def build_timing_graph(nl: LogicalNetlist, pnl: PackedNetlist,
             out_tnode[i] = new_tnode(i)
         elif p.kind == PRIM_LUT:
             out_tnode[i] = new_tnode(i)
-        elif p.kind in (PRIM_FF, PRIM_HARD):
-            # hard macros are registered (RAM/DSP): input setup endpoint,
-            # clk-to-q launch point — FF semantics at the block's timing
+        elif p.kind == PRIM_FF:
             in_tnode[i] = new_tnode(i)
             out_tnode[i] = new_tnode(i)
+        elif p.kind == PRIM_HARD:
+            # hard macros are registered (RAM/DSP): FF semantics at the
+            # block's timing, a setup endpoint per connected input PIN
+            # and a clk-to-q launch point per connected output pin (one
+            # node a block would hang 76 in-edges and every consumer of
+            # 64 outputs on two rows of the dense edge tables)
+            hard_in[i] = {n: new_tnode(i) for n in dict.fromkeys(p.inputs)
+                          if n is not None and n not in clocks}
+            hard_out[i] = {n: new_tnode(i) for n in p.outputs
+                           if n is not None}
         elif p.kind == PRIM_OUTPAD:
             in_tnode[i] = new_tnode(i)
     T = len(tnode_prim)
@@ -156,10 +166,14 @@ def build_timing_graph(nl: LogicalNetlist, pnl: PackedNetlist,
             if p.output is not None:
                 inpad_tnode[p.output] = int(out_tnode[i])
         elif p.kind in (PRIM_FF, PRIM_HARD):
-            arrival0[out_tnode[i]] = bt.T_clk_to_q
-            is_endpoint[in_tnode[i]] = True
+            ins, outs = (([in_tnode[i]], [out_tnode[i]])
+                         if p.kind == PRIM_FF else
+                         (list(hard_in[i].values()),
+                          list(hard_out[i].values())))
+            arrival0[outs] = bt.T_clk_to_q
+            is_endpoint[ins] = True
             if p.clock is not None:
-                endpoint_domain[in_tnode[i]] = dom_of[p.clock]
+                endpoint_domain[ins] = dom_of[p.clock]
         elif p.kind == PRIM_OUTPAD:
             is_endpoint[in_tnode[i]] = True
             outpad_tnode[p.name] = int(in_tnode[i])
@@ -185,11 +199,14 @@ def build_timing_graph(nl: LogicalNetlist, pnl: PackedNetlist,
             dst, extra = in_tnode[i], bt.T_setup
         else:                                       # outpad
             dst, extra = in_tnode[i], 0.0
-        for n in p.inputs:
+        # a hard block: its connected input nets, each to its own node
+        for n in hard_in[i] if p.kind == PRIM_HARD else p.inputs:
             if n is None or n in clocks:
                 continue          # unconnected port / ideal clock network
             dp = nl.net_driver[n]
-            src = out_tnode[dp]
+            src = (hard_out[dp][n] if dp in hard_out else out_tnode[dp])
+            if p.kind == PRIM_HARD:
+                dst = hard_in[i][n]
             const, ridx = extra, -1
             if block_of_prim[dp] == block_of_prim[i]:
                 const += t_local

@@ -130,29 +130,32 @@ def test_planes_relax_cropped_compiles_at_route_tile(one_chip):
     _fits_hbm(compiled)
 
 
-@pytest.mark.parametrize("n, W, tile", [
-    (11, 64, 8),        # route_k6n10_relaxed
-    (19, 88, 16),       # route_scale: the one populated rung, 16 x 16
+@pytest.mark.parametrize("arch_fn, n, W, tile", [
+    ("k6_n10_40nm_arch", 11, 64, 8),        # route_k6n10_relaxed
+    # route_scale: the one populated rung, 16 x 16
+    ("k6_n10_40nm_arch", 19, 88, 16),
+    # route_hetero: typed columns and tall blocks under the same wires
+    ("k6_frac_n10_mem32k_40nm_arch", 25, 64, 16),
 ])
-def test_directional_planes_relax_compiles_at_k6n10_canvas(one_chip, n, W,
-                                                           tile):
+def test_directional_planes_relax_compiles_at_k6n10_canvas(
+        one_chip, arch_fn, n, W, tile):
     """The directional relaxation (unidir graphs: group-min turns) at
-    the canvases of the cells ``route_k6n10_relaxed`` (11 x 11, W = 64)
-    and ``route_scale`` (19 x 19, W = 88) of length-4 single-driver
+    the canvases of the cells ``route_k6n10_relaxed`` (11 x 11, W = 64),
+    ``route_scale`` (19 x 19, W = 88) and ``route_hetero`` (25 x 25,
+    W = 64, on the heterogeneous device) of length-4 single-driver
     wires, 64 nets -- whole and cropped."""
     import warnings
 
-    from parallel_eda_tpu.arch.builtin import k6_n10_40nm_arch
+    from parallel_eda_tpu.arch import builtin
     from parallel_eda_tpu.route.planes import (build_planes, planes_relax,
                                                planes_relax_cropped)
     from parallel_eda_tpu.rr.graph import build_rr_graph
-    from parallel_eda_tpu.rr.grid import DeviceGrid
+    from parallel_eda_tpu.rr.grid import make_grid
 
-    arch = k6_n10_40nm_arch(chan_width=W)
+    arch = getattr(builtin, arch_fn)(chan_width=W)
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")     # the file asks for Wilton
-        pg = build_planes(build_rr_graph(
-            arch, DeviceGrid(n, n, arch.io_capacity)))
+        pg = build_planes(build_rr_graph(arch, make_grid(arch, n, n)))
     assert pg.shape_x[:2] == (W, n)
     assert pg.directional and pg.group_tracks == 8 and pg.max_span == 4
     fn = jax.jit(planes_relax, static_argnames=("nsweeps",))

@@ -125,6 +125,8 @@ def test_rr_build_span_and_gauges(tmp_path):
         reg.enabled = was
     (ev,) = [e for e in tr.events if e.get("name") == "rr.build"]
     assert ev["args"] == {"unidir": True, "W": 16, "max_span": 4,
-                          "nodes": rr.num_nodes, "edges": rr.num_edges}
+                          "nodes": rr.num_nodes, "edges": rr.num_edges,
+                          "block_types": 2, "hard_columns": 0,
+                          "tall_rows": 1}
     assert values["rr.exit_turns_min"] == 2
     assert values["rr.opin_starts_min"] == 2        # round(0.10 x 16)
