@@ -156,13 +156,22 @@ class RouterOpts:
     # 2.9x with the same parity)
     sweep_budget_div: int = 3
     # wirelength finishing pass (planes program, sink_group=0 only):
-    # at first convergence, rip up and re-route EVERYTHING once with
-    # the exact incremental sink schedule against the converged
-    # congestion picture, then run to legality again.  The fast
-    # doubling-schedule trees cost ~3% wirelength (measured mult8:
+    # at the first legal window, snapshot the state on the device and
+    # rip up and re-route the MULTI-SINK nets once with the exact
+    # incremental sink schedule against the converged congestion
+    # picture (a single-sink traceback is an exact path already).  The
+    # fast doubling-schedule trees cost ~3% wirelength (measured mult8:
     # dwl 3.10% -> 0.52% under the precise schedule); the reference's
     # serial baseline always builds exact trees (route_tree_timing.c),
-    # so parity needs the cleanup.  Costs ~1 extra window.
+    # so parity needs the cleanup.  What follows it: the nets the pass
+    # left fighting are re-legalised, precisely, window by window until
+    # legal or out of iterations -- never a phase-2 restart, which
+    # would route every net a second time (_phase2_restart_due) -- and
+    # a route that does not land restores the snapshot: the pass and
+    # its tail then bought nothing (RouteResult.total_relax_steps_
+    # discarded, route.endgame.finish_restored_total).  NOT a cheap
+    # window: one sink a wave makes it the dearest of a route, 12.97 s
+    # of route_hetero's 45.7 s on the chip (PERF.md, PR 32 and 33).
     finish_precise: bool = True
     # two-stage host/device software pipeline for the planes window
     # driver: while window k executes on device, the host consumes
@@ -305,6 +314,11 @@ class RouteResult:
     # area — the two cost very different device time; bench projections
     # need the split)
     total_relax_steps_cropped: int = 0
+    # of which: sweeps of the windows whose result was thrown away, the
+    # stats rows past the iteration of the pre-finish snapshot when the
+    # finishing pass could not re-legalise and the snapshot was restored
+    # (0 when the finished route is kept)
+    total_relax_steps_discarded: int = 0
     # traceback ledger (windowed planes program): pointer-chase steps
     # the walks ran, and the steps budgeted (max_len - 4 per executed
     # wave).  A share near 1 means paths are pressing on the budget.
@@ -533,6 +547,20 @@ def path_budget(span: int, cap: int) -> int:
 def _grow_paths(paths, L_new: int, N: int):
     return jnp.pad(paths, ((0, 0), (0, 0), (0, L_new - paths.shape[2])),
                    constant_values=N)
+
+
+def _phase2_restart_due(precise: bool, full_reroute_done: bool,
+                        finish_done: bool, n_over: int, widx: int) -> bool:
+    """When the planes window driver rips up and re-routes EVERY net
+    precisely (the phase-2 restart, once a route): a STALLED endgame --
+    overuse left under the precise schedule, from the fifth window on --
+    that has not seen the wirelength finishing pass.  The pass sets
+    ``precise`` too, but it has just rebuilt the multi-sink trees
+    precisely against the converged congestion: what is over after it
+    is its own transient, and the nets ``_mis_colors`` marks re-legalise
+    it; a restart there routes every net a second time."""
+    return (precise and not full_reroute_done and not finish_done
+            and n_over > 0 and widx >= 4)
 
 
 _COMPILE_CACHE_DIR = None      # what this process last set (no-op guard)
@@ -1608,6 +1636,13 @@ class Router:
         # a rung) / read (one a window: the last rung's summary)
         mis_calls = reg.counter("route.mis_colors.calls_total")
         mis_reads = reg.counter("route.mis_colors.read_total")
+        # the endgame's full rebuilds: finishing passes started, phase-2
+        # restarts fired, routes that fell back to the pre-finish
+        # snapshot (their windows past it are total_relax_steps_discarded)
+        finish_passes = reg.counter("route.endgame.finish_passes_total")
+        full_restarts = reg.counter("route.endgame.full_restarts_total")
+        finish_restored = reg.counter(
+            "route.endgame.finish_restored_total")
         # the plane dtype named by opts.plane_dtype is the dtype every
         # window of this route commits
         pd = str(opts.plane_dtype)
@@ -2310,14 +2345,18 @@ class Router:
                     # precise reroute of the MULTI-SINK nets (a
                     # single-sink traceback is already an exact path —
                     # only doubling trees carry waste), then back to
-                    # legality.  The phase-2 restart already rebuilt
-                    # every tree precisely, so it subsumes this.
+                    # legality by the nets that fight alone.  The two
+                    # full rebuilds exclude each other: a phase-2
+                    # restart already rebuilt every tree precisely, so
+                    # it subsumes this, and no restart follows this
+                    # (_phase2_restart_due).
                     # Best-effort by construction: the converged state
                     # is snapshotted ON DEVICE (cheap copies; skipped
                     # with the finish at >1 GB path stores) and restored
                     # if re-legalization does not land within budget — a
                     # legal route must never become a reported failure.
                     finish_done = True
+                    finish_passes.inc()
                     precise = True
                     force_all_next = True
                     rrm = finish_set
@@ -2377,11 +2416,12 @@ class Router:
             # fighters can't fit around — rip up and re-route EVERYTHING
             # precisely against the accumulated history costs (the
             # reference's congested-mode rebuild, …cxx:6238-6267)
-            if (precise and not full_reroute_done and n_over > 0
-                    and widx >= 4):
+            if _phase2_restart_due(precise, full_reroute_done,
+                                   finish_done, n_over, widx):
                 dirty = np.arange(R)
                 force_all_next = True
                 full_reroute_done = True
+                full_restarts.inc()
             if timing_cb is not None and analyzer is None:
                 # host timing callback forces K=1 per-iteration sync
                 # by design (documented in RouteOpts)
@@ -2472,6 +2512,10 @@ class Router:
             occ, paths, sink_delay, all_reached, bb, fin_it = fin_save
             result.success = True
             result.iterations = fin_it
+            finish_restored.inc()
+            result.total_relax_steps_discarded = sum(
+                s.relax_steps for s in result.stats
+                if s.iteration > fin_it)
         result.wirelength = int(wirelength_on_device(dev, paths))
         result.paths = np.asarray(paths)
         result.sink_delay = np.asarray(sink_delay)
