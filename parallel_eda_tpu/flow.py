@@ -295,20 +295,30 @@ def run_route(flow: FlowResult, opts: Optional[RouterOpts] = None,
 
     ``mesh``: optional (net, node) jax.sharding.Mesh — runs the same
     negotiation loop sharded over the devices (parallel.shard)."""
-    if timing_driven:
-        if flow.tg is None:
-            flow.tg = build_timing_graph(flow.nl, flow.pnl, flow.term)
-        if flow.analyzer is None:
-            flow.analyzer = TimingAnalyzer(flow.tg, sdc=flow.sdc)
-    router = Router(flow.rr, opts, mesh=mesh)
+    # the call's wall by named stage: what comes before the route (the
+    # timing graph and analyzer where missing, the router's tables),
+    # the route, and after it the STA and the oracle; the last two
+    # carry the id of the route's own spans (RouteResult.route_id)
+    with stage("flow.route.setup", flow.times, key="route.setup"):
+        if timing_driven:
+            if flow.tg is None:
+                flow.tg = build_timing_graph(flow.nl, flow.pnl, flow.term)
+            if flow.analyzer is None:
+                flow.analyzer = TimingAnalyzer(flow.tg, sdc=flow.sdc)
+        router = Router(flow.rr, opts, mesh=mesh)
     # timing-driven: the planes program fuses the per-iteration STA on
     # device (analyzer mode, K>1 windows); ELL falls back to the host cb
     with stage("route", flow.times, timing_driven=timing_driven):
         flow.route = router.route(
             flow.term, analyzer=flow.analyzer if timing_driven else None)
+    rid = flow.route.route_id
     if timing_driven:
-        flow.analyzer.analyze(flow.route.sink_delay)
+        with stage("flow.route.sta", flow.times, key="route.sta",
+                   route=rid):
+            flow.analyzer.analyze(flow.route.sink_delay)
     if verify and flow.route.success:
-        check_route(flow.rr, flow.term, flow.route.paths,
-                    occ=flow.route.occ)
+        with stage("flow.route.verify", flow.times, key="route.verify",
+                   route=rid):
+            check_route(flow.rr, flow.term, flow.route.paths,
+                        occ=flow.route.occ)
     return flow

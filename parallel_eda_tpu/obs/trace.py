@@ -385,10 +385,12 @@ class _StageCtx:
         return r
 
 
-def stage(name: str, times: Optional[dict] = None, **args) -> _StageCtx:
+def stage(name: str, times: Optional[dict] = None,
+          key: Optional[str] = None, **args) -> _StageCtx:
     """Flow-stage span ("pack", "place", "route", ...) that keeps the
-    legacy times dict populated with the same clock."""
-    return _StageCtx(name, times, span(name, cat="stage", **args))
+    legacy times dict populated with the same clock, under ``key``
+    where the dict's name for the stage is not the span's."""
+    return _StageCtx(key or name, times, span(name, cat="stage", **args))
 
 
 # ---- JAX compile-phase capture (/jax/core/compile/* monitoring) ----

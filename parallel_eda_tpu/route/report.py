@@ -69,6 +69,44 @@ def route_report(rr: RRGraph, occ: np.ndarray,
     return "\n".join(lines)
 
 
+# RouteResult.wall's intervals, in the order they happen
+WALL_KEYS = ("prologue_s", "windows_s", "control_s", "epilogue_s")
+
+
+def format_window_table(result) -> str:
+    """The route by window, one line a ``RouteResult.stats`` row: what
+    each window IS (``kind``), what it did and what it cost, and
+    whether its result is in the route returned (``kept``).  Under it
+    the route's wall by named interval where the result carries one
+    (``RouteResult.wall``: the four add up to the ``route`` stage)."""
+    head = ("window", "iter", "kind", "overused", "nets", "seconds",
+            "stall_s", "control_s", "sweeps", "waves", "batches", "kept")
+    rows = [(s.window, s.iteration, s.kind or "-", s.overused_nodes,
+             s.rerouted_nets, f"{s.route_time_s:.3f}", f"{s.stall_s:.3f}",
+             f"{s.control_s:.4f}", s.relax_steps, s.waves, s.batches,
+             "yes" if s.kept else "NO") for s in result.stats]
+    rows.append(("sum", result.iterations, "", "", "",
+                 f"{sum(s.route_time_s for s in result.stats):.3f}",
+                 f"{sum(s.stall_s for s in result.stats):.3f}",
+                 f"{sum(s.control_s for s in result.stats):.4f}",
+                 sum(s.relax_steps for s in result.stats),
+                 sum(s.waves for s in result.stats),
+                 sum(s.batches for s in result.stats),
+                 f"{sum(1 for s in result.stats if s.kept)}"
+                 f"/{len(result.stats)}"))
+    cells = [head] + [tuple(str(c) for c in r) for r in rows]
+    width = [max(len(r[i]) for r in cells) for i in range(len(head))]
+    lines = ["  ".join(c.ljust(w) if i == 2 else c.rjust(w)
+                       for i, (c, w) in enumerate(zip(r, width))).rstrip()
+             for r in cells]
+    wall = getattr(result, "wall", None)
+    if wall:
+        lines.append("wall: " + " + ".join(
+            f"{k} {wall[k]:.4f}" for k in WALL_KEYS if k in wall)
+            + f" = {sum(wall.values()):.4f} s")
+    return "\n".join(lines)
+
+
 def write_route_report(path: str, rr: RRGraph, occ: np.ndarray,
                        num_nets: int) -> None:
     with open(path, "w") as f:

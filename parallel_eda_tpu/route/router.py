@@ -31,7 +31,7 @@ import itertools
 import math
 import os
 import time
-from dataclasses import dataclass, field
+from dataclasses import asdict, dataclass, field
 from typing import Callable, List, Optional
 
 import jax
@@ -257,11 +257,25 @@ class RouteStats:
     overused_nodes: int
     overuse_total: int
     rerouted_nets: int
-    route_time_s: float
+    route_time_s: float          # perf_counter: the route.window span
     relax_steps: int = 0         # Bellman-Ford sweeps (heap-pops analogue)
     batches: int = 0             # device dispatches this iteration
     overuse_pct: float = 0.0     # overused nodes / all rr nodes
     crit_path_delay: float = float("nan")
+    # the window ledger (planes window driver; the ELL rows keep the
+    # defaults, kind "" = not a window of a kind)
+    window: int = 0              # the window's index, 1..n over a route
+    kind: str = ""               # one of WINDOW_KINDS
+    precise: bool = False        # the schedule the window ran under
+    sweep_boost: int = 1
+    waves: int = 0               # relaxations run to a fixpoint
+    relax_steps_cropped: int = 0  # of relax_steps, on cropped rungs
+    net_routes: int = 0          # nets routed, once an iteration each
+    stall_s: float = 0.0         # host blocked on the device
+    plan_s: float = 0.0          # host planning, staging, dispatching
+    dispatch_ms: float = 0.0     # of it, in route.pipeline.dispatch
+    control_s: float = 0.0       # the host's control step AFTER it
+    kept: bool = True            # False: computed and thrown away
 
 
 @dataclass
@@ -327,6 +341,14 @@ class RouteResult:
     # executed waves of the windowed planes program (one relaxation to
     # a fixpoint each): total_relax_steps over it is sweeps a wave
     total_waves: int = 0
+    # the route's wall by named interval (planes window driver), in
+    # perf_counter seconds: prologue_s (route_gen's entry to the first
+    # window), windows_s (the sum of the rows' route_time_s), control_s
+    # (of their control_s), epilogue_s (the last control step's end to
+    # the return).  They add up to the `route` stage.  route_id is the
+    # `route` arg of the route's spans (0: the ELL program).
+    wall: dict = field(default_factory=dict)
+    route_id: int = 0
     # nets whose bb was widened to the full device (left the windowed
     # program; 0 on a healthy windowed run of a routable circuit)
     widened_nets: int = 0
@@ -400,6 +422,9 @@ def write_stats_files(stats_dir: str, result: "RouteResult") -> None:
                 cpd = s.crit_path_delay
                 break
         f.write(f"final_crit_path_delay {cpd:.6e}\n")
+    from .report import format_window_table
+    with open(os.path.join(stats_dir, "window_table.txt"), "w") as f:
+        f.write(format_window_table(result) + "\n")
 
 
 def _median_cut_bins(pts_x: np.ndarray, pts_y: np.ndarray,
@@ -563,6 +588,31 @@ def _phase2_restart_due(precise: bool, full_reroute_done: bool,
             and n_over > 0 and widx >= 4)
 
 
+# what a window of the planes driver IS, which decides what it costs:
+# window 1 (every net, no history), a negotiation window (the nets
+# _mis_colors marked), the phase-2 restart (every net, precisely), the
+# wirelength finishing pass (the multi-sink nets, one sink a wave), a
+# window after the pass (the nets that fight the finished trees)
+WINDOW_KINDS = ("first", "negotiate", "restart", "finish", "relegalise")
+
+
+def _window_kind(widx: int, force_all: bool, full_reroute_done: bool,
+                 finish_done: bool) -> str:
+    """The kind of window ``widx`` (1-based) from the flags the control
+    step that plans it holds -- the same flags a RouteCheckpoint's
+    ``driver`` carries, so a resumed route names its first window by
+    this rule too (never ``first``: it resumes at widx >= 2).
+    ``force_all`` (force_all_next) is set by the two full rebuilds
+    only, each with its own done-flag, and they exclude each other."""
+    if widx == 1:
+        return "first"
+    if force_all and finish_done:
+        return "finish"
+    if force_all and full_reroute_done:
+        return "restart"
+    return "relegalise" if finish_done else "negotiate"
+
+
 _COMPILE_CACHE_DIR = None      # what this process last set (no-op guard)
 
 # <checkout>/.jax_cache (git-ignored): the fixed default — the path is
@@ -634,9 +684,7 @@ def _note_dispatch_variant(key) -> bool:
 # loop routes many times in one process: a job that never reaches a
 # gauge must not inherit the previous job's value)
 _PIPELINE_GAUGES = (
-    "route.pipeline.host_plan_ms",
     "route.pipeline.dispatch_ms",
-    "route.pipeline.device_exec_ms",
     "route.pipeline.stall_ms",
     "route.pipeline.overlap_frac",
     "route.pipeline.host_overlap_frac",
@@ -1124,7 +1172,8 @@ class Router:
                     relax_useful: Optional[int] = None,
                     bucket_occ=(), compaction: float = 1.0,
                     kernel_plans=(), tw1: Optional[float] = None,
-                    win_ev: Optional[dict] = None) -> None:
+                    win_ev: Optional[dict] = None,
+                    row: Optional[RouteStats] = None) -> None:
         """Trace + metrics for one committed window: the window's
         ledger on its route.window span, a route.iter child span where
         the window IS one iteration, and the per-iteration registry
@@ -1135,8 +1184,11 @@ class Router:
 
         ``win_ev`` is the tracer's event of the window's live
         route.window span (the planes driver opens one; None without a
-        tracer): the ledger is added to its args.  The ELL driver has
-        no live span and gets the event recorded here.
+        tracer): the ledger is added to its args, and ``row``, the
+        window's stats row, field for field (what was known at the
+        span's open is there already; control_s and kept follow at the
+        route's end).  The ELL driver has no live span and gets the
+        event recorded here.
 
         ``relax_useful`` / ``bucket_occ`` / ``compaction`` feed the
         work-efficiency ledger: sweeps that improved a distance vs.
@@ -1161,6 +1213,11 @@ class Router:
             relax_steps=relax_steps, relax_steps_useful=int(useful),
             relax_steps_wasted=int(relax_steps - useful))
         if win_ev is not None:
+            if row is not None:
+                # every field that holds a value (a NaN critical path
+                # is no JSON)
+                ledger.update({k: v for k, v in asdict(row).items()
+                               if v == v})
             win_ev["args"].update(ledger)
         elif tr is not None:
             tr.add_complete("route.window", tw0, tw1 - tw0, cat="route",
@@ -1216,7 +1273,7 @@ class Router:
                              SCAL_WALK_STEPS, SCAL_WAVES)
 
         w_steps = w_useful = w_steps_crop = 0
-        nroutes = nexec = 0
+        nroutes = nexec = w_waves = 0
         mesh_info = bk.get("mesh")
         halo_b = halo_ex = 0
         for ri, (scal_d, cropped) in enumerate(bk["rung_scals"]):
@@ -1227,7 +1284,7 @@ class Router:
             w_useful += int(v[SCAL_S_USEFUL])
             result.total_walk_steps += int(v[SCAL_WALK_STEPS])
             result.total_walk_budget += int(v[SCAL_WALK_BUDGET])
-            result.total_waves += int(v[SCAL_WAVES])
+            w_waves += int(v[SCAL_WAVES])
             if cropped:
                 w_steps_crop += int(v[SCAL_S_EXEC])
             if mesh_info is not None and mesh_info[0] > 1 \
@@ -1244,12 +1301,22 @@ class Router:
         result.total_relax_steps_useful += w_useful
         result.total_relax_steps_wasted += w_steps - w_useful
         result.total_relax_steps_cropped += w_steps_crop
-        result.stats.append(RouteStats(
+        result.total_waves += w_waves
+        row = RouteStats(
             bk["it_done"], bk["n_over"], bk["over_total"], bk["ndirty"],
-            bk["t_wall1"] - bk["t_wall0"], relax_steps=w_steps,
-            batches=nexec,
+            bk["tw1"] - bk["tw0"], relax_steps=w_steps, batches=nexec,
             overuse_pct=100.0 * bk["n_over"] / max(1, self.rr.num_nodes),
-            crit_path_delay=bk["cpd"]))
+            crit_path_delay=bk["cpd"], window=bk["widx"], kind=bk["kind"],
+            precise=bk["precise"], sweep_boost=bk["sweep_boost"],
+            waves=w_waves, relax_steps_cropped=w_steps_crop,
+            net_routes=nroutes, stall_s=bk["stall_s"],
+            plan_s=bk["plan_s"], dispatch_ms=bk["dispatch_ms"])
+        result.stats.append(row)
+        reg = get_metrics()
+        reg.counter(f"route.window.seconds_total.{row.kind}").inc(
+            row.route_time_s)
+        reg.counter(f"route.window.sweeps_total.{row.kind}").inc(w_steps)
+        reg.counter(f"route.window.count_total.{row.kind}").inc()
         self._obs_window(bk["tw0"], bk["it_done"], bk["K"], bk["n_over"],
                          bk["over_total"], bk["ndirty"], w_steps,
                          bk["pres"], bk["cpd"], nexec,
@@ -1257,9 +1324,8 @@ class Router:
                          bucket_occ=bk["bucket_occ"],
                          compaction=bk["compaction"],
                          kernel_plans=bk["kplans"], tw1=bk["tw1"],
-                         win_ev=bk["win_ev"])
+                         win_ev=bk["win_ev"], row=row)
         if mesh_info is not None:
-            reg = get_metrics()
             reg.counter("route.mesh.halo_bytes").inc(halo_b)
             reg.counter("route.mesh.halo_exchanges").inc(halo_ex)
             # overlap_frac per window: the dominant rung's modeled
@@ -1456,7 +1522,8 @@ class Router:
                               paths, sink_delay, all_reached, bb, full_bb,
                               source_d, sinks_d, planes_tbl, nsinks_np,
                               cx_np, cy_np, result, B, mlog,
-                              crop="auto", resume=None):
+                              crop="auto", resume=None, rid=0,
+                              t_enter=None):
         """Window-fused PathFinder driver for the planes program: the
         negotiation runs as a sequence of multi-iteration device programs
         (planes.route_window_planes) with ONE host sync per window — the
@@ -1485,7 +1552,17 @@ class Router:
         Every dispatch is still planned from a fully consumed summary —
         lag-0 — so results are bit-identical to pipeline=False, which
         drains each rung before any further host work (the --sync
-        escape hatch)."""
+        escape hatch).
+
+        ``rid`` is the route's id (the ``route`` arg of all its spans)
+        and ``t_enter`` the perf_counter second route_gen was entered:
+        the route's prologue runs from there to the first window's
+        open, its epilogue from the last control step's end to the
+        return.  Both are in ``RouteResult.wall`` and, measured
+        intervals, on the installed Tracer as route.prologue /
+        route.epilogue; they are NOT profiler annotations yet
+        (tests/benchmark/test_scope_reduce.py holds the names a device
+        gap may fall in to a closed list, PERF.md section 7)."""
         from .planes import (PLANE_DTYPES, route_window_planes,
                              route_window_planes_fused,
                              unpack_window_status)
@@ -1623,8 +1700,15 @@ class Router:
         book = None           # deferred bookkeeping of the last window
         reg = get_metrics()
         tr = get_tracer()
-        rid = next(_ROUTE_IDS)      # shared by every span of this route
         ctl = None      # the open route.pipeline.control span, if any
+        # the window ledger: the kind of the window about to be planned
+        # (set by the control step before it; a resumed route's first
+        # comes from the checkpoint's flags), and per window the
+        # tracer's event and the seconds of the control step after it
+        kind = _window_kind(widx + 1, force_all_next, full_reroute_done,
+                            finish_done)
+        win_evs, ctl_s = [], []
+        prologue_s = 0.0
         disp_total = reg.gauge("route.pipeline.dispatch_ms_total")
         # nets x windows handed to a cropped rung / to the full canvas
         crop_nets = reg.counter("route.crop.net_dispatches_cropped_total")
@@ -1953,17 +2037,28 @@ class Router:
                             wp_kwargs)
                     return route_window_planes(*wp_args, **wp_kwargs)
 
-            t0 = time.time()
             # the window's live span, closed after its stall; opened
             # and closed by hand because a `yield` (the fused dispatch)
-            # and most of this loop's body lie between the two
+            # and most of this loop's body lie between the two.  What
+            # the row knows at open goes on the span at open: a
+            # TraceAnnotation's args are fixed there, and they are what
+            # matches a device trace of a slow window to its kind
             if ctl is not None:
                 ctl.__exit__(None, None, None)
             win = span("route.window", cat="route", window=widx,
                        route=rid, first_iter=it_done + 1,
-                       last_iter=it_done + K, K=K)
+                       last_iter=it_done + K, K=K, kind=kind,
+                       nets=len(dirty), precise=precise,
+                       sweep_boost=sweep_boost)
             win.__enter__()
             tw0 = time.perf_counter()
+            if ctl is not None:
+                ctl_s.append(tw0 - t_prev_end)
+            else:
+                prologue_s = tw0 - t_enter
+                if tr is not None:
+                    tr.mark("route.prologue", t_enter, tw0, cat="route",
+                            route=rid)
             disp0_ms = disp_total.value or 0.0
             # dispatch order: cropped size classes ascending (the first
             # carries the acc escalation), full-canvas remainder last.
@@ -2247,11 +2342,9 @@ class Router:
             pl_exec += exec_s
             pl_stall += stall_s
             pl_serial += serial_s
+            disp_ms = (disp_total.value or 0.0) - disp0_ms
             reg.set_gauges({
-                "route.pipeline.host_plan_ms": round(tot_host_w * 1e3, 3),
-                "route.pipeline.dispatch_ms": round(
-                    (disp_total.value or 0.0) - disp0_ms, 3),
-                "route.pipeline.device_exec_ms": round(exec_s * 1e3, 3),
+                "route.pipeline.dispatch_ms": round(disp_ms, 3),
                 "route.pipeline.stall_ms": round(stall_s * 1e3, 3),
                 "route.pipeline.overlap_frac": round(
                     pl_exec / max(pl_exec + pl_serial, 1e-9), 4),
@@ -2294,9 +2387,9 @@ class Router:
             book = dict(
                 widx=widx, it_done=it_done, K=K, n_over=n_over,
                 over_total=over_total, ndirty=len(dirty), pres=pres,
-                cpd=cpd, t_wall0=t0, t_wall1=time.time(), tw0=tw0,
-                tw1=t_st1, win_ev=win.event,
-                rung_scals=rung_scals,
+                cpd=cpd, tw0=tw0, tw1=t_st1, win_ev=win.event,
+                kind=kind, stall_s=stall_s, plan_s=plan_s,
+                dispatch_ms=disp_ms, rung_scals=rung_scals,
                 bucket_occ=bucket_occ,
                 compaction=comp_num / max(1, comp_den), kplans=kplans,
                 colors_max=int(np.max(colors) + 1
@@ -2320,6 +2413,7 @@ class Router:
                       else (1, "single_chip")
                       if (rm_now is None or self._mesh_lost)
                       else (rm_now.n_shards, rm_now.impl)))
+            win_evs.append(win.event)
             if analyzer is not None and cpd == cpd:
                 analyzer.crit_path_delay = cpd
             if not pipelined:
@@ -2422,6 +2516,10 @@ class Router:
                 force_all_next = True
                 full_reroute_done = True
                 full_restarts.inc()
+            kind = _window_kind(widx + 1, force_all_next,
+                                full_reroute_done, finish_done)
+            if it_done < opts.max_router_iterations:
+                ctl.set(next_kind=kind)
             if timing_cb is not None and analyzer is None:
                 # host timing callback forces K=1 per-iteration sync
                 # by design (documented in RouteOpts)
@@ -2487,8 +2585,14 @@ class Router:
                     break
         else:
             result.iterations = opts.max_router_iterations
+        # the route's epilogue: from the last control step's end (the
+        # prologue's, if no window ran) to the return
+        t_loop_end = time.perf_counter()
         if ctl is not None:
             ctl.__exit__(None, None, None)
+            ctl_s.append(t_loop_end - t_prev_end)
+        else:               # no window ran: the prologue is all there is
+            prologue_s = t_loop_end - t_enter
 
         if book is not None:
             # drain the in-flight bookkeeping (loop exited via break or
@@ -2503,6 +2607,7 @@ class Router:
             reg.gauge("route.pipeline.host_plan_ms_total").set(round(
                 pl_tot_host * 1e3, 3))
 
+        fin_it = None
         if not result.success and fin_save is not None \
                 and not sliced_yield:
             # the finishing pass could not re-legalize within budget:
@@ -2513,9 +2618,24 @@ class Router:
             result.success = True
             result.iterations = fin_it
             finish_restored.inc()
-            result.total_relax_steps_discarded = sum(
-                s.relax_steps for s in result.stats
-                if s.iteration > fin_it)
+        # close the ledger: the control step after each window, and
+        # whether its result is in the route returned (the rows past a
+        # restored snapshot are not: ONE rule for sweeps and seconds)
+        for row, ev, c in zip(result.stats, win_evs, ctl_s):
+            row.control_s = c
+            row.kept = fin_it is None or row.iteration <= fin_it
+            if ev is not None:
+                ev["args"].update(control_s=c, kept=row.kept)
+        gone = [row for row in result.stats if not row.kept]
+        result.total_relax_steps_discarded = sum(
+            row.relax_steps for row in gone)
+        reg.counter("route.window.discarded_seconds_total").inc(
+            sum(row.route_time_s for row in gone))
+        result.route_id = rid
+        result.wall = dict(
+            prologue_s=prologue_s,
+            windows_s=sum(row.route_time_s for row in result.stats),
+            control_s=sum(ctl_s))
         result.wirelength = int(wirelength_on_device(dev, paths))
         result.paths = np.asarray(paths)
         result.sink_delay = np.asarray(sink_delay)
@@ -2535,6 +2655,11 @@ class Router:
                 # to metrics.json / the mdclog files
                 dp.capture_all()
                 dp.dump(os.path.join(opts.stats_dir, "devprof.json"))
+        t_end = time.perf_counter()
+        result.wall["epilogue_s"] = t_end - t_loop_end
+        if tr is not None:
+            tr.mark("route.epilogue", t_loop_end, t_end, cat="route",
+                    route=rid)
         return result
 
     def _planes_terminals(self, term):
@@ -2580,6 +2705,10 @@ class Router:
             raise ValueError(
                 "route_gen is supported by the planes program")
         opts = self.opts
+        # the route's wall opens here: its prologue is this set-up and
+        # the window loop's, up to the first window
+        rid = next(_ROUTE_IDS)      # shared by every span of this route
+        t_enter = time.perf_counter()
         # multi-route safety (the serve loop calls route() many times
         # on one process): zero the per-route pipeline gauges so a job
         # that never reaches a given gauge doesn't inherit the previous
@@ -2664,7 +2793,8 @@ class Router:
                 term, crit, timing_cb, analyzer, occ, acc, paths,
                 sink_delay, all_reached, bb, full_bb, source_d,
                 sinks_d, planes_tbl, nsinks_np, cx_np, cy_np,
-                result, B, mlog, crop=crop, resume=resume)
+                result, B, mlog, crop=crop, resume=resume, rid=rid,
+                t_enter=t_enter)
         return result
 
     def route(self, term: NetTerminals,
@@ -2824,7 +2954,6 @@ class Router:
         prev_steps = 0
 
         for it in range(1, opts.max_router_iterations + 1):
-            t0 = time.time()
             tw0 = time.perf_counter()
             if it <= opts.incremental_after:
                 idx = np.arange(R)
@@ -2972,7 +3101,8 @@ class Router:
             # (useful + wasted == total) holds across both programs
             result.total_relax_steps_useful += it_steps
             result.stats.append(RouteStats(
-                it, n_over, over_total, len(idx), time.time() - t0,
+                it, n_over, over_total, len(idx),
+                time.perf_counter() - tw0,
                 relax_steps=it_steps, batches=len(batches),
                 overuse_pct=100.0 * n_over / max(1, N)))
             self._obs_window(tw0, it, 1, n_over, over_total, len(idx),

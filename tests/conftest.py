@@ -29,3 +29,35 @@ import jax  # noqa: E402  (import does not initialize backends)
 jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import gc  # noqa: E402
+
+import pytest  # noqa: E402
+
+# XLA:CPU maps memory for every executable it compiles, and a process
+# may hold vm.max_map_count mappings (65,530 here).  An xdist worker
+# that compiles its way through enough test files crosses that, and the
+# next compile dies inside LLVM (SIGSEGV or SIGABRT in
+# backend_compile_and_load; the hung run that follows is cut at its
+# time limit).  Seen three runs in three once the suite grew to ~790
+# tests: the workers of a healthy run already stand at 55-60 thousand.
+# So between modules, past a third of the limit, the compiled programs
+# are let go (jax.clear_caches() unmaps them); the next module compiles
+# what it needs, as it would in a worker of its own.
+_MAPS_RELEASE_AT = 20000
+
+
+def _n_maps() -> int:
+    try:
+        with open("/proc/self/maps") as fh:
+            return sum(1 for _ in fh)
+    except OSError:
+        return 0
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    yield
+    if _n_maps() > _MAPS_RELEASE_AT:
+        jax.clear_caches()
+        gc.collect()
