@@ -1835,6 +1835,49 @@ def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
     return rrm, color
 
 
+def repack_plan(sel_plan, seg_plan, live):
+    """Re-pack a window plan's LIVE slots densely, in the plan's own
+    order, inside each conflict-colour segment (a pure function: the
+    window program calls it once an iteration of a rebuild's tail, on
+    the device).
+
+    ``sel_plan`` [G, B] net ids, ``seg_plan`` [G, B] ints: 0 on a pad
+    slot, else the slot's segment (the host's _plan_groups writes
+    1 + the index of the slot's colour class; a group holds one
+    segment and a segment is a run of consecutive groups), ``live``
+    [G, B] the slots that route this iteration.  The k-th live slot of a
+    segment, counted row by row, lands in slot (first group of the
+    segment) * B + k: the segment's leading groups fill up, the groups
+    past its last live slot come back empty, and no net crosses into
+    another segment's groups, so nets _mis_colors separated still
+    commit in separate batches.  With every valid slot of a
+    host-built plan live the plan comes back as it went in.
+
+    Dense compares and gathers over [G, B, G] and [G, B, B]; no sort,
+    no scatter.  Returns (sel [G, B] with 0 on an empty slot,
+    valid [G, B] bool)."""
+    G, B = sel_plan.shape
+    gi = jnp.arange(G, dtype=jnp.int32)
+    seg_g = seg_plan.max(axis=1)
+    starts = jnp.append(True, seg_g[1:] != seg_g[:-1])
+    g0 = lax.cummax(jnp.where(starts, gi, 0))      # my segment's first group
+    rowcum = jnp.cumsum(live, axis=1, dtype=jnp.int32)       # inclusive
+    cin = jnp.cumsum(rowcum[:, -1])        # live slots up to group g's end
+    cex = cin - rowcum[:, -1]
+    # slot (g, b) takes the live slot of this rank in plan order
+    want = ((cex[g0] + (gi - g0) * B)[:, None]
+            + jnp.arange(B, dtype=jnp.int32)[None, :])
+    src_g = (cin[None, None, :] <= want[:, :, None]).sum(
+        axis=2, dtype=jnp.int32)
+    valid = (src_g < G) & (g0[jnp.minimum(src_g, G - 1)] == g0[:, None])
+    src_g = jnp.minimum(src_g, G - 1)
+    rank = want - cex[src_g]               # among the source group's live
+    src_b = jnp.minimum(
+        (rowcum[src_g] <= rank[:, :, None]).sum(axis=2, dtype=jnp.int32),
+        B - 1)
+    return jnp.where(valid, sel_plan[src_g, src_b], 0), valid
+
+
 # the window program's static argnames — shared between the jit
 # decoration below and serve/library.py's AOT export split: a
 # jax.export'ed program BAKES its static values in, so the exported
@@ -1866,7 +1909,11 @@ def _window_body(
     """A WINDOW of K_iters complete PathFinder iterations as ONE device
     program: per iteration, every batch group in sel_plan [G, B] runs the
     fused rip-up/route/commit step (clean nets no-op via the device-side
-    reroute predicate), then the PathFinder present/history update
+    reroute predicate; in the TAIL of a full rebuild the plan's nets
+    that need a re-route are first re-packed into dense groups inside
+    their conflict-colour segments, repack_plan: valid_plan [G, B] holds
+    0 on a pad slot, else the slot's segment, and a bool plan is one
+    segment), then the PathFinder present/history update
     (congestion.h:177-193).  One host round trip per window instead of
     per batch — a host round trip costs a sync, and one per batch
     dominated every earlier design; the host fetches only this
@@ -1901,6 +1948,9 @@ def _window_body(
     (unpack_window_status / SCAL_* below)."""
     G = sel_plan.shape[0]
     R, Smax = sinks_all.shape
+    # valid_plan carries each slot's conflict-colour segment (0 = pad);
+    # a bool plan is one segment
+    seg_plan = valid_plan.astype(jnp.int32)
 
     def it_body(it, st):
         (occ, acc, paths, sink_delay, all_reached, bb, pres, nroutes,
@@ -1908,12 +1958,31 @@ def _window_body(
         with device_scope("route.dev.ripup"):
             force = (it0 + it) < force_until
 
+            def repacked():
+                # _step_core's own predicate, taken once for every net
+                over_it = jnp.append(occ > dev.capacity, False)
+                dirty = over_it[paths].any(axis=(1, 2)) | ~all_reached
+                return repack_plan(sel_plan, seg_plan,
+                                   (seg_plan > 0) & dirty[sel_plan])
+
+            # the TAIL of a full rebuild (a restart, a finishing pass: a
+            # forced window past the first, after its forced iteration):
+            # the plan holds every net (the pass: every multi-sink net)
+            # and a dozen of a group's 64 still fight, so the groups
+            # are re-packed.  Elsewhere they are
+            # the host's: re-packed in the negotiation proper too,
+            # route_tight (W_min + 1) took ten more iterations to turn
+            # legal (PERF.md PR 36)
+            sel_it, valid_it = lax.cond(
+                (force_until > it0) & (it0 > 0) & ~force, repacked,
+                lambda: (sel_plan, seg_plan > 0))
+
         def g_step(g, st2):
             def run(st3):
                 (occ2, paths2, sink_delay2, all_reached2, bb2, nr, ng,
                  led2) = st3
                 with device_scope("route.dev.ripup"):
-                    sel_g, valid_g = sel_plan[g], valid_plan[g]
+                    sel_g, valid_g = sel_it[g], valid_it[g]
                 (paths2, sink_delay2, all_reached2, bb2, occ2,
                  n_act, led_g) = _step_core(
                     pg, dev, occ2, acc, pres,
@@ -1931,15 +2000,16 @@ def _window_body(
                     return (occ2, paths2, sink_delay2, all_reached2, bb2,
                             nr + n_act, ng + 1, led2 + led_g)
 
-            # skip pow2-padding groups and fully-clean groups outright
+            # skip pow2-padding groups, the groups a re-pack emptied and
+            # fully-clean groups outright
             # (the group plan is padded to a power of two to bound the
             # compiled-program count; without the cond every pad group
             # would still pay the full relax).  ng counts the groups that
             # actually executed, so relax-step stats reflect real work
             with device_scope("route.dev.ripup"):
                 over_g = jnp.append(st2[0] > dev.capacity, False)
-                sel_g = sel_plan[g]
-                any_dirty = (valid_plan[g]
+                sel_g = sel_it[g]
+                any_dirty = (valid_it[g]
                              & (over_g[st2[1][sel_g]].any(axis=(1, 2))
                                 | ~st2[3][sel_g] | force)).any()
             return lax.cond(any_dirty, run, lambda s: s, st2)

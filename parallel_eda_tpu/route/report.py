@@ -80,18 +80,27 @@ def format_window_table(result) -> str:
     the route's wall by named interval where the result carries one
     (``RouteResult.wall``: the four add up to the ``route`` stage)."""
     head = ("window", "iter", "kind", "overused", "nets", "seconds",
-            "stall_s", "control_s", "sweeps", "waves", "batches", "kept")
+            "stall_s", "control_s", "sweeps", "waves", "batches", "routes",
+            "routes/batch", "kept")
+
+    def fill(routes, batches):
+        # net routes a batch the window ran: of B slots, how many worked
+        return f"{routes / batches:.1f}" if batches else "-"
+
     rows = [(s.window, s.iteration, s.kind or "-", s.overused_nodes,
              s.rerouted_nets, f"{s.route_time_s:.3f}", f"{s.stall_s:.3f}",
              f"{s.control_s:.4f}", s.relax_steps, s.waves, s.batches,
+             s.net_routes, fill(s.net_routes, s.batches),
              "yes" if s.kept else "NO") for s in result.stats]
+    batches = sum(s.batches for s in result.stats)
+    routes = sum(s.net_routes for s in result.stats)
     rows.append(("sum", result.iterations, "", "", "",
                  f"{sum(s.route_time_s for s in result.stats):.3f}",
                  f"{sum(s.stall_s for s in result.stats):.3f}",
                  f"{sum(s.control_s for s in result.stats):.4f}",
                  sum(s.relax_steps for s in result.stats),
                  sum(s.waves for s in result.stats),
-                 sum(s.batches for s in result.stats),
+                 batches, routes, fill(routes, batches),
                  f"{sum(1 for s in result.stats if s.kept)}"
                  f"/{len(result.stats)}"))
     cells = [head] + [tuple(str(c) for c in r) for r in rows]

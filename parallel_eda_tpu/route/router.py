@@ -1447,17 +1447,24 @@ class Router:
         device-computed conflict color (each class commits separately,
         custom_vertex_coloring semantics), then by fanout class
         (similar-depth wave loops), spatially round-robined (split_nets
-        load-spreading role), chunked to B."""
-        batches = []
+        load-spreading role), chunked to B.
+
+        Returns (sel_plan, valid_plan): valid_plan is 0 on a pad slot
+        and 1 + the index of its colour class on a net's, the segment
+        inside which the window program re-packs the nets that still
+        need a re-route in a rebuild's tail (planes.repack_plan)."""
+        batches, seg = [], []
         if colors is None or len(dirty) <= 1:
             groups = [dirty]
         else:
             cd = colors[dirty]
             groups = [dirty[cd == c] for c in np.unique(cd)]
-        for g in groups:
-            batches.extend(_order_and_chunk(g, nsinks, cx, cy, B))
+        for s, g in enumerate(groups):
+            chunks = _order_and_chunk(g, nsinks, cx, cy, B)
+            batches.extend(chunks)
+            seg.extend([s + 1] * len(chunks))
         if not batches:
-            batches = [np.zeros(0, dtype=np.int64)]
+            batches, seg = [np.zeros(0, dtype=np.int64)], [1]
         # converged-net compaction: once most nets are clean the per-
         # color chunks are far shorter than B — narrow the PLAN WIDTH to
         # the largest chunk (pow2-bucketed, floor 8, so the compiled
@@ -1475,10 +1482,10 @@ class Router:
         # padding keeps the set of compiled window programs small
         G = _pow2_at_least(len(batches))
         sel_plan = np.zeros((G, B_g), dtype=np.int32)
-        valid_plan = np.zeros((G, B_g), dtype=bool)
+        valid_plan = np.zeros((G, B_g), dtype=np.int8)
         for i, b in enumerate(batches):
             sel_plan[i, :len(b)] = b
-            valid_plan[i, :len(b)] = True
+            valid_plan[i, :len(b)] = seg[i]
         return sel_plan, valid_plan
 
     def _plan_block_nets(self, tile, nnets: int, nsw: int,
@@ -1957,7 +1964,7 @@ class Router:
                             grp_w=grp_w, doubling=doubling, wok=wok,
                             sel_d=sel_d, valid_d=valid_d, kplan=kplan,
                             sel_shape=sel_p.shape,
-                            ledger=(int(valid_p.sum()),
+                            ledger=(int((valid_p > 0).sum()),
                                     valid_p.shape[1],
                                     int(valid_p.any(axis=1).sum())))
 

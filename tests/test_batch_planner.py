@@ -223,11 +223,14 @@ class TestPlanGroupsCompaction:
         cy = np.asarray(f.term.bb_ymin + f.term.bb_ymax) / 2.0
         sel, valid = r._plan_groups(dirty, None, nsinks, cx, cy,
                                     B=32, R=R)
+        # valid carries a net slot's conflict-colour segment (PR 36):
+        # with no colouring the plan is the one segment, 0 on a pad
+        assert set(np.unique(valid)) <= {0, 1}
         # every dirty net appears in exactly one VALID slot
-        assert sorted(sel[valid].tolist()) == dirty.tolist()
+        assert sorted(sel[valid > 0].tolist()) == dirty.tolist()
         # padding is inert: invalid slots carry the 0 sentinel and the
         # device masks them; no dirty net hides in an invalid slot
-        assert (sel[~valid] == 0).all()
+        assert (sel[valid == 0] == 0).all()
 
     def test_width_compacts_to_pow2_of_largest_chunk(self, router):
         r, f = router
@@ -256,4 +259,4 @@ class TestPlanGroupsCompaction:
         sel, valid = r._plan_groups(dirty, None, nsinks, cx, cy,
                                     B=B, R=R)
         assert sel.shape[1] <= B
-        assert sorted(sel[valid].tolist()) == dirty.tolist()
+        assert sorted(sel[valid > 0].tolist()) == dirty.tolist()

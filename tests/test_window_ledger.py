@@ -307,15 +307,21 @@ def test_the_window_table_prints_the_rows(routed, tmp_path):
     lines = text.splitlines()
     assert lines[0].split() == [
         "window", "iter", "kind", "overused", "nets", "seconds", "stall_s",
-        "control_s", "sweeps", "waves", "batches", "kept"]
+        "control_s", "sweeps", "waves", "batches", "routes", "routes/batch",
+        "kept"]
     assert len(lines) == len(r.stats) + 3
     for line, s in zip(lines[1:], r.stats):
         cells = line.split()
         assert cells[:5] == [str(s.window), str(s.iteration), s.kind,
                              str(s.overused_nodes), str(s.rerouted_nets)]
         assert cells[8:] == [str(s.relax_steps), str(s.waves),
-                             str(s.batches), "yes" if s.kept else "NO"]
+                             str(s.batches), str(s.net_routes),
+                             "%.1f" % (s.net_routes / s.batches),
+                             "yes" if s.kept else "NO"]
     assert lines[-2].split()[0] == "sum"
+    assert lines[-2].split()[-3:-1] == [
+        str(r.total_net_routes), "%.1f" % (
+            r.total_net_routes / sum(s.batches for s in r.stats))]
     assert lines[-1].startswith("wall: prologue_s ")
     assert ("NO" in text) == (name == "pass_restored")
     write_stats_files(str(tmp_path), r)
