@@ -65,6 +65,7 @@ from jax import lax
 from ..obs import get_metrics
 from ..obs.trace import device_scope
 from ..rr.graph import CHANX, CHANY, RRGraph, unidir_exit_point
+from ..rr.terminals import FANOUT_BASE, FANOUT_STEP
 from .device_graph import DeviceRRGraph
 from .search import JITTER_EPS, congestion_cost, usage_from_paths
 
@@ -1389,6 +1390,30 @@ def sink_pick(dist, pin_congj, crit_w, cw, sink_tabs):
     return sink_dist, ent_cell, ent_ipin, ent_wdel
 
 
+def wave_segments(num_waves: int, group: int, doubling: bool):
+    """The wave loop as runs of waves [(first, past the last, pick
+    width)], in order.  A wave picks, walks, assembles and grows
+    ``width`` sinks a net whatever it has to pick, and wave k of the
+    doubling schedule picks at most 2^k: the early waves of a class of
+    hundreds of sinks run at the fanout ladder's narrower widths
+    (FANOUT_BASE, x FANOUT_STEP, ...), each run its own loop, and only
+    the last at the class's.  A wave at a wider pick is the same wave
+    (the slots past its picks are not valid), so the runs are the one
+    loop's, bit for bit.  A class of at most FANOUT_BASE sinks, and
+    every schedule that is not the doubling one, is ONE run at
+    ``group``: the loop as it always was."""
+    runs, first, width = [], 0, FANOUT_BASE
+    while doubling and width < group and first < num_waves:
+        # waves 0 .. log2(width) pick at most ``width`` sinks
+        last = min(num_waves, width.bit_length())
+        if last > first:
+            runs.append((first, last, width))
+        first, width = max(first, last), width * FANOUT_STEP
+    if first < num_waves or not runs:
+        runs.append((first, num_waves, group))
+    return runs
+
+
 def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                paths, sink_delay, all_reached, bb,
                source_all, sinks_all, crit_all,
@@ -1400,7 +1425,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                nsweeps: int, max_len: int, num_waves: int, group: int,
                doubling: bool, mesh,
                crop_tile=None, bb0_all=None, widen_ok=None,
-               plane_dtype: str = "f32"):
+               plane_dtype: str = "f32", local=None):
     """One fused batch step (traceable body shared by the standalone
     per-batch wrapper and the window program): rip up the selected nets,
     re-route each against the occupancy view of everyone-but-itself with
@@ -1410,34 +1435,45 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
     `force` is true, so a static batch plan can cover all nets every
     iteration and the device skips the clean ones.
 
+    Everything dense in the sink axis (``paths``, ``sink_delay``,
+    ``sinks_all``, ``crit_all``, ``sink_uid_all``, the three
+    ``direct_*``) is the table of ONE fanout class, [R_c, S_c, ...]: a
+    batch holds nets of one class and S, the width every wave works at,
+    is that class's.  ``local`` [R] is each net's row in its class's
+    tables; None where the route has one class and a net's row is its
+    index.  What is per net and not per sink (``all_reached``, ``bb``,
+    the source-side tables, the jitter's net id) stays indexed by the
+    net.
+
     Returns (paths, sink_delay, all_reached, bb, occ, n_active, st):
     ``st`` [5] int32 is the step's ledger — relaxation sweeps executed,
     sweeps that improved a distance, traceback walk steps run, walk
     steps budgeted, waves executed (one relaxation each) — in the
     order of scal's SCAL_S_EXEC.. tail."""
     N = dev.num_nodes
-    R = paths.shape[0]
+    R = all_reached.shape[0]
     B = sel.shape[0]
     S = sinks_all.shape[1]
     ncells = pg.ncells
     Kw = max_len - 4            # walk budget: sink+ipin+opin+source slots
 
     with device_scope("route.dev.ripup"):
-        b_paths = paths[sel]
+        lsel = sel if local is None else local[sel]
+        b_paths = paths[lsel]
         b_src = source_all[sel]
-        b_sinks = sinks_all[sel]
+        b_sinks = sinks_all[lsel]
         b_bb = bb[sel]
-        b_crit = crit_all[sel]
+        b_crit = crit_all[lsel]
         b_opin = opin_node_all[sel]                  # [B, O]
         b_ecell = entry_cell_all[sel]                # [B, Ko]
         b_eoidx = entry_oidx_all[sel]
         b_edelay = entry_delay_all[sel]
-        b_uid = sink_uid_all[sel]                    # [B, S]
+        b_uid = sink_uid_all[lsel]                   # [B, S]
         sink_tabs = (uid_ucell[b_uid], uid_upin[b_uid],  # [B, S, C], P
                      uid_pcdel[b_uid], uid_pcrank[b_uid])   # [B, S, P, C]
-        b_doidx = direct_oidx_all[sel]               # [B, S] (-1 = none)
-        b_dipin = direct_ipin_all[sel]
-        b_ddel = direct_delay_all[sel]
+        b_doidx = direct_oidx_all[lsel]              # [B, S] (-1 = none)
+        b_dipin = direct_ipin_all[lsel]
+        b_ddel = direct_delay_all[lsel]
         gspmd = mesh is not None and _as_row_mesh(mesh) is None
         if gspmd:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1521,7 +1557,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             crop_oy = jnp.clip(bb_anchor[:, 2] - Lm, 0, NYg - cny_t
                                ).astype(jnp.int32)
 
-    def wave_run(wave, state):
+    def wave_run(wave, state, group):
         (seed_cells, tdel_cells, opin_used, remaining, wpaths, delay,
          reached_all, st) = state
         with device_scope("route.dev.cost_fields"):
@@ -1698,15 +1734,15 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
         return (seed_cells, tdel_cells, opin_used, remaining, wpaths,
                 delay, reached_all, st)
 
-    def wave_body(wave, state):
+    def wave_body(group, wave, state):
         # once every (valid) sink is reached the remaining waves are
         # identity passes — skip their relaxations entirely (exact: a
         # wave with no remaining sinks picks nothing and commits
         # nothing, verified against the unconditional body)
         with device_scope("route.dev.sink_pick"):
             pending = state[3].any()
-        return lax.cond(pending,
-                        lambda s: wave_run(wave, s), lambda s: s, state)
+        return lax.cond(pending, lambda s: wave_run(wave, s, group),
+                        lambda s: s, state)
 
     with device_scope("route.dev.cost_fields"):
         state0 = (seed0, jnp.zeros((B, ncells), jnp.float32),
@@ -1716,8 +1752,11 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                   jnp.full((B, S), INF, jnp.float32),
                   jnp.zeros((B, S), bool),
                   jnp.zeros((5,), jnp.int32))
-    (_, _, _, _, p, delay, reached, st) = lax.fori_loop(
-        0, num_waves, wave_body, state0)
+    state = state0
+    for lo, hi, width in wave_segments(num_waves, group, doubling):
+        state = lax.fori_loop(lo, hi, functools.partial(wave_body, width),
+                              state)
+    (_, _, _, _, p, delay, reached, st) = state
 
     with device_scope("route.dev.commit"):
         usage = usage_from_paths(p, nodes_p1) & valid[:, None]
@@ -1739,8 +1778,10 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                            full_bb[None, :])
 
         sel_v = jnp.where(valid, sel, R).astype(jnp.int32)
-        paths = paths.at[sel_v].set(p, mode="drop")
-        sink_delay = sink_delay.at[sel_v].set(delay, mode="drop")
+        lsel_v = sel_v if local is None else jnp.where(
+            valid, lsel, paths.shape[0]).astype(jnp.int32)
+        paths = paths.at[lsel_v].set(p, mode="drop")
+        sink_delay = sink_delay.at[lsel_v].set(delay, mode="drop")
         all_reached = all_reached.at[sel_v].set(ok, mode="drop")
         bb = bb.at[sel_v].set(new_bb, mode="drop")
         return (paths, sink_delay, all_reached, bb, occ_new,
@@ -1786,7 +1827,7 @@ def route_batch_resident_planes(
 
 @device_scope("route.dev.mis_colors")
 def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
-                topk: int, n_colors: int):
+                topk: int, n_colors: int, fan=None):
     """Device-side conflict scheduling: greedy parallel MIS coloring of
     the reroute set over the top-K MOST-OVERUSED nodes (the linear-work
     replacement for the host O(I^2) greedy coloring of round 2 — the
@@ -1807,22 +1848,43 @@ def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
     (tests/mis_colors_refs.py keeps the searchsorted form this
     replaced: fifteen gather rounds over the path store).
 
+    With fanout classes (``fan`` = (local, members), ``paths`` a store a
+    class) each store is read once, at its own width, and the rows of U
+    stand class after class: a claim is a min of net ids down a column
+    and a conflict an any across columns, so the order of the rows
+    moves no colour either, and the net ids ride in ``prio``.  The
+    conflict picture is ONE picture of all classes.
+
     Returns (rrm [R], colors [R])."""
     N = dev.num_nodes
-    R = paths.shape[0]
+    R = all_reached.shape[0]
     over = jnp.maximum(occ - dev.capacity, 0)
     val, ids = lax.top_k(over, topk)
     code = jnp.append(jnp.where(over > 0, topk, topk + 1),
                       topk + 1).astype(jnp.int32)
     code = code.at[jnp.where(val > 0, ids, N + 1)].set(
         jnp.arange(topk, dtype=jnp.int32), mode="drop")
-    col = code[paths.reshape(R, -1)]
-    rrm = (col <= topk).any(axis=1) | ~all_reached
-    U = jnp.zeros((R, topk + 1), bool).at[
-        jnp.arange(R)[:, None], jnp.minimum(col, topk)].set(
-        True)[:, :topk]
-    U = U & rrm[:, None]
-    prio = jnp.arange(R, dtype=jnp.int32)
+
+    def columns(store):
+        return code[store.reshape(store.shape[0], -1)]
+
+    def rows(col):
+        n = col.shape[0]
+        return jnp.zeros((n, topk + 1), bool).at[
+            jnp.arange(n)[:, None], jnp.minimum(col, topk)].set(
+            True)[:, :topk]
+
+    if fan is None:
+        col = columns(paths)
+        rrm = (col <= topk).any(axis=1) | ~all_reached
+        U = rows(col) & rrm[:, None]
+        prio = jnp.arange(R, dtype=jnp.int32)
+    else:
+        cols = [columns(store) for store in paths]
+        prio = jnp.concatenate(fan[1])
+        rrm = jnp.concatenate([(col <= topk).any(axis=1)
+                               for col in cols]) | ~all_reached[prio]
+        U = jnp.concatenate([rows(col) for col in cols]) & rrm[:, None]
     color = jnp.full(R, n_colors - 1, jnp.int32)
     uncol = rrm
     for c in range(n_colors - 1):
@@ -1832,6 +1894,10 @@ def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
         joins = uncol & ~conflict
         color = jnp.where(joins, c, color)
         uncol = uncol & ~joins
+    if fan is not None:
+        # class after class -> by net
+        rrm = jnp.zeros(R, bool).at[prio].set(rrm)
+        color = jnp.zeros(R, jnp.int32).at[prio].set(color)
     return rrm, color
 
 
@@ -1886,7 +1952,7 @@ def repack_plan(sel_plan, seg_plan, live):
 WINDOW_STATIC_ARGNAMES = ("K_iters", "nsweeps", "max_len", "num_waves",
                           "group", "doubling", "topk", "n_colors",
                           "mesh", "sta_depth", "crit_exp", "max_crit",
-                          "use_sdc", "crop_tile", "plane_dtype")
+                          "use_sdc", "crop_tile", "plane_dtype", "fclass")
 
 
 def _window_body(
@@ -1905,7 +1971,7 @@ def _window_body(
         crit_exp: float = 1.0, max_crit: float = 0.99,
         use_sdc: bool = False,
         crop_tile=None, bb0_all=None, widen_ok=None,
-        plane_dtype: str = "f32"):
+        plane_dtype: str = "f32", fan=None, fclass: int = 0):
     """A WINDOW of K_iters complete PathFinder iterations as ONE device
     program: per iteration, every batch group in sel_plan [G, B] runs the
     fused rip-up/route/commit step (clean nets no-op via the device-side
@@ -1947,10 +2013,21 @@ def _window_body(
     can pull the whole window summary with one async copy
     (unpack_window_status / SCAL_* below)."""
     G = sel_plan.shape[0]
-    R, Smax = sinks_all.shape
     # valid_plan carries each slot's conflict-colour segment (0 = pad);
     # a bool plan is one segment
     seg_plan = valid_plan.astype(jnp.int32)
+    local = None if fan is None else fan[0]
+
+    def mine(x):
+        # the plan's class's table of a table kept a class
+        return x if fan is None else x[fclass]
+
+    def with_mine(x, v):
+        return v if fan is None else x[:fclass] + (v,) + x[fclass + 1:]
+    sinks_c = mine(sinks_all)
+    tabs_c = (mine(sink_uid_all), uid_ucell, uid_upin, uid_pcdel,
+              uid_pcrank, mine(direct_oidx_all), mine(direct_ipin_all),
+              mine(direct_delay_all))
 
     def it_body(it, st):
         (occ, acc, paths, sink_delay, all_reached, bb, pres, nroutes,
@@ -1960,10 +2037,16 @@ def _window_body(
 
             def repacked():
                 # _step_core's own predicate, taken once for every net
+                # (of the plan's class)
                 over_it = jnp.append(occ > dev.capacity, False)
-                dirty = over_it[paths].any(axis=(1, 2)) | ~all_reached
-                return repack_plan(sel_plan, seg_plan,
-                                   (seg_plan > 0) & dirty[sel_plan])
+                if fan is None:
+                    dirty = over_it[paths].any(axis=(1, 2)) | ~all_reached
+                    return repack_plan(sel_plan, seg_plan,
+                                       (seg_plan > 0) & dirty[sel_plan])
+                over_c = over_it[mine(paths)].any(axis=(1, 2))
+                return repack_plan(
+                    sel_plan, seg_plan, (seg_plan > 0) & (
+                        over_c[local[sel_plan]] | ~all_reached[sel_plan]))
 
             # the TAIL of a full rebuild (a restart, a finishing pass: a
             # forced window past the first, after its forced iteration):
@@ -1987,15 +2070,12 @@ def _window_body(
                  n_act, led_g) = _step_core(
                     pg, dev, occ2, acc, pres,
                     paths2, sink_delay2, all_reached2, bb2,
-                    source_all, sinks_all, crit_all,
+                    source_all, sinks_c, mine(crit_all),
                     opin_node_all, entry_cell_all, entry_oidx_all,
-                    entry_delay_all,
-                    sink_uid_all, uid_ucell, uid_upin, uid_pcdel,
-                    uid_pcrank,
-                    direct_oidx_all, direct_ipin_all, direct_delay_all,
+                    entry_delay_all, *tabs_c,
                     sel_g, valid_g, force, full_bb,
                     nsweeps, max_len, num_waves, group, doubling, mesh,
-                    crop_tile, bb0_all, widen_ok, plane_dtype)
+                    crop_tile, bb0_all, widen_ok, plane_dtype, local)
                 with device_scope("route.dev.commit"):
                     return (occ2, paths2, sink_delay2, all_reached2, bb2,
                             nr + n_act, ng + 1, led2 + led_g)
@@ -2009,16 +2089,19 @@ def _window_body(
             with device_scope("route.dev.ripup"):
                 over_g = jnp.append(st2[0] > dev.capacity, False)
                 sel_g = sel_it[g]
+                lsel_g = sel_g if fan is None else local[sel_g]
                 any_dirty = (valid_it[g]
-                             & (over_g[st2[1][sel_g]].any(axis=(1, 2))
+                             & (over_g[st2[1][lsel_g]].any(axis=(1, 2))
                                 | ~st2[3][sel_g] | force)).any()
             return lax.cond(any_dirty, run, lambda s: s, st2)
 
-        (occ, paths, sink_delay, all_reached, bb, nroutes,
+        (occ, paths_c, sink_delay_c, all_reached, bb, nroutes,
          nexec, led) = lax.fori_loop(
             0, G, g_step,
-            (occ, paths, sink_delay, all_reached, bb, nroutes, nexec,
-             led))
+            (occ, mine(paths), mine(sink_delay), all_reached, bb,
+             nroutes, nexec, led))
+        paths = with_mine(paths, paths_c)
+        sink_delay = with_mine(sink_delay, sink_delay_c)
         with device_scope("route.dev.history"):
             # PathFinder history/present escalation once per iteration
             acc = acc + acc_fac * jnp.maximum(
@@ -2028,12 +2111,23 @@ def _window_body(
             # device-resident analyze_timing + update_sink_criticalities
             from ..timing.sta import sta_crit
             with device_scope("route.dev.sta"):
-                flat = jnp.append(
-                    sink_delay.reshape(-1), jnp.float32(0.0))
+                if fan is None:
+                    flat = jnp.append(
+                        sink_delay.reshape(-1), jnp.float32(0.0))
+                else:
+                    flat = jnp.concatenate(
+                        [sd.reshape(-1) for sd in sink_delay]
+                        + [jnp.zeros(1, jnp.float32)])
                 crit_flat, dmax, _, _ = sta_crit(
                     tdev, flat, sta_depth, crit_exp, max_crit,
                     req_seed=req_seed, use_sdc=use_sdc)
-                crit_all = crit_flat.reshape(R, Smax)
+                if fan is None:
+                    crit_all = crit_flat.reshape(sink_delay.shape)
+                else:
+                    ends = np.cumsum([sd.size for sd in sink_delay])
+                    crit_all = tuple(
+                        crit_flat[e - sd.size:e].reshape(sd.shape)
+                        for e, sd in zip(ends.tolist(), sink_delay))
                 dmax_hist = dmax_hist.at[it].set(dmax)
         return (occ, acc, paths, sink_delay, all_reached, bb, pres,
                 nroutes, nexec, crit_all, dmax_hist, led)
@@ -2047,8 +2141,8 @@ def _window_body(
          jnp.zeros((5,), jnp.int32)))
     s_exec, s_useful = led[0], led[1]
 
-    rrm, colors = _mis_colors(dev, occ, paths, all_reached,
-                              topk, n_colors)
+    rrm, colors = _mis_colors(dev, occ, paths, all_reached, topk, n_colors,
+                              **({} if fan is None else {"fan": fan}))
     with device_scope("route.dev.window_summary"):
         over = jnp.maximum(occ - dev.capacity, 0)
         # max bb half-perimeter of a still-dirty net: the host compares it
@@ -2122,7 +2216,7 @@ def route_window_planes(
         crit_exp: float = 1.0, max_crit: float = 0.99,
         use_sdc: bool = False,
         crop_tile=None, bb0_all=None, widen_ok=None,
-        plane_dtype: str = "f32"):
+        plane_dtype: str = "f32", fan=None, fclass: int = 0):
     """One window RUNG as its own jit program (contract: _window_body's
     docstring) — the per-rung dispatch shape the Router's crop ladder
     used before the fused program below, kept as the watchdog fallback
@@ -2137,7 +2231,7 @@ def route_window_planes(
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
         K_iters, nsweeps, max_len, num_waves, group, doubling, topk,
         n_colors, mesh, tdev, req_seed, sta_depth, crit_exp, max_crit,
-        use_sdc, crop_tile, bb0_all, widen_ok, plane_dtype)
+        use_sdc, crop_tile, bb0_all, widen_ok, plane_dtype, fan, fclass)
 
 
 # the fused program's static argnames: the per-rung statics
@@ -2149,7 +2243,7 @@ def route_window_planes(
 FUSED_WINDOW_STATIC_ARGNAMES = tuple(
     n for n in WINDOW_STATIC_ARGNAMES
     if n not in ("nsweeps", "num_waves", "group", "doubling",
-                 "crop_tile")) + ("rung_desc",)
+                 "crop_tile", "fclass")) + ("rung_desc",)
 
 
 def _fused_ladder(
@@ -2164,7 +2258,7 @@ def _fused_ladder(
         K_iters: int, max_len: int, rung_desc, topk: int,
         n_colors: int, mesh, tdev, req_seed, sta_depth: int,
         crit_exp: float, max_crit: float, use_sdc: bool,
-        bb0_all, widen_oks, plane_dtype: str):
+        bb0_all, widen_oks, plane_dtype: str, fan=None):
     """The traced body shared by route_window_planes_fused (one job)
     and route_window_planes_multi (one job per co-admitted tenant):
     walk the ragged ``rung_desc`` descriptor table, threading the
@@ -2174,8 +2268,9 @@ def _fused_ladder(
         widen_oks = (None,) * len(rung_desc)
     out = None
     scals = []
-    for r, (crop_tile, nsweeps, num_waves, group,
-            doubling) in enumerate(rung_desc):
+    for r, (crop_tile, nsweeps, num_waves, group, doubling,
+            *fclass) in enumerate(rung_desc):
+        # a rung of a route with fanout classes names its class last
         out = _window_body(
             pg, dev, occ, acc, paths, sink_delay, all_reached, bb,
             source_all, sinks_all, crit_all,
@@ -2190,7 +2285,7 @@ def _fused_ladder(
             K_iters, nsweeps, max_len, num_waves, group, doubling,
             topk, n_colors, mesh, tdev, req_seed, sta_depth, crit_exp,
             max_crit, use_sdc, crop_tile, bb0_all, widen_oks[r],
-            plane_dtype)
+            plane_dtype, fan, *fclass)
         (occ, acc, paths, sink_delay, all_reached, bb) = out[:6]
         crit_all = out[13]
         scals.append(out[22])
@@ -2218,11 +2313,13 @@ def route_window_planes_fused(
         tdev=None, req_seed=None, sta_depth: int = 0,
         crit_exp: float = 1.0, max_crit: float = 0.99,
         use_sdc: bool = False,
-        bb0_all=None, widen_oks=None, plane_dtype: str = "f32"):
+        bb0_all=None, widen_oks=None, plane_dtype: str = "f32",
+        fan=None):
     """The WHOLE window dispatch ladder as ONE device program: walk the
     ragged ``rung_desc`` descriptor table — one static
     (crop_tile, nsweeps, num_waves, group, doubling) tuple per
-    populated size-class rung — running each rung's _window_body on its
+    populated size-class rung, with the rung's fanout class sixth where
+    the route has classes (``fan``, _window_body) — running each rung's _window_body on its
     own sel/valid plan and threading the negotiation state
     (occ/acc/paths/sink_delay/all_reached/bb/crit_all) rung to rung,
     exactly as the per-rung dispatch loop does host-side.  One dispatch
@@ -2251,7 +2348,7 @@ def route_window_planes_fused(
         pres0, pres_mult, max_pres, acc_fac, it0, force_until,
         K_iters, max_len, rung_desc, topk, n_colors, mesh, tdev,
         req_seed, sta_depth, crit_exp, max_crit, use_sdc,
-        bb0_all, widen_oks, plane_dtype)
+        bb0_all, widen_oks, plane_dtype, fan)
 
 
 # the multi-job program's static argnames: one (K_iters, max_len,

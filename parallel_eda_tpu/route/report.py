@@ -76,7 +76,9 @@ WALL_KEYS = ("prologue_s", "windows_s", "control_s", "epilogue_s")
 def format_window_table(result) -> str:
     """The route by window, one line a ``RouteResult.stats`` row: what
     each window IS (``kind``), what it did and what it cost, and
-    whether its result is in the route returned (``kept``).  Under it
+    whether its result is in the route returned (``kept``); on a route
+    with fanout classes two more columns, the sweeps and waves of the
+    batches of a class above the first.  Under it
     the route's wall by named interval where the result carries one
     (``RouteResult.wall``: the four add up to the ``route`` stage)."""
     head = ("window", "iter", "kind", "overused", "nets", "seconds",
@@ -103,6 +105,13 @@ def format_window_table(result) -> str:
                  batches, routes, fill(routes, batches),
                  f"{sum(1 for s in result.stats if s.kept)}"
                  f"/{len(result.stats)}"))
+    if any(getattr(s, "fanout_class", 0) for s in result.stats):
+        # a route with fanout classes: of sweeps and waves, what the
+        # batches of a class above the first spent
+        wide = [(s.relax_steps_wide, s.waves_wide) for s in result.stats]
+        wide.append(tuple(sum(col) for col in zip(*wide)))
+        head += ("sweeps_wide", "waves_wide")
+        rows = [r + w for r, w in zip(rows, wide)]
     cells = [head] + [tuple(str(c) for c in r) for r in rows]
     width = [max(len(r[i]) for r in cells) for i in range(len(head))]
     lines = ["  ".join(c.ljust(w) if i == 2 else c.rjust(w)

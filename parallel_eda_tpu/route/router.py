@@ -41,7 +41,7 @@ import numpy as np
 from ..obs import get_devprof, get_metrics, get_tracer, span
 from ..obs.trace import compile_phases, enable_compile_capture
 from ..rr.graph import RRGraph
-from ..rr.terminals import NetTerminals
+from ..rr.terminals import NetTerminals, class_rows
 from .device_graph import DeviceRRGraph, to_device
 from .search import (build_windows, conflict_subset, iteration_summary,
                      route_batch_resident, route_batch_resident_win,
@@ -276,6 +276,12 @@ class RouteStats:
     dispatch_ms: float = 0.0     # of it, in route.pipeline.dispatch
     control_s: float = 0.0       # the host's control step AFTER it
     kept: bool = True            # False: computed and thrown away
+    # fanout classes: the class of the widest net of the window's
+    # batches (0: the first class, the only one of most circuits), and
+    # of waves / relax_steps what batches of a class above it spent
+    fanout_class: int = 0
+    waves_wide: int = 0
+    relax_steps_wide: int = 0
 
 
 @dataclass
@@ -291,6 +297,9 @@ class RouteCheckpoint:
     single chip, deterministically."""
     occ: np.ndarray
     acc: np.ndarray
+    # paths / sink_delay / crit: the device's tables as they stand, so
+    # one array of a route of one fanout class and a tuple of one array
+    # a class ([R_c, S_c, ...]) of a route of several
     paths: np.ndarray
     sink_delay: np.ndarray
     all_reached: np.ndarray
@@ -311,7 +320,10 @@ class RouteCheckpoint:
 class RouteResult:
     success: bool
     iterations: int
-    paths: np.ndarray            # [R, Smax, Lmax] int32, sentinel N = pad
+    # [R, Smax, Lmax] int32, sentinel N = pad; of a route of several
+    # fanout classes a ClassedPaths, which answers paths[r][s],
+    # paths[r, s], .shape and np.asarray() from the stores a class
+    paths: np.ndarray
     sink_delay: np.ndarray       # [R, Smax] f32
     occ: np.ndarray              # [N] int32 final occupancy
     wirelength: int
@@ -333,6 +345,8 @@ class RouteResult:
     # finishing pass could not re-legalise and the snapshot was restored
     # (0 when the finished route is kept)
     total_relax_steps_discarded: int = 0
+    # of which: sweeps of the batches of a fanout class above the first
+    total_relax_steps_wide: int = 0
     # traceback ledger (windowed planes program): pointer-chase steps
     # the walks ran, and the steps budgeted (max_len - 4 per executed
     # wave).  A share near 1 means paths are pressing on the budget.
@@ -570,8 +584,66 @@ def path_budget(span: int, cap: int) -> int:
 
 
 def _grow_paths(paths, L_new: int, N: int):
-    return jnp.pad(paths, ((0, 0), (0, 0), (0, L_new - paths.shape[2])),
-                   constant_values=N)
+    return jax.tree.map(
+        lambda p: jnp.pad(p, ((0, 0), (0, 0), (0, L_new - p.shape[2])),
+                          constant_values=N), paths)
+
+
+def _by_class(dense: np.ndarray, classes):
+    """A host table dense in the sink axis [R, Smax, ...] as the device
+    holds it: the array itself where the route has one fanout class,
+    else a tuple of one [R_c, S_c, ...] array a class."""
+    if len(classes) == 1:
+        return jnp.asarray(dense)
+    return tuple(jnp.asarray(dense[c.nets, :c.width]) for c in classes)
+
+
+def _dense(tables, classes, fill) -> np.ndarray:
+    """_by_class's inverse on the host: [R, Smax, ...] with ``fill`` in
+    the slots a class's table does not have."""
+    if not isinstance(tables, tuple):
+        return np.asarray(tables)
+    parts = [np.asarray(t) for t in tables]
+    R = sum(len(c.nets) for c in classes)
+    out = np.full((R, classes[-1].width) + parts[0].shape[2:], fill,
+                  dtype=parts[0].dtype)
+    for c, t in zip(classes, parts):
+        out[c.nets, :c.width] = t
+    return out
+
+
+class ClassedPaths:
+    """The host's view of a routed path store kept a fanout class:
+    what ``RouteResult.paths`` is for a route of several classes.  It
+    answers what the oracles ask of the dense [R, Smax, L] array --
+    ``paths[r]`` ([S_c, L]: every sink slot the net has), ``paths[r,
+    s]``, ``.shape``, ``len()`` -- from the stores as they came off the
+    device, and ``np.asarray(paths)`` builds the dense array (pad N)
+    for whoever needs all of it at once."""
+
+    def __init__(self, parts, classes, pad: int):
+        self.parts = [np.asarray(p) for p in parts]
+        self.classes = classes
+        self.pad = int(pad)
+        self._cls, self._row = class_rows(classes)
+        self.shape = (len(self._cls), classes[-1].width,
+                      self.parts[0].shape[2])
+        self.dtype = self.parts[0].dtype
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def __getitem__(self, key):
+        r, rest = (key[0], key[1:]) if isinstance(key, tuple) \
+            else (key, ())
+        if not isinstance(r, (int, np.integer)):
+            return np.asarray(self)[key]
+        net = self.parts[self._cls[r]][self._row[r]]
+        return net[rest] if rest else net
+
+    def __array__(self, dtype=None, copy=None):
+        out = _dense(tuple(self.parts), self.classes, self.pad)
+        return out if dtype is None else out.astype(dtype)
 
 
 def _phase2_restart_due(precise: bool, full_reroute_done: bool,
@@ -1274,6 +1346,9 @@ class Router:
 
         w_steps = w_useful = w_steps_crop = 0
         nroutes = nexec = w_waves = 0
+        w_steps_wide = w_waves_wide = 0
+        rung_classes = bk.get("rung_classes") or [0] * len(
+            bk["rung_scals"])
         mesh_info = bk.get("mesh")
         halo_b = halo_ex = 0
         for ri, (scal_d, cropped) in enumerate(bk["rung_scals"]):
@@ -1287,6 +1362,9 @@ class Router:
             w_waves += int(v[SCAL_WAVES])
             if cropped:
                 w_steps_crop += int(v[SCAL_S_EXEC])
+            if rung_classes[ri]:
+                w_steps_wide += int(v[SCAL_S_EXEC])
+                w_waves_wide += int(v[SCAL_WAVES])
             if mesh_info is not None and mesh_info[0] > 1 \
                     and ri < len(bk["kplans"]):
                 # halo ledger: every executed sweep exchanged one halo
@@ -1301,6 +1379,7 @@ class Router:
         result.total_relax_steps_useful += w_useful
         result.total_relax_steps_wasted += w_steps - w_useful
         result.total_relax_steps_cropped += w_steps_crop
+        result.total_relax_steps_wide += w_steps_wide
         result.total_waves += w_waves
         row = RouteStats(
             bk["it_done"], bk["n_over"], bk["over_total"], bk["ndirty"],
@@ -1310,7 +1389,9 @@ class Router:
             precise=bk["precise"], sweep_boost=bk["sweep_boost"],
             waves=w_waves, relax_steps_cropped=w_steps_crop,
             net_routes=nroutes, stall_s=bk["stall_s"],
-            plan_s=bk["plan_s"], dispatch_ms=bk["dispatch_ms"])
+            plan_s=bk["plan_s"], dispatch_ms=bk["dispatch_ms"],
+            fanout_class=max(rung_classes),
+            waves_wide=w_waves_wide, relax_steps_wide=w_steps_wide)
         result.stats.append(row)
         reg = get_metrics()
         reg.counter(f"route.window.seconds_total.{row.kind}").inc(
@@ -1530,7 +1611,7 @@ class Router:
                               source_d, sinks_d, planes_tbl, nsinks_np,
                               cx_np, cy_np, result, B, mlog,
                               crop="auto", resume=None, rid=0,
-                              t_enter=None):
+                              t_enter=None, fan_d=None):
         """Window-fused PathFinder driver for the planes program: the
         negotiation runs as a sequence of multi-iteration device programs
         (planes.route_window_planes) with ONE host sync per window — the
@@ -1578,8 +1659,15 @@ class Router:
         rr, dev = self.rr, self.dev
         R, Smax = term.sinks.shape
         N = rr.num_nodes
-        grp = Smax if opts.sink_group == 0 else opts.sink_group
-        grp = max(1, min(grp, Smax))
+        # the fanout classes: a batch holds nets of one class and the
+        # window program runs it at that class's width.  A class above
+        # the first (a net of hundreds of sinks spans the device) takes
+        # the full canvas only
+        classes = term.fanout_classes
+        widths = [c.width for c in classes]
+        cls_of = term.class_rows()[0]
+        # what a window program of a route with classes is told besides
+        fan_kw = {} if fan_d is None else {"fan": fan_d}
 
         # device-fused STA config (analyzer mode): the full timing sweep
         # runs between iterations inside the window program
@@ -1592,7 +1680,8 @@ class Router:
                 use_sdc=analyzer._req_seed is not None)
 
         pres = opts.initial_pres_fac
-        crit_d = jnp.asarray(crit)
+        crit_d = (jax.tree.map(jnp.asarray, crit)
+                  if isinstance(crit, tuple) else _by_class(crit, classes))
         it_done = 0
         dirty = np.arange(R)
         colors = None
@@ -1657,11 +1746,11 @@ class Router:
                 # re-arm the pre-finish legal snapshot: if the resumed
                 # finishing pass cannot re-legalize within budget, the
                 # legal route is restored instead of reporting failure
-                fin_save = (jnp.asarray(fs[0]), jnp.asarray(fs[1]),
-                            jnp.asarray(fs[2]), jnp.asarray(fs[3]),
-                            jnp.asarray(fs[4]), int(fs[5]))
+                fin_save = tuple(jax.tree.map(jnp.asarray, v)
+                                 for v in fs[:5]) + (int(fs[5]),)
 
-        L = int(paths.shape[2])          # current path-slot budget
+        # current path-slot budget (one for every class's store)
+        L = int(jax.tree.leaves(paths)[0].shape[2])
         L_cap = self.max_len
         next_ckpt = (it_done + opts.checkpoint_every
                      if opts.checkpoint_every else None)
@@ -1723,6 +1812,13 @@ class Router:
         # of both, the nets with a terminal on a hard block (0 on a
         # device of identical clusters)
         hard_nets = reg.counter("route.hetero.net_dispatches_hard_total")
+        # of both, the nets of a fanout class above the first; and over
+        # every net dispatched its sinks and the sink slots its class's
+        # tables give it (their ratio is what a batch's S axis holds)
+        wide_nets = reg.counter("route.fanout.net_dispatches_wide_total")
+        sinks_disp = reg.counter("route.fanout.sinks_dispatched_total")
+        slots_disp = reg.counter(
+            "route.fanout.sink_slots_dispatched_total")
         # conflict colourings run (one a dispatched _window_body, so one
         # a rung) / read (one a window: the last rung's summary)
         mis_calls = reg.counter("route.mis_colors.calls_total")
@@ -1781,6 +1877,16 @@ class Router:
             # summary (device-side widening feeds the next partition —
             # the measured-cost re-partition analogue, ...cxx:909-916);
             # nets the host widened take full-device spans
+            # the work set's nets of the first fanout class, which the
+            # crop ladder below bins; the classes above it come first,
+            # widest first, on the full canvas (VPR's order: the nets of
+            # most sinks are routed first)
+            dirty_all = dirty
+            wide_rungs = [(dirty[cls_of[dirty] == c], None, c)
+                          for c in range(len(classes) - 1, 0, -1)
+                          if (cls_of[dirty] == c).any()]
+            if wide_rungs:
+                dirty = dirty[cls_of[dirty] == 0]
             w_all = np.where(wide[dirty], rr.grid.nx + 2, np.maximum(
                 term.bb_xmax[dirty] - term.bb_xmin[dirty] + 1,
                 live_w[dirty])) if len(dirty) else np.array([8])
@@ -1812,14 +1918,14 @@ class Router:
                     dispatch.append((dirty[~narrow], None))
             elif not crop_full and len(dirty):
                 Lm = self.pg.max_span
-                classes, assign = _size_class_buckets(
+                tiles, assign = _size_class_buckets(
                     w_all + 2 * Lm, h_all + 2 * Lm,
                     rr.grid.nx, rr.grid.ny,
                     min_count=max(1, B // 8))
                 dispatch = [(dirty[assign == k], tile)
-                            for k, tile in enumerate(classes)]
-                if (assign == len(classes)).any():
-                    dispatch.append((dirty[assign == len(classes)],
+                            for k, tile in enumerate(tiles)]
+                if (assign == len(tiles)).any():
+                    dispatch.append((dirty[assign == len(tiles)],
                                      None))
             else:
                 dispatch = [(dirty, None)]
@@ -1854,14 +1960,24 @@ class Router:
                 # crop ladder is single-device VMEM machinery — the
                 # row mesh splits the canvas across chips instead
                 dispatch = [(dirty, None)]
-            for rung_nets, rung_tile in dispatch:
+            # every rung names its fanout class; a first class left
+            # with no net beside a wider one dispatches nothing
+            dispatch = wide_rungs + [
+                (sub, tile, 0) for sub, tile in dispatch
+                if len(sub) or not wide_rungs]
+            dirty = dirty_all
+            for rung_nets, rung_tile, rung_cls in dispatch:
                 (full_nets if rung_tile is None
                  else crop_nets).inc(len(rung_nets))
                 if term.hard is not None:
                     hard_nets.inc(int(term.hard[rung_nets].sum()))
+                if rung_cls:
+                    wide_nets.inc(len(rung_nets))
+                sinks_disp.inc(int(nsinks_np[rung_nets].sum()))
+                slots_disp.inc(len(rung_nets) * widths[rung_cls])
             mis_calls.inc(len(dispatch))
 
-            def plan_rung(sub, tile, ri):
+            def plan_rung(sub, tile, ri, fcls):
                 """Host planning for one rung of this window's dispatch
                 ladder (the plan half of the old window_call): batch
                 plan, sweep budget, widen gate, kernel-layout plan, and
@@ -1924,14 +2040,24 @@ class Router:
                     wok_np[sub[spans_full <= nsw]] = True
                     wok = self._staging.put(f"{stg}wok{ri}", wok_np)
                 maxfan = int(nsinks_np[sub].max()) if len(sub) else 1
-                doubling = opts.sink_group == 0 and not precise
-                grp_w = 1 if precise and opts.sink_group == 0 else grp
+                # a class above the first keeps the doubling schedule
+                # under ``precise`` too: the exact schedule is one
+                # relaxation a sink, 204 a batch for a net of 204 sinks
+                # (PERF.md PR 37 has what a 13-sinks-a-wave middle cost)
+                doubling = opts.sink_group == 0 and (not precise or fcls > 0)
+                # the class's width is what Smax was: the most sinks a
+                # wave may pick, and the cap of the wave count
+                S_c = widths[fcls]
+                grp_w = max(1, min(S_c if opts.sink_group == 0
+                                   else opts.sink_group, S_c))
+                if not doubling and opts.sink_group == 0:
+                    grp_w = 1
                 # the wave cap is a ceiling too (the wave loop skips
                 # once no sinks are pending), so the precise schedule's
                 # count also quantizes to pow-2 for free
                 waves = (max(1, math.ceil(math.log2(maxfan + 1))) + 1
                          if doubling
-                         else min(Smax, _pow2_at_least(
+                         else min(S_c, _pow2_at_least(
                              math.ceil(maxfan / grp_w) + 1)))
                 kplan = self._plan_block_nets(tile, len(sub), nsw,
                                               plane_dtype=pd)
@@ -1960,7 +2086,11 @@ class Router:
                 valid_d = self._staging.put(f"{stg}valid{ri}", valid_p)
                 # ledger: filled batch slots, plan width, and real
                 # (non-pad) batch rows of this planned dispatch
-                return dict(tile=tile, nsw=nsw, waves=waves,
+                # the class rides in a dispatch key only where the
+                # route has classes: one class keeps the parent's keys
+                return dict(tile=tile, fcls=fcls,
+                            fkey=(fcls,) if fan_d is not None else (),
+                            nsw=nsw, waves=waves,
                             grp_w=grp_w, doubling=doubling, wok=wok,
                             sel_d=sel_d, valid_d=valid_d, kplan=kplan,
                             sel_shape=sel_p.shape,
@@ -1998,7 +2128,8 @@ class Router:
             def rung_kwargs(p):
                 return dict(crop_tile=p["tile"], bb0_all=bb0_d,
                             widen_ok=p["wok"], plane_dtype=pd,
-                            **sta_kw)
+                            **fan_kw, **sta_kw,
+                            **({"fclass": p["fcls"]} if fan_kw else {}))
 
             def window_call(p, esc, pres_in, ri):
                 """One route_window_planes dispatch of planned rung
@@ -2010,7 +2141,7 @@ class Router:
                 vkey = (p["tile"], K, p["nsw"], L, p["waves"],
                         p["grp_w"], p["doubling"], p["sel_shape"][0],
                         p["sel_shape"][1], p["wok"] is None, mesh_vk,
-                        bool(sta_kw), R, Smax, N, pd)
+                        bool(sta_kw), R, Smax, N, pd) + p["fkey"]
                 wp_args = rung_args(
                     p, (occ, acc, paths, sink_delay, all_reached, bb,
                         crit_d), esc, pres_in)
@@ -2020,7 +2151,7 @@ class Router:
                 # can AOT-relower this exact variant later
                 get_devprof().note_variant(
                     (p["tile"], K, p["nsw"], L, p["waves"],
-                     p["grp_w"]), p["kplan"],
+                     p["grp_w"]) + p["fkey"], p["kplan"],
                     route_window_planes, wp_args, wp_kwargs)
                 with dispatching(window=widx, route=rid, rung=ri):
                     if resil_rt is not None \
@@ -2102,10 +2233,11 @@ class Router:
                                stage="plan", window=widx, route=rid,
                                rung=0, nets=len(dirty), fused=True,
                                rungs=len(dispatch),
-                               tiles=[t for _, t in dispatch])
+                               tiles=[t for _, t, _ in dispatch],
+                               fanout_class=[c for _, _, c in dispatch])
                 plan_sp.__enter__()
-                plans = [plan_rung(sub0, tile, ri)
-                         for ri, (sub0, tile) in enumerate(dispatch)]
+                plans = [plan_rung(sub0, tile, ri, fcls)
+                         for ri, (sub0, tile, fcls) in enumerate(dispatch)]
                 for p in plans:
                     kplans.append(p["kplan"])
                     nvalid, bg, grows = p["ledger"]
@@ -2115,7 +2247,7 @@ class Router:
                         comp_den += grows * B
                 rung_desc = tuple(
                     (p["tile"], p["nsw"], p["waves"], p["grp_w"],
-                     p["doubling"]) for p in plans)
+                     p["doubling"]) + p["fkey"] for p in plans)
                 widen_oks = (None
                              if all(p["wok"] is None for p in plans)
                              else tuple(p["wok"] for p in plans))
@@ -2137,7 +2269,7 @@ class Router:
                     rung_desc=rung_desc, topk=min(4096, N),
                     n_colors=5, mesh=mesh_now, bb0_all=bb0_d,
                     widen_oks=widen_oks, plane_dtype=pd,
-                    **sta_kw)
+                    **fan_kw, **sta_kw)
                 vkey = ("fused", rung_desc, K, L,
                         tuple(p["sel_shape"] for p in plans),
                         widen_oks is None, mesh_vk, bool(sta_kw),
@@ -2213,12 +2345,13 @@ class Router:
                 outs.append((o, dispatch[-1][1]))
             else:
                 esc = True
-                for ri, (sub0, tile) in enumerate(dispatch):
+                for ri, (sub0, tile, fcls) in enumerate(dispatch):
                     tp0 = time.perf_counter()
                     with span("route.pipeline.plan", cat="route",
                               stage="plan", window=widx, route=rid,
-                              rung=ri, nets=len(sub0), tile=tile):
-                        p = plan_rung(sub0, tile, ri)
+                              rung=ri, nets=len(sub0), tile=tile,
+                              fanout_class=fcls):
+                        p = plan_rung(sub0, tile, ri, fcls)
                     o = window_call(p, esc, pres, ri)
                     esc = False
                     kplans.append(p["kplan"])
@@ -2405,6 +2538,7 @@ class Router:
                 dirty_next=int(rrm.sum()), precise=precise,
                 sweep_boost=sweep_boost, widened=result.widened_nets,
                 dmax_hist=dmax_hist,
+                rung_classes=[c for _, _, c in dispatch],
                 # occ snapshot for the congestion top-k: inline in
                 # --sync (booked before the next dispatch donates the
                 # array), a non-donated async-readback copy when
@@ -2433,15 +2567,20 @@ class Router:
             if opts.stats_dir and opts.dump_routes:
                 # stats/debug mode only; the sync is the point of it
                 self._dump_routes(opts.stats_dir, it_done,
-                                  np.asarray(paths), N)  # graftlint: ignore[pipeline-sync]
+                                  _dense(paths, classes, N), N)  # graftlint: ignore[pipeline-sync]
 
             if n_over == 0 and not rrm.any():
-                finish_set = nsinks_np > 1
+                # the pass rebuilds the doubling trees one sink a wave:
+                # the multi-sink nets of the first fanout class (a wider
+                # class keeps the doubling schedule, so the pass has
+                # nothing to give it)
+                finish_set = (nsinks_np > 1) & (cls_of == 0)
                 if (opts.finish_precise and opts.sink_group == 0
                         and not finish_done and not full_reroute_done
                         and finish_set.any()
                         and it_done + 4 < opts.max_router_iterations
-                        and int(paths.size) * 4 <= (1 << 30)):
+                        and sum(int(p.size) for p in
+                                jax.tree.leaves(paths)) * 4 <= (1 << 30)):
                     # wirelength finishing pass (see RouterOpts): one
                     # precise reroute of the MULTI-SINK nets (a
                     # single-sink traceback is already an exact path —
@@ -2461,7 +2600,9 @@ class Router:
                     precise = True
                     force_all_next = True
                     rrm = finish_set
-                    fin_save = (occ + 0, paths + 0, sink_delay + 0,
+                    fin_save = (occ + 0,
+                                jax.tree.map(lambda p: p + 0, paths),
+                                jax.tree.map(lambda d: d + 0, sink_delay),
                                 all_reached | False, bb + 0, it_done)
                     # fresh plateau state: the cleanup's transient
                     # overuse must not trip the stall valve
@@ -2530,7 +2671,7 @@ class Router:
             if timing_cb is not None and analyzer is None:
                 # host timing callback forces K=1 per-iteration sync
                 # by design (documented in RouteOpts)
-                result.sink_delay = np.asarray(sink_delay)  # graftlint: ignore[pipeline-sync]
+                result.sink_delay = _dense(sink_delay, classes, np.inf)  # graftlint: ignore[pipeline-sync]
                 new_crit = np.minimum(np.asarray(
                     timing_cb(result), dtype=np.float32), 0.99)
                 if np.array_equal(new_crit, crit):
@@ -2541,26 +2682,26 @@ class Router:
                     reg.counter("route.pipeline.crit_upload_skips").inc()
                 else:
                     crit = new_crit
-                    crit_d = jnp.asarray(crit)
+                    crit_d = _by_class(crit, classes)
 
             if next_ckpt is not None and it_done >= next_ckpt:
                 # window-boundary snapshot: everything the resume needs
                 # to continue this negotiation under any mesh
                 # graftlint: ignore[pipeline-sync] — durable snapshot at
                 # a window boundary is a sanctioned sync (resil contract)
-                a = [np.asarray(v) for v in jax.device_get(
+                a = jax.tree.map(np.asarray, list(jax.device_get(
                     (occ, acc, paths, sink_delay, all_reached, bb,
-                     crit_d))]
+                     crit_d))))
                 fin_ck = None
                 if fin_save is not None:
                     # the finishing pass is live: the checkpoint must
                     # carry the pre-finish legal snapshot, or a resumed
                     # run that fails to re-legalize would report
                     # success=False after a legal route existed
-                    fin_ck = tuple(
-                        np.asarray(v)
-                        for v in jax.device_get(fin_save[:5])  # graftlint: ignore[pipeline-sync]
-                    ) + (int(fin_save[5]),)
+                    fin_ck = tuple(jax.tree.map(
+                        np.asarray,
+                        jax.device_get(fin_save[:5])  # graftlint: ignore[pipeline-sync]
+                    )) + (int(fin_save[5]),)
                 result.checkpoint = RouteCheckpoint(
                     occ=a[0], acc=a[1], paths=a[2], sink_delay=a[3],
                     all_reached=a[4], bb=a[5], crit=a[6],
@@ -2643,9 +2784,14 @@ class Router:
             prologue_s=prologue_s,
             windows_s=sum(row.route_time_s for row in result.stats),
             control_s=sum(ctl_s))
-        result.wirelength = int(wirelength_on_device(dev, paths))
-        result.paths = np.asarray(paths)
-        result.sink_delay = np.asarray(sink_delay)
+        result.wirelength = int(wirelength_on_device(
+            dev, jnp.concatenate([p.ravel() for p in paths])
+            if isinstance(paths, tuple) else paths))
+        # the host's result keeps its shape: [R, Smax] sink delays, and
+        # paths that answer paths[r][s] whatever the device's store is
+        result.paths = (ClassedPaths(paths, classes, N)
+                        if isinstance(paths, tuple) else np.asarray(paths))
+        result.sink_delay = _dense(sink_delay, classes, np.inf)
         result.occ = np.asarray(occ)
         self._obs_final(result)
         if opts.stats_dir:
@@ -2672,17 +2818,33 @@ class Router:
     def _planes_terminals(self, term):
         """Device entry tables for ``term`` (planes.PlanesTerminals),
         cached on id(term) across route() calls on the same terminals
-        — uploaded once, they stay device-resident."""
+        — uploaded once, they stay device-resident.  Returns (sinks,
+        the twelve tables, fan): what is dense in the sink axis (the
+        sinks, ``sink_uid``, the three ``direct_*``) one table a fanout
+        class where ``term`` has several, and ``fan`` = (each net's row
+        in its class's tables, each class's nets), None where it has
+        one."""
         if getattr(self, "_pt_key", None) != id(term):
             from .planes import build_planes_terminals
+            classes = term.fanout_classes
             pt = build_planes_terminals(
                 self.rr, term.source, term.sinks,
                 np.asarray(self.pg.cell_of_node), self.pg.ncells)
-            self._pt = tuple(jnp.asarray(a) for a in (
-                pt.opin_node, pt.entry_cell, pt.entry_oidx,
-                pt.entry_delay, pt.sink_uid, pt.uid_ucell,
-                pt.uid_upin, pt.uid_pcdel, pt.uid_pcrank, pt.direct_oidx,
-                pt.direct_ipin, pt.direct_delay))
+            self._pt = (
+                _by_class(term.sinks.astype(np.int32), classes),
+                tuple(jnp.asarray(a) for a in (
+                    pt.opin_node, pt.entry_cell, pt.entry_oidx,
+                    pt.entry_delay))
+                + (_by_class(pt.sink_uid, classes),)
+                + tuple(jnp.asarray(a) for a in (
+                    pt.uid_ucell, pt.uid_upin, pt.uid_pcdel,
+                    pt.uid_pcrank))
+                + tuple(_by_class(a, classes) for a in (
+                    pt.direct_oidx, pt.direct_ipin, pt.direct_delay)),
+                None if len(classes) == 1 else (
+                    jnp.asarray(term.class_rows()[1].astype(np.int32)),
+                    tuple(jnp.asarray(c.nets.astype(np.int32))
+                          for c in classes)))
             self._pt_key = id(term)
             self._pt_ref = term          # keep id(term) alive
         return self._pt
@@ -2754,10 +2916,16 @@ class Router:
         else:
             span0 = 8
         L = path_budget(span0, self.max_len)
+        # the path store and the sink delays: one table a fanout class
+        # ([R_c, S_c, L]; the bare array where there is one class)
+        classes = term.fanout_classes
         if resume is None:
-            paths = jnp.full((R, Smax, L), N, dtype=jnp.int32)
-            sink_delay = jnp.full((R, Smax), jnp.inf,
-                                  dtype=jnp.float32)
+            def store(tail, fill, dtype):
+                t = tuple(jnp.full((len(c.nets), c.width) + tail, fill,
+                                   dtype=dtype) for c in classes)
+                return t[0] if len(t) == 1 else t
+            paths = store((L,), N, jnp.int32)
+            sink_delay = store((), jnp.inf, jnp.float32)
             all_reached = jnp.zeros(R, dtype=bool)
             bb = jnp.asarray(np.stack(
                 [term.bb_xmin, term.bb_xmax, term.bb_ymin,
@@ -2769,19 +2937,18 @@ class Router:
             # allocation — the checkpoint IS the path store
             occ = self._put_node(jnp.asarray(resume.occ))
             acc = self._put_node(jnp.asarray(resume.acc))
-            paths = jnp.asarray(resume.paths)
+            paths = jax.tree.map(jnp.asarray, resume.paths)
             crit = resume.crit
-            sink_delay = jnp.asarray(resume.sink_delay)
+            sink_delay = jax.tree.map(jnp.asarray, resume.sink_delay)
             all_reached = jnp.asarray(resume.all_reached)
             bb = jnp.asarray(resume.bb)
         full_bb = jnp.asarray(np.array(
             [0, rr.grid.nx + 1, 0, rr.grid.ny + 1], dtype=np.int32))
         source_d = jnp.asarray(term.source.astype(np.int32))
-        sinks_d = jnp.asarray(term.sinks.astype(np.int32))
         nsinks_np = term.num_sinks.astype(np.int64)
         cx_np = ((term.bb_xmin + term.bb_xmax) // 2).astype(np.int64)
         cy_np = ((term.bb_ymin + term.bb_ymax) // 2).astype(np.int64)
-        planes_tbl = self._planes_terminals(term)
+        sinks_d, planes_tbl, fan_d = self._planes_terminals(term)
         result = RouteResult(False, 0, None, None, None, 0)
         # structured per-(window, category) logging (zlog/MDC
         # equivalent): no-op unless a stats_dir sink is configured.
@@ -2801,7 +2968,7 @@ class Router:
                 sink_delay, all_reached, bb, full_bb, source_d,
                 sinks_d, planes_tbl, nsinks_np, cx_np, cy_np,
                 result, B, mlog, crop=crop, resume=resume, rid=rid,
-                t_enter=t_enter)
+                t_enter=t_enter, fan_d=fan_d)
         return result
 
     def route(self, term: NetTerminals,

@@ -10,7 +10,7 @@ bb_factor-expanded bounding box the router restricts its search to
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import List, Optional
 
 import numpy as np
@@ -22,7 +22,9 @@ from .graph import RRGraph
 
 @dataclass
 class NetTerminals:
-    """Flat arrays over routable nets (padded to max fanout)."""
+    """Flat arrays over routable nets (padded to max fanout: the host's
+    tables stay dense, the device's are built a fanout class each,
+    ``fanout_classes``)."""
     net_ids: np.ndarray        # [R] packed-netlist net index per routable net
     source: np.ndarray         # [R] SOURCE rr-node
     sinks: np.ndarray          # [R, Smax] SINK rr-nodes, -1 padded
@@ -44,12 +46,100 @@ class NetTerminals:
     def max_sinks(self) -> int:
         return self.sinks.shape[1]
 
+    @property
+    def fanout_classes(self) -> List["FanoutClass"]:
+        """The nets by fanout class (``fanout_ladder`` of the sink
+        counts at the table's own width), built once."""
+        if self._classes is None:
+            self._classes = fanout_ladder(self.num_sinks, self.max_sinks)
+        return self._classes
+
+    _classes: Optional[List["FanoutClass"]] = field(
+        default=None, init=False, repr=False, compare=False)
+
+    def class_rows(self):
+        """([R] each net's fanout class, [R] its row in that class's
+        tables)."""
+        return class_rows(self.fanout_classes)
+
+    def sink_slots(self) -> np.ndarray:
+        """[R, Smax] the slot of every (net, sink) in the flat vector
+        of routed delays the STA reads: the classes' [R_c, S_c] tables
+        laid end to end (r * Smax + s where there is one class), -1
+        where the net's class has no such slot."""
+        cls, row = self.class_rows()
+        width = np.array([c.width for c in self.fanout_classes])
+        size = np.array([c.width * len(c.nets)
+                         for c in self.fanout_classes])
+        base = np.cumsum(size) - size
+        s = np.arange(self.max_sinks)[None, :]
+        slots = (base[cls] + row * width[cls])[:, None] + s
+        return np.where(s < width[cls][:, None], slots, -1)
+
+
+@dataclass
+class FanoutClass:
+    """The nets of one fanout class: what a net of it costs follows
+    ``width`` and not the widest net of the circuit."""
+    width: int                 # S_c: sink slots a net of the class has
+    nets: np.ndarray           # [R_c] routable-net indices, ascending
+
+
+def class_rows(classes: List[FanoutClass]):
+    """([R] each net's class, [R] its row in that class's tables)."""
+    R = sum(len(c.nets) for c in classes)
+    cls = np.zeros(R, dtype=np.int64)
+    row = np.zeros(R, dtype=np.int64)
+    for k, c in enumerate(classes):
+        cls[c.nets] = k
+        row[c.nets] = np.arange(len(c.nets))
+    return cls, row
+
+
+# a net of at most FANOUT_BASE sinks is of the first class, one of at
+# most FANOUT_BASE * FANOUT_STEP of the second, and so on; a class of
+# fewer than FANOUT_MIN_NETS nets joins the next one up
+FANOUT_BASE, FANOUT_STEP, FANOUT_MIN_NETS = 16, 4, 8
+
+
+def fanout_ladder(num_sinks: np.ndarray, width: int) -> List[FanoutClass]:
+    """The fanout classes of a problem, read from its own sink counts.
+
+    Rungs at FANOUT_BASE x FANOUT_STEP^k sinks: a net is of the first
+    rung that holds its sink count.  A populated rung of fewer than
+    FANOUT_MIN_NETS nets joins the next populated rung up (a class
+    costs a dispatch a window and a set of compiled programs; the
+    widest class takes what is left and joins nothing below it: that
+    would make every net of the class below pay its width).  A class
+    is as wide as its widest net, and the top class as wide as the
+    table it was read from (``width``; a subset of a circuit keeps the
+    circuit's).  A fixed function of the sink counts: every net of at
+    most FANOUT_BASE sinks is ONE class of the table's width."""
+    ns = np.asarray(num_sinks, dtype=np.int64)
+    rung = np.zeros(len(ns), dtype=np.int64)
+    cap = FANOUT_BASE
+    while len(ns) and ns.max() > cap:
+        rung += ns > cap
+        cap *= FANOUT_STEP
+    used = sorted(set(rung.tolist()))
+    for k, nxt in zip(used, used[1:]):
+        if (rung == k).sum() < FANOUT_MIN_NETS:
+            rung[rung == k] = nxt
+    out = []
+    for k in sorted(set(rung.tolist())) or [0]:
+        nets = np.flatnonzero(rung == k)
+        out.append(FanoutClass(int(ns[nets].max()) if len(nets) else 1,
+                               nets))
+    out[-1].width = int(width)
+    return out
+
 
 def net_terminals(pnl: PackedNetlist, rr: RRGraph, pos: np.ndarray,
                   bb_factor: int = 3) -> NetTerminals:
     """``pos`` is [num_blocks, 3] (x, y, subtile).  bb_factor default mirrors
-    SetupVPR.c:337.  Sets the gauge ``route.hetero.nets_hard``: the
-    routed nets with a terminal on a block of a typed column."""
+    SetupVPR.c:337.  Sets the gauge ``route.hetero.nets_hard`` (the
+    routed nets with a terminal on a block of a typed column) and the
+    three ``route.fanout.*`` gauges of the fanout classes."""
     routable = pnl.routed_nets
     R = len(routable)
     Smax = max((pnl.nets[i].num_sinks for i in routable), default=1)
@@ -86,13 +176,21 @@ def net_terminals(pnl: PackedNetlist, rr: RRGraph, pos: np.ndarray,
         bby0[r] = max(0, min(ys) - bb_factor)
         bby1[r] = min(ny + 1, max(ys) + bb_factor)
 
-    get_metrics().set_gauges({"route.hetero.nets_hard": int(hard.sum())})
-    return NetTerminals(
+    term = NetTerminals(
         net_ids=np.array(routable, dtype=np.int32),
         source=source, sinks=sinks, num_sinks=num_sinks,
         bb_xmin=bbx0, bb_xmax=bbx1, bb_ymin=bby0, bb_ymax=bby1,
         hard=hard,
     )
+    classes = term.fanout_classes
+    get_metrics().set_gauges({
+        "route.hetero.nets_hard": int(hard.sum()),
+        "route.fanout.max_sinks": int(Smax),
+        "route.fanout.classes": len(classes),
+        # real sinks over the sink slots of all class tables
+        "route.fanout.sink_slot_fill": int(num_sinks.sum()) / max(
+            1, sum(c.width * len(c.nets) for c in classes))})
+    return term
 
 
 def subset_terminals(term: NetTerminals, frac: float,

@@ -76,9 +76,11 @@ class SliceEntry:
 def _mergeable(req: WindowDispatchRequest) -> bool:
     """A request can join a multi program iff its window runs the
     single-device, host-crit configuration route_window_planes_multi
-    supports (no mesh sharding, no device-resident STA)."""
+    supports (no mesh sharding, no device-resident STA, one fanout
+    class: a job's tables are bare arrays there)."""
     kw = req.f_kwargs
-    return kw.get("mesh") is None and kw.get("tdev") is None
+    return (kw.get("mesh") is None and kw.get("tdev") is None
+            and kw.get("fan") is None)
 
 
 def _shared_key(req: WindowDispatchRequest):

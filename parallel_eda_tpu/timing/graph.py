@@ -40,6 +40,14 @@ from ..rr.terminals import NetTerminals
 # stands in for VPR7's intra-pb interconnect delays
 T_LOCAL = 150e-12
 
+# the backward sweep reads the out-edge ELL [T, D] once a level, and D
+# is the widest tnode's: a primary input that feeds 260 LUT pins makes
+# every tnode pay for 285 out-edge slots.  A tnode's out-edges past
+# this many go to a flat overflow list the sweep folds in by a
+# scatter-min (timing/sta.py); no tnode of the circuits whose widest
+# net has a dozen sinks comes near it (their D is 6 to 19)
+OUT_ELL_CAP = 32
+
 
 def _ell(num_nodes: int, ends: np.ndarray, other: np.ndarray,
          const: np.ndarray, ridx: np.ndarray):
@@ -80,7 +88,8 @@ class TimingGraph:
     out_valid: np.ndarray
     arrival0: np.ndarray       # f32 [T] startpoint seeds (-inf elsewhere)
     is_endpoint: np.ndarray    # bool [T]
-    num_route_slots: int       # R * Smax (size of the routed-delay vector)
+    num_route_slots: int       # size of the routed-delay vector: R * Smax,
+    #                            or the fanout classes' R_c * S_c summed
     # diagnostics: tnode -> primitive index
     tnode_prim: np.ndarray
     # multi-clock (SDC): endpoint -> clock-domain index into ``domains``
@@ -92,6 +101,13 @@ class TimingGraph:
     # outpads by both the pad name and the net they read)
     inpad_tnode: dict = None
     outpad_tnode: dict = None
+    # [R, Smax] the (net, sink) -> routed-delay slot map where the
+    # terminals have several fanout classes (NetTerminals.sink_slots);
+    # None where slot = r * Smax + s
+    route_slots: np.ndarray = None
+    # the out-edges past OUT_ELL_CAP a tnode, flat: (src [E], dst [E],
+    # const [E], ridx [E]); None where no tnode has that many
+    out_overflow: tuple = None
 
 
 def build_timing_graph(nl: LogicalNetlist, pnl: PackedNetlist,
@@ -99,7 +115,7 @@ def build_timing_graph(nl: LogicalNetlist, pnl: PackedNetlist,
                        t_local: float = T_LOCAL) -> TimingGraph:
     """Build the DAG.  ``term`` supplies the routed-net numbering the delay
     vector uses; pnl supplies prim->block placement of the packing."""
-    R, Smax = term.sinks.shape
+    slots = term.sink_slots()
 
     block_of_prim = {}
     for bi, b in enumerate(pnl.blocks):
@@ -111,7 +127,7 @@ def build_timing_graph(nl: LogicalNetlist, pnl: PackedNetlist,
     conn_ridx = {}
     for ni, r in r_of_net.items():
         for s, pin in enumerate(pnl.nets[ni].sinks):
-            conn_ridx[(ni, pin.block)] = r * Smax + s
+            conn_ridx[(ni, pin.block)] = int(slots[r, s])
 
     clocks = set(nl.clocks)
 
@@ -254,15 +270,25 @@ def build_timing_graph(nl: LogicalNetlist, pnl: PackedNetlist,
 
     in_src, in_const, in_ridx, in_valid = _ell(T, e_dst, e_src, e_const,
                                                e_ridx)
-    out_dst, out_const, out_ridx, out_valid = _ell(T, e_src, e_dst, e_const,
-                                                   e_ridx)
+    # a tnode's first OUT_ELL_CAP out-edges in the ELL (starts and
+    # order_e are the levelisation's: the edges grouped by source), the
+    # rest flat
+    rank = np.zeros(len(e_src), dtype=np.int64)
+    rank[order_e] = np.arange(len(e_src)) - starts[srcs_sorted]
+    over = rank >= OUT_ELL_CAP
+    out_overflow = ((e_src[over], e_dst[over], e_const[over], e_ridx[over])
+                    if over.any() else None)
+    out_dst, out_const, out_ridx, out_valid = _ell(
+        T, e_src[~over], e_dst[~over], e_const[~over], e_ridx[~over])
     return TimingGraph(
         num_tnodes=T, depth=depth,
         in_src=in_src, in_const=in_const, in_ridx=in_ridx, in_valid=in_valid,
         out_dst=out_dst, out_const=out_const, out_ridx=out_ridx,
         out_valid=out_valid,
         arrival0=arrival0, is_endpoint=is_endpoint,
-        num_route_slots=R * Smax,
+        num_route_slots=int(slots.max()) + 1 if slots.size else 0,
+        route_slots=(slots if len(term.fanout_classes) > 1 else None),
+        out_overflow=out_overflow,
         tnode_prim=np.array(tnode_prim, dtype=np.int32),
         endpoint_domain=endpoint_domain, domains=domains,
         inpad_tnode=inpad_tnode,

@@ -17,7 +17,7 @@ a max-reduce, closing the analyze_timing -> update_sink_criticalities loop
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +41,9 @@ class DeviceTimingGraph:
     out_valid: jnp.ndarray
     arrival0: jnp.ndarray
     is_endpoint: jnp.ndarray
+    # TimingGraph.out_overflow: the out-edges past the ELL's width,
+    # flat (src, dst, const, ridx); None (no leaf) where there are none
+    out_overflow: Optional[Tuple[jnp.ndarray, ...]] = None
 
 
 def to_device(tg: TimingGraph) -> DeviceTimingGraph:
@@ -52,6 +55,8 @@ def to_device(tg: TimingGraph) -> DeviceTimingGraph:
         out_valid=jnp.asarray(tg.out_valid),
         arrival0=jnp.asarray(tg.arrival0),
         is_endpoint=jnp.asarray(tg.is_endpoint),
+        out_overflow=(None if tg.out_overflow is None else tuple(
+            jnp.asarray(a) for a in tg.out_overflow)),
     )
 
 
@@ -81,6 +86,12 @@ def sta_crit(dev: DeviceTimingGraph, route_delay: jnp.ndarray,
 
     d_in = dev.in_const + rd[dev.in_ridx]          # [T, D] (-1 -> last slot)
     d_out = dev.out_const + rd[dev.out_ridx]
+    if dev.out_overflow is not None:
+        # the widest tnodes' out-edges past the ELL: one more candidate
+        # a level each, folded in by a scatter-min (the same min, so the
+        # required times are the full table's)
+        o_src, o_dst, o_const, o_ridx = dev.out_overflow
+        d_ovf = o_const + rd[o_ridx]
 
     def fwd(_, arr):
         cand = arr[dev.in_src] + d_in
@@ -109,10 +120,19 @@ def sta_crit(dev: DeviceTimingGraph, route_delay: jnp.ndarray,
             cand_all = jnp.concatenate([cand, req0[:, None]], axis=1)
             per_all = jnp.concatenate([cper, per0[:, None]], axis=1)
             j = jnp.argmin(cand_all, axis=1)
-            return (jnp.take_along_axis(cand_all, j[:, None],
-                                        axis=1)[:, 0],
-                    jnp.take_along_axis(per_all, j[:, None],
-                                        axis=1)[:, 0])
+            new = jnp.take_along_axis(cand_all, j[:, None], axis=1)[:, 0]
+            nper = jnp.take_along_axis(per_all, j[:, None], axis=1)[:, 0]
+            if dev.out_overflow is not None:
+                # an overflow edge that beats the ELL's best brings its
+                # domain's period (the widest among equals)
+                c_o = req[o_dst] - d_ovf
+                best = new.at[o_src].min(c_o)
+                wins = (c_o == best[o_src]) & (c_o < new[o_src])
+                nper = jnp.where(best < new, jnp.zeros_like(per).at[
+                    jnp.where(wins, o_src, per.shape[0])].max(
+                    per[o_dst], mode="drop"), nper)
+                new = best
+            return new, nper
 
         req, per = jax.lax.fori_loop(0, depth, bwd, (req0, per0))
         denom = jnp.where(per > 0, per, jnp.maximum(dmax, 1e-30))[:, None]
@@ -122,7 +142,10 @@ def sta_crit(dev: DeviceTimingGraph, route_delay: jnp.ndarray,
         def bwd(_, req):
             cand = req[dev.out_dst] - d_out
             cand = jnp.where(dev.out_valid, cand, jnp.inf)
-            return jnp.minimum(req0, cand.min(axis=1))
+            new = jnp.minimum(req0, cand.min(axis=1))
+            if dev.out_overflow is not None:
+                new = new.at[o_src].min(req[o_dst] - d_ovf)
+            return new
 
         req = jax.lax.fori_loop(0, depth, bwd, req0)
         denom = jnp.maximum(dmax, 1e-30)
@@ -220,14 +243,22 @@ class TimingAnalyzer:
         also records crit_path_delay and (SDC mode) worst_slack, both in
         seconds."""
         R, Smax = sink_delay.shape
-        flat = np.append(sink_delay.ravel().astype(np.float32), 0.0)
+        slots = self.tg.route_slots
+        if slots is None:
+            flat = np.append(sink_delay.ravel().astype(np.float32), 0.0)
+        else:
+            # several fanout classes: the classes' tables end to end
+            flat = np.zeros(self.tg.num_route_slots + 1, np.float32)
+            flat[slots[slots >= 0]] = sink_delay[slots >= 0]
         crit, dmax, worst, _ = sta_sweep(
             self.dev, jnp.asarray(flat), self.tg.depth, self.crit_exp,
             self.max_crit, req_seed=self._req_seed,
             use_sdc=self._req_seed is not None)
         self.crit_path_delay = float(dmax)
         self.worst_slack = float(worst)
-        return np.asarray(crit).reshape(R, Smax)
+        if slots is None:
+            return np.asarray(crit).reshape(R, Smax)
+        return np.where(slots >= 0, np.asarray(crit)[slots], 0.0)
 
     def timing_cb(self, result) -> np.ndarray:
         """Router timing_cb hook (router.py Router.route); stamps the

@@ -376,14 +376,20 @@ def main():
                  + (f.term.bb_ymax - f.term.bb_ymin)).max())
     L_bb = path_budget(span0, 4 * (f.rr.grid.nx + f.rr.grid.ny) + 64)
 
-    def model(R_, S_, nc_, N_, U_, C_, L_):
+    # the path store and the sink index are kept a fanout class
+    # (rr/terminals.py fanout_ladder): their size follows the classes'
+    # sink slots, sum of R_c * S_c, not R * Smax
+    slots = sum(c.width * len(c.nets) for c in f.term.fanout_classes)
+
+    def model(slots_, nc_, N_, U_, C_, L_):
         return [
             ("planes dist/pred/w (per batch)", "3*B*Ncells*4",
              3 * Bt * nc_ * 4),
             ("congestion cc (per batch)", "B*Ncells*4", Bt * nc_ * 4),
             ("occ/acc/history", "N*8", N_ * 8),
-            ("paths (bb-adaptive L)", "R*S*L_bb*4", R_ * S_ * L_ * 4),
-            ("sink uid index", "R*S*4", R_ * S_ * 4),
+            ("paths (a store a fanout class, bb-adaptive L)",
+             "sum(R_c*S_c)*L_bb*4", slots_ * L_ * 4),
+            ("sink uid index", "sum(R_c*S_c)*4", slots_ * 4),
             ("unique-sink tables (cells x pins)", "U*(P*C*8+(P+C)*4)",
              U_ * (P * C_ * 8 + (P + C_) * 4)),
             ("planes masks/delays (static)", "~12*Ncells*4", 12 * nc_ * 4),
@@ -398,16 +404,17 @@ def main():
     print("| structure | formula | this circuit |")
     print("|---|---|---|")
     total = 0
-    for name, formula, b in model(R, S, nc, N, U, C, L_bb):
+    for name, formula, b in model(slots, nc, N, U, C, L_bb):
         total += b
         print(f"| {name} | {formula} | {b/1e6:.1f} MB |")
     print(f"| **total** | | **{total/1e6:.1f} MB** |")
 
     # Titan proxy: 1e6 rr nodes, 1e5 nets (bitcoin_miner-class,
     # BASELINE.md ladder step 5): 300x300 grid, W=80, avg fanout ~4
-    # (S here is the batch-padded fanout class cap, not the global max:
-    # batches are fanout-classed so the dominant population routes at
-    # S~8; L_bb ~ a few hundred for bb-local nets)
+    # (S here is the width of the dominant fanout class, not the global
+    # max: the tables are kept a class, so the population routes at
+    # S~8 and a handful of wide nets add R_c * S_c of their own; L_bb ~
+    # a few hundred for bb-local nets)
     gx = 300
     W_t = 80
     nc_t = 2 * W_t * gx * (gx + 1)
@@ -428,7 +435,7 @@ def main():
     print("| structure | bytes |")
     print("|---|---|")
     tot = 0
-    for name, formula, b in model(R_t, S_t, nc_t, N_t, U_t, C_t, L_t):
+    for name, formula, b in model(R_t * S_t, nc_t, N_t, U_t, C_t, L_t):
         tot += b
         print(f"| {name} | {b/1e9:.2f} GB |")
     print(f"| **total** | **{tot/1e9:.2f} GB** |")
