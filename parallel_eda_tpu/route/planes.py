@@ -1356,12 +1356,23 @@ def sink_pick(dist, pin_congj, crit_w, cw, sink_tabs):
 
     A distance is read once per distinct CELL of a sink, [B, S, C]
     element reads, and the hops are formed dense over (pin, cell) and
-    reduced in one pass, with no second gather."""
-    b_ucell, b_upin, b_pcdel, b_pcrank = sink_tabs
-    B, S, P, C = b_pcrank.shape
+    reduced in one pass, with no second gather.  This is the DENSE form:
+    every slot of the batch, whatever it holds (sink_pick_wave reads
+    the live slots only where few are)."""
+    b_ucell = sink_tabs[0]
+    B, S, C = b_ucell.shape
     dist_p1 = jnp.concatenate([dist, jnp.full((B, 1), INF)], axis=1)
     dist_c = jnp.take_along_axis(
         dist_p1, b_ucell.reshape(B, -1), axis=1).reshape(B, S, C)
+    return _cheapest_hops(dist_c, pin_congj, crit_w, cw, sink_tabs)
+
+
+def _cheapest_hops(dist_c, pin_congj, crit_w, cw, sink_tabs):
+    """sink_pick's reduction: the hops of every sink slot formed from
+    the distances of its cells ``dist_c`` [B, S, C] and reduced to the
+    cheapest, (sink_dist, ent_cell, ent_ipin, ent_wdel) each [B, S]."""
+    b_ucell, b_upin, b_pcdel, b_pcrank = sink_tabs
+    B, S, P, C = b_pcrank.shape
     cand = jnp.where(
         b_pcrank < RANK_PAD,
         dist_c[:, :, None, :] + crit_w[:, None, None, None] * b_pcdel
@@ -1388,6 +1399,81 @@ def sink_pick(dist, pin_congj, crit_w, cw, sink_tabs):
     ent_wdel = jnp.take_along_axis(b_pcdel.reshape(B, S, P * C), pc,
                                    axis=2)[:, :, 0]
     return sink_dist, ent_cell, ent_ipin, ent_wdel
+
+
+def live_pick_rungs(B: int, S: int):
+    """The live pick's list widths for a batch of B x S sink slots,
+    ascending: an eighth, a quarter and a half of the slots, each
+    rounded up to whole sublanes of 8, those that are narrower than the
+    batch.  A function of the shape alone."""
+    n = B * S
+    return tuple(sorted({m for m in (-(-n // (8 * d)) * 8 for d in (8, 4, 2))
+                         if m < n}))
+
+
+def sink_pick_live(dist, pin_congj, crit_w, cw, sink_tabs, remaining,
+                   M: int):
+    """sink_pick over the batch's LIVE sink slots alone (``remaining``
+    [B, S]: a real sink of a net being routed that no wave has reached;
+    at most ``M`` of them): the live flat slots b * S + s are listed
+    densely in slot order, their M rows of the tables fetched (row
+    gathers), M x C distances read where the dense form reads
+    B x S x C, the hops reduced by sink_pick's own reduction and the
+    winners written back to [B, S].  A slot that is not live gets
+    sink_dist INF (a wave picks live slots only) and zeros for the
+    rest; a live slot what sink_pick gives it, bit for bit."""
+    B, S, P, C = sink_tabs[3].shape
+    n, ncells = B * S, dist.shape[1]
+    # the m-th live slot is past as many slots as have fewer than m + 1
+    # live up to and including themselves; n past the live count
+    upto = jnp.cumsum(remaining.reshape(n), dtype=jnp.int32)
+    slot = (upto[None, :] <= jnp.arange(M, dtype=jnp.int32)[:, None]).sum(
+        axis=1, dtype=jnp.int32)                               # [M]
+    at = jnp.minimum(slot, n - 1)
+    net = at // S
+    rows = tuple(t.reshape((n,) + t.shape[2:])[at][:, None]
+                 for t in sink_tabs)                           # [M, 1, ...]
+    ucell = rows[0][:, 0]                                      # [M, C]
+    dist_c = jnp.where(
+        ucell < ncells,
+        jnp.take(dist.reshape(-1), net[:, None] * ncells
+                 + jnp.minimum(ucell, ncells - 1), mode="clip"), INF)
+    picked = _cheapest_hops(
+        dist_c[:, None], pin_congj.reshape(n, P)[at][:, None],
+        crit_w[net], cw[net], rows)                            # [M, 1] each
+    return tuple(
+        jnp.full((n,), fill, v.dtype).at[slot].set(
+            v[:, 0], mode="drop").reshape(B, S)
+        for v, fill in zip(picked, (INF, 0, 0, 0.0)))
+
+
+def sink_pick_wave(dist, pin_congj, crit_w, cw, sink_tabs, remaining,
+                   rungs):
+    """The wave's sink pick at the width its live slots need: the
+    narrowest of ``rungs`` (live_pick_rungs) that holds them all,
+    sink_pick_live there, and past the widest the dense sink_pick.  The
+    rung is one switch on the live count, which only falls within a
+    step.  Returns sink_pick's four and the sink ROWS whose distances
+    were read (C elements each): the rung's M, or B x S."""
+    B, S = remaining.shape
+
+    def dense(*a):
+        return sink_pick(*a[:5]) + (jnp.int32(B * S),)
+
+    def live(M, *a):
+        return sink_pick_live(*a, M) + (jnp.int32(M),)
+
+    args = (dist, pin_congj, crit_w, cw, sink_tabs, remaining)
+    if not rungs:
+        return dense(*args)
+    count = remaining.sum(dtype=jnp.int32)
+    return lax.switch(
+        jnp.sum(count > jnp.array(rungs), dtype=jnp.int32),
+        [functools.partial(live, M) for M in rungs] + [dense], *args)
+
+
+# entries of _step_core's ledger vector (scal's SCAL_S_EXEC.. tail)
+STEP_LEDGER_LEN = 7
 
 
 def wave_segments(num_waves: int, group: int, doubling: bool):
@@ -1446,10 +1532,12 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
     net.
 
     Returns (paths, sink_delay, all_reached, bb, occ, n_active, st):
-    ``st`` [5] int32 is the step's ledger — relaxation sweeps executed,
-    sweeps that improved a distance, traceback walk steps run, walk
-    steps budgeted, waves executed (one relaxation each) — in the
-    order of scal's SCAL_S_EXEC.. tail."""
+    ``st`` [STEP_LEDGER_LEN] int32 is the step's ledger — relaxation
+    sweeps executed, sweeps that improved a distance, traceback walk
+    steps run, walk steps budgeted, waves executed (one relaxation
+    each), sink rows whose distances the picks read (sink_pick_wave;
+    cells_per_sink elements a row) and the B * S a wave of the dense
+    pick reads — in the order of scal's SCAL_S_EXEC.. tail."""
     N = dev.num_nodes
     R = all_reached.shape[0]
     B = sel.shape[0]
@@ -1496,6 +1584,9 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             b_dipin = c(b_dipin, "net", None)
             b_ddel = c(b_ddel, "net", None)
 
+        # the sink pick compacts across the batch axis, which a mesh
+        # shards: there the dense pick alone
+        pick_rungs = live_pick_rungs(B, S) if mesh is None else ()
         arangeB = jnp.arange(B)
         O = b_opin.shape[1]
         Ko = b_ecell.shape[1]
@@ -1591,8 +1682,9 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             st = st.at[:2].add(rst)
 
         with device_scope("route.dev.sink_pick"):
-            sink_dist, ent_cell, ent_ipin, ent_wdel = sink_pick(
-                dist, pin_congj, crit_w, cw, sink_tabs)
+            (sink_dist, ent_cell, ent_ipin, ent_wdel,
+             sink_rows) = sink_pick_wave(dist, pin_congj, crit_w, cw,
+                                         sink_tabs, remaining, pick_rungs)
 
             # --- dedicated direct candidate (OPIN->IPIN->SINK, bypassing
             # the fabric): competes with the relaxation candidates; the
@@ -1646,9 +1738,11 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                 pred, wenter, noc_p1, pick_cell,
                 ~pick_valid | pick_direct, Kw)
             # the ledger's walk half: steps this wave ran, of its
-            # budget, and the wave itself
+            # budget, the wave itself, and the pick's sink rows read of
+            # the batch's
             st = st.at[2:].add(jnp.stack([wsteps, jnp.int32(Kw),
-                                          jnp.int32(1)]))
+                                          jnp.int32(1), sink_rows,
+                                          jnp.int32(B * S)]))
             # a walk is complete iff it reached a pred==self cell in budget
             nxt_last = jnp.take_along_axis(
                 pred, jnp.clip(cur, 0, ncells - 1), axis=1)
@@ -1751,7 +1845,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                   jnp.full((B, S, max_len), N, jnp.int32),
                   jnp.full((B, S), INF, jnp.float32),
                   jnp.zeros((B, S), bool),
-                  jnp.zeros((5,), jnp.int32))
+                  jnp.zeros((STEP_LEDGER_LEN,), jnp.int32))
     state = state0
     for lo, hi, width in wave_segments(num_waves, group, doubling):
         state = lax.fori_loop(lo, hi, functools.partial(wave_body, width),
@@ -2005,9 +2099,10 @@ def _window_body(
     steps_useful are the MEASURED relaxation-sweep counters summed over
     every executed group/wave of the window (executed trips of the
     bounded while_loop, and the subset that improved some distance);
-    scal's last three entries are the traceback walk's ledger summed
-    the same way (steps run, and the Kw budgeted per executed wave) and
-    the executed waves themselves;
+    scal's last five entries are the traceback walk's ledger summed
+    the same way (steps run, and the Kw budgeted per executed wave),
+    the executed waves themselves and the sink pick's (sink rows read,
+    and the B * S per executed wave a dense pick reads);
     ``status``/``scal`` repack the per-net mask/color/bb fields and the
     scalar counters into two small int32 arrays so the pipelined driver
     can pull the whole window summary with one async copy
@@ -2138,7 +2233,7 @@ def _window_body(
         (occ, acc, paths, sink_delay, all_reached, bb, pres0,
          jnp.int32(0), jnp.int32(0), crit_all,
          jnp.full(K_iters, jnp.nan, jnp.float32),
-         jnp.zeros((5,), jnp.int32)))
+         jnp.zeros((STEP_LEDGER_LEN,), jnp.int32)))
     s_exec, s_useful = led[0], led[1]
 
     rrm, colors = _mis_colors(dev, occ, paths, all_reached, topk, n_colors,
@@ -2427,12 +2522,14 @@ SCAL_OVER_TOTAL = 1
 SCAL_NROUTES = 2
 SCAL_NEXEC = 3
 SCAL_MAX_SPAN = 4
-SCAL_S_EXEC = 5       # 5..9: _step_core's ledger vector, in its order
+SCAL_S_EXEC = 5       # 5..11: _step_core's ledger vector, in its order
 SCAL_S_USEFUL = 6
 SCAL_WALK_STEPS = 7
 SCAL_WALK_BUDGET = 8
 SCAL_WAVES = 9
-SCAL_LEN = 10
+SCAL_SINK_ROWS = 10
+SCAL_SINK_ROWS_DENSE = 11
+SCAL_LEN = 12
 
 
 def unpack_window_status(status):

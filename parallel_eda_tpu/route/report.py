@@ -78,7 +78,9 @@ def format_window_table(result) -> str:
     each window IS (``kind``), what it did and what it cost, and
     whether its result is in the route returned (``kept``); on a route
     with fanout classes two more columns, the sweeps and waves of the
-    batches of a class above the first.  Under it
+    batches of a class above the first; on a route of the window
+    program one more, the distance elements its sink picks read as a
+    share of what dense picks read in the same waves.  Under it
     the route's wall by named interval where the result carries one
     (``RouteResult.wall``: the four add up to the ``route`` stage)."""
     head = ("window", "iter", "kind", "overused", "nets", "seconds",
@@ -112,6 +114,14 @@ def format_window_table(result) -> str:
         wide.append(tuple(sum(col) for col in zip(*wide)))
         head += ("sweeps_wide", "waves_wide")
         rows = [r + w for r, w in zip(rows, wide)]
+    reads = [(s.sink_reads, s.sink_reads_dense) for s in result.stats]
+    if any(dense for _, dense in reads):
+        # a route of the window program: of the distance elements dense
+        # sink picks read in the window's waves, what its picks read
+        reads.append(tuple(sum(col) for col in zip(*reads)))
+        head += ("pick_read%",)
+        rows = [r + (f"{100.0 * got / dense:.1f}" if dense else "-",)
+                for r, (got, dense) in zip(rows, reads)]
     cells = [head] + [tuple(str(c) for c in r) for r in rows]
     width = [max(len(r[i]) for r in cells) for i in range(len(head))]
     lines = ["  ".join(c.ljust(w) if i == 2 else c.rjust(w)

@@ -282,6 +282,10 @@ class RouteStats:
     fanout_class: int = 0
     waves_wide: int = 0
     relax_steps_wide: int = 0
+    # distance elements the waves' sink picks read, and what dense
+    # picks read in the same waves (RouteResult.total_sink_reads*)
+    sink_reads: int = 0
+    sink_reads_dense: int = 0
 
 
 @dataclass
@@ -352,6 +356,13 @@ class RouteResult:
     # wave).  A share near 1 means paths are pressing on the budget.
     total_walk_steps: int = 0
     total_walk_budget: int = 0
+    # sink-pick ledger (windowed planes program): distance elements the
+    # waves' picks read (the live rung's M sink rows, or all B * S on
+    # the dense rung, x cells_per_sink), and what a dense pick reads in
+    # the same waves.  Their ratio is how much of the read the live
+    # list saves.
+    total_sink_reads: int = 0
+    total_sink_reads_dense: int = 0
     # executed waves of the windowed planes program (one relaxation to
     # a fixpoint each): total_relax_steps over it is sweeps a wave
     total_waves: int = 0
@@ -1341,12 +1352,14 @@ class Router:
         value captured at that window's control step — later control
         mutations (pres, plateau state, widened_nets) cannot leak in."""
         from .planes import (SCAL_NEXEC, SCAL_NROUTES, SCAL_S_EXEC,
-                             SCAL_S_USEFUL, SCAL_WALK_BUDGET,
+                             SCAL_S_USEFUL, SCAL_SINK_ROWS,
+                             SCAL_SINK_ROWS_DENSE, SCAL_WALK_BUDGET,
                              SCAL_WALK_STEPS, SCAL_WAVES)
 
         w_steps = w_useful = w_steps_crop = 0
         nroutes = nexec = w_waves = 0
         w_steps_wide = w_waves_wide = 0
+        w_sink_rows = w_sink_rows_dense = 0
         rung_classes = bk.get("rung_classes") or [0] * len(
             bk["rung_scals"])
         mesh_info = bk.get("mesh")
@@ -1360,6 +1373,8 @@ class Router:
             result.total_walk_steps += int(v[SCAL_WALK_STEPS])
             result.total_walk_budget += int(v[SCAL_WALK_BUDGET])
             w_waves += int(v[SCAL_WAVES])
+            w_sink_rows += int(v[SCAL_SINK_ROWS])
+            w_sink_rows_dense += int(v[SCAL_SINK_ROWS_DENSE])
             if cropped:
                 w_steps_crop += int(v[SCAL_S_EXEC])
             if rung_classes[ri]:
@@ -1381,6 +1396,11 @@ class Router:
         result.total_relax_steps_cropped += w_steps_crop
         result.total_relax_steps_wide += w_steps_wide
         result.total_waves += w_waves
+        # the device counts sink rows; a row is sink_cells elements
+        sink_reads = w_sink_rows * bk["sink_cells"]
+        sink_reads_dense = w_sink_rows_dense * bk["sink_cells"]
+        result.total_sink_reads += sink_reads
+        result.total_sink_reads_dense += sink_reads_dense
         row = RouteStats(
             bk["it_done"], bk["n_over"], bk["over_total"], bk["ndirty"],
             bk["tw1"] - bk["tw0"], relax_steps=w_steps, batches=nexec,
@@ -1391,7 +1411,8 @@ class Router:
             net_routes=nroutes, stall_s=bk["stall_s"],
             plan_s=bk["plan_s"], dispatch_ms=bk["dispatch_ms"],
             fanout_class=max(rung_classes),
-            waves_wide=w_waves_wide, relax_steps_wide=w_steps_wide)
+            waves_wide=w_waves_wide, relax_steps_wide=w_steps_wide,
+            sink_reads=sink_reads, sink_reads_dense=sink_reads_dense)
         result.stats.append(row)
         reg = get_metrics()
         reg.counter(f"route.window.seconds_total.{row.kind}").inc(
@@ -1668,6 +1689,9 @@ class Router:
         cls_of = term.class_rows()[0]
         # what a window program of a route with classes is told besides
         fan_kw = {} if fan_d is None else {"fan": fan_d}
+        # distance elements a sink row of the pick's tables holds
+        # (planes_tbl[5] is uid_ucell [U + 1, C])
+        sink_cells = int(planes_tbl[5].shape[1])
 
         # device-fused STA config (analyzer mode): the full timing sweep
         # runs between iterations inside the window program
@@ -2539,6 +2563,7 @@ class Router:
                 sweep_boost=sweep_boost, widened=result.widened_nets,
                 dmax_hist=dmax_hist,
                 rung_classes=[c for _, _, c in dispatch],
+                sink_cells=sink_cells,
                 # occ snapshot for the congestion top-k: inline in
                 # --sync (booked before the next dispatch donates the
                 # array), a non-donated async-readback copy when

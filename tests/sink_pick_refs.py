@@ -8,6 +8,8 @@ Kept as the REFERENCE the factored tables and pick of ``route/planes.py``
 bit for bit, alone and inside a whole route (not a test file: imported
 by tests/test_planes.py and tests/test_cost_field_forms.py)."""
 
+import contextlib
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -105,3 +107,63 @@ def flat_forms(K, N):
             flat_tabs_of(sink_tabs, K, dist.shape[1], N))
 
     return sink_pin_costs, sink_pick
+
+
+# ---- the live pick's ladder forced to the dense rung (ISSUE 38) ----
+
+@contextlib.contextmanager
+def dense_ladder():
+    """Inside: ``planes.live_pick_rungs`` gives the empty ladder, so
+    every wave of every program traced takes the DENSE sink_pick.  The
+    jitted window programs are dropped on the way in and out (they hold
+    what they traced)."""
+    from parallel_eda_tpu.route import planes
+
+    def drop_programs():
+        for prog in (planes.route_window_planes,
+                     planes.route_window_planes_fused,
+                     planes.route_window_planes_multi,
+                     planes.route_batch_resident_planes):
+            prog.clear_cache()
+
+    built = planes.live_pick_rungs
+    drop_programs()
+    planes.live_pick_rungs = lambda B, S: ()
+    try:
+        yield
+    finally:
+        planes.live_pick_rungs = built
+        drop_programs()
+
+
+# fields of a RouteResult / a stats row that a clock, a process-wide id
+# or the pick's own ledger moves
+_RESULT_SKIP = {"paths", "sink_delay", "occ", "stats", "wall", "route_id",
+                "checkpoint", "total_sink_reads", "total_sink_reads_dense"}
+_ROW_SKIP = {"route_time_s", "stall_s", "plan_s", "dispatch_ms",
+             "control_s", "sink_reads", "sink_reads_dense"}
+
+
+def assert_same_route(res, ref):
+    """``res`` and ``ref`` (RouteResults) are one route: paths, sink
+    delays and occupancy node for node, every counter and every field
+    of every window row but the clocks and the pick's own two."""
+    import dataclasses
+
+    def same(a, b):
+        if isinstance(a, (tuple, list)):
+            return len(a) == len(b) and all(map(same, a, b))
+        return np.array_equal(np.asarray(a), np.asarray(b),
+                              equal_nan=np.asarray(a).dtype.kind == "f")
+
+    for name in ("paths", "sink_delay", "occ"):
+        assert same(getattr(res, name), getattr(ref, name)), name
+    for f in dataclasses.fields(res):
+        if f.name not in _RESULT_SKIP:
+            assert getattr(res, f.name) == getattr(ref, f.name), f.name
+    assert len(res.stats) == len(ref.stats)
+    for row, row_ref in zip(res.stats, ref.stats):
+        for f in dataclasses.fields(row):
+            if f.name not in _ROW_SKIP:
+                a, b = getattr(row, f.name), getattr(row_ref, f.name)
+                assert a == b or (a != a and b != b), (row.window, f.name)

@@ -13,7 +13,9 @@ import pytest
 from cost_field_refs import entry_fields_gather, node_cost_field_gather
 from mis_colors_refs import mis_colors_searchsorted
 from parallel_eda_tpu.route.planes import (_mis_colors, entry_fields,
+                                           live_pick_rungs,
                                            node_cost_field, sink_pick,
+                                           sink_pick_live, sink_pick_wave,
                                            sink_pin_costs)
 from sink_pick_refs import sink_pick_flat, sink_pin_costs_flat
 
@@ -21,11 +23,25 @@ from sink_pick_refs import sink_pick_flat, sink_pin_costs_flat
 SHAPES = {"route_relaxed": (64, 160, 20240, 29656),
           "route_k6n10_relaxed": (64, 24, 16896, 13560),
           "route_tight": (64, 128, 16192, 25608)}
-# (S, K, C, P) of the same cells' sink tables: sink slots a net, flat
-# candidates a sink, distinct wire cells and pins among them
+# (S, K, C, P) of the cells' sink tables: sink slots a net, flat
+# candidates a sink, distinct wire cells and pins among them;
+# route_fanout's two fanout classes apart
 SINK_SHAPES = {"route_relaxed": (8, 400, 80, 10),
                "route_k6n10_relaxed": (7, 1320, 256, 33),
-               "route_tight": (8, 320, 64, 10)}
+               "route_tight": (8, 320, 64, 10),
+               "route_scale": (9, 1716, 352, 33),
+               "route_hetero": (13, 1600, 256, 40),
+               "route_fanout": (15, 1056, 224, 33),
+               "route_fanout.wide": (204, 1056, 224, 33)}
+# (B, ncells) of the batches the live sink pick runs on: the six cells'
+# and the wide class's at two of its batch widths
+LIVE_SHAPES = {**{cell: (B, ncells)
+                  for cell, (B, _, ncells, _) in SHAPES.items()},
+               "route_scale": (64, 66880),
+               "route_hetero": (64, 83200),
+               "route_fanout": (64, 47040),
+               "route_fanout.wide": (16, 47040),
+               "route_fanout.wide32": (32, 47040)}
 # (R, Smax, L, N) of the FOUR cells' path stores
 MIS_SHAPES = {"route_relaxed": (962, 8, 192, 29656),
               "route_k6n10_relaxed": (1017, 7, 152, 13560),
@@ -116,6 +132,42 @@ def test_sink_pick_gathers_a_distance_per_cell_of_a_sink(cell):
     assert K >= 5 * C
 
 
+def _live_avals(cell):
+    """(B, S, C, sink_pick_live's avals) of the cell's batch."""
+    B, ncells = LIVE_SHAPES[cell]
+    S, _, C, P = SINK_SHAPES[cell.replace("wide32", "wide")]
+    s, f32, i32 = jax.ShapeDtypeStruct, jnp.float32, jnp.int32
+    return B, S, C, (
+        s((B, ncells), f32), s((B, S, P), f32), s((B,), f32), s((B,), f32),
+        (s((B, S, C), i32), s((B, S, P), i32), s((B, S, P, C), f32),
+         s((B, S, P, C), i32)), s((B, S), jnp.bool_))
+
+
+@pytest.mark.parametrize("cell", sorted(LIVE_SHAPES))
+def test_the_live_sink_pick_gathers_a_distance_per_cell_of_a_live_sink(
+        cell):
+    """On a rung of width M the wave's pick fetches M rows of each
+    table and M * C distances, and nothing of the batch's B * S * C;
+    the wave's switch holds one such branch a rung and the dense pick
+    once, past the widest."""
+    B, S, C, avals = _live_avals(cell)
+    rungs = live_pick_rungs(B, S)
+    assert len(rungs) == 3 and rungs[-1] * 2 <= B * S + 16
+    for M in rungs:
+        rows = gather_index_rows(
+            lambda *a: sink_pick_live(*a, M), *avals)
+        # the distances; then rows of the four tables, the pins' costs
+        # and the nets' two weights, and the winner's three fields
+        assert sorted(rows) == [M] * 10 + [M * C], (M, rows)
+    rows = gather_index_rows(
+        lambda *a: sink_pick_wave(*a, rungs), *avals)
+    assert sorted(r for r in rows if r > max(rungs)) == sorted(
+        [M * C for M in rungs] + [B * S] * 3 + [B * S * C]), rows
+    # a mesh's program: the dense pick alone
+    rows = gather_index_rows(lambda *a: sink_pick_wave(*a, ()), *avals)
+    assert sorted(rows) == [B * S] * 3 + [B * S * C], rows
+
+
 @pytest.mark.parametrize("cell", sorted(SHAPES))
 def test_sink_pin_costs_gather_a_cost_per_pin_of_a_sink(cell):
     """Once a step: B * S * P element reads of the node costs."""
@@ -188,6 +240,8 @@ def test_a_whole_step_gathers_no_result_of_candidate_size(monkeypatch):
     pin_costs_flat, pick_flat = flat_forms(K, p["dev"].num_nodes)
     monkeypatch.setattr(planes, "sink_pin_costs", pin_costs_flat)
     monkeypatch.setattr(planes, "sink_pick", pick_flat)
+    # the flat pick stands in for the dense rung alone
+    monkeypatch.setattr(planes, "live_pick_rungs", lambda B, S: ())
     planes.route_batch_resident_planes.clear_cache()
     try:
         ref = gather_index_rows(graft.entry()[0], *args)

@@ -97,6 +97,22 @@ def test_after_the_finishing_pass_only_the_fighting_nets_are_rerouted():
     assert res.total_relax_steps == sum(w[3] for w in windows) == 842
 
 
+def test_the_endgame_is_the_endgame_of_dense_sink_picks():
+    """Negotiation, finishing pass and re-legalisation with the live
+    sink pick's ladder forced to the dense rung: the same route, window
+    for window, and the ladder as built read fewer distances."""
+    from sink_pick_refs import assert_same_route, dense_ladder
+
+    res, windows, _ = _route(9)
+    with dense_ladder():
+        dense, windows_dense, _ = _route(9)
+    assert windows == windows_dense == W9_TO_THE_PASS + [(22, 0, 3, 45, 2)]
+    assert_same_route(res, dense)
+    assert dense.total_sink_reads == dense.total_sink_reads_dense
+    assert 0 < res.total_sink_reads < res.total_sink_reads_dense \
+        == dense.total_sink_reads_dense
+
+
 def test_a_finish_that_does_not_land_restores_the_snapshot():
     """The pass starts (11 + 4 < 16), its window ends two nodes over and
     the iterations run out: the route returned is the snapshot of

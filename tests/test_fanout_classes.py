@@ -169,6 +169,27 @@ def test_the_rows_say_what_the_wide_class_cost(routed):
         assert (row.fanout_class > 0) == (row.waves_wide > 0)
 
 
+def test_the_route_is_the_route_of_dense_sink_picks(placed, routed):
+    """The waves' live sink pick (planes.sink_pick_wave) moves nothing:
+    with its ladder forced to the dense rung the two-class route comes
+    back node for node and count for count; the ladder as built read
+    fewer distances, in the narrow class and in the wide one."""
+    from sink_pick_refs import assert_same_route, dense_ladder
+
+    with dense_ladder():
+        dense = _route(placed)
+    assert_same_route(routed, dense)
+    assert dense.total_sink_reads == dense.total_sink_reads_dense > 0
+    assert routed.total_sink_reads_dense == dense.total_sink_reads_dense
+    assert 0 < routed.total_sink_reads < routed.total_sink_reads_dense
+    for row, row_dense in zip(routed.stats, dense.stats):
+        assert row_dense.sink_reads == row_dense.sink_reads_dense \
+            == row.sink_reads_dense
+        assert 0 < row.sink_reads < row.sink_reads_dense
+    assert sum(s.sink_reads for s in routed.stats) \
+        == routed.total_sink_reads
+
+
 def test_two_runs_are_identical(placed, routed):
     again = _route(placed)
     assert again.wirelength == routed.wirelength

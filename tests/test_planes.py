@@ -4,6 +4,7 @@ relaxation (route/search.py _relax) — the two independent implementations
 of the same cost model are each other's oracle — and the planes router
 must produce legal, deterministic routings."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -977,7 +978,160 @@ def test_route_equals_the_route_under_the_flat_sink_pick(kind,
     pin_costs_flat, pick_flat = flat_forms(pt.sink_cands, f.rr.num_nodes)
     _assert_route_unmoved_by(
         f, monkeypatch,
-        {"sink_pin_costs": pin_costs_flat, "sink_pick": pick_flat})
+        {"sink_pin_costs": pin_costs_flat, "sink_pick": pick_flat,
+         # the flat pick is the DENSE rung's reference: the live rungs
+         # read the factored tables themselves
+         "live_pick_rungs": lambda B, S: ()})
+
+
+# ---- the LIVE sink pick (planes.sink_pick_live / sink_pick_wave: the
+# wave's unrouted sinks listed densely, M x C distances read) against
+# the dense sink_pick ----
+
+LIVE_B, LIVE_S = 16, 6          # _sink_case's batch: rungs (16, 24, 48)
+# slots the cases are about, live first whatever the count: the two
+# planted ties (equal costs parted by rank), a sink of the net nothing
+# reached (every hop INF), an ordinary one
+LIVE_SPOTS = [(0, 0), (1, 0), (2, 0), (7, 1)]
+INVALID_NETS = (5, 6)
+
+
+def _live_case(kind="directional_l4"):
+    rr, pg = _field_graph(kind)
+    dist, congj_p1, crit_w, cw, fact, _, _ = _sink_case(
+        rr, pg, LIVE_B, seed=7)
+    from parallel_eda_tpu.route.planes import sink_pin_costs
+    return (dist, sink_pin_costs(congj_p1, fact), crit_w, cw, fact), \
+        pg.ncells
+
+
+def _live_mask(fact, ncells, count):
+    """``count`` live slots, the LIVE_SPOTS first, then seeded: real
+    sinks (not pad slots) of valid nets alone, as the wave's
+    ``remaining`` holds them; None for every slot of the batch."""
+    if count is None:
+        return np.ones((LIVE_B, LIVE_S), bool)
+    ok = np.asarray(fact[0])[:, :, 0] != ncells
+    ok[list(INVALID_NETS)] = False
+    assert not ok[3].any() and not ok[:, -2:].any()     # the pad slots
+    spots = [b * LIVE_S + s for b, s in LIVE_SPOTS]
+    assert ok.reshape(-1)[spots].all()
+    rest = np.setdiff1d(np.flatnonzero(ok), spots)
+    order = spots + list(np.random.default_rng(count).permutation(rest))
+    assert count <= len(order)
+    m = np.zeros(LIVE_B * LIVE_S, bool)
+    m[order[:count]] = True
+    return m.reshape(LIVE_B, LIVE_S)
+
+
+def _assert_live_equals_dense(got, want, mask):
+    names = ("sink_dist", "ent_cell", "ent_ipin", "ent_wdel")
+    for name, g, w in zip(names, got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert np.array_equal(g[mask], w[mask]), name
+    assert np.isinf(np.asarray(got[0])[~mask]).all()
+
+
+def test_the_live_rungs_of_a_batch():
+    from parallel_eda_tpu.route.planes import live_pick_rungs
+
+    assert live_pick_rungs(LIVE_B, LIVE_S) == (16, 24, 48)
+    assert live_pick_rungs(64, 8) == (64, 128, 256)
+    assert live_pick_rungs(64, 13) == (104, 208, 416)
+    assert live_pick_rungs(16, 204) == (408, 816, 1632)
+    # a batch too small for a list narrower than itself has none
+    assert live_pick_rungs(2, 4) == ()
+    assert live_pick_rungs(4, 3) == (8,)
+
+
+def test_the_forms_tool_times_nothing_off_the_chip(capsys):
+    """tools/sink_pick_forms.py: a time is a device number only from a
+    TPU, so off one the tool exits 2 before it times a form (the suite
+    runs on the CPU); --allow-cpu is a rehearsal that says so."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parent.parent / "tools" / \
+        "sink_pick_forms.py"
+    spec = importlib.util.spec_from_file_location("sink_pick_forms", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.main(["--shapes", "route_tight", "--reps", "1"]) == 2
+    out, err = capsys.readouterr()
+    assert out == "" and "not a TPU" in err
+
+
+# live counts of a rung of width M, by name
+RUNG_COUNTS = {"0": lambda M: 0, "1": lambda M: 1, "4": lambda M: 4,
+               "M-1": lambda M: M - 1, "M": lambda M: M}
+
+
+@pytest.mark.parametrize("count", list(RUNG_COUNTS))
+@pytest.mark.parametrize("rung", [0, 1, 2])
+def test_the_live_pick_equals_the_dense_pick_on_a_rung(rung, count):
+    """sink_pick_live at one list width: bit for bit sink_pick on the
+    live slots (ties by rank, a sink without a finite hop), INF on the
+    others -- pad sinks and invalid nets' slots among them -- from no
+    live slot to a full list."""
+    from parallel_eda_tpu.route.planes import (live_pick_rungs, sink_pick,
+                                               sink_pick_live)
+
+    args, ncells = _live_case()
+    M = live_pick_rungs(LIVE_B, LIVE_S)[rung]
+    mask = _live_mask(args[4], ncells, RUNG_COUNTS[count](M))
+    # compiled, both, as the window program holds them (op by op the
+    # CPU rounds a hop's two products apart, compiled it fuses them)
+    want = jax.jit(sink_pick)(*args)
+    got = jax.jit(sink_pick_live, static_argnames="M")(
+        *args, jnp.asarray(mask), M=M)
+    _assert_live_equals_dense(got, want, mask)
+    if mask[2, 0]:
+        assert np.isinf(np.asarray(got[0])[2, 0])       # live, no hop
+        assert np.isfinite(np.asarray(got[0])[mask]).any()
+
+
+# (live count, sink rows the wave must have read): each rung's last
+# count and the first past it; None = every slot of the batch live
+WAVE_CASES = [(0, 16), (1, 16), (16, 16), (17, 24), (24, 24), (25, 48),
+              (48, 48), (49, LIVE_B * LIVE_S), (52, LIVE_B * LIVE_S),
+              (None, LIVE_B * LIVE_S)]
+
+
+@pytest.mark.parametrize("count, rows", WAVE_CASES)
+@pytest.mark.parametrize("kind", ["directional_l4", "bidirectional",
+                                  "directs"])
+def test_the_wave_pick_takes_the_narrowest_rung_that_holds(kind, count,
+                                                           rows):
+    """sink_pick_wave over the rung boundaries, on one-way and two-way
+    wires and on a graph whose sinks hold a DIRECT hop (OPIN -> IPIN,
+    on the pad cell) beside their fabric hops."""
+    from parallel_eda_tpu.route.planes import (RANK_PAD, live_pick_rungs,
+                                               sink_pick, sink_pick_wave)
+
+    args, ncells = _live_case(kind)
+    mask = _live_mask(args[4], ncells, count)
+    if kind == "directs" and (count is None or count >= 16):
+        direct = ((np.asarray(args[4][3]) < RANK_PAD)
+                  & (np.asarray(args[4][0]) == ncells)[:, :, None, :]
+                  ).any(axis=(2, 3))
+        assert (direct & mask).any()
+    want = jax.jit(sink_pick)(*args)
+    wave = jax.jit(sink_pick_wave, static_argnames="rungs")
+    *got, read = wave(*args, jnp.asarray(mask),
+                      rungs=live_pick_rungs(LIVE_B, LIVE_S))
+    assert int(read) == rows
+    if rows == LIVE_B * LIVE_S:
+        # the dense rung IS sink_pick, every slot
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+    else:
+        _assert_live_equals_dense(got, want, mask)
+    # no ladder (a mesh): the dense pick, statically
+    *dense, read = wave(*args, jnp.asarray(mask), rungs=())
+    assert int(read) == LIVE_B * LIVE_S
+    for g, w in zip(dense, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
 
 
 # ---- the conflict colouring (planes._mis_colors: a slot's column of

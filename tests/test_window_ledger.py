@@ -308,7 +308,7 @@ def test_the_window_table_prints_the_rows(routed, tmp_path):
     assert lines[0].split() == [
         "window", "iter", "kind", "overused", "nets", "seconds", "stall_s",
         "control_s", "sweeps", "waves", "batches", "routes", "routes/batch",
-        "kept"]
+        "kept", "pick_read%"]
     assert len(lines) == len(r.stats) + 3
     for line, s in zip(lines[1:], r.stats):
         cells = line.split()
@@ -317,9 +317,13 @@ def test_the_window_table_prints_the_rows(routed, tmp_path):
         assert cells[8:] == [str(s.relax_steps), str(s.waves),
                              str(s.batches), str(s.net_routes),
                              "%.1f" % (s.net_routes / s.batches),
-                             "yes" if s.kept else "NO"]
+                             "yes" if s.kept else "NO",
+                             "%.1f" % (100.0 * s.sink_reads
+                                       / s.sink_reads_dense)]
     assert lines[-2].split()[0] == "sum"
-    assert lines[-2].split()[-3:-1] == [
+    assert lines[-2].split()[-1] == "%.1f" % (
+        100.0 * r.total_sink_reads / r.total_sink_reads_dense)
+    assert lines[-2].split()[-4:-2] == [
         str(r.total_net_routes), "%.1f" % (
             r.total_net_routes / sum(s.batches for s in r.stats))]
     assert lines[-1].startswith("wall: prologue_s ")
