@@ -84,7 +84,8 @@ def k6_n10_40nm_arch(chan_width: int = 120) -> Arch:
     return arch
 
 
-def k6_frac_n10_mem32k_40nm_arch(chan_width: int = 120) -> Arch:
+def k6_frac_n10_mem32k_40nm_arch(chan_width: int = 120,
+                                 mult_combinational: bool = False) -> Arch:
     """VTR 7.0's flagship heterogeneous architecture,
     ``vtr_flow/arch/timing/k6_frac_N10_mem32K_40nm.xml``, built ON
     ``k6_n10_40nm_arch``: the routing half (segment, mux, Fc, connection
@@ -104,7 +105,23 @@ def k6_frac_n10_mem32k_40nm_arch(chan_width: int = 120) -> Arch:
     of this repo has (its timing graph takes a hard block as
     registered); the packer fills an FLE with ONE LUT and drives the
     first of its two outputs (no pb tree: the two-mode pack is
-    ``frac_arch``'s, at test size)."""
+    ``frac_arch``'s, at test size).
+
+    ``mult_combinational=True`` builds ``mult_36`` AS PUBLISHED: no
+    clock pin (144 pins), combinational, the pin-to-pin
+    ``delay_constant`` by mode (a/b -> out 1.523e-9 in the 9x9 and
+    18x18 modes, 1.93e-9 in 36x36, recalled from the file; a primitive
+    that names no mode gets the 36x36 figure), so timing paths run
+    THROUGH a multiplier (timing/graph.py).  The default keeps the
+    registered stand-in the ``route_hetero`` configuration states under
+    ``assumed`` and is measured on."""
+    mult_timing = (dict(combinational=True, T_comb=1.93e-9,
+                        mode_T_comb={"mult_9x9": 1.523e-9,
+                                     "mult_18x18": 1.523e-9,
+                                     "mult_36x36": 1.93e-9})
+                   if mult_combinational else
+                   dict(T_comb=1.523e-9, T_setup=66e-12,
+                        T_clk_to_q=124e-12))
     arch = k6_n10_40nm_arch(chan_width)
     arch.name = "k6_frac_N10_mem32K_40nm"
     arch.I = 40
@@ -114,8 +131,7 @@ def k6_frac_n10_mem32k_40nm_arch(chan_width: int = 120) -> Arch:
                       T_comb=261e-12, T_setup=66e-12, T_clk_to_q=124e-12,
                       output_equivalent=False, outputs_per_ble=2),
         make_hard_type("mult_36", index=2, num_in=36 + 36, num_out=72,
-                       height=4, T_comb=1.523e-9, T_setup=66e-12,
-                       T_clk_to_q=124e-12),
+                       height=4, **mult_timing),
         make_hard_type("memory", index=3, num_in=15 + 15 + 64 + 2,
                        num_out=64, height=6, T_comb=1.234e-9,
                        T_setup=509e-12, T_clk_to_q=1.234e-9),

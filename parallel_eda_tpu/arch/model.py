@@ -146,6 +146,18 @@ class BlockType:
     T_comb: float = 400e-12
     T_setup: float = 60e-12
     T_clk_to_q: float = 80e-12
+    # a hard block's timing kind: False = registered (a setup endpoint a
+    # used input pin, a clock-to-Q launch a used output pin: a RAM);
+    # True = combinational (every used output pin depends on every used
+    # input pin at ONE pin-to-pin delay, no clock: the published
+    # mult_36).  ``mode_T_comb`` is that delay by the mode a netlist
+    # primitive names (``Primitive.mode``; <delay_constant> of the
+    # mode's pb_type), ``T_comb`` where it names none
+    combinational: bool = False
+    mode_T_comb: Dict[str, float] = field(default_factory=dict)
+
+    def comb_delay(self, mode: Optional[str]) -> float:
+        return self.mode_T_comb.get(mode, self.T_comb)
 
     @property
     def num_input_pins(self) -> int:
@@ -322,25 +334,33 @@ def make_clb_type(index: int, K: int, N: int, I: int,
 def make_hard_type(name: str, index: int, num_in: int, num_out: int,
                    T_comb: float = 1.5e-9, T_setup: float = 100e-12,
                    T_clk_to_q: float = 400e-12,
-                   height: int = 1) -> BlockType:
+                   height: int = 1, combinational: bool = False,
+                   mode_T_comb: Optional[Dict[str, float]] = None
+                   ) -> BlockType:
     """A hard block type (RAM / DSP column block): num_in data+address
     input pins, num_out output pins, one clock, ``height`` grid rows.
+    ``combinational``: NO clock pin, and the timing graph runs paths
+    through the block at ``T_comb`` (or its mode's ``mode_T_comb``).
     The pins of a hard block are NOT logically equivalent (data bit 3 of
     a RAM is not bit 7): every pin is a class of its own, so pin p is
     class p and a net reaches the pin the netlist names.
     Stratix-IV-style heterogeneous tile (physical_types.h
     t_type_descriptor with its own pin classes and timing)."""
-    num_pins = num_in + num_out + 1
+    num_pins = num_in + num_out + (0 if combinational else 1)
     pin_classes = (
         [PinClass(PIN_CLASS_RECEIVER, [p]) for p in range(num_in)]
         + [PinClass(PIN_CLASS_DRIVER, [p])
-           for p in range(num_in, num_in + num_out)]
-        + [PinClass(PIN_CLASS_RECEIVER, [num_in + num_out], is_clock=True)])
+           for p in range(num_in, num_in + num_out)])
+    if not combinational:
+        pin_classes.append(PinClass(PIN_CLASS_RECEIVER,
+                                    [num_in + num_out], is_clock=True))
     return BlockType(
         name=name, index=index, num_pins=num_pins, capacity=1,
         pin_classes=pin_classes, pin_class_of=list(range(num_pins)),
         is_io=False, height=int(height),
         T_comb=T_comb, T_setup=T_setup, T_clk_to_q=T_clk_to_q,
+        combinational=bool(combinational),
+        mode_T_comb=dict(mode_T_comb or {}),
     )
 
 

@@ -314,8 +314,9 @@ def run_route(flow: FlowResult, opts: Optional[RouterOpts] = None,
     rid = flow.route.route_id
     if timing_driven:
         with stage("flow.route.sta", flow.times, key="route.sta",
-                   route=rid):
+                   route=rid) as st:
             flow.analyzer.analyze(flow.route.sink_delay)
+            st.set(crit_path_hard_arcs=flow.analyzer.crit_path_hard_arcs())
     if verify and flow.route.success:
         with stage("flow.route.verify", flow.times, key="route.verify",
                    route=rid):

@@ -32,6 +32,10 @@ class Primitive:
     # and outputs are positional against the hard block type's pin order)
     model: Optional[str] = None
     outputs: List[str] = field(default_factory=list)
+    # PRIM_HARD only: the mode of the block type the instance runs in
+    # ("mult_18x18": arch.model.BlockType.mode_T_comb); None = the
+    # type's default timing
+    mode: Optional[str] = None
 
 
 @dataclass

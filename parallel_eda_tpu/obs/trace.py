@@ -41,7 +41,8 @@ from typing import Optional
 
 # the window program's stages on the device: every op of
 # route_window_planes / _fused / _multi lies under exactly one
-# top-level name (nested ones only under route.dev.relax).  The names
+# top-level name (nested ones only under route.dev.relax and
+# route.dev.sta).  The names
 # are what a reduction of a device trace keys on (benchmark/
 # scope_reduce.py, OBSERVABILITY.md), so they outlive any refactor of
 # what is inside them; tests/test_device_scopes.py holds the compiled
@@ -59,6 +60,7 @@ DEVICE_SCOPES = (
     "route.dev.commit",          # occupancy commit, scatter to state
     "route.dev.history",         # per-iteration acc / pres escalation
     "route.dev.sta",             # the fused STA
+    "route.dev.sta.wide_fold",   #   in-edges past the ELL (scatter-max)
     "route.dev.mis_colors",      # conflict colouring of the dirty set
     "route.dev.window_summary",  # packed status / scal, rung stacking
 )
@@ -377,6 +379,10 @@ class _StageCtx:
         self._t_in = time.perf_counter()
         self.inner.__enter__()
         return self
+
+    def set(self, **args) -> None:
+        """Args known only inside the stage (the span's ``set``)."""
+        self.inner.set(**args)
 
     def __exit__(self, *exc):
         r = self.inner.__exit__(*exc)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
+from ..obs.trace import device_scope
 from .graph import TimingGraph
 
 NEG = -jnp.inf
@@ -31,6 +32,22 @@ NEG = -jnp.inf
 
 @struct.dataclass
 class DeviceTimingGraph:
+    """``TimingGraph``'s arrays on the device, a pytree the window
+    program takes as an argument.  T tnodes; D_in / D_out the ELLs'
+    widths.
+
+    in_src [T, D_in] int32, in_const [T, D_in] f32 (seconds),
+    in_ridx [T, D_in] int32 (routed-delay slot, -1 = none: the delay
+    vector's trailing zero), in_valid [T, D_in] bool: the in-edges, read
+    by the forward (arrival) sweep and by the per-connection slacks.
+    out_dst / out_const / out_ridx / out_valid [T, D_out]: the same
+    edges by source, read by the backward (required) sweep.
+    arrival0 [T] f32: the startpoints' seeds, -inf elsewhere.
+    is_endpoint [T] bool.
+    out_overflow / in_overflow: the edges the two ELLs do not hold, as
+    flat lists; None is NO pytree leaf, so a graph without them has the
+    structure, and the compiled programs, of one built before they
+    existed."""
     in_src: jnp.ndarray
     in_const: jnp.ndarray
     in_ridx: jnp.ndarray
@@ -44,6 +61,14 @@ class DeviceTimingGraph:
     # TimingGraph.out_overflow: the out-edges past the ELL's width,
     # flat (src, dst, const, ridx); None (no leaf) where there are none
     out_overflow: Optional[Tuple[jnp.ndarray, ...]] = None
+    # TimingGraph.in_overflow: the in-edges of combinational hard
+    # blocks' junctions past the in-edge ELL's width, flat (dst, src,
+    # const, ridx); None (no leaf) where there are none
+    in_overflow: Optional[Tuple[jnp.ndarray, ...]] = None
+
+
+def _on_device(edges) -> Optional[Tuple[jnp.ndarray, ...]]:
+    return None if edges is None else tuple(jnp.asarray(a) for a in edges)
 
 
 def to_device(tg: TimingGraph) -> DeviceTimingGraph:
@@ -55,8 +80,8 @@ def to_device(tg: TimingGraph) -> DeviceTimingGraph:
         out_valid=jnp.asarray(tg.out_valid),
         arrival0=jnp.asarray(tg.arrival0),
         is_endpoint=jnp.asarray(tg.is_endpoint),
-        out_overflow=(None if tg.out_overflow is None else tuple(
-            jnp.asarray(a) for a in tg.out_overflow)),
+        out_overflow=_on_device(tg.out_overflow),
+        in_overflow=_on_device(tg.in_overflow),
     )
 
 
@@ -93,10 +118,21 @@ def sta_crit(dev: DeviceTimingGraph, route_delay: jnp.ndarray,
         o_src, o_dst, o_const, o_ridx = dev.out_overflow
         d_ovf = o_const + rd[o_ridx]
 
+    if dev.in_overflow is not None:
+        # the junctions' in-edges past the ELL: one more candidate a
+        # level each, folded in by a scatter-max (the same max, so the
+        # arrivals are the full table's)
+        i_dst, i_src, i_const, i_ridx = dev.in_overflow
+        d_iovf = i_const + rd[i_ridx]
+
     def fwd(_, arr):
         cand = arr[dev.in_src] + d_in
         cand = jnp.where(dev.in_valid, cand, NEG)
-        return jnp.maximum(dev.arrival0, cand.max(axis=1))
+        new = jnp.maximum(dev.arrival0, cand.max(axis=1))
+        if dev.in_overflow is not None:
+            with device_scope("route.dev.sta.wide_fold"):
+                new = new.at[i_dst].max(arr[i_src] + d_iovf)
+        return new
 
     arr = jax.lax.fori_loop(0, depth, fwd, dev.arrival0)
 
@@ -166,6 +202,16 @@ def sta_crit(dev: DeviceTimingGraph, route_delay: jnp.ndarray,
     idx = jnp.where(ok, dev.in_ridx, RS)
     crit_flat = jnp.zeros(RS + 1, jnp.float32).at[idx.ravel()].max(
         jnp.where(ok, crit, 0.0).ravel())
+    if dev.in_overflow is not None:
+        with device_scope("route.dev.sta.wide_fold"):
+            slack_o = req[i_dst] - arr[i_src] - d_iovf
+            crit_o = jnp.clip(1.0 - slack_o / (
+                denom[i_dst, 0] if use_sdc else denom), 0.0, max_crit)
+            if crit_exp != 1.0:
+                crit_o = crit_o ** crit_exp
+            ok_o = (i_ridx >= 0) & jnp.isfinite(slack_o)
+            crit_flat = crit_flat.at[jnp.where(ok_o, i_ridx, RS)].max(
+                jnp.where(ok_o, crit_o, 0.0))
     return crit_flat[:RS], dmax, worst, arr
 
 
@@ -191,6 +237,7 @@ class TimingAnalyzer:
         self.worst_slack = float("nan")
         self.sdc = sdc
         self._req_seed = None
+        self._last = None       # (flat delays, arrivals) of analyze()
         if sdc is not None:
             # a typo'd -clock reference must error, not silently fall
             # back to the default period (same contract as port names)
@@ -250,15 +297,54 @@ class TimingAnalyzer:
             # several fanout classes: the classes' tables end to end
             flat = np.zeros(self.tg.num_route_slots + 1, np.float32)
             flat[slots[slots >= 0]] = sink_delay[slots >= 0]
-        crit, dmax, worst, _ = sta_sweep(
+        crit, dmax, worst, arr = sta_sweep(
             self.dev, jnp.asarray(flat), self.tg.depth, self.crit_exp,
             self.max_crit, req_seed=self._req_seed,
             use_sdc=self._req_seed is not None)
+        self._last = (flat, arr)
         self.crit_path_delay = float(dmax)
         self.worst_slack = float(worst)
         if slots is None:
             return np.asarray(crit).reshape(R, Smax)
         return np.where(slots >= 0, np.asarray(crit)[slots], 0.0)
+
+    def critical_path(self) -> list:
+        """The tnodes of the last ``analyze``'s longest path, endpoint
+        first: from the latest endpoint back along the in-edge (the
+        ELL's or the overflow list's) that set each arrival, to a
+        startpoint."""
+        tg = self.tg
+        flat, arr = self._last
+        flat = np.where(np.isfinite(flat), flat, 0.0)
+        arr = np.asarray(arr)
+        ends = np.flatnonzero(tg.is_endpoint & np.isfinite(arr))
+        if not len(ends):
+            return []
+        v = int(ends[np.argmax(arr[ends])])
+        path = [v]
+        for _ in range(tg.depth):
+            src = tg.in_src[v][tg.in_valid[v]]
+            d = (tg.in_const[v] + flat[tg.in_ridx[v]])[tg.in_valid[v]]
+            if tg.in_overflow is not None:
+                o_dst, o_src, o_const, o_ridx = tg.in_overflow
+                m = o_dst == v
+                src = np.concatenate([src, o_src[m]])
+                d = np.concatenate([d, o_const[m] + flat[o_ridx[m]]])
+            cand = arr[src] + d
+            if not len(cand) or cand.max() <= tg.arrival0[v]:
+                break               # the node's own seed set it
+            v = int(src[np.argmax(cand)])
+            path.append(v)
+        return path
+
+    def crit_path_hard_arcs(self) -> int:
+        """Pin-to-pin arcs of combinational hard blocks on the last
+        ``analyze``'s critical path (an edge OUT of a block's junction
+        node); 0, and no walk, on a graph without such a block."""
+        if self.tg.comb_junction is None:
+            return 0
+        return int(np.isin(self.critical_path()[1:],
+                           self.tg.comb_junction).sum())
 
     def timing_cb(self, result) -> np.ndarray:
         """Router timing_cb hook (router.py Router.route); stamps the

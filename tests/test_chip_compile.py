@@ -197,6 +197,42 @@ def test_route_window_program_compiles_for_every_bench_variant(one_chip):
         _fits_hbm(compiled)
 
 
+def test_fused_sta_compiles_with_route_dsps_wide_junctions(one_chip):
+    """``sta_crit`` at ``route_dsp``'s full-size timing graph (6,989
+    nodes, 12 levels, 18 junctions whose 30 in-edges past the table's
+    six columns lie in the flat list): the scatter-max fold under
+    ``route.dev.sta.wide_fold`` before the v5e compiler, plain and in
+    SDC mode, and named in the compiled program."""
+    import os
+    import sys
+    import warnings
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark import harness, problem
+    from parallel_eda_tpu.timing.graph import build_timing_graph
+    from parallel_eda_tpu.timing.sta import sta_crit, to_device
+
+    cell = harness.load_cell(harness.load_manifest(repo), repo,
+                             "route_dsp")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        f = problem.build_placed(cell, int(cell.traffic["chan_width"]))
+    tg = build_timing_graph(f.nl, f.pnl, f.term)
+    assert tg.in_src.shape[1] == 6 and len(tg.in_overflow[0]) == 540
+    dev = _on(_avatarize(to_device(tg)), one_chip)
+    S = jax.ShapeDtypeStruct
+    flat = S((tg.num_route_slots + 1,), jnp.float32, sharding=one_chip)
+    seed = S((tg.num_tnodes,), jnp.float32, sharding=one_chip)
+    for use_sdc in (False, True):
+        fn = jax.jit(functools.partial(
+            sta_crit, depth=tg.depth, use_sdc=use_sdc))
+        compiled = fn.lower(dev, flat, req_seed=seed).compile()
+        _fits_hbm(compiled)
+        assert "route.dev.sta.wide_fold" in compiled.as_text()
+
+
 # ---- the paths that exist only across chips ------------------------
 
 def test_remote_slab_permute_compiles_on_four_chip_mesh(row_mesh):

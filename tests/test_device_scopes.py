@@ -130,7 +130,10 @@ def test_vocabulary_covers_the_window_program(placed, fused):
         seen |= names
         assert not outside, (key, outside[:5])
         assert len(made) * 4 < judged, (key, len(made), judged)
-    assert seen == set(DEVICE_SCOPES), (
+    # the wide fold exists only where a combinational hard block's
+    # junction is wider than the STA's in-edge table: a whole route on
+    # such a circuit looks for it (tests/test_timing_comb_hard.py)
+    assert seen == set(DEVICE_SCOPES) - {"route.dev.sta.wide_fold"}, (
         sorted(set(DEVICE_SCOPES) - seen), sorted(seen - set(DEVICE_SCOPES)))
 
 
