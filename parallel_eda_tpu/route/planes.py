@@ -605,26 +605,93 @@ def geom_full(pg: PlanesGraph) -> PlanesGeom:
         inc_track=pg.inc_track, group_tracks=pg.group_tracks)
 
 
-def geom_cropped(pg: PlanesGraph, ox, oy, cnx: int, cny: int,
-                 full: Optional[PlanesGeom] = None) -> PlanesGeom:
+def _shift_bits(n: int):
+    """The powers of two whose sums are the shifts 0..n (static)."""
+    return [1 << j for j in range(max(n, 0).bit_length())]
+
+
+def _pad_axis(a, before: int, after: int, axis: int):
+    """Zeros before and after ``a`` along ``axis``: ``lax.pad`` with a
+    host scalar (``jnp.pad`` converts its constant by an eager op a
+    call, and these forms pad a hundred times a program's trace)."""
+    config = [(0, 0, 0)] * a.ndim
+    config[axis] = (before, after, 0)
+    return lax.pad(a, np.zeros((), a.dtype), config)
+
+
+def _cut_axis(a, o, size: int, axis: int):
+    """``size`` entries of ``a`` along ``axis`` from the per-net start
+    ``o`` (int32, broadcastable against ``a``, 0 <= o <= a.shape[axis]
+    - size): one select a BIT of the largest start, highest bit first,
+    between two static slices -- the array shrinks to what the lower
+    bits can still reach as the shift resolves.  No gather: XLA:TPU
+    expands a per-net dynamic slice into a loop over the batch."""
+    for k in reversed(_shift_bits(a.shape[axis] - size)):
+        L = a.shape[axis]
+        keep = min(size + k - 1, L)
+        lo = lax.slice_in_dim(a, 0, keep, axis=axis)
+        hi = lax.slice_in_dim(a, k, min(k + keep, L), axis=axis)
+        if hi.shape[axis] < keep:
+            # past the end only where a set bit k leaves the lower bits
+            # less than k - 1 to add: never part of a tile
+            hi = _pad_axis(hi, 0, keep - hi.shape[axis], axis)
+        a = jnp.where((o & k) != 0, hi, lo)
+    return a
+
+
+def _place_axis(t, o, length: int, axis: int):
+    """The mirror of _cut_axis: ``t`` moved right by the per-net ``o``
+    along ``axis`` into ``length`` entries (0 <= o <= length -
+    t.shape[axis]); what lies outside the moved tile is unspecified."""
+    for k in _shift_bits(length - t.shape[axis]):   # lowest first: grows
+        L = t.shape[axis]
+        new = min(L + k, length)
+        hi = lax.slice_in_dim(_pad_axis(t, k, 0, axis), 0, new, axis=axis)
+        t = jnp.where((o & k) != 0, hi, _pad_axis(t, 0, new - L, axis))
+    return t
+
+
+def cut_tiles(a, ox, oy, xs: int, ys: int):
+    """Per-net tiles of the canvases ``a`` [G, ..., X, Y] (G == 1: one
+    canvas shared by the batch, or G == B): net b's (xs, ys) tile
+    starts at (ox[b], oy[b]) -> [B, ..., xs, ys]."""
+    o = (slice(None),) + (None,) * (a.ndim - 1)
+    t = _cut_axis(_cut_axis(a, ox[o], xs, a.ndim - 2), oy[o], ys,
+                  a.ndim - 1)
+    return jnp.broadcast_to(t, ox.shape + t.shape[1:])
+
+
+def put_tiles(full, tiles, ox, oy):
+    """``full`` [G, ..., X, Y] with net b's tile written at (ox[b],
+    oy[b]) -> [B, ..., X, Y]: the tile moved to its place by
+    _place_axis, then ONE select under the tile's footprint (two
+    iotas against the origins).  No scatter, no loop over the batch."""
+    nd = full.ndim
+    X, Y = full.shape[-2:]
+    xs, ys = tiles.shape[-2:]
+    o = (slice(None),) + (None,) * (nd - 1)
+    ox, oy = ox[o], oy[o]
+    moved = _place_axis(_place_axis(tiles, oy, Y, nd - 1), ox, X, nd - 2)
+    ix = lax.broadcasted_iota(jnp.int32, (1,) * (nd - 2) + (X, 1), nd - 2)
+    iy = lax.broadcasted_iota(jnp.int32, (1,) * (nd - 1) + (Y,), nd - 1)
+    inside = ((ix >= ox) & (ix < ox + xs) & (iy >= oy) & (iy < oy + ys))
+    return jnp.where(inside, moved, full)
+
+
+def geom_cropped(pg: PlanesGraph, ox, oy, cnx: int,
+                 cny: int) -> PlanesGeom:
     """Per-net cropped geometry: net b's slice starts at grid cell
     (ox[b], oy[b]) and spans a STATIC (cnx, cny) tile (compile-time;
     the caller buckets tile sizes).  Exact iff every wire a net may
     legally use (bb-intersecting, see the window cc mask) lies inside
     its tile — callers expand the bb by (max wire length - 1) and clamp
     to the grid."""
-    full = full if full is not None else geom_full(pg)
-    W, NX, NYp1 = pg.shape_x
-    _, NXp1, NY = pg.shape_y
+    full = geom_full(pg)
+    NYp1 = pg.shape_x[2]
 
     def crop(a, xs, ys):
-        # a: [1, W, X, Y]; per-net slice -> [B, W, xs, ys]
-        return jax.vmap(lambda x0, y0: lax.dynamic_slice(
-            a[0], (0, x0, y0), (a.shape[1], xs, ys)))(ox, oy)
-
-    def crop2(a, xs, ys):
-        return jax.vmap(lambda x0, y0: lax.dynamic_slice(
-            a[0], (x0, y0), (xs, ys)))(ox, oy)
+        # a: [1, (W,) X, Y]; per-net tile -> [B, (W,) xs, ys]
+        return cut_tiles(a, ox, oy, xs, ys)
 
     return PlanesGeom(
         brk_before_x=crop(full.brk_before_x, cnx, cny + 1),
@@ -641,7 +708,7 @@ def geom_cropped(pg: PlanesGraph, ox, oy, cnx: int, cny: int,
         delay_y_rot1=crop(full.delay_y_rot1, cnx + 1, cny),
         idxx=crop(full.idxx, cnx, cny + 1),
         idxy=crop(full.idxy, cnx + 1, cny),
-        base_par=crop2(full.base_par, cnx + 1, cny + 1),
+        base_par=crop(full.base_par, cnx + 1, cny + 1),
         stride_x=NYp1, directional=pg.directional,
         inc_track=pg.inc_track, group_tracks=pg.group_tracks)
 
@@ -1103,35 +1170,68 @@ def planes_relax(pg: PlanesGraph, d0_flat, cc_flat, crit_c, wenter0,
     return flat(dx, dy), flat(predx, predy), flat(wx, wy), stats
 
 
+@struct.dataclass
+class CropCut:
+    """What a cropped relaxation needs that its origins fix: the
+    cropped geometry and the tiles of a congestion field.  A step cuts
+    it ONCE (its origins hold for every wave) from the step's unscaled
+    field and each wave scales the tiles by its own weight."""
+    gm: PlanesGeom
+    cc_x: jnp.ndarray               # [B, W, cnx, cny+1]
+    cc_y: jnp.ndarray               # [B, W, cnx+1, cny]
+
+    def scaled(self, cw):
+        """The tiles of ``cw[:, None] * field``: the same multiply an
+        element as scaling the field and cutting it after.  The
+        products are held as values of their own, as a field scaled
+        before the cut is: fused into the sum that takes them, a
+        backend may round the two differently (XLA:CPU contracts one
+        of a sum's two products into a fused multiply-add)."""
+        cw = cw[:, None, None, None]
+        cc_x, cc_y = lax.optimization_barrier(
+            (cw * self.cc_x, cw * self.cc_y))
+        return self.replace(cc_x=cc_x, cc_y=cc_y)
+
+
+def _canvases(pg: PlanesGraph, flat):
+    """[B, ncells] -> the ([B, W, NX, NY+1], [B, W, NX+1, NY]) planes."""
+    B = flat.shape[0]
+    ncx = pg.shape_x[0] * pg.shape_x[1] * pg.shape_x[2]
+    return (flat[:, :ncx].reshape(B, *pg.shape_x),
+            flat[:, ncx:].reshape(B, *pg.shape_y))
+
+
 @device_scope("route.dev.relax.crop")
-def crop_state(pg: PlanesGraph, d0_flat, cc_flat, wenter0, ox, oy,
+@functools.partial(jax.jit, static_argnames=("cnx", "cny"))
+def crop_cut(pg: PlanesGraph, ox, oy, cnx: int, cny: int,
+             cc_flat) -> CropCut:
+    """The CropCut of the tiles at (ox, oy) and the field ``cc_flat``
+    [B, ncells]."""
+    ccxf, ccyf = _canvases(pg, cc_flat)
+    return CropCut(gm=geom_cropped(pg, ox, oy, cnx, cny),
+                   cc_x=cut_tiles(ccxf, ox, oy, cnx, cny + 1),
+                   cc_y=cut_tiles(ccyf, ox, oy, cnx + 1, cny))
+
+
+@device_scope("route.dev.relax.crop")
+@functools.partial(jax.jit, static_argnames=("cnx", "cny"))
+def crop_state(pg: PlanesGraph, d0_flat, wenter0, ox, oy,
                cnx: int, cny: int):
-    """Crop scaffolding of planes_relax_cropped: reshape the
-    [B, Ncells] flats into canvases and slice each net's (cnx, cny)
-    tile at its origin.  Returns (full canvases
-    (dxf, dyf, wxf, wyf), tiles (dx, dy, ccx, ccy, wx, wy))."""
-    B = d0_flat.shape[0]
-    W, NX, NYp1 = pg.shape_x
-    _, NXp1, NY = pg.shape_y
-    ncx = W * NX * NYp1
-
-    def crop4(a, xs, ys):
-        return jax.vmap(lambda t, x0, y0: lax.dynamic_slice(
-            t, (0, x0, y0), (W, xs, ys)))(a, ox, oy)
-
-    dxf = d0_flat[:, :ncx].reshape(B, W, NX, NYp1)
-    dyf = d0_flat[:, ncx:].reshape(B, W, NXp1, NY)
-    ccxf = cc_flat[:, :ncx].reshape(B, W, NX, NYp1)
-    ccyf = cc_flat[:, ncx:].reshape(B, W, NXp1, NY)
-    wxf = wenter0[:, :ncx].reshape(B, W, NX, NYp1)
-    wyf = wenter0[:, ncx:].reshape(B, W, NXp1, NY)
+    """A wave's half of the crop scaffolding: reshape the [B, Ncells]
+    seeds into canvases and cut each net's (cnx, cny) tile at its
+    origin.  Returns (full canvases (dxf, dyf, wxf, wyf), tiles (dx,
+    dy, wx, wy))."""
+    dxf, dyf = _canvases(pg, d0_flat)
+    wxf, wyf = _canvases(pg, wenter0)
     return ((dxf, dyf, wxf, wyf),
-            (crop4(dxf, cnx, cny + 1), crop4(dyf, cnx + 1, cny),
-             crop4(ccxf, cnx, cny + 1), crop4(ccyf, cnx + 1, cny),
-             crop4(wxf, cnx, cny + 1), crop4(wyf, cnx + 1, cny)))
+            (cut_tiles(dxf, ox, oy, cnx, cny + 1),
+             cut_tiles(dyf, ox, oy, cnx + 1, cny),
+             cut_tiles(wxf, ox, oy, cnx, cny + 1),
+             cut_tiles(wyf, ox, oy, cnx + 1, cny)))
 
 
 @device_scope("route.dev.relax.crop")
+@jax.jit
 def scatter_state(gm_full: PlanesGeom, fulls, tiles, ox, oy):
     """Shared scatter-back: write each net's relaxed tile into its full
     canvases (cells outside the tile keep d0 / SELF-pred / wenter0 —
@@ -1142,24 +1242,21 @@ def scatter_state(gm_full: PlanesGeom, fulls, tiles, ox, oy):
     B = dxf.shape[0]
 
     def put(full, tile):
-        return jax.vmap(lambda f, t, x0, y0: lax.dynamic_update_slice(
-            f, t, (0, x0, y0)))(full, tile, ox, oy)
-
-    idxx_f = jnp.broadcast_to(gm_full.idxx, dxf.shape)
-    idxy_f = jnp.broadcast_to(gm_full.idxy, dyf.shape)
+        return put_tiles(full, tile, ox, oy)
 
     def flat(a, b):
         return jnp.concatenate([a.reshape(B, -1), b.reshape(B, -1)],
                                axis=1)
 
     return (flat(put(dxf, dx), put(dyf, dy)),
-            flat(put(idxx_f, predx), put(idxy_f, predy)),
+            flat(put(gm_full.idxx, predx), put(gm_full.idxy, predy)),
             flat(put(wxf, wx), put(wyf, wy)))
 
 
 def planes_relax_cropped(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
                          wenter0, nsweeps: int, ox, oy,
-                         cnx: int, cny: int, plane_dtype: str = "f32"):
+                         cnx: int, cny: int, plane_dtype: str = "f32",
+                         cut: Optional[CropCut] = None):
     """planes_relax on per-net (cnx, cny) CROPPED canvases: net b sweeps
     only the tile starting at grid cell (ox[b], oy[b]) — work per net
     scales with its bounding box, not the device (the reference's
@@ -1172,12 +1269,16 @@ def planes_relax_cropped(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
     the tile return their d0 / self-pred / wenter0 unchanged (they are
     unreachable in the full program too: their cc is INF).
 
+    ``cut``: the geometry and the tiles of ``cc_flat`` at (ox, oy),
+    where the caller holds them already (a step's waves share their
+    origins: _step_core); cut here otherwise.
+
     Same (dist, pred, wenter, stats) returns as planes_relax."""
-    gm_full = geom_full(pg)
-    with device_scope("route.dev.relax.crop"):
-        gm = geom_cropped(pg, ox, oy, cnx, cny, full=gm_full)
-    fulls, (dx, dy, cc_x, cc_y, wx, wy) = crop_state(
-        pg, d0_flat, cc_flat, wenter0, ox, oy, cnx, cny)
+    if cut is None:
+        cut = crop_cut(pg, ox, oy, cnx, cny, cc_flat)
+    gm, cc_x, cc_y = cut.gm, cut.cc_x, cut.cc_y
+    fulls, (dx, dy, wx, wy) = crop_state(
+        pg, d0_flat, wenter0, ox, oy, cnx, cny)
     if plane_dtype != "f32":
         # same one-time congestion quantization as planes_relax
         dt = plane_jnp_dtype(plane_dtype)
@@ -1195,9 +1296,9 @@ def planes_relax_cropped(pg: PlanesGraph, d0_flat, cc_flat, crit_c,
                               nsweeps, plane_dtype)
     if plane_dtype != "f32":
         tiles = _dequantize_plane_state(tiles)
-    # scatter the tiles back into the full canvases (one full-canvas
+    # write the tiles back into the full canvases (one full-canvas
     # write per relaxation instead of ~15 traversals per sweep)
-    return scatter_state(gm_full, fulls, tiles, ox, oy) + (stats,)
+    return scatter_state(geom_full(pg), fulls, tiles, ox, oy) + (stats,)
 
 
 # ---------------------------------------------------------------------------
@@ -1648,6 +1749,14 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             crop_oy = jnp.clip(bb_anchor[:, 2] - Lm, 0, NYg - cny_t
                                ).astype(jnp.int32)
 
+    if crop_tile is not None:
+        # the origins hold for the whole step: its geometry and the
+        # tiles of the unscaled congestion field are cut here, once, and
+        # not by every wave
+        with device_scope("route.dev.relax"):
+            crop_base = crop_cut(pg, crop_ox, crop_oy, cnx_t, cny_t,
+                                 cc_flat_base)
+
     def wave_run(wave, state, group):
         (seed_cells, tdel_cells, opin_used, remaining, wpaths, delay,
          reached_all, st) = state
@@ -1668,7 +1777,7 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                 dist, pred, wenter, rst = planes_relax_cropped(
                     pg, d0, cc_flat, crit_c, wenter0, nsweeps,
                     crop_ox, crop_oy, cnx_t, cny_t,
-                    plane_dtype=plane_dtype)
+                    plane_dtype=plane_dtype, cut=crop_base.scaled(cw))
             elif _as_row_mesh(mesh) is not None:
                 from .planes_shard import planes_relax_sharded
                 dist, pred, wenter, rst = planes_relax_sharded(

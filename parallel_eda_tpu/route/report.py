@@ -84,8 +84,8 @@ def format_window_table(result) -> str:
     the route's wall by named interval where the result carries one
     (``RouteResult.wall``: the four add up to the ``route`` stage)."""
     head = ("window", "iter", "kind", "overused", "nets", "seconds",
-            "stall_s", "control_s", "sweeps", "waves", "batches", "routes",
-            "routes/batch", "kept")
+            "stall_s", "control_s", "sweeps", "waves", "waves_crop",
+            "batches", "routes", "routes/batch", "kept")
 
     def fill(routes, batches):
         # net routes a batch the window ran: of B slots, how many worked
@@ -93,7 +93,8 @@ def format_window_table(result) -> str:
 
     rows = [(s.window, s.iteration, s.kind or "-", s.overused_nodes,
              s.rerouted_nets, f"{s.route_time_s:.3f}", f"{s.stall_s:.3f}",
-             f"{s.control_s:.4f}", s.relax_steps, s.waves, s.batches,
+             f"{s.control_s:.4f}", s.relax_steps, s.waves,
+             s.waves_cropped, s.batches,
              s.net_routes, fill(s.net_routes, s.batches),
              "yes" if s.kept else "NO") for s in result.stats]
     batches = sum(s.batches for s in result.stats)
@@ -104,6 +105,7 @@ def format_window_table(result) -> str:
                  f"{sum(s.control_s for s in result.stats):.4f}",
                  sum(s.relax_steps for s in result.stats),
                  sum(s.waves for s in result.stats),
+                 sum(s.waves_cropped for s in result.stats),
                  batches, routes, fill(routes, batches),
                  f"{sum(1 for s in result.stats if s.kept)}"
                  f"/{len(result.stats)}"))

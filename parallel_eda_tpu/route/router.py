@@ -270,6 +270,7 @@ class RouteStats:
     sweep_boost: int = 1
     waves: int = 0               # relaxations run to a fixpoint
     relax_steps_cropped: int = 0  # of relax_steps, on cropped rungs
+    waves_cropped: int = 0       # of waves: cropped relaxation CALLS
     net_routes: int = 0          # nets routed, once an iteration each
     stall_s: float = 0.0         # host blocked on the device
     plan_s: float = 0.0          # host planning, staging, dispatching
@@ -344,6 +345,10 @@ class RouteResult:
     # area — the two cost very different device time; bench projections
     # need the split)
     total_relax_steps_cropped: int = 0
+    # the waves of cropped rungs: the calls of the cropped relaxation,
+    # what its cut and write-back are paid by (tools/crop_forms.py
+    # times one)
+    total_waves_cropped: int = 0
     # of which: sweeps of the windows whose result was thrown away, the
     # stats rows past the iteration of the pre-finish snapshot when the
     # finishing pass could not re-legalise and the snapshot was restored
@@ -1356,7 +1361,7 @@ class Router:
                              SCAL_SINK_ROWS_DENSE, SCAL_WALK_BUDGET,
                              SCAL_WALK_STEPS, SCAL_WAVES)
 
-        w_steps = w_useful = w_steps_crop = 0
+        w_steps = w_useful = w_steps_crop = w_waves_crop = 0
         nroutes = nexec = w_waves = 0
         w_steps_wide = w_waves_wide = 0
         w_sink_rows = w_sink_rows_dense = 0
@@ -1377,6 +1382,7 @@ class Router:
             w_sink_rows_dense += int(v[SCAL_SINK_ROWS_DENSE])
             if cropped:
                 w_steps_crop += int(v[SCAL_S_EXEC])
+                w_waves_crop += int(v[SCAL_WAVES])
             if rung_classes[ri]:
                 w_steps_wide += int(v[SCAL_S_EXEC])
                 w_waves_wide += int(v[SCAL_WAVES])
@@ -1394,6 +1400,7 @@ class Router:
         result.total_relax_steps_useful += w_useful
         result.total_relax_steps_wasted += w_steps - w_useful
         result.total_relax_steps_cropped += w_steps_crop
+        result.total_waves_cropped += w_waves_crop
         result.total_relax_steps_wide += w_steps_wide
         result.total_waves += w_waves
         # the device counts sink rows; a row is sink_cells elements
@@ -1408,6 +1415,7 @@ class Router:
             crit_path_delay=bk["cpd"], window=bk["widx"], kind=bk["kind"],
             precise=bk["precise"], sweep_boost=bk["sweep_boost"],
             waves=w_waves, relax_steps_cropped=w_steps_crop,
+            waves_cropped=w_waves_crop,
             net_routes=nroutes, stall_s=bk["stall_s"],
             plan_s=bk["plan_s"], dispatch_ms=bk["dispatch_ms"],
             fanout_class=max(rung_classes),

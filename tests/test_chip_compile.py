@@ -130,6 +130,37 @@ def test_planes_relax_cropped_compiles_at_route_tile(one_chip):
     _fits_hbm(compiled)
 
 
+def _while_loops(compiled) -> int:
+    """The ``while`` instructions of a compiled program's text."""
+    import re
+
+    return len(re.findall(r"^\s*(?:ROOT )?%\S+ = .* while\(",
+                          compiled.as_text(), re.M))
+
+
+def test_the_cropped_relaxation_loops_over_its_sweeps_alone(one_chip):
+    """The v5e compiler's program of planes_relax_cropped at the route
+    tile holds ONE ``while``, the relaxation's: no cut and no write-back
+    is a loop over the batch.  The per-net dynamic slices it replaced
+    (tests/crop_refs.py) compile to one loop a cut and a put, 27 more:
+    the guard that keeps them from coming back unnoticed, and the proof
+    that it would see them."""
+    from crop_refs import planes_relax_cropped_vmap
+    from parallel_eda_tpu.route.planes import planes_relax_cropped
+
+    pg = _planes(ROUTE_NX, ROUTE_W)
+    origin = jax.ShapeDtypeStruct((ROUTE_B,), jnp.int32, sharding=one_chip)
+
+    def loops(relax):
+        fn = jax.jit(relax, static_argnames=("nsweeps", "cnx", "cny"))
+        return _while_loops(fn.lower(
+            *_relax_avatars(pg, ROUTE_B, one_chip), nsweeps=ROUTE_SWEEPS,
+            ox=origin, oy=origin, cnx=ROUTE_TILE, cny=ROUTE_TILE).compile())
+
+    assert loops(planes_relax_cropped) == 1
+    assert loops(planes_relax_cropped_vmap) == 1 + 15 + 6 + 6
+
+
 @pytest.mark.parametrize("arch_fn, n, W, tile", [
     ("k6_n10_40nm_arch", 11, 64, 8),        # route_k6n10_relaxed
     # route_scale: the one populated rung, 16 x 16

@@ -720,7 +720,7 @@ def test_directional_cropped_route_equals_the_full_canvas_route(
     from parallel_eda_tpu.route import planes
 
     def full_canvas(pg, d0, cc, crit_c, wenter0, nsweeps, ox, oy, cnx,
-                    cny, plane_dtype="f32"):
+                    cny, plane_dtype="f32", cut=None):
         assert (cnx, cny) == (16, 16)
         return planes.planes_relax(pg, d0, cc, crit_c, wenter0, nsweeps,
                                    None, plane_dtype)

@@ -141,9 +141,12 @@ def test_the_rows_add_up_to_the_result(routed):
                          ("waves", r.total_waves),
                          ("net_routes", r.total_net_routes),
                          ("relax_steps_cropped",
-                          r.total_relax_steps_cropped)):
+                          r.total_relax_steps_cropped),
+                         ("waves_cropped", r.total_waves_cropped)):
         assert sum(getattr(s, field) for s in r.stats) == total, field
     assert r.total_relax_steps > 0 and r.total_waves > 0
+    # a 5 x 5 grid has no crop rung: no cropped relaxation was called
+    assert r.total_waves_cropped == r.total_relax_steps_cropped == 0
     for s in r.stats:
         assert 0 <= s.stall_s <= s.route_time_s
         assert 0 <= s.plan_s <= s.route_time_s
@@ -295,6 +298,32 @@ def test_a_resumed_route_takes_its_first_kind_from_the_checkpoint():
     assert f.route.success and f.route.iterations == 22
 
 
+def test_a_route_with_a_populated_rung_books_its_cropped_waves():
+    """``waves_cropped``: the calls of the cropped relaxation, by row
+    and on the result, on a route whose 19 x 19 grid has a 16 x 16 rung
+    that every window populates (tests/test_planes.py's fixture): of a
+    row's waves, those of its cropped rungs; with its cropped sweeps or
+    not at all; the rows' sum the result's; a column of the table."""
+    from test_planes import _placed
+
+    f = _placed("directional_l4_19x19")
+    r = Router(f.rr, RouterOpts(batch_size=16)).route(f.term)
+    assert r.success
+    assert 0 < r.total_waves_cropped < r.total_waves
+    assert sum(s.waves_cropped for s in r.stats) == r.total_waves_cropped
+    for s in r.stats:
+        assert 0 <= s.waves_cropped <= s.waves
+        assert (s.waves_cropped > 0) == (s.relax_steps_cropped > 0)
+        # a wave runs at least the sweep that finds its fixpoint
+        assert s.relax_steps_cropped >= s.waves_cropped
+    lines = format_window_table(r).splitlines()
+    # from the right: the sum row leaves three cells empty on the left
+    col = lines[0].split().index("waves_crop") - len(lines[0].split())
+    assert [ln.split()[col] for ln in lines[1:len(r.stats) + 2]] == [
+        str(s.waves_cropped) for s in r.stats] + [
+        str(r.total_waves_cropped)]
+
+
 def test_other_constructors_of_a_row_still_work():
     s = RouteStats(3, 0, 0, 5, 0.25)
     assert (s.window, s.kind, s.kept, s.control_s) == (0, "", True, 0.0)
@@ -307,14 +336,15 @@ def test_the_window_table_prints_the_rows(routed, tmp_path):
     lines = text.splitlines()
     assert lines[0].split() == [
         "window", "iter", "kind", "overused", "nets", "seconds", "stall_s",
-        "control_s", "sweeps", "waves", "batches", "routes", "routes/batch",
-        "kept", "pick_read%"]
+        "control_s", "sweeps", "waves", "waves_crop", "batches", "routes",
+        "routes/batch", "kept", "pick_read%"]
     assert len(lines) == len(r.stats) + 3
     for line, s in zip(lines[1:], r.stats):
         cells = line.split()
         assert cells[:5] == [str(s.window), str(s.iteration), s.kind,
                              str(s.overused_nodes), str(s.rerouted_nets)]
         assert cells[8:] == [str(s.relax_steps), str(s.waves),
+                             str(s.waves_cropped),
                              str(s.batches), str(s.net_routes),
                              "%.1f" % (s.net_routes / s.batches),
                              "yes" if s.kept else "NO",
