@@ -1574,7 +1574,112 @@ def sink_pick_wave(dist, pin_congj, crit_w, cw, sink_tabs, remaining,
 
 
 # entries of _step_core's ledger vector (scal's SCAL_S_EXEC.. tail)
-STEP_LEDGER_LEN = 7
+STEP_LEDGER_LEN = 8
+
+# walk slots a trip of walk_scatters takes (tools/walk_forms.py times
+# the candidates alone; PERF.md section 6, PR 42, has the table)
+WALK_CHUNK = 16
+
+
+def walk_slots_read(steps, Kw: int):
+    """(trips, slots): the chunks of ``WALK_CHUNK`` walk slots that hold
+    a wave's first ``steps`` (int32) of ``Kw``, and the slots those
+    trips read a walk: ``steps`` rounded up to whole chunks, at most
+    Kw."""
+    chunk = min(WALK_CHUNK, Kw)
+    trips = (steps + (chunk - 1)) // chunk
+    return trips, jnp.minimum(trips * chunk, Kw)
+
+
+def walk_scatters(buf, seg, walk_cells, walk_tdel, nodes_w, keep, posn):
+    """A wave's two element scatters out of its walk records (each
+    [B, G, Kw], slot k what step k of ``traceback_walk`` recorded):
+
+    buf [B, ncells + 1]    min= ``walk_tdel`` at ``walk_cells`` (the
+                           tree grows; column ncells is the dump)
+    seg [B, G, max_len]    the kept nodes (``keep``) of ``nodes_w`` at
+                           ``posn + 2``, in walk order behind the sink
+                           and its IPIN
+
+    taken from the slots up to the last one that holds a record of a
+    KEPT walk ONLY (``walk_cells`` under ncells: the caller has sent
+    every other walk's cells to the dump column, and ``keep`` is false
+    wherever the cell is the dump), in trips of ``WALK_CHUNK``: past it
+    every record is the fill, which goes to the dump column and is not
+    kept.  A walk that overran its budget is not kept, so it costs no
+    trip.  ``min`` is exact in any order and a walk's kept targets are
+    distinct, so the trips write what one scatter over all Kw slots
+    writes (walk_scatters_dense), bit for bit but for the dump column,
+    which nobody reads; the last chunk of a Kw that is
+    no multiple of the chunk starts at Kw - chunk and writes some slots
+    a second time, the same values.  No kept walk, no trip.
+
+    Returns (buf, seg, the slots the trips read a walk: int32)."""
+    B, G, Kw = nodes_w.shape
+    width, max_len = buf.shape[1], seg.shape[2]
+    chunk = min(WALK_CHUNK, Kw)
+    # both stores are carried FLAT, under flat indices: the v5e's
+    # scatter works on a linear operand, and re-laying a [B, ncells + 1]
+    # canvas into one and back again costs more than a trip's updates
+    # (PERF.md section 6, PR 42); flat, a wave re-lays it once
+    with device_scope("route.dev.tree_grow"):
+        last = jnp.max(jnp.where(
+            walk_cells < width - 1,
+            jnp.arange(1, Kw + 1, dtype=jnp.int32), 0))
+        trips, slots = walk_slots_read(last, Kw)
+        row0 = (jnp.arange(B, dtype=jnp.int32) * width)[:, None, None]
+        buf = buf.reshape(-1)
+    with device_scope("route.dev.traceback"):
+        seg0 = (jnp.arange(B * G, dtype=jnp.int32)
+                * max_len).reshape(B, G, 1)
+        seg = seg.reshape(-1)
+
+    def cut(a, k):
+        # the clamp spelled out: left to dynamic_slice, the v5e
+        # compiler's program read the last chunk's records from
+        # different slots (my chip run, PR 42)
+        return lax.dynamic_slice_in_dim(
+            a, jnp.minimum(k * chunk, Kw - chunk), chunk, axis=2)
+
+    def trip(k, carry):
+        buf, seg = carry
+        with device_scope("route.dev.traceback"):
+            seg = seg.at[jnp.where(cut(keep, k), seg0 + cut(posn, k) + 2,
+                                   B * G * max_len).reshape(-1)].set(
+                cut(nodes_w, k).reshape(-1), mode="drop")
+        with device_scope("route.dev.tree_grow"):
+            buf = buf.at[(row0 + cut(walk_cells, k)).reshape(-1)].min(
+                cut(walk_tdel, k).reshape(-1))
+        return buf, seg
+
+    # the loop itself (its counter, its carry) is booked to the tree
+    # grow; a trip's path-row ops name their own scope inside it
+    with device_scope("route.dev.tree_grow"):
+        buf, seg = lax.fori_loop(0, trips, trip, (buf, seg))
+        buf = buf.reshape(B, width)
+    with device_scope("route.dev.traceback"):
+        return buf, seg.reshape(B, G, max_len), slots
+
+
+def walk_scatters_dense(buf, seg, walk_cells, walk_tdel, nodes_w, keep,
+                        posn):
+    """walk_scatters' stores by ONE scatter each over all Kw slots, the
+    batch a batch dimension of both (the program until PR 42; the fill
+    goes to the dump column / is dropped): the form under a GSPMD mesh,
+    whose 'net' axis shards B.  walk_scatters' flat stores hide B from
+    the partitioner (two all-gathers and two all-reduces a wave on a
+    2 x 2 mesh, none here: the v5e compiler off the chip, PR 42).
+    Reads the budget; the reference of tests/walk_refs.py."""
+    B, G, Kw = nodes_w.shape
+    rows = jnp.arange(B)[:, None]
+    with device_scope("route.dev.traceback"):
+        seg = seg.at[rows[:, :, None], jnp.arange(G)[None, :, None],
+                     jnp.where(keep, posn + 2, seg.shape[2])].set(
+            nodes_w, mode="drop")
+    with device_scope("route.dev.tree_grow"):
+        buf = buf.at[rows, walk_cells.reshape(B, -1)].min(
+            walk_tdel.reshape(B, -1))
+    return buf, seg, jnp.int32(Kw)
 
 
 def wave_segments(num_waves: int, group: int, doubling: bool):
@@ -1637,8 +1742,10 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
     sweeps executed, sweeps that improved a distance, traceback walk
     steps run, walk steps budgeted, waves executed (one relaxation
     each), sink rows whose distances the picks read (sink_pick_wave;
-    cells_per_sink elements a row) and the B * S a wave of the dense
-    pick reads — in the order of scal's SCAL_S_EXEC.. tail."""
+    cells_per_sink elements a row), the B * S a wave of the dense
+    pick reads and the walk slots the waves' scatters read
+    (walk_scatters; of the budgeted ones) — in the order of scal's
+    SCAL_S_EXEC.. tail."""
     N = dev.num_nodes
     R = all_reached.shape[0]
     B = sel.shape[0]
@@ -1846,12 +1953,6 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             cur, _, cells_w, nodes_w, wst, wsteps = traceback_walk(
                 pred, wenter, noc_p1, pick_cell,
                 ~pick_valid | pick_direct, Kw)
-            # the ledger's walk half: steps this wave ran, of its
-            # budget, the wave itself, and the pick's sink rows read of
-            # the batch's
-            st = st.at[2:].add(jnp.stack([wsteps, jnp.int32(Kw),
-                                          jnp.int32(1), sink_rows,
-                                          jnp.int32(B * S)]))
             # a walk is complete iff it reached a pred==self cell in budget
             nxt_last = jnp.take_along_axis(
                 pred, jnp.clip(cur, 0, ncells - 1), axis=1)
@@ -1887,9 +1988,6 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             seg = jnp.full((B, G, max_len), N, jnp.int32)
             seg = seg.at[:, :, 0].set(jnp.where(ok, pick_sink, N))
             seg = seg.at[:, :, 1].set(jnp.where(ok, pick_ipin, N))
-            seg = seg.at[ar_b[:, :, None], ar_g[:, :, None],
-                         jnp.where(keep, posn + 2, max_len)].set(
-                nodes_w, mode="drop")
             nkeep = jnp.sum(keep, axis=2)                          # [B, G]
             put_e = at_entry & ok
             seg = seg.at[ar_b, ar_g,
@@ -1907,6 +2005,27 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
                          jnp.where(pdm, 3, max_len)].set(
                 jnp.broadcast_to(b_src[:, None], (B, G)), mode="drop")
 
+        with device_scope("route.dev.tree_grow"):
+            walk_cells = jnp.where((ok & ~pick_direct)[:, :, None],
+                                   cells_w, ncells)
+            walk_tdel = tdel_base[:, :, None] + wsum
+
+        # --- the walked nodes into the path rows and the walked cells
+        # into the tree (cell space, deterministically via min): the
+        # slots the kept walks ran, no others; a mesh that shards the
+        # batch keeps it a dimension of both scatters ---
+        buf, seg, wslots = (walk_scatters_dense if gspmd
+                            else walk_scatters)(
+            jnp.full((B, ncells + 1), INF, jnp.float32), seg,
+            walk_cells, walk_tdel, nodes_w, keep, posn)
+
+        with device_scope("route.dev.traceback"):
+            # the ledger's walk half: steps this wave ran, of its
+            # budget, the wave itself, the pick's sink rows read of
+            # the batch's, and the walk slots the two scatters read
+            st = st.at[2:].add(jnp.stack([
+                wsteps, jnp.int32(Kw), jnp.int32(1), sink_rows,
+                jnp.int32(B * S), wslots]))
             # --- store results at the picked sink slots ---
             old = jnp.take_along_axis(wpaths, order[:, :, None], axis=1)
             wpaths = wpaths.at[ar_b, order].set(
@@ -1919,12 +2038,6 @@ def _step_core(pg: PlanesGraph, dev: DeviceRRGraph, occ, acc, pres_fac,
             remaining = remaining.at[ar_b, order].set(old_rem & ~ok)
 
         with device_scope("route.dev.tree_grow"):
-            # --- grow the tree (cell space), deterministically via min ---
-            walk_cells = jnp.where((ok & ~pick_direct)[:, :, None], cells_w,
-                                   ncells).reshape(B, -1)
-            walk_tdel = (tdel_base[:, :, None] + wsum).reshape(B, -1)
-            buf = jnp.full((B, ncells + 1), INF, jnp.float32)
-            buf = buf.at[arangeB[:, None], walk_cells].min(walk_tdel)
             newly = jnp.isfinite(buf[:, :ncells])
             tdel_cells = jnp.where(newly, buf[:, :ncells], tdel_cells)
             seed_cells = seed_cells | newly
@@ -2208,10 +2321,11 @@ def _window_body(
     steps_useful are the MEASURED relaxation-sweep counters summed over
     every executed group/wave of the window (executed trips of the
     bounded while_loop, and the subset that improved some distance);
-    scal's last five entries are the traceback walk's ledger summed
+    scal's last six entries are the traceback walk's ledger summed
     the same way (steps run, and the Kw budgeted per executed wave),
-    the executed waves themselves and the sink pick's (sink rows read,
-    and the B * S per executed wave a dense pick reads);
+    the executed waves themselves, the sink pick's (sink rows read,
+    and the B * S per executed wave a dense pick reads) and the walk
+    slots the waves' two scatters read;
     ``status``/``scal`` repack the per-net mask/color/bb fields and the
     scalar counters into two small int32 arrays so the pipelined driver
     can pull the whole window summary with one async copy
@@ -2631,14 +2745,15 @@ SCAL_OVER_TOTAL = 1
 SCAL_NROUTES = 2
 SCAL_NEXEC = 3
 SCAL_MAX_SPAN = 4
-SCAL_S_EXEC = 5       # 5..11: _step_core's ledger vector, in its order
+SCAL_S_EXEC = 5       # 5..12: _step_core's ledger vector, in its order
 SCAL_S_USEFUL = 6
 SCAL_WALK_STEPS = 7
 SCAL_WALK_BUDGET = 8
 SCAL_WAVES = 9
 SCAL_SINK_ROWS = 10
 SCAL_SINK_ROWS_DENSE = 11
-SCAL_LEN = 12
+SCAL_WALK_SLOTS = 12
+SCAL_LEN = 13
 
 
 def unpack_window_status(status):

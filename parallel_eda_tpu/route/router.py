@@ -361,6 +361,11 @@ class RouteResult:
     # wave).  A share near 1 means paths are pressing on the budget.
     total_walk_steps: int = 0
     total_walk_budget: int = 0
+    # of the budgeted slots, those the waves' two element scatters
+    # (tree grow, path assembly) read: the steps of each wave's longest
+    # KEPT walk rounded up to whole chunks (planes.walk_scatters); a
+    # program that scatters every slot (a GSPMD mesh's) reads the budget
+    total_walk_slots_read: int = 0
     # sink-pick ledger (windowed planes program): distance elements the
     # waves' picks read (the live rung's M sink rows, or all B * S on
     # the dense rung, x cells_per_sink), and what a dense pick reads in
@@ -1359,7 +1364,8 @@ class Router:
         from .planes import (SCAL_NEXEC, SCAL_NROUTES, SCAL_S_EXEC,
                              SCAL_S_USEFUL, SCAL_SINK_ROWS,
                              SCAL_SINK_ROWS_DENSE, SCAL_WALK_BUDGET,
-                             SCAL_WALK_STEPS, SCAL_WAVES)
+                             SCAL_WALK_SLOTS, SCAL_WALK_STEPS,
+                             SCAL_WAVES)
 
         w_steps = w_useful = w_steps_crop = w_waves_crop = 0
         nroutes = nexec = w_waves = 0
@@ -1377,6 +1383,7 @@ class Router:
             w_useful += int(v[SCAL_S_USEFUL])
             result.total_walk_steps += int(v[SCAL_WALK_STEPS])
             result.total_walk_budget += int(v[SCAL_WALK_BUDGET])
+            result.total_walk_slots_read += int(v[SCAL_WALK_SLOTS])
             w_waves += int(v[SCAL_WAVES])
             w_sink_rows += int(v[SCAL_SINK_ROWS])
             w_sink_rows_dense += int(v[SCAL_SINK_ROWS_DENSE])

@@ -41,10 +41,12 @@ import pytest  # noqa: E402
 # backend_compile_and_load; the hung run that follows is cut at its
 # time limit).  Seen three runs in three once the suite grew to ~790
 # tests: the workers of a healthy run already stand at 55-60 thousand.
-# So between modules, past a third of the limit, the compiled programs
+# So between modules, past a fifth of the limit, the compiled programs
 # are let go (jax.clear_caches() unmaps them); the next module compiles
-# what it needs, as it would in a worker of its own.
-_MAPS_RELEASE_AT = 20000
+# what it needs, as it would in a worker of its own.  A module that
+# maps most of the limit by itself (tests/test_kernel_pack.py: 57
+# thousand) calls release_compiled_programs between its own tests.
+_MAPS_RELEASE_AT = 12000
 
 
 def _n_maps() -> int:
@@ -55,9 +57,13 @@ def _n_maps() -> int:
         return 0
 
 
+def release_compiled_programs(at: int = _MAPS_RELEASE_AT) -> None:
+    if _n_maps() > at:
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _release_compiled_programs():
     yield
-    if _n_maps() > _MAPS_RELEASE_AT:
-        jax.clear_caches()
-        gc.collect()
+    release_compiled_programs()

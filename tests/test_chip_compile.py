@@ -161,6 +161,74 @@ def test_the_cropped_relaxation_loops_over_its_sweeps_alone(one_chip):
     assert loops(planes_relax_cropped_vmap) == 1 + 15 + 6 + 6
 
 
+def test_the_walk_scatters_are_one_loop_and_no_copy_of_themselves(one_chip):
+    """The v5e compiler's program of planes.walk_scatters at
+    route_relaxed's wave (B 64, G 8, Kw 188, 20,240 cells) holds ONE
+    ``while`` more than one scatter each over the whole budget
+    (tests/walk_refs.py) and the same number of scatters: the trips are
+    a loop, not a ladder of copies."""
+    import re
+
+    from parallel_eda_tpu.route.planes import walk_scatters
+    from walk_refs import walk_scatters_dense
+
+    B, G, Kw, ncells = 64, 8, 188, 20240
+
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (a((B, ncells + 1), jnp.float32), a((B, G, Kw + 4), jnp.int32),
+            a((B, G, Kw), jnp.int32), a((B, G, Kw), jnp.float32),
+            a((B, G, Kw), jnp.int32), a((B, G, Kw), jnp.bool_),
+            a((B, G, Kw), jnp.int32))
+
+    def counts(form):
+        compiled = jax.jit(form).lower(*args).compile()
+        _fits_hbm(compiled)
+        return (_while_loops(compiled),
+                len(re.findall(r" scatter\(", compiled.as_text())))
+
+    loops, scatters = counts(walk_scatters)
+    loops_dense, scatters_dense = counts(walk_scatters_dense)
+    assert loops == loops_dense + 1
+    assert scatters == scatters_dense == 2
+
+
+def test_the_mesh_form_of_the_walk_scatters_holds_no_collective(topo):
+    """Under a GSPMD mesh whose 'net' axis shards the batch, the wave
+    runs planes.walk_scatters_dense: B stays a batch dimension of both
+    scatters and the v5e partitioner adds no collective (the flat stores
+    of walk_scatters cost it all-gathers and all-reduces there)."""
+    import re
+
+    from parallel_eda_tpu.route.planes import walk_scatters_dense
+
+    B, G, Kw, ncells = 64, 8, 188, 20240
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("net", "node"))
+
+    def by_net(ndim):
+        return NamedSharding(mesh, P("net", *(None,) * (ndim - 1)))
+
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=by_net(len(shape)))
+
+    def form(*args):
+        buf, seg, slots = walk_scatters_dense(*args)
+        return (jax.lax.with_sharding_constraint(buf, by_net(2)),
+                jax.lax.with_sharding_constraint(seg, by_net(3)), slots)
+
+    text = jax.jit(form).lower(
+        a((B, ncells + 1), jnp.float32), a((B, G, Kw + 4), jnp.int32),
+        a((B, G, Kw), jnp.int32), a((B, G, Kw), jnp.float32),
+        a((B, G, Kw), jnp.int32), a((B, G, Kw), jnp.bool_),
+        a((B, G, Kw), jnp.int32)).compile().as_text()
+    assert not re.findall(
+        r" (?:all-gather|all-reduce|all-to-all|collective-permute)"
+        r"(?:-start)?\(", text)
+    assert len(re.findall(r" scatter\(", text)) == 2
+
+
 @pytest.mark.parametrize("arch_fn, n, W, tile", [
     ("k6_n10_40nm_arch", 11, 64, 8),        # route_k6n10_relaxed
     # route_scale: the one populated rung, 16 x 16

@@ -113,6 +113,23 @@ def test_the_endgame_is_the_endgame_of_dense_sink_picks():
         == dense.total_sink_reads_dense
 
 
+def test_the_endgame_is_the_endgame_of_dense_walk_scatters():
+    """Negotiation, finishing pass and re-legalisation with every wave
+    scattering ALL its walk slots (tests/walk_refs.py, the program until
+    PR 42): the same route, window for window; the trips as built read
+    the steps in whole chunks, never the budget."""
+    from sink_pick_refs import assert_same_route
+    from walk_refs import dense_scatters
+
+    res, windows, _ = _route(9)
+    with dense_scatters():
+        dense, windows_dense, _ = _route(9)
+    assert windows == windows_dense == W9_TO_THE_PASS + [(22, 0, 3, 45, 2)]
+    assert_same_route(res, dense, but=("total_walk_slots_read",))
+    assert 0 < res.total_walk_slots_read < res.total_walk_budget \
+        == dense.total_walk_slots_read
+
+
 def test_a_finish_that_does_not_land_restores_the_snapshot():
     """The pass starts (11 + 4 < 16), its window ends two nodes over and
     the iterations run out: the route returned is the snapshot of

@@ -190,6 +190,21 @@ def test_the_route_is_the_route_of_dense_sink_picks(placed, routed):
         == routed.total_sink_reads
 
 
+def test_the_route_is_the_route_of_dense_walk_scatters(placed, routed):
+    """The waves' chunked walk scatters (planes.walk_scatters) move
+    nothing: with ONE scatter each over every walk slot (the program
+    until PR 42) the two-class route comes back node for node and count
+    for count, the wide class's waves of 40 picks among them."""
+    from sink_pick_refs import assert_same_route
+    from walk_refs import dense_scatters
+
+    with dense_scatters():
+        dense = _route(placed)
+    assert_same_route(routed, dense, but=("total_walk_slots_read",))
+    assert 0 < routed.total_walk_slots_read < routed.total_walk_budget \
+        == dense.total_walk_slots_read
+
+
 def test_two_runs_are_identical(placed, routed):
     again = _route(placed)
     assert again.wirelength == routed.wirelength

@@ -32,6 +32,18 @@ from parallel_eda_tpu.serve.batcher import (VMEM_BUDGET_BYTES,
                                             unpacked_lane_occupancy)
 
 
+@pytest.fixture(autouse=True)
+def _release_between_tests():
+    """This module's routes map 57 of the 65 thousand memory mappings a
+    process may hold (tests/conftest.py): entered by a worker that
+    already holds nine, its last route dies in the compiler.  Its tests
+    share no compiled program that matters, so the programs are let go
+    between them past half the limit."""
+    yield
+    from conftest import release_compiled_programs
+    release_compiled_programs(at=32000)
+
+
 def _instance(arch, nx, ny, B, seed):
     grid = DeviceGrid(nx, ny, arch.io_capacity)
     rr = build_rr_graph(arch, grid)

@@ -161,6 +161,25 @@ def test_wave_ledger_counts_and_repeats(routed):
     assert _route_tiny().total_waves == res.total_waves
 
 
+@pytest.mark.ledger
+def test_walk_slot_ledger_counts_and_repeats(routed):
+    """The eighth entry of the step's ledger vector: the walk slots the
+    waves' two scatters read, each wave the steps of its longest KEPT
+    walk in whole chunks of planes.WALK_CHUNK and at most its budget; a
+    wave that picked only direct connections walked and read nothing."""
+    from parallel_eda_tpu.route.planes import (SCAL_LEN, SCAL_WALK_SLOTS,
+                                               STEP_LEDGER_LEN, WALK_CHUNK)
+
+    res, _, _ = routed
+    assert (STEP_LEDGER_LEN, SCAL_WALK_SLOTS, SCAL_LEN) == (8, 12, 13)
+    assert 0 < res.total_walk_slots_read < res.total_walk_budget
+    # no wave read a chunk's worth more than it walked (it reads less
+    # where its longest walk overran and was not kept)
+    assert res.total_walk_slots_read - res.total_walk_steps \
+        < WALK_CHUNK * res.total_waves
+    assert _route_tiny().total_walk_slots_read == res.total_walk_slots_read
+
+
 def _full_budget_walk(pred, wenter, noc_p1, pick_cell, done0, Kw):
     """The walk as it was before it could end early: a fixed-trip loop
     of Kw steps, each a scatter at one position of [B, G, Kw]."""
@@ -223,6 +242,9 @@ def test_early_ending_walk_routes_as_the_full_budget_walk(
         forget()
     assert full.total_walk_steps == full.total_walk_budget \
         == res.total_walk_budget
+    # the scatters follow the records the walks kept, not the trips
+    # the walk's loop ran
+    assert full.total_walk_slots_read == res.total_walk_slots_read
     assert res.total_walk_steps < full.total_walk_steps
     assert (res.success, res.iterations, res.wirelength,
             res.total_relax_steps, res.total_relax_steps_useful) == (

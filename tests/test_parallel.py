@@ -146,6 +146,11 @@ def test_planes_window_sharded_matches_single_device(shape):
     assert np.array_equal(res0.occ, res1.occ)
     assert np.isclose(cpd0, cpd1, rtol=1e-6)
     check_route(rr, term, res1.paths, occ=res1.occ)
+    # the mesh shards the batch: its waves scatter every walk slot with
+    # B a batch dimension (planes.walk_scatters_dense), one device the
+    # slots the kept walks ran
+    assert 0 < res0.total_walk_slots_read < res0.total_walk_budget \
+        == res1.total_walk_budget == res1.total_walk_slots_read
 
 
 def test_windowed_sharded_matches_single_device():

@@ -190,12 +190,14 @@ def _one_window(mesh=None, shard=None):
     return out[:6] + (out[22],)
 
 
-def _assert_window_equal(got):
+def _assert_window_equal(got, shards_batch=False):
     if "window" not in _BASE:
         _BASE["window"] = _one_window()
     want = _BASE["window"]
     from parallel_eda_tpu.route.planes import (SCAL_SINK_ROWS,
-                                               SCAL_SINK_ROWS_DENSE)
+                                               SCAL_SINK_ROWS_DENSE,
+                                               SCAL_WALK_BUDGET,
+                                               SCAL_WALK_SLOTS)
 
     assert np.asarray(want[4]).any()          # nets were routed
     # under a mesh the sink pick is the dense one, statically (the live
@@ -205,6 +207,12 @@ def _assert_window_equal(got):
     assert scal[SCAL_SINK_ROWS] == scal[SCAL_SINK_ROWS_DENSE] \
         == scal_one[SCAL_SINK_ROWS_DENSE] > scal_one[SCAL_SINK_ROWS] > 0
     scal[SCAL_SINK_ROWS] = scal_one[SCAL_SINK_ROWS]
+    if shards_batch:
+        # a GSPMD mesh's waves scatter every walk slot, the batch a
+        # dimension of both scatters (planes.walk_scatters_dense)
+        assert scal[SCAL_WALK_SLOTS] == scal[SCAL_WALK_BUDGET] \
+            == scal_one[SCAL_WALK_BUDGET] > scal_one[SCAL_WALK_SLOTS] > 0
+        scal[SCAL_WALK_SLOTS] = scal_one[SCAL_WALK_SLOTS]
     for name, a, b in zip(("occ", "acc", "paths", "sink_delay",
                            "all_reached", "bb", "scal"),
                           got[:6] + (scal,), want):
@@ -217,7 +225,8 @@ def test_window_under_gspmd_net_mesh_equals_one_device():
     from parallel_eda_tpu.parallel.shard import make_mesh, shard_graph
     mesh = make_mesh(2, shape=(2, 1))
     _assert_window_equal(
-        _one_window(mesh, lambda dev: shard_graph(dev, mesh)))
+        _one_window(mesh, lambda dev: shard_graph(dev, mesh)),
+        shards_batch=True)
 
 
 @needs_mesh
