@@ -116,15 +116,14 @@ def _config_key(args) -> str:
     from parallel_eda_tpu.route import RouterOpts as _RO
     div = (f"_d{args.budget_div}"
            if args.budget_div != _RO().sweep_budget_div else "")
-    # same stability rule for the PR-11 kernel knobs: suffix only when
-    # they leave the default, so the f32/per-rung config of record
-    # keeps its scenario id
+    # same stability rule for the plane dtype: suffix only when it
+    # leaves the default, so the f32 config of record keeps its
+    # scenario id
     pd = (f"_p{args.plane_dtype}"
           if getattr(args, "plane_dtype", "f32") != "f32" else "")
-    fu = "_fused" if getattr(args, "fused_dispatch", False) else ""
     return (f"scale{int(bool(args.scale))}_l{args.luts}"
             f"_w{args.chan_width}_{args.program}_b{args.batch}"
-            f"{div}{pd}{fu}")
+            f"{div}{pd}")
 
 
 def _runstore():
@@ -478,10 +477,6 @@ def main():
                          "(bf16 halves the modeled plane traffic and "
                          "is the dtype the route commits: a different "
                          "result, not a faster f32 one)")
-    ap.add_argument("--fused_dispatch", action="store_true",
-                    help="one ragged packed window program walking "
-                         "every populated crop rung instead of one "
-                         "dispatch per rung")
     args = ap.parse_args()
     serial_error = None
     if args.budget_div is None:
@@ -543,8 +538,7 @@ def main():
     router = Router(rr, RouterOpts(
         batch_size=args.batch, program=args.program,
         sweep_budget_div=args.budget_div, pipeline=not args.sync,
-        plane_dtype=args.plane_dtype,
-        fused_dispatch=args.fused_dispatch))
+        plane_dtype=args.plane_dtype))
     from parallel_eda_tpu.obs import (compile_seconds, get_metrics,
                                       reset_compile_seconds)
     c0 = compile_seconds()

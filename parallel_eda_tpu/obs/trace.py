@@ -40,7 +40,7 @@ from typing import Optional
 
 
 # the window program's stages on the device: every op of
-# route_window_planes / _fused / _multi lies under exactly one
+# route_window_planes lies under exactly one
 # top-level name (nested ones only under route.dev.relax and
 # route.dev.sta).  The names
 # are what a reduction of a device trace keys on (benchmark/

@@ -5,7 +5,7 @@ The contract every component here enforces is the repo's bit-identical
 discipline: a recovery action may change *timing* (retries, backoff,
 slower fallback programs) but never *QoR*.  Each rung of the
 degradation ladder is one of the already-proven bit-identical
-alternates (AOT library vs live jit, fused vs per-rung dispatch,
+alternates (AOT library vs live jit, the mesh transports,
 pipelined vs --sync, checkpoint-resume vs straight-through), so a run
 that weathers injected faults must finish with wirelength identical to
 the fault-free run — the chaos CI gate asserts exactly that.

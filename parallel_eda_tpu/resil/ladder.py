@@ -1,12 +1,10 @@
 """The graceful-degradation ladder.
 
-Four dimensions, each an ordered list of execution levels, fastest
+Three dimensions, each an ordered list of execution levels, fastest
 first, all bit-identical in QoR:
 
   pipeline: pipelined -> sync
   program:  aot -> jit
-  dispatch: fused -> per_rung   (one ragged packed dispatch per
-            window vs one dispatch per populated crop rung)
   mesh:     pallas_halo -> ppermute -> single_chip   (multi-chip
             halo-exchange relaxation, route/planes_shard.py: the
             overlapped remote-DMA transport, the on-critical-path
@@ -17,12 +15,9 @@ first, all bit-identical in QoR:
 
 "program" descends *per dispatch-variant* inside ``DispatchGuard``
 (quarantine picks the rung); the ladder records every such step.
-"pipeline", "dispatch", and a floor override for "program" are
-*global*: the service steps them when a whole job attempt is
-poisoned, and the router consults ``level()`` when building a
-dispatch chain.  The "dispatch" levels are inert unless
-RouterOpts.fused_dispatch opted in — level 0 names the opt-in mode,
-not a default.
+"pipeline" and a floor override for "program" are *global*: the
+service steps them when a whole job attempt is poisoned, and the
+router consults ``level()`` when building a dispatch chain.
 Every step is observable — the ``route.resil.degradation_steps``
 counter, per-dimension ``route.resil.level.<dim>`` gauges, and a
 trace instant.
@@ -36,7 +31,6 @@ from ..obs.trace import get_tracer
 DIMS: Dict[str, tuple] = {
     "pipeline": ("pipelined", "sync"),
     "program": ("aot", "jit"),
-    "dispatch": ("fused", "per_rung"),
     "mesh": ("pallas_halo", "ppermute", "single_chip"),
 }
 
@@ -44,8 +38,6 @@ DIMS: Dict[str, tuple] = {
 _LABEL_DIM = {
     "aot": "program",
     "jit": "program",
-    "fused": "dispatch",
-    "per_rung": "dispatch",
     "pallas_halo": "mesh",
     "ppermute": "mesh",
     "single_chip": "mesh",

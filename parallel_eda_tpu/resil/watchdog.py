@@ -3,8 +3,7 @@ variant quarantine and descent down a chain of bit-identical rungs.
 
 The router hands ``DispatchGuard.run`` an ordered chain of ``Rung``s —
 alternate ways to execute the SAME window program with the SAME
-arguments (AOT library, live jit, fused vs per-rung dispatch, the
-mesh transports).  Every rung is bit-identical by construction, so
+arguments (AOT library, live jit, the mesh transports).  Every rung is bit-identical by construction, so
 stepping down the chain changes timing only.  A rung that keeps failing (or exceeds the
 watchdog budget) is quarantined *for that dispatch-variant key*: later
 dispatches of the same variant skip it, i.e. the variant is

@@ -54,7 +54,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -2271,7 +2271,53 @@ WINDOW_STATIC_ARGNAMES = ("K_iters", "nsweeps", "max_len", "num_waves",
                           "use_sdc", "crop_tile", "plane_dtype", "fclass")
 
 
-def _window_body(
+class WindowOut(NamedTuple):
+    """What route_window_planes returns, in order (a tuple still: a
+    positional reader and jit's pytree of leaves see the 23 results)."""
+    # the negotiation state, threaded to the next dispatch (the first
+    # six and crit_all are donated in)
+    occ: Any
+    acc: Any
+    paths: Any
+    sink_delay: Any
+    all_reached: Any
+    bb: Any
+    pres: Any           # the present factor after K_iters escalations
+    rrm: Any            # [R] nets to re-route next window
+    colors: Any         # [R] their conflict colours (_mis_colors)
+    n_over: Any
+    over_total: Any
+    nroutes: Any        # net routes run / groups executed, this window
+    nexec: Any
+    crit_all: Any       # loop state (donated); the device STA's when tdev
+    dmax_hist: Any      # [K_iters] crit-path delay an iteration (NaN: no STA)
+    max_span: Any       # widest live bb half-perimeter of a dirty net
+    dev_wide: Any       # [R] nets whose live bb widened to device scale
+    live_wh: Any        # [R] uint16 (ceil(w/8) << 8) | ceil(h/8), live bb
+    unreached: Any      # [R] nets that missed a sink
+    # the MEASURED relaxation-sweep counters summed over every executed
+    # group/wave of the window: executed trips of the bounded
+    # while_loop, and the subset that improved some distance
+    steps_exec: Any
+    steps_useful: Any
+    # the per-net mask/colour/bb fields and the scalar counters repacked
+    # into two small int32 arrays, so the pipelined driver pulls the
+    # whole window summary with one async copy each
+    status: Any         # [R], unpack_window_status below
+    scal: Any           # [SCAL_LEN], SCAL_* below: the five scalars,
+    #                     then _step_core's ledger summed like steps_exec
+    #                     (walk steps run and the Kw budgeted per
+    #                     executed wave, the executed waves, the sink
+    #                     pick's rows read and the B * S a dense pick
+    #                     reads, the walk slots the two scatters read)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=WINDOW_STATIC_ARGNAMES,
+    donate_argnames=("occ", "acc", "paths", "sink_delay", "all_reached",
+                     "bb", "crit_all"))
+def route_window_planes(
         pg: PlanesGraph, dev: DeviceRRGraph, occ, acc,
         paths, sink_delay, all_reached, bb,
         source_all, sinks_all, crit_all,
@@ -2314,22 +2360,7 @@ def _window_body(
     (donated) and the per-iteration crit-path delays come back in
     dmax_hist [K_iters].
 
-    Returns (occ, acc, paths, sink_delay, all_reached, bb, pres,
-    rrm [R], colors [R], n_over, over_total, nroutes, nexec, crit_all,
-    dmax_hist, max_span, dev_wide, live_wh, unreached, steps_exec,
-    steps_useful, status [R], scal [SCAL_LEN]) — steps_exec/
-    steps_useful are the MEASURED relaxation-sweep counters summed over
-    every executed group/wave of the window (executed trips of the
-    bounded while_loop, and the subset that improved some distance);
-    scal's last six entries are the traceback walk's ledger summed
-    the same way (steps run, and the Kw budgeted per executed wave),
-    the executed waves themselves, the sink pick's (sink rows read,
-    and the B * S per executed wave a dense pick reads) and the walk
-    slots the waves' two scatters read;
-    ``status``/``scal`` repack the per-net mask/color/bb fields and the
-    scalar counters into two small int32 arrays so the pipelined driver
-    can pull the whole window summary with one async copy
-    (unpack_window_status / SCAL_* below)."""
+    Returns a WindowOut."""
     G = sel_plan.shape[0]
     # valid_plan carries each slot's conflict-colour segment (0 = pad);
     # a bool plan is one segment
@@ -2507,235 +2538,11 @@ def _window_body(
             jnp.stack([n_over_s, over_tot_s, nroutes, nexec,
                        max_span.astype(jnp.int32)]).astype(jnp.int32),
             led])
-    return (occ, acc, paths, sink_delay, all_reached, bb, pres, rrm,
-            colors, n_over_s, over_tot_s, nroutes, nexec, crit_all,
-            dmax_hist, max_span, dev_wide, live_wh, unreached,
-            s_exec, s_useful, status, scal)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=WINDOW_STATIC_ARGNAMES,
-    donate_argnames=("occ", "acc", "paths", "sink_delay", "all_reached",
-                     "bb", "crit_all"))
-def route_window_planes(
-        pg: PlanesGraph, dev: DeviceRRGraph, occ, acc,
-        paths, sink_delay, all_reached, bb,
-        source_all, sinks_all, crit_all,
-        opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
-        direct_oidx_all, direct_ipin_all, direct_delay_all,
-        sel_plan, valid_plan, full_bb,
-        pres0, pres_mult, max_pres, acc_fac, it0, force_until,
-        K_iters: int, nsweeps: int, max_len: int, num_waves: int,
-        group: int, doubling: bool = True, topk: int = 1024,
-        n_colors: int = 5, mesh=None,
-        tdev=None, req_seed=None, sta_depth: int = 0,
-        crit_exp: float = 1.0, max_crit: float = 0.99,
-        use_sdc: bool = False,
-        crop_tile=None, bb0_all=None, widen_ok=None,
-        plane_dtype: str = "f32", fan=None, fclass: int = 0):
-    """One window RUNG as its own jit program (contract: _window_body's
-    docstring) — the per-rung dispatch shape the Router's crop ladder
-    used before the fused program below, kept as the watchdog fallback
-    and the bit-exactness reference of the fused mode."""
-    return _window_body(
-        pg, dev, occ, acc, paths, sink_delay, all_reached, bb,
-        source_all, sinks_all, crit_all,
-        opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
-        direct_oidx_all, direct_ipin_all, direct_delay_all,
-        sel_plan, valid_plan, full_bb,
-        pres0, pres_mult, max_pres, acc_fac, it0, force_until,
-        K_iters, nsweeps, max_len, num_waves, group, doubling, topk,
-        n_colors, mesh, tdev, req_seed, sta_depth, crit_exp, max_crit,
-        use_sdc, crop_tile, bb0_all, widen_ok, plane_dtype, fan, fclass)
-
-
-# the fused program's static argnames: the per-rung statics
-# (crop_tile / nsweeps / num_waves / group / doubling) move into the
-# ragged ``rung_desc`` descriptor table; everything else is shared with
-# the per-rung program.  serve/library.py resolves a function's static
-# split via its ``_static_argnames`` attribute (set below), falling
-# back to WINDOW_STATIC_ARGNAMES for the legacy per-rung program.
-FUSED_WINDOW_STATIC_ARGNAMES = tuple(
-    n for n in WINDOW_STATIC_ARGNAMES
-    if n not in ("nsweeps", "num_waves", "group", "doubling",
-                 "crop_tile", "fclass")) + ("rung_desc",)
-
-
-def _fused_ladder(
-        pg: PlanesGraph, dev: DeviceRRGraph, occ, acc,
-        paths, sink_delay, all_reached, bb,
-        source_all, sinks_all, crit_all,
-        opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
-        direct_oidx_all, direct_ipin_all, direct_delay_all,
-        sel_plans, valid_plans, full_bb,
-        pres0, pres_mult, max_pres, acc_fac, it0, force_until,
-        K_iters: int, max_len: int, rung_desc, topk: int,
-        n_colors: int, mesh, tdev, req_seed, sta_depth: int,
-        crit_exp: float, max_crit: float, use_sdc: bool,
-        bb0_all, widen_oks, plane_dtype: str, fan=None):
-    """The traced body shared by route_window_planes_fused (one job)
-    and route_window_planes_multi (one job per co-admitted tenant):
-    walk the ragged ``rung_desc`` descriptor table, threading the
-    negotiation state rung to rung exactly as the host per-rung loop
-    does.  See route_window_planes_fused for the full contract."""
-    if widen_oks is None:
-        widen_oks = (None,) * len(rung_desc)
-    out = None
-    scals = []
-    for r, (crop_tile, nsweeps, num_waves, group, doubling,
-            *fclass) in enumerate(rung_desc):
-        # a rung of a route with fanout classes names its class last
-        out = _window_body(
-            pg, dev, occ, acc, paths, sink_delay, all_reached, bb,
-            source_all, sinks_all, crit_all,
-            opin_node_all, entry_cell_all, entry_oidx_all,
-            entry_delay_all, sink_uid_all, uid_ucell, uid_upin,
-            uid_pcdel, uid_pcrank, direct_oidx_all, direct_ipin_all,
-            direct_delay_all,
-            sel_plans[r], valid_plans[r], full_bb,
-            pres0, pres_mult, max_pres,
-            acc_fac if r == 0 else jnp.float32(0.0),
-            it0, force_until,
-            K_iters, nsweeps, max_len, num_waves, group, doubling,
-            topk, n_colors, mesh, tdev, req_seed, sta_depth, crit_exp,
-            max_crit, use_sdc, crop_tile, bb0_all, widen_oks[r],
-            plane_dtype, fan, *fclass)
-        (occ, acc, paths, sink_delay, all_reached, bb) = out[:6]
-        crit_all = out[13]
-        scals.append(out[22])
-    with device_scope("route.dev.window_summary"):
-        ladder_scals = jnp.stack(scals)
-    return out + (ladder_scals,)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=FUSED_WINDOW_STATIC_ARGNAMES,
-    donate_argnames=("occ", "acc", "paths", "sink_delay", "all_reached",
-                     "bb", "crit_all"))
-def route_window_planes_fused(
-        pg: PlanesGraph, dev: DeviceRRGraph, occ, acc,
-        paths, sink_delay, all_reached, bb,
-        source_all, sinks_all, crit_all,
-        opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
-        direct_oidx_all, direct_ipin_all, direct_delay_all,
-        sel_plans, valid_plans, full_bb,
-        pres0, pres_mult, max_pres, acc_fac, it0, force_until,
-        K_iters: int, max_len: int, rung_desc=(), topk: int = 1024,
-        n_colors: int = 5, mesh=None,
-        tdev=None, req_seed=None, sta_depth: int = 0,
-        crit_exp: float = 1.0, max_crit: float = 0.99,
-        use_sdc: bool = False,
-        bb0_all=None, widen_oks=None, plane_dtype: str = "f32",
-        fan=None):
-    """The WHOLE window dispatch ladder as ONE device program: walk the
-    ragged ``rung_desc`` descriptor table — one static
-    (crop_tile, nsweeps, num_waves, group, doubling) tuple per
-    populated size-class rung, with the rung's fanout class sixth where
-    the route has classes (``fan``, _window_body) — running each rung's _window_body on its
-    own sel/valid plan and threading the negotiation state
-    (occ/acc/paths/sink_delay/all_reached/bb/crit_all) rung to rung,
-    exactly as the per-rung dispatch loop does host-side.  One dispatch
-    per window replaces one per populated rung, killing the
-    per-dispatch overhead devprof flags on small-window variants.
-
-    Each rung keeps ITS OWN static shapes inside the one XLA program
-    (the descriptor is static, so the trace unrolls per rung) — this is
-    what preserves bit-exactness vs the per-rung loop: a common-tile
-    ragged kernel would pad associative-scan axes and change the
-    min-plus combine tree.  The acc escalation applies on rung 0 only
-    and pres re-escalates identically per rung from the same pres0,
-    mirroring the host loop's esc=True-then-False protocol.
-
-    Returns the last rung's 23-tuple (the window summary the control
-    loop consumes) plus a stacked [n_rungs, SCAL_LEN] int32 of every
-    rung's ``scal`` vector as a 24th element — the per-rung ledger rows
-    _book_window would otherwise have collected per dispatch."""
-    return _fused_ladder(
-        pg, dev, occ, acc, paths, sink_delay, all_reached, bb,
-        source_all, sinks_all, crit_all,
-        opin_node_all, entry_cell_all, entry_oidx_all, entry_delay_all,
-        sink_uid_all, uid_ucell, uid_upin, uid_pcdel, uid_pcrank,
-        direct_oidx_all, direct_ipin_all, direct_delay_all,
-        sel_plans, valid_plans, full_bb,
-        pres0, pres_mult, max_pres, acc_fac, it0, force_until,
-        K_iters, max_len, rung_desc, topk, n_colors, mesh, tdev,
-        req_seed, sta_depth, crit_exp, max_crit, use_sdc,
-        bb0_all, widen_oks, plane_dtype, fan)
-
-
-# the multi-job program's static argnames: one (K_iters, max_len,
-# rung_desc) triple per co-admitted job rides the ``job_statics``
-# descriptor, everything else is shared grid-level configuration
-MULTI_WINDOW_STATIC_ARGNAMES = ("job_statics", "n_colors",
-                                "plane_dtype")
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=MULTI_WINDOW_STATIC_ARGNAMES,
-    donate_argnames=("job_states",))
-def route_window_planes_multi(
-        pg: PlanesGraph, dev: DeviceRRGraph, job_states, job_dynamics,
-        job_statics=(), n_colors: int = 5,
-        plane_dtype: str = "f32"):
-    """Continuous-batching window dispatch: the fused window ladders of
-    EVERY co-admitted job as ONE device program on the shared device
-    graph.  Each job keeps its own donated negotiation state
-    (``job_states[j]`` = (occ, acc, paths, sink_delay, all_reached, bb,
-    crit_all)), its own terminals/plan tensors (``job_dynamics[j]`` =
-    (source_all, sinks_all, tables[11], sel_plans, valid_plans,
-    full_bb, pres0, pres_mult, max_pres, acc_fac, it0, force_until,
-    bb0_all, widen_oks)) and its own static descriptor
-    (``job_statics[j]`` = (K_iters, max_len, rung_desc, topk) — topk
-    is per job because it tracks each job's net count, and a tiny job
-    must fuse with a full-size one), so every
-    job's ladder traces into an INDEPENDENT subgraph of the one XLA
-    program — per-job results are bit-identical to dispatching each
-    job's route_window_planes_fused alone, by construction, while the
-    scheduler overlaps all jobs' lane-starved windows on the device.
-
-    Single-device only (no mesh sharding, no device-resident STA): the
-    serve layer falls back to per-job solo dispatch for those modes.
-
-    Returns a tuple over jobs of route_window_planes_fused's 24-tuple,
-    in ``job_states`` order — the caller demuxes occ/paths/wirelength
-    strictly per job."""
-    outs = []
-    for st, dyn, (K_iters, max_len, rung_desc, topk) in zip(
-            job_states, job_dynamics, job_statics):
-        occ, acc, paths, sink_delay, all_reached, bb, crit_all = st
-        (source_all, sinks_all, tables, sel_plans, valid_plans,
-         full_bb, pres0, pres_mult, max_pres, acc_fac, it0,
-         force_until, bb0_all, widen_oks) = dyn
-        outs.append(_fused_ladder(
-            pg, dev, occ, acc, paths, sink_delay, all_reached, bb,
-            source_all, sinks_all, crit_all, *tables,
-            sel_plans, valid_plans, full_bb,
-            pres0, pres_mult, max_pres, acc_fac, it0, force_until,
-            K_iters, max_len, rung_desc, topk, n_colors, None, None,
-            None, 0, 1.0, 0.99, False, bb0_all, widen_oks,
-            plane_dtype))
-    return tuple(outs)
-
-
-try:
-    # the AOT library's static/dynamic arg split reads this attribute;
-    # jax's jit wrapper may reject attribute writes on some versions,
-    # in which case library._static_names falls back to matching the
-    # function by name
-    route_window_planes_fused._static_argnames = \
-        FUSED_WINDOW_STATIC_ARGNAMES
-    route_window_planes._static_argnames = WINDOW_STATIC_ARGNAMES
-    route_window_planes_multi._static_argnames = \
-        MULTI_WINDOW_STATIC_ARGNAMES
-except (AttributeError, TypeError):          # pragma: no cover
-    pass
+    return WindowOut(
+        occ, acc, paths, sink_delay, all_reached, bb, pres, rrm,
+        colors, n_over_s, over_tot_s, nroutes, nexec, crit_all,
+        dmax_hist, max_span, dev_wide, live_wh, unreached,
+        s_exec, s_useful, status, scal)
 
 
 # indices into the packed ``scal`` summary vector of route_window_planes

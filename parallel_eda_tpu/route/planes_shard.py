@@ -112,7 +112,7 @@ class RowMesh:
     """Hashable handle for the row-sharded relaxation: rides the
     existing ``mesh`` static argname through route_window_planes ->
     _step_core -> the relax dispatch, so the whole window program
-    (fused or per-rung) re-jits per (mesh, impl) variant."""
+    re-jits per (mesh, impl) variant."""
     mesh: Mesh
     n_shards: int
     impl: str = "ppermute"

@@ -47,9 +47,6 @@ from .search import (build_windows, conflict_subset, iteration_summary,
                      route_batch_resident, route_batch_resident_win,
                      window_sizes, wirelength_on_device)
 
-_DEBUG_CROP = bool(os.environ.get("PEDA_DEBUG_CROP"))
-
-
 def normalize_crop(value) -> str:
     """Validate + normalize a crop knob ('auto' | 'off' | 'WxH').
     Shared by the CLI and Router.route so a typo'd programmatic value
@@ -222,16 +219,6 @@ class RouterOpts:
     # benchmark/tools/control_runs.py and tests/benchmark/ pass the
     # key (ROADMAP.md Queue 3 names the remedy).
     dtype_guard: str = "off"
-    # Ragged fused dispatch: walk the whole crop-ladder of a window
-    # (every populated size-class rung) inside ONE device program
-    # (planes.route_window_planes_fused) instead of one dispatch per
-    # rung — same per-rung programs, same static shapes, bit-identical
-    # results; kills the per-dispatch overhead devprof flags on
-    # small-window variants.  The fused program is one more
-    # canonicalized variant key (dispatch cache / AOT library /
-    # watchdog chain / devprof all apply); under resil it degrades
-    # fused -> per_rung via the ladder "dispatch" dimension.
-    fused_dispatch: bool = False
     # Multi-chip halo-exchange routing (route/planes_shard.py): shard
     # the relaxation canvases over a 1-D device mesh on the canvas row
     # axis, each chip relaxing its own column block and exchanging
@@ -377,7 +364,7 @@ class RouteResult:
     # a fixpoint each): total_relax_steps over it is sweeps a wave
     total_waves: int = 0
     # the route's wall by named interval (planes window driver), in
-    # perf_counter seconds: prologue_s (route_gen's entry to the first
+    # perf_counter seconds: prologue_s (route()'s entry to the first
     # window), windows_s (the sum of the rows' route_time_s), control_s
     # (of their control_s), epilogue_s (the last control step's end to
     # the return).  They add up to the `route` stage.  route_id is the
@@ -822,33 +809,6 @@ def dispatching(**args):
             total.set((total.value or 0.0) + ms)
 
 
-class WindowDispatchRequest:
-    """One planned fused-window dispatch, externalized by the
-    generator-mode driver (Router.route_gen): the canonical variant
-    key, the positional/keyword args of
-    planes.route_window_planes_fused, the planned per-rung fallback
-    chain and the resilience runtime — everything
-    Router._exec_window_request needs to issue the dispatch.  The
-    serve layer's continuous batcher (serve/fused.py) merges
-    co-admitted jobs' requests into ONE route_window_planes_multi
-    program per lockstep step; the solo driver executes them one at a
-    time — either way the 24-tuple result is sent back into the
-    yielding generator unchanged, so per-job results are bit-identical
-    by construction.  ``span_args`` (window index, route id) go on the
-    dispatch span whoever issues it."""
-    __slots__ = ("vkey", "f_args", "f_kwargs", "per_rung_fb",
-                 "resil_rt", "span_args")
-
-    def __init__(self, vkey, f_args, f_kwargs, per_rung_fb, resil_rt,
-                 span_args):
-        self.vkey = vkey
-        self.f_args = f_args
-        self.f_kwargs = f_kwargs
-        self.per_rung_fb = per_rung_fb
-        self.resil_rt = resil_rt
-        self.span_args = span_args
-
-
 # how many overused rr-node ids each window's congestion record lists
 _CONGESTION_TOPK = 8
 
@@ -961,12 +921,6 @@ class Router:
         # uploads) + persistent compile cache, both for the pipelined
         # window driver
         self._staging = _PlanStaging()
-        # staging-slot namespace: the serve layer's continuous batcher
-        # drives several jobs' window generators against ONE router, so
-        # it prefixes each job's slot names (sel0/valid0/...) with the
-        # job id — without this, interleaved jobs would alias each
-        # other's slots and lose every hash-skip (correct, just slow)
-        self._staging_prefix = ""
         self._cap_np = None    # host capacity copy for congestion top-k
         # AOT program library (serve/library.py): loaded keys are
         # pre-registered as SEEN dispatch variants — a warm serve's
@@ -1139,105 +1093,6 @@ class Router:
 
         rungs.append(Rung("jit", run_jit))
         return resil_rt.guard.run(vkey, rungs)
-
-    def _guarded_dispatch_fused(self, resil_rt, vkey, f_args, f_kwargs,
-                                per_rung_fb):
-        """Fused-window dispatch under the resilience guard: AOT
-        library -> live jit of the fused ragged program -> the
-        sequential per-rung dispatch loop (the ladder's "dispatch"
-        dimension; bit-identical by construction — the fallback walks
-        the SAME planned rungs in the same threading order the fused
-        program unrolls on device)."""
-        from ..resil.watchdog import Rung
-        from .planes import _as_row_mesh, route_window_planes_fused
-        ladder = resil_rt.ladder
-        rungs = []
-        rm = _as_row_mesh(f_kwargs.get("mesh"))
-        if rm is not None:
-            # sharded fused ladder: transport rungs first (each fires
-            # the sticky backend.loss check before the jitted call),
-            # then the single-chip fused program, then the sequential
-            # per-rung fallback — same shape as the unsharded chain
-            # below with the mesh dimension stacked on top
-            def mesh_run(label, rm_):
-                def run():
-                    _note_dispatch_variant(
-                        vkey if label == rm.impl else vkey + (label,))
-                    self._check_mesh_member(resil_rt, rm_)
-                    return route_window_planes_fused(
-                        *f_args, **{**f_kwargs, "mesh": rm_})
-                return run
-
-            def quar(reason):
-                self._mesh_demote(resil_rt, reason)
-
-            rungs.append(Rung(rm.impl, mesh_run(rm.impl, rm), quar))
-            if rm.impl == "pallas_halo":
-                rungs.append(Rung(
-                    "ppermute",
-                    mesh_run("ppermute", rm.with_impl("ppermute")),
-                    quar))
-
-            def run_single():
-                _note_dispatch_variant(vkey + ("single_chip",))
-                return route_window_planes_fused(
-                    *f_args, **{**f_kwargs, "mesh": None})
-
-            rungs.append(Rung("single_chip", run_single))
-            rungs.append(Rung("per_rung", per_rung_fb))
-            return resil_rt.guard.run(vkey, rungs)
-        if (self._library is not None
-                and ladder.level("program") == 0):
-            def run_aot():
-                _note_dispatch_variant(vkey)
-                return self._library.dispatch(
-                    vkey, route_window_planes_fused, f_args, f_kwargs)
-
-            def evict_aot(reason):
-                self._library.evict(vkey, reason)
-
-            rungs.append(Rung("aot", run_aot, evict_aot))
-
-        def run_fused():
-            _note_dispatch_variant(vkey)
-            return route_window_planes_fused(*f_args, **f_kwargs)
-
-        rungs.append(Rung("fused", run_fused))
-        rungs.append(Rung("per_rung", per_rung_fb))
-        return resil_rt.guard.run(vkey, rungs)
-
-    def _exec_window_request(self, req: WindowDispatchRequest):
-        """Issue ONE externalized fused-window dispatch: exactly the
-        guarded / AOT-library / live-jit chain the inline driver used
-        before the generator refactor, now behind the yield boundary —
-        the solo driver (_drive_windows) and the serve batcher's
-        per-job fallback both come through here, so a job dispatched
-        alone is bit-identical to the pre-generator code path."""
-        from .planes import route_window_planes_fused
-        resil_rt = req.resil_rt
-        with dispatching(fused=True, **req.span_args):
-            if resil_rt is not None and resil_rt.guard is not None:
-                return self._guarded_dispatch_fused(
-                    resil_rt, req.vkey, req.f_args, req.f_kwargs,
-                    req.per_rung_fb)
-            _note_dispatch_variant(req.vkey)
-            if self._library is not None:
-                return self._library.dispatch(
-                    req.vkey, route_window_planes_fused, req.f_args,
-                    req.f_kwargs)
-            return route_window_planes_fused(*req.f_args, **req.f_kwargs)
-
-    def _drive_windows(self, gen) -> "RouteResult":
-        """Trivial solo executor over a window-dispatch generator
-        (route_gen): every yielded WindowDispatchRequest is issued
-        immediately and its 24-tuple sent back in — behavior-identical
-        to the pre-generator inline dispatch."""
-        try:
-            req = next(gen)
-            while True:
-                req = gen.send(self._exec_window_request(req))
-        except StopIteration as e:
-            return e.value
 
     @staticmethod
     def _dump_routes(stats_dir: str, it: int, paths: np.ndarray,
@@ -1679,7 +1534,7 @@ class Router:
         escape hatch).
 
         ``rid`` is the route's id (the ``route`` arg of all its spans)
-        and ``t_enter`` the perf_counter second route_gen was entered:
+        and ``t_enter`` the perf_counter second route() was entered:
         the route's prologue runs from there to the first window's
         open, its epilogue from the last control step's end to the
         return.  Both are in ``RouteResult.wall`` and, measured
@@ -1687,8 +1542,8 @@ class Router:
         route.epilogue; they are NOT profiler annotations yet
         (tests/benchmark/test_scope_reduce.py holds the names a device
         gap may fall in to a closed list, PERF.md section 7)."""
-        from .planes import (PLANE_DTYPES, route_window_planes,
-                             route_window_planes_fused,
+        from .planes import (PLANE_DTYPES, SCAL_MAX_SPAN, SCAL_N_OVER,
+                             SCAL_OVER_TOTAL, route_window_planes,
                              unpack_window_status)
 
         opts = self.opts
@@ -1968,22 +1823,9 @@ class Router:
                                      None))
             else:
                 dispatch = [(dirty, None)]
-            if _DEBUG_CROP:
-                print("DBGCROP", "dispatch",
-                      [(len(s), t) for s, t in dispatch],
-                      "crop_full", crop_full, flush=True)
-
-            stg = self._staging_prefix
             widen_d = (None if opts.sweep_budget_div <= 1
-                       else self._staging.put(stg + "widen",
-                                              budget_full))
+                       else self._staging.put("widen", budget_full))
 
-            # per-window dispatch resolution (re-checked every window:
-            # a service-side ladder step takes effect at the next
-            # window boundary)
-            fused_now = (bool(opts.fused_dispatch) and self.mesh is None
-                         and (lad is None
-                              or lad.level("dispatch") == 0))
             # active mesh for this window: the legacy (net, node) GSPMD
             # mesh if constructed with one, else the halo-exchange
             # RowMesh at the resil ladder's current "mesh" level
@@ -2018,12 +1860,8 @@ class Router:
 
             def plan_rung(sub, tile, ri, fcls):
                 """Host planning for one rung of this window's dispatch
-                ladder (the plan half of the old window_call): batch
-                plan, sweep budget, widen gate, kernel-layout plan, and
-                the staged device uploads.  Shared verbatim by the
-                per-rung and fused dispatch paths, so the fused program
-                walks EXACTLY the rungs the per-rung loop would have
-                dispatched."""
+                ladder: batch plan, sweep budget, widen gate,
+                kernel-layout plan, and the staged device uploads."""
                 sel_p, valid_p = self._plan_groups(
                     sub, colors, nsinks_np, cx_np, cy_np, B, R)
                 ws = np.where(wide[sub], rr.grid.nx + 2, np.maximum(
@@ -2077,7 +1915,7 @@ class Router:
                     # would burn a pointless promotion round trip)
                     wok_np = budget_full.copy()
                     wok_np[sub[spans_full <= nsw]] = True
-                    wok = self._staging.put(f"{stg}wok{ri}", wok_np)
+                    wok = self._staging.put(f"wok{ri}", wok_np)
                 maxfan = int(nsinks_np[sub].max()) if len(sub) else 1
                 # a class above the first keeps the doubling schedule
                 # under ``precise`` too: the exact schedule is one
@@ -2121,8 +1959,8 @@ class Router:
                 # reuse the staged device buffer outright, and fresh
                 # ones go up with a non-blocking device_put while the
                 # previous rung still executes
-                sel_d = self._staging.put(f"{stg}sel{ri}", sel_p)
-                valid_d = self._staging.put(f"{stg}valid{ri}", valid_p)
+                sel_d = self._staging.put(f"sel{ri}", sel_p)
+                valid_d = self._staging.put(f"valid{ri}", valid_p)
                 # ledger: filled batch slots, plan width, and real
                 # (non-pad) batch rows of this planned dispatch
                 # the class rides in a dispatch key only where the
@@ -2137,42 +1975,12 @@ class Router:
                                     valid_p.shape[1],
                                     int(valid_p.any(axis=1).sum())))
 
-            def rung_args(p, st, esc, pres_in):
-                """Positional route_window_planes args for planned rung
-                ``p`` against the state tuple ``st`` (occ, acc, paths,
-                sink_delay, all_reached, bb, crit).  esc=False freezes
-                the acc escalation (the first rung already applied it
-                this window; pres re-escalates identically in every
-                rung so iteration k sees the same pres)."""
-                occ2, acc2, paths2, sd2, ar2, bb2, crit2 = st
-                return (
-                    self.pg, dev, occ2, acc2, paths2, sd2, ar2, bb2,
-                    source_d, sinks_d, crit2,
-                    *planes_tbl,
-                    p["sel_d"], p["valid_d"], full_bb,
-                    jnp.float32(pres_in),
-                    jnp.float32(opts.pres_fac_mult),
-                    jnp.float32(opts.max_pres_fac),
-                    jnp.float32(opts.acc_fac if esc else 0.0),
-                    jnp.int32(it_done),
-                    jnp.int32(it_done + 1 if force_all_next
-                              else opts.incremental_after),
-                    K, p["nsw"], L, p["waves"], p["grp_w"],
-                    p["doubling"], min(4096, N), 5,
-                    # re-read at call time: the per-rung fallback of a
-                    # window whose mesh member died mid-chain must not
-                    # redispatch onto the dead mesh
-                    None if self._mesh_lost else mesh_now)
-
-            def rung_kwargs(p):
-                return dict(crop_tile=p["tile"], bb0_all=bb0_d,
-                            widen_ok=p["wok"], plane_dtype=pd,
-                            **fan_kw, **sta_kw,
-                            **({"fclass": p["fcls"]} if fan_kw else {}))
-
-            def window_call(p, esc, pres_in, ri):
+            def window_call(p, ri):
                 """One route_window_planes dispatch of planned rung
-                ``p`` (rung ``ri`` of this window's dispatch ladder)."""
+                ``p`` (rung ``ri`` of this window's dispatch ladder).
+                The first rung alone escalates acc (acc_fac is 0 in the
+                others); pres re-escalates identically in every rung,
+                so iteration k sees the same pres."""
                 # canonical dispatch signature: everything jit traces
                 # as a static arg or shape.  New key = a fresh XLA
                 # compile (or persistent-cache load); known key = a jit
@@ -2181,10 +1989,29 @@ class Router:
                         p["grp_w"], p["doubling"], p["sel_shape"][0],
                         p["sel_shape"][1], p["wok"] is None, mesh_vk,
                         bool(sta_kw), R, Smax, N, pd) + p["fkey"]
-                wp_args = rung_args(
-                    p, (occ, acc, paths, sink_delay, all_reached, bb,
-                        crit_d), esc, pres_in)
-                wp_kwargs = rung_kwargs(p)
+                wp_args = (
+                    self.pg, dev, occ, acc, paths, sink_delay,
+                    all_reached, bb, source_d, sinks_d, crit_d,
+                    *planes_tbl,
+                    p["sel_d"], p["valid_d"], full_bb,
+                    jnp.float32(pres),
+                    jnp.float32(opts.pres_fac_mult),
+                    jnp.float32(opts.max_pres_fac),
+                    jnp.float32(opts.acc_fac if ri == 0 else 0.0),
+                    jnp.int32(it_done),
+                    jnp.int32(it_done + 1 if force_all_next
+                              else opts.incremental_after),
+                    K, p["nsw"], L, p["waves"], p["grp_w"],
+                    p["doubling"], min(4096, N), 5,
+                    # re-read at every rung: a window whose mesh member
+                    # died in an earlier rung must not dispatch the
+                    # next onto the dead mesh
+                    None if self._mesh_lost else mesh_now)
+                wp_kwargs = dict(
+                    crop_tile=p["tile"], bb0_all=bb0_d,
+                    widen_ok=p["wok"], plane_dtype=pd,
+                    **fan_kw, **sta_kw,
+                    **({"fclass": p["fcls"]} if fan_kw else {}))
                 # device-truth profiling: avatarize the REAL call args
                 # BEFORE the dispatch donates them, so capture_all()
                 # can AOT-relower this exact variant later
@@ -2215,8 +2042,8 @@ class Router:
                     return route_window_planes(*wp_args, **wp_kwargs)
 
             # the window's live span, closed after its stall; opened
-            # and closed by hand because a `yield` (the fused dispatch)
-            # and most of this loop's body lie between the two.  What
+            # and closed by hand, as the control span after it is (that
+            # one runs into the next turn of the loop).  What
             # the row knows at open goes on the span at open: a
             # TraceAnnotation's args are fixed there, and they are what
             # matches a device trace of a slow window to its kind
@@ -2252,207 +2079,76 @@ class Router:
             outs = []
             bucket_occ = []
             kplans = []
-            rung_scals = []
             comp_num = comp_den = 0
             plan_s = 0.0          # host plan/stage/dispatch, this window
             plan0_s = 0.0         # rung 0's share (nothing in flight yet)
             t_disp0 = None        # first dispatch return: exec start
             sync_block_s = 0.0    # --sync per-rung drain time
-            if fused_now:
-                # ---- fused ragged dispatch: plan EVERY populated rung
-                # first, then issue the whole ladder as ONE device
-                # program (planes.route_window_planes_fused) walking
-                # the static rung_desc table — bit-identical to the
-                # per-rung loop below (each rung keeps its own static
-                # shapes inside the one program; acc escalates on rung
-                # 0 only, mirroring esc=True-then-False) with one
-                # dispatch's overhead instead of one per rung ----
+            for ri, (sub0, tile, fcls) in enumerate(dispatch):
                 tp0 = time.perf_counter()
-                plan_sp = span("route.pipeline.plan", cat="route",
-                               stage="plan", window=widx, route=rid,
-                               rung=0, nets=len(dirty), fused=True,
-                               rungs=len(dispatch),
-                               tiles=[t for _, t, _ in dispatch],
-                               fanout_class=[c for _, _, c in dispatch])
-                plan_sp.__enter__()
-                plans = [plan_rung(sub0, tile, ri, fcls)
-                         for ri, (sub0, tile, fcls) in enumerate(dispatch)]
-                for p in plans:
-                    kplans.append(p["kplan"])
-                    nvalid, bg, grows = p["ledger"]
-                    if grows:
-                        bucket_occ.append(nvalid / (grows * bg))
-                        comp_num += grows * bg
-                        comp_den += grows * B
-                rung_desc = tuple(
-                    (p["tile"], p["nsw"], p["waves"], p["grp_w"],
-                     p["doubling"]) + p["fkey"] for p in plans)
-                widen_oks = (None
-                             if all(p["wok"] is None for p in plans)
-                             else tuple(p["wok"] for p in plans))
-                f_args = (
-                    self.pg, dev, occ, acc, paths, sink_delay,
-                    all_reached, bb, source_d, sinks_d, crit_d,
-                    *planes_tbl,
-                    tuple(p["sel_d"] for p in plans),
-                    tuple(p["valid_d"] for p in plans), full_bb,
-                    jnp.float32(pres),
-                    jnp.float32(opts.pres_fac_mult),
-                    jnp.float32(opts.max_pres_fac),
-                    jnp.float32(opts.acc_fac),
-                    jnp.int32(it_done),
-                    jnp.int32(it_done + 1 if force_all_next
-                              else opts.incremental_after),
-                    K, L)
-                f_kwargs = dict(
-                    rung_desc=rung_desc, topk=min(4096, N),
-                    n_colors=5, mesh=mesh_now, bb0_all=bb0_d,
-                    widen_oks=widen_oks, plane_dtype=pd,
-                    **fan_kw, **sta_kw)
-                vkey = ("fused", rung_desc, K, L,
-                        tuple(p["sel_shape"] for p in plans),
-                        widen_oks is None, mesh_vk, bool(sta_kw),
-                        R, Smax, N, pd)
-                dom = max(kplans, key=lambda kp: kp.get("nets", 0))
-                get_devprof().note_variant(
-                    ("fused", rung_desc, K, L), dom,
-                    route_window_planes_fused, f_args, f_kwargs)
-
-                def run_per_rung_fb():
-                    # ladder "dispatch" fallback: the SAME planned
-                    # rungs, dispatched sequentially — equivalent
-                    # 24-tuple by construction (state threads rung to
-                    # rung exactly as the fused program unrolls it)
-                    st = (occ, acc, paths, sink_delay, all_reached,
-                          bb, crit_d)
-                    o2 = None
-                    scals = []
-                    for ri2, p2 in enumerate(plans):
-                        _note_dispatch_variant(
-                            vkey + ("per_rung", ri2))
-                        o2 = route_window_planes(
-                            *rung_args(p2, st, ri2 == 0, pres),
-                            **rung_kwargs(p2))
-                        st = o2[:6] + (o2[13],)
-                        scals.append(o2[22])
-                    return o2 + (jnp.stack(scals),)
-
-                # externalized dispatch: the driver — route()'s solo
-                # loop, or the serve batcher merging co-admitted jobs
-                # into one multi-job program — issues the request and
-                # sends the 24-tuple back in (_exec_window_request
-                # holds the old guarded/AOT/jit dispatch chain)
-                plan_sp.__exit__(None, None, None)
-                out24 = yield WindowDispatchRequest(
-                    vkey, f_args, f_kwargs, run_per_rung_fb, resil_rt,
-                    dict(window=widx, route=rid))
-                o = tuple(out24[:23])
+                with span("route.pipeline.plan", cat="route",
+                          stage="plan", window=widx, route=rid,
+                          rung=ri, nets=len(sub0), tile=tile,
+                          fanout_class=fcls):
+                    p = plan_rung(sub0, tile, ri, fcls)
+                o = window_call(p, ri)
+                kplans.append(p["kplan"])
+                # park the just-donated state refs before
+                # rebinding: dropping the last reference to a
+                # donated in-flight buffer blocks until its
+                # execution completes
                 retire.append((occ, acc, paths, sink_delay,
                                all_reached, bb, crit_d))
-                occ, acc, paths, sink_delay, all_reached, bb = o[:6]
-                crit_d = o[13]
-                # the per-rung ledger rows come back as one stacked
-                # [n_rungs, SCAL_LEN] array (24th element)
-                rung_scals = [(out24[23][r],
-                               rung_desc[r][0] is not None)
-                              for r in range(len(rung_desc))]
-                small = (o[21], o[22], out24[23]) + (
-                    (o[14],) if analyzer is not None else ())
+                occ, acc, paths, sink_delay, all_reached, bb = (
+                    o.occ, o.acc, o.paths, o.sink_delay,
+                    o.all_reached, o.bb)
+                crit_d = o.crit_all
+                # start the packed summary copies now: by stall
+                # time they are already host-side (replaces the
+                # 13-array blocking jax.device_get of the
+                # pre-pipeline driver)
+                small = (o.status, o.scal, o.dmax_hist) \
+                    if analyzer is not None else (o.status, o.scal)
                 for a in small:
                     if hasattr(a, "copy_to_host_async"):
                         a.copy_to_host_async()
                 tp1 = time.perf_counter()
-                # everything is planned before the single dispatch, so
-                # the whole plan time is rung-0-equivalent (unoverlapped)
-                plan_s = plan0_s = tp1 - tp0
-                t_disp0 = tp1
+                plan_s += tp1 - tp0
+                if ri == 0:
+                    plan0_s = tp1 - tp0
+                    t_disp0 = tp1
                 if not pipelined:
-                    # --sync escape hatch: drain before ANY further
-                    # host work (trace_report --check contract)
+                    # --sync escape hatch: drain the rung before
+                    # ANY further host work, so plan spans can
+                    # never overlap device execution
+                    # (trace_report --check asserts exactly this)
                     with span("route.pipeline.stall", cat="route",
-                              window=widx, route=rid, sync=True):
-                        # graftlint: ignore[pipeline-sync] — this IS
-                        # the sanctioned --sync drain
-                        jax.block_until_ready(o[21])
+                              window=widx, route=rid, rung=ri,
+                              sync=True):
+                        # graftlint: ignore[pipeline-sync] — this
+                        # IS the sanctioned --sync drain
+                        jax.block_until_ready(o.status)
                     te1 = time.perf_counter()
                     sync_block_s += te1 - tp1
-                    reg.counter("route.pipeline.blocking_syncs").inc()
+                    reg.counter(
+                        "route.pipeline.blocking_syncs").inc()
                     if tr is not None:
                         tr.mark("route.pipeline.exec", tp1, te1,
-                                cat="route", window=widx, rung=0,
+                                cat="route", window=widx, rung=ri,
                                 K=K, pipelined=False)
-                outs.append((o, dispatch[-1][1]))
-            else:
-                esc = True
-                for ri, (sub0, tile, fcls) in enumerate(dispatch):
-                    tp0 = time.perf_counter()
-                    with span("route.pipeline.plan", cat="route",
-                              stage="plan", window=widx, route=rid,
-                              rung=ri, nets=len(sub0), tile=tile,
-                              fanout_class=fcls):
-                        p = plan_rung(sub0, tile, ri, fcls)
-                    o = window_call(p, esc, pres, ri)
-                    esc = False
-                    kplans.append(p["kplan"])
-                    # park the just-donated state refs before
-                    # rebinding: dropping the last reference to a
-                    # donated in-flight buffer blocks until its
-                    # execution completes
-                    retire.append((occ, acc, paths, sink_delay,
-                                   all_reached, bb, crit_d))
-                    occ, acc, paths, sink_delay, all_reached, bb = \
-                        o[:6]
-                    crit_d = o[13]
-                    # start the packed summary copies now: by stall
-                    # time they are already host-side (replaces the
-                    # 13-array blocking jax.device_get of the
-                    # pre-pipeline driver)
-                    small = (o[21], o[22], o[14]) \
-                        if analyzer is not None else (o[21], o[22])
-                    for a in small:
-                        if hasattr(a, "copy_to_host_async"):
-                            a.copy_to_host_async()
-                    tp1 = time.perf_counter()
-                    plan_s += tp1 - tp0
-                    if ri == 0:
-                        plan0_s = tp1 - tp0
-                        t_disp0 = tp1
-                    if not pipelined:
-                        # --sync escape hatch: drain the rung before
-                        # ANY further host work, so plan spans can
-                        # never overlap device execution
-                        # (trace_report --check asserts exactly this)
-                        with span("route.pipeline.stall", cat="route",
-                                  window=widx, route=rid, rung=ri,
-                                  sync=True):
-                            # graftlint: ignore[pipeline-sync] — this
-                            # IS the sanctioned --sync drain
-                            jax.block_until_ready(o[21])
-                        te1 = time.perf_counter()
-                        sync_block_s += te1 - tp1
-                        reg.counter(
-                            "route.pipeline.blocking_syncs").inc()
-                        if tr is not None:
-                            tr.mark("route.pipeline.exec", tp1, te1,
-                                    cat="route", window=widx, rung=ri,
-                                    K=K, pipelined=False)
-                    outs.append((o, tile))
-                    nvalid, bg, grows = p["ledger"]
-                    if grows:
-                        bucket_occ.append(nvalid / (grows * bg))
-                        comp_num += grows * bg
-                        comp_den += grows * B
-                rung_scals = [(o2[22], tc is not None)
-                              for o2, tc in outs]
-            out, last_tile = outs[-1]
+                outs.append((o, tile))
+                nvalid, bg, grows = p["ledger"]
+                if grows:
+                    bucket_occ.append(nvalid / (grows * bg))
+                    comp_num += grows * bg
+                    comp_den += grows * B
+            rung_scals = [(o2.scal, tc is not None)
+                          for o2, tc in outs]
+            out = outs[-1][0]
             force_all_next = False
-            # one relaxation dispatch per window when fused, one per
-            # populated crop rung otherwise
-            reg.set_gauges({
-                "route.kernel.fused_rungs": len(dispatch),
-                "route.kernel.dispatches_per_window":
-                    1 if fused_now else len(dispatch),
-            })
+            # one dispatch per populated rung
+            reg.gauge("route.kernel.dispatches_per_window").set(
+                len(dispatch))
 
             # ---- overlapped host stage: consume the PREVIOUS window's
             # summary (its bookkeeping was deferred to here, where this
@@ -2472,9 +2168,9 @@ class Router:
             t_st0 = time.perf_counter()
             with span("route.pipeline.stall", cat="route", window=widx,
                       route=rid):
-                status_np = np.asarray(out[21])  # graftlint: ignore[pipeline-sync]
-                scal_np = np.asarray(out[22])    # graftlint: ignore[pipeline-sync]
-                dmax_hist = (np.asarray(out[14])  # graftlint: ignore[pipeline-sync]
+                status_np = np.asarray(out.status)  # graftlint: ignore[pipeline-sync]
+                scal_np = np.asarray(out.scal)    # graftlint: ignore[pipeline-sync]
+                dmax_hist = (np.asarray(out.dmax_hist)  # graftlint: ignore[pipeline-sync]
                              if analyzer is not None
                              else None)
             t_st1 = time.perf_counter()
@@ -2544,14 +2240,15 @@ class Router:
             (rrm, colors, dev_wide, unreached, live_w,
              live_h) = unpack_window_status(status_np)
             mis_reads.inc()
-            n_over, over_total = int(scal_np[0]), int(scal_np[1])
-            max_span = int(scal_np[4])
+            n_over = int(scal_np[SCAL_N_OVER])
+            over_total = int(scal_np[SCAL_OVER_TOTAL])
+            max_span = int(scal_np[SCAL_MAX_SPAN])
             if opts.sweep_budget_div > 1:
                 # reduced-budget promotion: a miss retries at full
                 # budget (feature-off runs must not accumulate state —
                 # a later resume with div>1 would be pre-promoted)
                 budget_full |= unreached
-            crit_d = out[13]            # donated in; stays device-resident
+            crit_d = out.crit_all       # donated in; stays device-resident
             # fold device-side widening into the host classification:
             # those nets must take the full-canvas window from now on
             # (their crop tile covers only their static bb0)
@@ -2889,128 +2586,6 @@ class Router:
             self._pt_ref = term          # keep id(term) alive
         return self._pt
 
-    def route_gen(self, term: NetTerminals,
-                  crit: Optional[np.ndarray] = None,
-                  timing_cb: Optional[
-                      Callable[["RouteResult"], np.ndarray]] = None,
-                  analyzer=None,
-                  resume: Optional[RouteCheckpoint] = None):
-        """Generator-mode entry for the planes program: performs
-        route()'s device-state setup, then runs the window loop as a
-        generator that YIELDS a WindowDispatchRequest at every fused
-        window dispatch and expects the 24-tuple result sent back in.
-        ``route()`` drives it with the trivial solo loop
-        (_drive_windows) for exactly the historical behavior; the
-        serve layer's continuous batcher (serve/fused.py) instead
-        drives many jobs' generators in lockstep, merging concurrent
-        requests into one multi-job program.  The StopIteration value
-        is the RouteResult.
-
-        Setup runs lazily at the FIRST next(): callers co-driving
-        several jobs must set ``self.opts`` (and ``_staging_prefix``)
-        for the owning job before EVERY advance — the generator reads
-        router state mid-step (opts, staging, plan caches)."""
-        if self.pg is None:
-            raise ValueError(
-                "route_gen is supported by the planes program")
-        opts = self.opts
-        # the route's wall opens here: its prologue is this set-up and
-        # the window loop's, up to the first window
-        rid = next(_ROUTE_IDS)      # shared by every span of this route
-        t_enter = time.perf_counter()
-        # multi-route safety (the serve loop calls route() many times
-        # on one process): zero the per-route pipeline gauges so a job
-        # that never reaches a given gauge doesn't inherit the previous
-        # job's value.  The dispatch-variant seen-set is process state
-        # on purpose and is NOT reset: warm variants stay warm.
-        get_metrics().set_gauges(dict.fromkeys(_PIPELINE_GAUGES, 0.0))
-        # normalized into a LOCAL — never mutate the caller's
-        # RouterOpts (the same opts object may drive several routers,
-        # and the caller may compare it against what it passed in)
-        crop = normalize_crop(opts.crop)
-        rr = self.rr
-        R, Smax = term.sinks.shape
-        N = rr.num_nodes
-        B = min(opts.batch_size, max(1, R))
-        if self.mesh is not None and B % self._net_axis:
-            # batch must tile the net axis evenly
-            B = ((B + self._net_axis - 1)
-                 // self._net_axis) * self._net_axis
-        if crit is None:
-            crit = np.zeros((R, Smax), dtype=np.float32)
-        else:
-            # max_criticality clamp (VPR --max_criticality 0.99): crit
-            # of exactly 1 zeroes the congestion term and kills
-            # negotiation
-            crit = np.minimum(np.asarray(crit, dtype=np.float32), 0.99)
-        # host<->device transfers are the scarce resource, so every
-        # whole-circuit array lives on device for the entire call; the
-        # host loop moves net indices in and scalars out (search.py
-        # "device-resident stepping")
-        occ = self._put_node(jnp.zeros(N, dtype=jnp.int32))
-        acc = self._put_node(jnp.ones(N, dtype=jnp.float32))
-        # bb-adaptive path-slot budget (see route() notes)
-        if R:
-            span0 = int(((term.bb_xmax - term.bb_xmin)
-                         + (term.bb_ymax - term.bb_ymin)).max())
-        else:
-            span0 = 8
-        L = path_budget(span0, self.max_len)
-        # the path store and the sink delays: one table a fanout class
-        # ([R_c, S_c, L]; the bare array where there is one class)
-        classes = term.fanout_classes
-        if resume is None:
-            def store(tail, fill, dtype):
-                t = tuple(jnp.full((len(c.nets), c.width) + tail, fill,
-                                   dtype=dtype) for c in classes)
-                return t[0] if len(t) == 1 else t
-            paths = store((L,), N, jnp.int32)
-            sink_delay = store((), jnp.inf, jnp.float32)
-            all_reached = jnp.zeros(R, dtype=bool)
-            bb = jnp.asarray(np.stack(
-                [term.bb_xmin, term.bb_xmax, term.bb_ymin,
-                 term.bb_ymax], axis=1).astype(np.int32))
-        else:
-            # re-upload the checkpointed negotiation under THIS mesh
-            # (elastic shrink/grow: the sharding comes from this
-            # Router's layout, not the checkpoint's origin); no fresh
-            # allocation — the checkpoint IS the path store
-            occ = self._put_node(jnp.asarray(resume.occ))
-            acc = self._put_node(jnp.asarray(resume.acc))
-            paths = jax.tree.map(jnp.asarray, resume.paths)
-            crit = resume.crit
-            sink_delay = jax.tree.map(jnp.asarray, resume.sink_delay)
-            all_reached = jnp.asarray(resume.all_reached)
-            bb = jnp.asarray(resume.bb)
-        full_bb = jnp.asarray(np.array(
-            [0, rr.grid.nx + 1, 0, rr.grid.ny + 1], dtype=np.int32))
-        source_d = jnp.asarray(term.source.astype(np.int32))
-        nsinks_np = term.num_sinks.astype(np.int64)
-        cx_np = ((term.bb_xmin + term.bb_xmax) // 2).astype(np.int64)
-        cy_np = ((term.bb_ymin + term.bb_ymax) // 2).astype(np.int64)
-        sinks_d, planes_tbl, fan_d = self._planes_terminals(term)
-        result = RouteResult(False, 0, None, None, None, 0)
-        # structured per-(window, category) logging (zlog/MDC
-        # equivalent): no-op unless a stats_dir sink is configured.
-        # Context-managed AROUND the yield loop, so an abandoned
-        # generator (gen.close() on an evicted job) still closes the
-        # per-window file handles via GeneratorExit
-        from ..mdclog import MdcLogger
-        tr = get_tracer()
-        if opts.stats_dir:
-            # a stats_dir run is the diagnostics mode: the device-
-            # truth profiler rides along and dumps devprof.json
-            get_devprof().enabled = True
-        with MdcLogger(opts.stats_dir,
-                       t0=tr.t0 if tr is not None else None) as mlog:
-            result = yield from self._route_planes_windows(
-                term, crit, timing_cb, analyzer, occ, acc, paths,
-                sink_delay, all_reached, bb, full_bb, source_d,
-                sinks_d, planes_tbl, nsinks_np, cx_np, cy_np,
-                result, B, mlog, crop=crop, resume=resume, rid=rid,
-                t_enter=t_enter, fan_d=fan_d)
-        return result
-
     def route(self, term: NetTerminals,
               crit: Optional[np.ndarray] = None,
               timing_cb: Optional[Callable[["RouteResult"], np.ndarray]]
@@ -3031,15 +2606,10 @@ class Router:
             timing_cb = analyzer.timing_cb
         if resume is not None and self.pg is None:
             raise ValueError("resume is supported by the planes program")
-        if self.pg is not None:
-            # planes path: setup + window loop live in route_gen (a
-            # generator yielding one WindowDispatchRequest per fused
-            # window); route() is its trivial solo executor —
-            # behavior-identical to the pre-generator inline dispatch
-            return self._drive_windows(self.route_gen(
-                term, crit=crit, timing_cb=timing_cb,
-                analyzer=analyzer, resume=resume))
         opts = self.opts
+        # the route's wall opens here: its prologue is this set-up and
+        # the window loop's, up to the first window
+        t_enter = time.perf_counter()
         # multi-route safety (the serve loop calls route() many times
         # on one process): zero the per-route pipeline gauges so a job
         # that never reaches a given gauge doesn't inherit the previous
@@ -3085,33 +2655,65 @@ class Router:
             span0 = 8
         L = path_budget(span0, self.max_len)
         if resume is None:
-            paths = jnp.full((R, Smax, L), N, dtype=jnp.int32)
-        else:
-            # re-upload the checkpointed negotiation under THIS mesh
-            # (elastic shrink/grow: the sharding comes from this
-            # Router's layout, not the checkpoint's origin); no fresh
-            # allocation — the checkpoint IS the path store
-            occ = self._put_node(jnp.asarray(resume.occ))
-            acc = self._put_node(jnp.asarray(resume.acc))
-            paths = jnp.asarray(resume.paths)
-            crit = resume.crit
-        if resume is None:
-            sink_delay = jnp.full((R, Smax), jnp.inf, dtype=jnp.float32)
             all_reached = jnp.zeros(R, dtype=bool)
             bb = jnp.asarray(np.stack(
                 [term.bb_xmin, term.bb_xmax, term.bb_ymin, term.bb_ymax],
                 axis=1).astype(np.int32))
-        else:
-            sink_delay = jnp.asarray(resume.sink_delay)
-            all_reached = jnp.asarray(resume.all_reached)
-            bb = jnp.asarray(resume.bb)
         full_bb = jnp.asarray(np.array(
             [0, rr.grid.nx + 1, 0, rr.grid.ny + 1], dtype=np.int32))
         source_d = jnp.asarray(term.source.astype(np.int32))
-        sinks_d = jnp.asarray(term.sinks.astype(np.int32))
         nsinks_np = term.num_sinks.astype(np.int64)
         cx_np = ((term.bb_xmin + term.bb_xmax) // 2).astype(np.int64)
         cy_np = ((term.bb_ymin + term.bb_ymax) // 2).astype(np.int64)
+        result = RouteResult(False, 0, None, None, None, 0)
+
+        if self.pg is not None:
+            # the planes program: the window loop (_route_planes_windows)
+            rid = next(_ROUTE_IDS)      # shared by every span of this route
+            # the path store and the sink delays: one table a fanout
+            # class ([R_c, S_c, L]; the bare array where there is one)
+            classes = term.fanout_classes
+            if resume is None:
+                def store(tail, fill, dtype):
+                    t = tuple(jnp.full((len(c.nets), c.width) + tail, fill,
+                                       dtype=dtype) for c in classes)
+                    return t[0] if len(t) == 1 else t
+                paths = store((L,), N, jnp.int32)
+                sink_delay = store((), jnp.inf, jnp.float32)
+            else:
+                # re-upload the checkpointed negotiation under THIS mesh
+                # (elastic shrink/grow: the sharding comes from this
+                # Router's layout, not the checkpoint's origin); no fresh
+                # allocation — the checkpoint IS the path store
+                occ = self._put_node(jnp.asarray(resume.occ))
+                acc = self._put_node(jnp.asarray(resume.acc))
+                paths = jax.tree.map(jnp.asarray, resume.paths)
+                crit = resume.crit
+                sink_delay = jax.tree.map(jnp.asarray, resume.sink_delay)
+                all_reached = jnp.asarray(resume.all_reached)
+                bb = jnp.asarray(resume.bb)
+            sinks_d, planes_tbl, fan_d = self._planes_terminals(term)
+            # structured per-(window, category) logging (zlog/MDC
+            # equivalent): no-op unless a stats_dir sink is configured
+            from ..mdclog import MdcLogger
+            tr = get_tracer()
+            if opts.stats_dir:
+                # a stats_dir run is the diagnostics mode: the device-
+                # truth profiler rides along and dumps devprof.json
+                get_devprof().enabled = True
+            with MdcLogger(opts.stats_dir,
+                           t0=tr.t0 if tr is not None else None) as mlog:
+                return self._route_planes_windows(
+                    term, crit, timing_cb, analyzer, occ, acc, paths,
+                    sink_delay, all_reached, bb, full_bb, source_d,
+                    sinks_d, planes_tbl, nsinks_np, cx_np, cy_np,
+                    result, B, mlog, crop=crop, resume=resume, rid=rid,
+                    t_enter=t_enter, fan_d=fan_d)
+
+        # the ELL program (never resumed): dense stores
+        paths = jnp.full((R, Smax, L), N, dtype=jnp.int32)
+        sink_delay = jnp.full((R, Smax), jnp.inf, dtype=jnp.float32)
+        sinks_d = jnp.asarray(term.sinks.astype(np.int32))
 
         # --- bb-windowed search setup (VPR's per-net boxes as gathered
         # fixed-size windows; search.py "Bounding-box-windowed search") ---
@@ -3120,7 +2722,7 @@ class Router:
         wide = np.zeros(R, dtype=bool)   # nets routed in global space
         bb_full = np.zeros(R, dtype=bool)  # nets already on full-device bb
         win_row = None                   # net id -> compacted table row
-        if opts.windowed and self.pg is None:
+        if opts.windowed:
             # chunk over nets: window_sizes/build_windows hold an
             # [chunk, N] membership intermediate — unchunked that is
             # R x N and OOMs Titan-class graphs during setup
@@ -3154,7 +2756,6 @@ class Router:
                                        dtype=jnp.float32)
 
         pres_fac = opts.initial_pres_fac
-        result = RouteResult(False, 0, None, None, None, 0)
         if win is not None:
             result.windowed_nets = int((~wide).sum())
         n_over = -1                      # previous iteration's overuse

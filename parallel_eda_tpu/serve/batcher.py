@@ -1,21 +1,20 @@
-"""Cross-job net bin-packing.
+"""Cross-job net bin-packing, as a MODEL: it plans no dispatch.
 
-Folds nets from multiple admitted jobs into shared size-class packed
-dispatches.  The planes relaxation is per-net: each net relaxes on its
-own canvas against its own congestion view, and a batch is bit-identical
-to its nets relaxed one at a time
-(tests/test_kernel_pack.py::test_relax_net_independent*) — so a batch
-mixing nets from different jobs computes, net for net, exactly what
-each job's solo batch computes.  The batcher's job is therefore pure
-bookkeeping: bin the UNION of all jobs' nets onto one size-class crop
-ladder (the same ``_size_class_buckets`` pow-2 ladder the Router uses
-solo), plan one shared ``PackedLayout`` + ``auto_block_nets`` G per
-populated rung, and demultiplex packed slots strictly back to
-(job, net) — a slot belongs to exactly one job, pad slots to none.
-
-The win is occupancy: two 15-LUT jobs half-filling a G=16 block solo
-share one full block batched, so the device sees fewer, fuller
-dispatches for the same total work.
+The service routes one job's slice at a time (serve/queue.py); nothing
+merges two jobs' nets into one device program.  What this module
+computes is how the admitted set WOULD fold onto one size-class crop
+ladder (the ``_size_class_buckets`` pow-2 ladder the Router bins one
+job's nets on): the UNION of all jobs' nets binned, one
+``PackedLayout`` + ``auto_block_nets`` G per populated rung, packed
+slots demultiplexed back to (job, net).  Its one consumer,
+``RouteService.admit``, keeps of that the four ``route.serve.pack.*``
+gauges.  (The planes relaxation is per-net -- a batch is bit-identical
+to its nets relaxed one at a time,
+tests/test_kernel_pack.py::test_relax_net_independent* -- so a
+scheduler that did merge jobs would compute, net for net, what each
+job's solo batch computes: PERF.md section 7's ``serve_burst`` row
+says when one is worth building.)  ROADMAP.md Queue 3 item 8 names
+this module's arithmetic as the next deletion.
 
 Inputs are plain numpy spans; no jax, no Router import at module load.
 """
@@ -208,15 +207,6 @@ class CrossJobPlan:
         return round(sum(r.lane_occupancy * r.nets for r in self.rungs)
                      / max(1, self.total_nets), 4)
 
-    def signature(self) -> Tuple:
-        """Canonicalized pack shape: the rung descriptor table + block
-        layout, independent of job identity and arrival order.  Packs
-        that quantize to the same signature dispatch through the same
-        compiled program family, so a join/finish that lands on an
-        already-seen signature recompiles nothing."""
-        return tuple((r.tile, r.shape_x, r.shape_y, r.block_nets,
-                      r.blocks) for r in self.rungs)
-
     def job_slots(self, job_id: str) -> List[Tuple[int, int, int]]:
         """[(rung, packed_slot, job_net_idx)] for one job."""
         out = []
@@ -224,32 +214,6 @@ class CrossJobPlan:
             for s, idx in r.demux().get(job_id, []):
                 out.append((ri, s, idx))
         return out
-
-
-#: machine-readable rebatch causes (flow_doctor validates against this)
-REBATCH_CAUSES = ("join", "finish", "evict", "failover")
-
-
-def diff_packs(prev_ids, cur_ids,
-               is_done=None, is_failover=None) -> List[Dict[str, str]]:
-    """Classify one rebatch boundary: which jobs entered/left the
-    co-admitted set between two slice rounds, each with a
-    machine-readable cause from ``REBATCH_CAUSES``.  ``is_done`` /
-    ``is_failover`` are job_id predicates supplied by the scheduler
-    (queue terminal state; fleet failover admission) — without them
-    entries default to ``join`` and exits to ``evict``."""
-    prev = frozenset(prev_ids or ())
-    cur = frozenset(cur_ids)
-    causes: List[Dict[str, str]] = []
-    for jid in sorted(cur - prev):
-        fo = is_failover is not None and is_failover(jid)
-        causes.append({"job_id": jid, "cause": "failover" if fo
-                       else "join"})
-    for jid in sorted(prev - cur):
-        done = is_done is not None and is_done(jid)
-        causes.append({"job_id": jid, "cause": "finish" if done
-                       else "evict"})
-    return causes
 
 
 def pack_jobs(job_nets: Dict[str, Tuple[np.ndarray, np.ndarray]],

@@ -66,15 +66,10 @@ def build_parser() -> argparse.ArgumentParser:
                    "job config)")
     p.add_argument("--sync", action="store_true",
                    help="disable the host-device pipeline")
-    p.add_argument("--fused", action="store_true",
-                   help="continuous batching: co-admit every runnable "
-                   "job into one fused lockstep dispatch per slice "
-                   "round, rebatched at each join/finish/evict "
-                   "(serve/fused.py)")
     p.add_argument("--stagger", type=int, default=0,
-                   help="admit jobs in waves of this many per batch "
-                   "round instead of all upfront (exercises rebatch "
-                   "joins; 0 = admit everything before running)")
+                   help="admit jobs in waves of this many, one wave "
+                   "after each slice, instead of all upfront (0 = "
+                   "admit everything before running)")
     p.add_argument("--profile", default="uniform",
                    choices=["uniform", "small-heavy"],
                    help="job-size mix (mirrors tools/traffic_gen.py): "
@@ -155,7 +150,7 @@ def main(argv=None) -> int:
         cfg=dict(luts=args.luts, chan_width=args.chan_width,
                  jobs=args.jobs, batch=args.batch_size,
                  slice=args.slice_iters),
-        resil=resil, fused=args.fused)
+        resil=resil)
 
     terms = {}
     if args.profile == "small-heavy":
@@ -189,29 +184,16 @@ def main(argv=None) -> int:
         _admit(j, f)
     del pending[:first]
     if pending:
-        # staggered stream: the next wave joins at each slice
-        # boundary, exercising the rebatch path mid-drain
-        def _admit_wave():
+        # staggered stream: the next wave joins at each slice boundary
+        inner_r = svc._runner
+
+        def _wrapped_runner(job):
+            out = inner_r(job)
             for j, f in pending[:args.stagger]:
                 _admit(j, f)
             del pending[:args.stagger]
-
-        if args.fused:
-            inner_b = svc._batch_runner
-
-            def _wrapped_batch(batch):
-                out = inner_b(batch)
-                _admit_wave()
-                return out
-            svc._batch_runner = _wrapped_batch
-        else:
-            inner_r = svc._runner
-
-            def _wrapped_runner(job):
-                out = inner_r(job)
-                _admit_wave()
-                return out
-            svc._runner = _wrapped_runner
+            return out
+        svc._runner = _wrapped_runner
 
     jobs = svc.run()
     exported = 0
@@ -237,7 +219,6 @@ def main(argv=None) -> int:
         "dispatch_cache_hits": m.counter(
             "route.dispatch.cache_hits").value,
         "serve": serve_vals,
-        "rebatch": svc.rebatch_summary(),
         "library_exported": exported,
         "wall_s": round(time.perf_counter() - t_start, 3),
     }

@@ -58,7 +58,7 @@ import statistics
 import threading
 import time
 from contextlib import contextmanager
-from dataclasses import dataclass, replace as dc_replace
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..obs.metrics import get_metrics
@@ -114,9 +114,6 @@ class DaemonOpts:
     poll_s: float = 0.2            # inbox poll period when idle
     heartbeat_s: float = 1.0       # liveness beat period
     slices_per_cycle: int = 4      # queue slices run between polls
-    fused: bool = False            # continuous batching: each slice
-    #                                round co-admits every runnable job
-    #                                into one lockstep fused dispatch
     admit_horizon_s: float = 600.0  # reject if est. completion exceeds
     overload_factor: float = 2.0   # shed when backlog_s > factor*horizon
     max_queue_depth: int = 64      # hard cap on queued jobs
@@ -709,9 +706,6 @@ class RouteDaemon:
             lambda jid=job_id: self.slo.runstore_fields(
                 jid, now=self._clock()))
         if failover:
-            # the batch scheduler reads this to stamp the job's
-            # rebatch-entry cause as "failover" rather than "join"
-            job.scratch["failover"] = True
             self.failed_over_ids.append(job_id)
             get_metrics().counter("route.fleet.jobs_failed_over").inc()
             if tr is not None:
@@ -1000,42 +994,6 @@ class RouteDaemon:
                 self.lease.renew(job.job_id)
         return verdict, value
 
-    def _batch_runner(self, jobs: List[RouteJob]):
-        """Batched queue runner (continuous batching): the service's
-        fused lockstep slice over the whole co-admitted set, then the
-        same per-job verdict/lease bookkeeping ``_runner`` does."""
-        ids = ",".join(j.job_id for j in jobs)
-        t_start, c0, s0 = self._slice_marks()
-        with self._alive_through_slice(), \
-                span("route.trace.slice", cat="lifecycle",
-                     job_id=f"fused[{ids}]",
-                     slice=max(j.slices for j in jobs),
-                     worker=self.worker or "solo"):
-            verdicts = self.service._batch_runner(jobs)
-        for job in jobs:
-            # lockstep costs are joint: every member LIVED through the
-            # whole fused wall, so each job's waterfall is charged the
-            # full slice window (per-job nets/s attribution stays the
-            # service's even-share route_s policy)
-            self._observe_slice(job, t_start, c0, s0)
-        for job in jobs:
-            verdict = verdicts.get(job.job_id, ("failed", ""))[0]
-            self._last_slice = {"job_id": job.job_id,
-                                "slice": job.slices,
-                                "verdict": verdict}
-            self.last_verdicts.append(
-                {"job_id": job.job_id, "verdict": verdict,
-                 "slice": job.slices, "ts": round(self._wall(), 3)})
-            self.recorder.note("slice", job_id=job.job_id,
-                               slice=job.slices, verdict=verdict)
-            if self.lease is not None:
-                if verdict == "done":
-                    self.lease.release(job.job_id, state="done")
-                elif verdict == "preempted":
-                    self.lease.renew(job.job_id)
-        del self.last_verdicts[:-8]
-        return verdicts
-
     # ------------------------------------------------- journal
 
     def _journal_entries(self) -> Dict[str, dict]:
@@ -1269,14 +1227,7 @@ class RouteDaemon:
             self._lease_sweep()
             if q.depth() == 0:
                 break
-            if self.opts.fused:
-                # continuous batching: one rebatch-and-fuse round over
-                # every runnable job.  The lease sweep above fences
-                # stolen jobs BEFORE the re-pack, so a fenced job
-                # drops out of the batch at this slice boundary
-                q.run_batch(self._batch_runner, max_batches=1)
-            else:
-                q.run(self._runner, max_slices=1)
+            q.run(self._runner, max_slices=1)
             hb_state["queue_depth"] = q.depth()
             self.heartbeat.beat(**hb_state)
             self._scan_terminal()
@@ -1389,10 +1340,6 @@ class RouteDaemon:
             },
             "trace": m.values("route.trace."),
             "serve": m.values("route.serve."),
-            "rebatch": (self.service.rebatch_summary()
-                        if hasattr(self.service, "rebatch_summary")
-                        else {"fused": False, "rounds": 0,
-                              "events": [], "counters": {}}),
             "dispatch_compiles": m.counter(
                 "route.dispatch.compiles").value,
             "resil": {"metrics": m.values("route.resil.")},
@@ -1408,8 +1355,7 @@ def build_daemon(inbox_dir: str, *, luts: int, chan_width: int = 16,
                  checkpoint_dir: Optional[str] = None,
                  opts: Optional[DaemonOpts] = None,
                  fault_plan=None,
-                 sync: bool = False,
-                 fused: bool = False) -> RouteDaemon:
+                 sync: bool = False) -> RouteDaemon:
     """Wire a production-shaped daemon: real synth flow on one device
     graph, resilience layer armed with durable checkpoints under the
     inbox, service corpus rows feeding the admission estimator.
@@ -1420,7 +1366,6 @@ def build_daemon(inbox_dir: str, *, luts: int, chan_width: int = 16,
     from ..flow import synth_flow
     from ..resil import ResilOpts
 
-    fused = fused or bool(opts is not None and opts.fused)
     flow = synth_flow(num_luts=luts, chan_width=chan_width)
     scenario = scenario or f"daemon_l{luts}_w{chan_width}"
     ropts = RouterOpts(
@@ -1437,11 +1382,7 @@ def build_daemon(inbox_dir: str, *, luts: int, chan_width: int = 16,
         runs_dir=runs_dir or None, scenario=scenario,
         cfg={"luts": luts, "chan_width": chan_width,
              "slice": slice_iters, "daemon": True},
-        resil=resil, fused=fused)
-    if fused and opts is not None and not opts.fused:
-        opts = dc_replace(opts, fused=True)
-    elif fused and opts is None:
-        opts = DaemonOpts(fused=True)
+        resil=resil)
     return RouteDaemon(service, inbox_dir, opts,
                        grid_cfg={"luts": luts,
                                  "chan_width": chan_width})

@@ -57,18 +57,13 @@ def build_parser() -> argparse.ArgumentParser:
     r.add_argument("--compile_cache_dir", default="")
     r.add_argument("--export_library", action="store_true",
                    help="export every dispatch variant seen this run "
-                   "(merged pack-shape programs included) into "
-                   "--library at shutdown — the warm-up half of the "
+                   "into --library at shutdown — the warm-up half of the "
                    "zero-recompile serving round trip")
     r.add_argument("--runs_dir", default="",
                    help="observatory corpus (also feeds admission "
                    "capacity from recent per-tenant nets/s)")
     r.add_argument("--scenario", default="")
     r.add_argument("--sync", action="store_true")
-    r.add_argument("--fused", action="store_true",
-                   help="continuous batching: re-pack every runnable "
-                   "job into one fused lockstep dispatch per slice "
-                   "round, rebatched at each join/finish/evict")
     r.add_argument("--poll_s", type=float, default=0.2)
     r.add_argument("--heartbeat_s", type=float, default=1.0)
     r.add_argument("--slices_per_cycle", type=int, default=4)
@@ -159,9 +154,6 @@ def build_parser() -> argparse.ArgumentParser:
     f.add_argument("--runs_dir", default="")
     f.add_argument("--scenario", default="")
     f.add_argument("--sync", action="store_true")
-    f.add_argument("--fused", action="store_true",
-                   help="every worker runs continuous batching over "
-                   "its co-admitted jobs (daemon run --fused)")
     f.add_argument("--heartbeat_s", type=float, default=0.5)
     f.add_argument("--poll_s", type=float, default=0.1)
     f.add_argument("--lease_ttl_s", type=float, default=4.0)
@@ -228,7 +220,6 @@ def _cmd_run(args) -> int:
         max_queue_depth=args.max_queue_depth,
         aging_rate=args.aging_rate,
         exit_when_idle=args.exit_when_idle,
-        fused=getattr(args, "fused", False),
         worker=worker, workers=roster,
         lease_ttl_s=args.lease_ttl_s,
         foreign_grace_s=args.foreign_grace_s,
@@ -404,7 +395,7 @@ def _cmd_fleet(args) -> int:
         max_router_iterations=args.max_router_iterations,
         library_dir=args.library, cache_base=args.cache_base,
         runs_dir=args.runs_dir, scenario=args.scenario,
-        sync=args.sync, fused=getattr(args, "fused", False),
+        sync=args.sync,
         heartbeat_s=args.heartbeat_s,
         poll_s=args.poll_s, lease_ttl_s=args.lease_ttl_s,
         foreign_grace_s=args.foreign_grace_s,

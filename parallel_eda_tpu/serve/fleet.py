@@ -139,7 +139,6 @@ class FleetOpts:
     runs_dir: str = ""
     scenario: str = ""
     sync: bool = False
-    fused: bool = False            # workers run continuous batching
     heartbeat_s: float = 0.5
     poll_s: float = 0.1
     lease_ttl_s: float = 4.0
@@ -230,8 +229,6 @@ class FleetSupervisor:
             cmd += ["--scenario", o.scenario]
         if o.sync:
             cmd += ["--sync"]
-        if o.fused:
-            cmd += ["--fused"]
         if self.worker_chaos:
             cmd += ["--chaos", self.worker_chaos,
                     "--chaos_seed", str(o.chaos_seed)]
@@ -521,23 +518,9 @@ class FleetSupervisor:
             jobs.extend(doc.get("jobs") or [])
             if isinstance(doc.get("slo"), dict):
                 slo_sections[w] = doc["slo"]
-            rb = doc.get("rebatch") or {}
-            if rb.get("fused"):
-                row["rebatch"] = {"rounds": rb.get("rounds", 0),
-                                  "events": len(rb.get("events") or [])}
             fleet = doc.get("fleet") or {}
             for k, v in (fleet.get("metrics") or {}).items():
                 if isinstance(v, (int, float)):
-                    merged[k] = merged.get(k, 0) + v
-            # continuous-batching counters are per-worker serve
-            # metrics; sum them fleet-wide so the fused A/B and the
-            # doctor see one aggregate rebatch/fusion picture
-            for k, v in (doc.get("serve") or {}).items():
-                if (k.startswith(("route.serve.rebatch.",
-                                  "route.serve.fused."))
-                        and isinstance(v, (int, float))
-                        and not k.endswith((".width",
-                                            ".slice_wall_s"))):
                     merged[k] = merged.get(k, 0) + v
         # a gauge is a point-in-time reading, not summable: report the
         # supervisor's own final observation

@@ -80,17 +80,11 @@ def _avatarize(tree):
         tree, is_leaf=lambda a: _is_array(a) or a is None)
 
 
-def _static_names(fn) -> Tuple[str, ...]:
-    """The static argnames of a jit-wrapped fn.  The planes module
-    stamps ``_static_argnames`` on each window program (the fused
-    ragged-dispatch program has a different static set than the
-    per-rung one); fall back to the shared per-rung constant for
-    wrappers built before the stamp existed."""
-    names = getattr(fn, "_static_argnames", None)
-    if names is not None:
-        return tuple(names)
+def _statics() -> set:
+    """The window program's static argnames (route/ is imported
+    lazily: the router imports this module)."""
     from ..route.planes import WINDOW_STATIC_ARGNAMES
-    return WINDOW_STATIC_ARGNAMES
+    return set(WINDOW_STATIC_ARGNAMES)
 
 
 def _positional_names(fn) -> List[str]:
@@ -103,7 +97,7 @@ def _positional_names(fn) -> List[str]:
 def _split_dynamic(fn, args: tuple, kwargs: dict):
     """Drop static-argname entries from (args, kwargs): the exported
     program has them baked in and its call() rejects them."""
-    statics = set(_static_names(fn))
+    statics = _statics()
     names = _positional_names(fn)
     dyn_args = tuple(a for name, a in zip(names, args)
                      if name not in statics)
@@ -117,7 +111,7 @@ def _sig_digest(fn, args: tuple, kwargs: dict) -> str:
     """Digest of the DYNAMIC call structure (treedef + leaf
     shapes/dtypes) plus the static values: detects a library entry
     whose baked program no longer matches the live call."""
-    statics = set(_static_names(fn))
+    statics = _statics()
     names = _positional_names(fn)
     stat_repr = [(n, repr(a)) for n, a in zip(names, args)
                  if n in statics]
@@ -173,6 +167,17 @@ def _register_tree_serialization(tree) -> None:
             walk(c)
 
     walk(jax.tree_util.tree_structure(tree))
+    # the program's result, a NamedTuple, is in the exported calling
+    # convention too
+    from ..route.planes import WindowOut
+    if WindowOut not in _SERIALIZABLE:
+        try:
+            jexport.register_namedtuple_serialization(
+                WindowOut, serialized_name=(
+                    f"{WindowOut.__module__}.{WindowOut.__qualname__}"))
+        except ValueError:
+            pass  # registered elsewhere
+        _SERIALIZABLE.add(WindowOut)
 
 
 def _provenance(repo_dir: Optional[str] = None) -> Dict[str, Any]:

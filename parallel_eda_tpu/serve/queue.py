@@ -224,8 +224,7 @@ class JobQueue:
         return list(self.jobs)
 
     def _apply(self, job: RouteJob, verdict: str, value: Any) -> None:
-        """Apply a runner verdict to a job — the single state machine
-        shared by the one-at-a-time loop and the batched loop."""
+        """Apply a runner verdict to a job: the state machine."""
         m = get_metrics()
         if verdict == "done":
             job.state = JobState.DONE
@@ -267,72 +266,3 @@ class JobQueue:
                     self._push(job)
         else:
             raise ValueError(f"runner returned {verdict!r}")
-
-    # -------------------------------------------------- batched run
-
-    def _pop_runnable(self) -> List[RouteJob]:
-        """Pop EVERY currently-runnable queued job off the heap (aged
-        priority order), skipping tombstones, timing out past-deadline
-        jobs, and re-pushing backoff-gated ones.  The batch scheduler's
-        admission step: whatever this returns is co-admitted into one
-        fused slice."""
-        m = get_metrics()
-        out: List[RouteJob] = []
-        gated: List[RouteJob] = []
-        while self._heap:
-            _, _, job = heapq.heappop(self._heap)
-            if job.state is not JobState.QUEUED:
-                continue               # shed tombstone
-            now = self._clock()
-            if job.deadline_exceeded(now):
-                job.state = JobState.TIMEOUT
-                job.error = (f"deadline {job.deadline_s}s exceeded "
-                             f"after {now - job.admitted_t:.2f}s")
-                m.counter("route.serve.jobs_timeout").inc()
-                continue
-            if now < job.not_before:
-                gated.append(job)      # backoff not elapsed
-                continue
-            out.append(job)
-        for job in gated:
-            self._push(job)
-        return out
-
-    def run_batch(self, batch_runner: Callable[
-            [List[RouteJob]], Dict[str, Outcome]],
-            max_batches: int = 100000) -> List[RouteJob]:
-        """Drain the queue through a BATCH runner: each round pops all
-        runnable jobs, hands the whole co-admitted set to
-        ``batch_runner`` (returns ``{job_id: (verdict, value)}``), and
-        applies each verdict through the same state machine as
-        ``run()``.  One round costs one slice per member job; a raised
-        batch runner counts as a failed attempt for every member."""
-        m = get_metrics()
-        rounds = 0
-        while rounds < max_batches:
-            batch = self._pop_runnable()
-            if not batch:
-                gated = self.queued_jobs()
-                if not gated:
-                    break              # drained
-                # every queued job is backoff-gated: wait out the
-                # soonest gate instead of spinning
-                self._sleep(max(0.0, min(j.not_before for j in gated)
-                                 - self._clock()))
-                continue
-            rounds += 1
-            for job in batch:
-                job.state = JobState.RUNNING
-                job.slices += 1
-            self._depth_gauge()
-            try:
-                verdicts = batch_runner(batch)
-            except Exception as e:
-                verdicts = {j.job_id: (
-                    "failed", f"{type(e).__name__}: {e}") for j in batch}
-            for job in batch:
-                verdict, value = verdicts.get(job.job_id, (
-                    "failed", "batch runner returned no verdict"))
-                self._apply(job, verdict, value)
-            self._depth_gauge()
-        return list(self.jobs)

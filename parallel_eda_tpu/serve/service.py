@@ -50,18 +50,13 @@ class RouteService:
                  runs_dir: Optional[str] = None,
                  scenario: str = "serve_smoke",
                  cfg: Optional[dict] = None,
-                 resil: Optional[ResilOpts] = None,
-                 fused: bool = False):
+                 resil: Optional[ResilOpts] = None):
         """``slice_iters`` > 0 preempts each job after that many router
         iterations (checkpointed, requeued) — the fairness knob; 0
         runs each job to completion in one slice.  ``resil`` arms the
         resilience layer: guarded dispatches, durable checkpoints
         (when a checkpoint_dir is set), fault-injection sites, and
-        diagnostic bundles for poisoned jobs.  ``fused`` turns on
-        continuous batching: every slice round co-admits all runnable
-        jobs and drives their window dispatches in lockstep through
-        one merged program per step (serve/fused.py), rebatching at
-        each slice boundary as jobs join/finish/evict."""
+        diagnostic bundles for poisoned jobs."""
         self.rr = rr
         self.resil = Resilience(resil) if resil is not None else None
         base = opts or RouterOpts()
@@ -80,13 +75,6 @@ class RouteService:
         self.cfg = dict(cfg or {})
         self.queue = JobQueue()
         self.draining = False
-        self.fused = bool(fused)
-        self._fused_runner = None      # built lazily (serve/fused.py)
-        # rebatch bookkeeping: the co-admitted set of the previous
-        # batch round, and the event log the summary/doctor consume
-        self._last_batch_ids: Optional[frozenset] = None
-        self._rounds = 0
-        self.rebatch_events: List[dict] = []
         self._t_init = time.perf_counter()
         self._first_slice_s: Optional[float] = None
         # host-context hook: the daemon/fleet layer injects a callable
@@ -133,13 +121,11 @@ class RouteService:
 
     def _publish_pack_plan(self):
         """Shared packed-dispatch plan over every queued job (batcher
-        telemetry: how the admitted set folds onto one crop ladder).
-        Called at admit AND at every rebatch boundary, so the pack
-        gauges (lane_occupancy in particular) always reflect the
-        CURRENT co-admitted set, not the initial one."""
+        telemetry: how the admitted set folds onto one crop ladder),
+        as of the last admit."""
         pg = self.router.pg
         if pg is None:
-            return None
+            return
         Lm = pg.max_span
         job_nets = {}
         for job in self.queue.jobs:
@@ -150,16 +136,14 @@ class RouteService:
                 (t.bb_xmax - t.bb_xmin + 1 + 2 * Lm).astype(np.int64),
                 (t.bb_ymax - t.bb_ymin + 1 + 2 * Lm).astype(np.int64))
         if job_nets:
-            return pack_jobs(job_nets, pg.shape_x, pg.shape_y)
-        return None
+            pack_jobs(job_nets, pg.shape_x, pg.shape_y)
 
     # ------------------------------------------------------ runner
 
-    def _pre_slice(self, job: RouteJob, fused: bool = False):
-        """Shared slice prologue for both schedulers: fire the
-        backend-loss site, recover the resume checkpoint (in-memory or
-        durable), and build the per-job RouterOpts.  Returns
-        ``(total, ck, opts)``."""
+    def _pre_slice(self, job: RouteJob):
+        """A slice's prologue: fire the backend-loss site, recover the
+        resume checkpoint (in-memory or durable), and build the per-job
+        RouterOpts.  Returns ``(total, ck, opts)``."""
         spec = job.payload
         total = spec.max_iterations or self.base_opts.max_router_iterations
         rt = self.resil
@@ -189,17 +173,13 @@ class RouteService:
         # sliced-and-resumed == unsliced, bit for bit.
         kw = dict(max_router_iterations=total,
                   slice_iterations=max(0, self.slice_iters))
-        if fused:
-            # lockstep merging needs the generator to yield at the
-            # fused ragged dispatch site
-            kw["fused_dispatch"] = True
         if (rt is not None and self.base_opts.pipeline
                 and rt.ladder.level("pipeline") > 0):
             kw["pipeline"] = False   # degraded: the --sync escape hatch
         return total, ck, replace(self.base_opts, **kw)
 
     def _post_slice(self, job: RouteJob, res, ck, total: int):
-        """Shared slice epilogue: turn a RouteResult into the queue
+        """A slice's epilogue: turn a RouteResult into the queue
         verdict, managing the durable checkpoint either way."""
         rt = self.resil
         if res.success:
@@ -244,124 +224,6 @@ class RouteService:
         self._note_first_slice()
         job.scratch["route_s"] = job.scratch.get("route_s", 0.0) + dt
         return self._post_slice(job, res, ck, total)
-
-    # ---------------------------------------------- fused batch runner
-
-    def _note_rebatch(self, jobs: List[RouteJob]) -> None:
-        """Record the rebatch boundary when the co-admitted set
-        changed: machine-readable causes, ``route.serve.rebatch.*``
-        counters, a lifecycle trace instant, and — satellite of this
-        change — refreshed pack gauges so lane occupancy is live."""
-        from .batcher import diff_packs
-        cur = frozenset(j.job_id for j in jobs)
-        prev = self._last_batch_ids
-        self._rounds += 1
-        if prev is not None and cur == prev:
-            return
-        self._last_batch_ids = cur
-
-        def is_done(jid):
-            j = self.queue.get(jid)
-            return j is not None and j.state is JobState.DONE
-
-        def is_failover(jid):
-            j = self.queue.get(jid)
-            return j is not None and bool(j.scratch.get("failover"))
-
-        causes = diff_packs(prev, cur, is_done=is_done,
-                            is_failover=is_failover)
-        m = get_metrics()
-        m.counter("route.serve.rebatch.events").inc()
-        for c in causes:
-            # one counter per cause, named by the cause verbatim:
-            # route.serve.rebatch.{join,finish,evict,failover}
-            m.counter(f"route.serve.rebatch.{c['cause']}").inc()
-        plan = self._publish_pack_plan()   # live pack gauges
-        event = dict(
-            round=self._rounds, jobs=sorted(cur), causes=causes,
-            lane_occupancy=(plan.lane_occupancy
-                            if plan is not None else None),
-            pack_signature=(repr(plan.signature())
-                            if plan is not None else None))
-        self.rebatch_events.append(event)
-        tr = get_tracer()
-        if tr is not None:
-            tr.instant("route.trace.rebatch", cat="lifecycle",
-                       jobs=len(cur),
-                       causes=",".join(f"{c['job_id']}:{c['cause']}"
-                                       for c in causes))
-
-    def _batch_runner(self, jobs: List[RouteJob]) -> Dict[str, Any]:
-        """Queue batch runner: one fused lockstep slice over the whole
-        co-admitted set.  Per-job prologue/epilogue are the same
-        _pre_slice/_post_slice the solo runner uses — checkpoints stay
-        strictly per job, so SIGKILL/failover resume is unchanged."""
-        from .fused import FusedSliceRunner, SliceEntry
-        if self._fused_runner is None:
-            self._fused_runner = FusedSliceRunner(self.router,
-                                                  resil=self.resil)
-        self._note_rebatch(jobs)
-        rt = self.resil
-        verdicts: Dict[str, Any] = {}
-        entries: List[SliceEntry] = []
-        meta: Dict[str, tuple] = {}
-        for job in jobs:
-            try:
-                total, ck, opts = self._pre_slice(job, fused=True)
-            except Exception as e:   # e.g. injected backend loss
-                verdicts[job.job_id] = (
-                    "failed", f"{type(e).__name__}: {e}")
-                continue
-            # the generator body runs lazily at first next(); the
-            # runner asserts this job's opts/prefix before every
-            # advance, so construction order here is immaterial
-            gen = self.router.route_gen(job.payload.term,
-                                        crit=job.payload.crit,
-                                        resume=ck)
-            entries.append(SliceEntry(
-                job, gen, opts, job.job_id + ":",
-                prev_it=(ck.it_done if ck is not None else 0)))
-            meta[job.job_id] = (total, ck)
-        t0 = time.perf_counter()
-        if entries:
-            self._fused_runner.run_slice(entries)
-        wall = time.perf_counter() - t0
-        self._note_first_slice()
-        # lockstep wall is a joint cost: attribute evenly.  Aggregate
-        # nets/s (the number the A/B benchmark reads) uses the true
-        # run() wall, so the attribution policy only shapes per-job
-        # route_s reporting.
-        share = wall / max(1, len(entries))
-        for e in entries:
-            job = e.job
-            job.scratch["route_s"] = (
-                job.scratch.get("route_s", 0.0) + share)
-            total, ck = meta[job.job_id]
-            if e.error is not None:
-                if isinstance(e.error, DispatchPoisonedError) \
-                        and rt is not None:
-                    rt.ladder.step("pipeline", reason=str(e.error))
-                verdicts[job.job_id] = (
-                    "failed",
-                    f"{type(e.error).__name__}: {e.error}")
-            else:
-                verdicts[job.job_id] = self._post_slice(
-                    job, e.result, ck, total)
-        return verdicts
-
-    def rebatch_summary(self) -> dict:
-        """Continuous-batching section of the serve summary: batch
-        rounds, the rebatch event log (causes per boundary), and the
-        fused/rebatch counters — what flow_doctor's rebatch rules
-        validate."""
-        m = get_metrics()
-        return {
-            "fused": self.fused,
-            "rounds": self._rounds,
-            "events": list(self.rebatch_events),
-            "counters": {**m.values("route.serve.rebatch."),
-                         **m.values("route.serve.fused.")},
-        }
 
     def _finish(self, job: RouteJob, res) -> dict:
         spec = job.payload
@@ -450,14 +312,10 @@ class RouteService:
     # --------------------------------------------------------- run
 
     def run(self) -> List[RouteJob]:
-        """Drain the queue; returns all jobs with terminal states.
-        Fused mode drains through the batched scheduler (continuous
-        batching); otherwise one job at a time."""
+        """Drain the queue, one slice of one job at a time; returns
+        all jobs with terminal states."""
         t0 = time.perf_counter()
-        if self.fused:
-            jobs = self.queue.run_batch(self._batch_runner)
-        else:
-            jobs = self.queue.run(self._runner)
+        jobs = self.queue.run(self._runner)
         wall = time.perf_counter() - t0
         done = [j for j in jobs if j.state == JobState.DONE]
         nets = sum(len(j.payload.term.source) for j in done)

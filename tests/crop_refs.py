@@ -148,8 +148,6 @@ def vmap_scaffolding():
     traced)."""
     def drop_programs():
         for prog in (planes.route_window_planes,
-                     planes.route_window_planes_fused,
-                     planes.route_window_planes_multi,
                      planes.route_batch_resident_planes):
             prog.clear_cache()
 

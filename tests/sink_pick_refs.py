@@ -120,8 +120,6 @@ def patched_planes(name, value):
 
     def drop_programs():
         for prog in (planes.route_window_planes,
-                     planes.route_window_planes_fused,
-                     planes.route_window_planes_multi,
                      planes.route_batch_resident_planes):
             prog.clear_cache()
 

@@ -104,27 +104,22 @@ def placed():
                                 bb_factor=1))
 
 
-@pytest.mark.parametrize("fused", [False, True],
-                         ids=["route_window_planes",
-                              "route_window_planes_fused"])
-def test_vocabulary_covers_the_window_program(placed, fused):
+def test_vocabulary_covers_the_window_program(placed):
     """Route a tiny placed problem, then lower every dispatched variant
     again from its recorded avatars and audit the compiled text."""
     old = get_devprof()
     dp = set_devprof(DevProfiler(enabled=True))
     try:
-        opts = RouterOpts(program="planes", batch_size=16, crop="5x5",
-                          fused_dispatch=fused)
+        opts = RouterOpts(program="planes", batch_size=16, crop="5x5")
         f = run_route(placed, opts, timing_driven=True, verify=False)
         assert f.route.success
         pending = list(dp._pending)
     finally:
         set_devprof(old)
     assert pending
-    want = "route_window_planes_fused" if fused else "route_window_planes"
     seen = set()
     for key, _, fn, args, kwargs in pending:
-        assert fn.__name__ == want
+        assert fn.__name__ == "route_window_planes"
         names, outside, made, judged = audit(
             fn.lower(*args, **kwargs).compile().as_text())
         seen |= names

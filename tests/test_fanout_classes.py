@@ -353,16 +353,6 @@ def test_a_batch_at_a_wider_table_is_the_same_batch():
     assert narrow[2].any() and narrow[4].sum() > 0
 
 
-def test_the_serving_batcher_leaves_a_classed_job_alone():
-    from parallel_eda_tpu.route.router import WindowDispatchRequest
-    from parallel_eda_tpu.serve.fused import _mergeable
-
-    def req(**kw):
-        return WindowDispatchRequest(None, (), kw, None, None, {})
-    assert _mergeable(req(mesh=None, tdev=None))
-    assert not _mergeable(req(mesh=None, tdev=None, fan=(1, 2)))
-
-
 # ---- the STA's out-edge table: a tnode's out-edges past OUT_ELL_CAP
 # are a flat overflow list (a primary input of 260 LUT pins made every
 # tnode pay for 285 out-edge slots a level) ----

@@ -386,3 +386,21 @@ def test_trace_and_metrics_passthrough(tmp_path):
         "route.relax_steps_wasted": 3,
         "route.devcost.bytes_delta": 500.0}, "snapshots": []}))
     assert any("band" in e for e in fd.check_metrics(str(m)))
+
+
+@pytest.mark.parametrize("doc,bad", [
+    ({"dispatch_compiles": 0}, None),
+    ({"dispatch_compiles": 3}, "compiled 3"),
+    ({}, "no dispatch_compiles"),
+], ids=["warm", "compiled", "no_field"])
+def test_warm_gate_reads_dispatch_compiles_alone(doc, bad):
+    """``--warm`` over a serve or daemon summary: zero window-program
+    compiles or unhealthy, whatever else the summary holds (a
+    ``rebatch`` section of an old summary is not looked at)."""
+    fd = _load()
+    errs, notes = fd.check_warm({**doc, "rebatch": {"fused": True}})
+    if bad is None:
+        assert errs == [] and any("gate ok" in n for n in notes)
+    else:
+        assert len(errs) == 1 and bad in errs[0]
+    assert not hasattr(fd, "check_rebatch")

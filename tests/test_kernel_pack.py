@@ -1,7 +1,6 @@
 """The planes relaxation is PER-NET, and plane_dtype names what commits.
 
-First half: net independence of the XLA relaxation, the property
-`route_window_planes_multi` and `serve/fused.py` rest on — a batch
+First half: net independence of the XLA relaxation — a batch
 relaxes each net on its own canvas against its own congestion view, so
 `planes_relax` / `planes_relax_cropped` over a batch equal each net
 relaxed alone, bit for bit (a converged net's extra trips while a
@@ -10,11 +9,14 @@ kinds (bidirectional, directional) and two crop-ladder rungs.  The
 host-side block-planning arithmetic (`serve/batcher.py`) is checked
 beside it.
 
-Second half, at full routing fidelity: the fused ragged window dispatch
-reproduces the f32 per-rung route exactly on every graph kind;
-``plane_dtype="bf16"`` COMMITS bf16 — a legal route whose wirelength
-recount holds, per-rung and fused; and the option values that selected
-the deleted Pallas lowering and the shadow guards are refused by name.
+Second half, at full routing fidelity: a window of TWO populated rungs
+escalates the history cost on its first rung only and threads the
+donated state rung to rung, on the directional graph and a second
+random circuit, in both plane dtypes; ``plane_dtype="bf16"`` COMMITS
+bf16 — a legal route whose wirelength recount holds; the option values
+that selected the deleted Pallas lowering and the shadow guards are
+refused by name, and the options and flags of the deleted fused
+scheduler by the dataclass or the parser itself.
 """
 
 import jax.numpy as jnp
@@ -178,11 +180,9 @@ def test_block_planning_model():
 
 
 # --------------------------------------------------------------------
-# Full-route checks.  Flows and the f32 per-rung baseline route are
-# cached at module scope so each mode pays one route.
+# Full-route checks.  Flows are cached at module scope.
 
 _FLOWS: dict = {}
-_BASE: dict = {}
 _GRAPHS = ["bench", "unidir", "random7"]
 
 
@@ -208,46 +208,83 @@ def _flow(name):
     return _FLOWS[name]
 
 
-def _baseline(name):
-    from parallel_eda_tpu.route import Router, RouterOpts
-    if name not in _BASE:
-        f = _flow(name)
-        res = Router(f.rr, RouterOpts(batch_size=32)).route(f.term)
-        assert res.success
-        _BASE[name] = res
-    return _BASE[name]
+def _two_rung_flow(name):
+    """``name``'s architecture and seed at 100 LUTs, placed, nets boxed
+    tightly (bb_factor=1): on its 8 x 8 grid ``crop="7x7"`` hands the
+    first window's narrow nets a cropped rung and the rest the full
+    canvas (the 3 x 3 grids of ``_flow`` have no rung to populate)."""
+    from parallel_eda_tpu.flow import run_place_native, synth_flow
+    key = name + "_100"
+    if key not in _FLOWS:
+        kw = (dict(chan_width=14, seed=5,
+                   arch=unidir_arch(chan_width=14, length=2))
+              if name == "unidir" else dict(chan_width=12, seed=7))
+        _FLOWS[key] = run_place_native(
+            synth_flow(num_luts=100, bb_factor=1, **kw))
+    return _FLOWS[key]
 
 
-def _assert_route_parity(name, kw):
-    from parallel_eda_tpu.route import Router, RouterOpts, check_route
-    f = _flow(name)
-    base = _baseline(name)
-    res = Router(f.rr, RouterOpts(batch_size=32, **kw)).route(f.term)
-    assert res.success, kw
-    assert np.array_equal(base.paths, res.paths), kw
-    assert np.array_equal(base.occ, res.occ), kw
-    assert base.wirelength == res.wirelength, kw
-    check_route(f.rr, f.term, res.paths, occ=res.occ)
-
-
-@pytest.mark.parametrize("kw", [
-    dict(fused_dispatch=True),                       # 1 dispatch/window
-], ids=["fused"])
-def test_route_parity_bench_arch(kw):
-    _assert_route_parity("bench", kw)
-
-
+@pytest.mark.parametrize("pd", ["f32", "bf16"])
 @pytest.mark.parametrize("name", ["unidir", "random7"])
-def test_route_parity_other_archs(name):
-    """Directional wiring and a second random circuit through the fused
-    ragged window program."""
-    _assert_route_parity(name, dict(fused_dispatch=True))
+def test_two_rung_window_escalates_once_and_threads_the_state(
+        name, pd, monkeypatch):
+    """A window that populates two rungs dispatches route_window_planes
+    twice: ``acc_fac`` is the option's on the first and 0 on the
+    second (the history cost escalates once a window), every rung gets
+    the same ``pres0`` / ``it0``, and the second is handed the very
+    arrays the first returned (they are donated on).  The route is the
+    ``pipeline=False`` route node for node, legal, and recounts to its
+    own wirelength."""
+    import inspect
+
+    from parallel_eda_tpu.route import (Router, RouterOpts, check_route,
+                                        planes)
+    f = _two_rung_flow(name)
+    kw = dict(batch_size=32, crop="7x7", plane_dtype=pd)
+    base = Router(f.rr, RouterOpts(pipeline=False, **kw)).route(f.term)
+    assert base.success
+
+    real = planes.route_window_planes
+    sig = inspect.signature(real.__wrapped__)
+    state = ("occ", "acc", "paths", "sink_delay", "all_reached", "bb",
+             "crit_all")
+    windows: dict = {}
+    last = []
+
+    def spy(*a, **k):
+        arg = sig.bind(*a, **k).arguments
+        rungs = windows.setdefault(int(arg["it0"]), [])
+        if rungs:
+            # a later rung of the same window: the state is what the
+            # rung before it returned, not a copy
+            for n in state:
+                assert arg[n] is getattr(last[0], n), n
+        rungs.append((float(arg["acc_fac"]), float(arg["pres0"]),
+                      arg["crop_tile"]))
+        out = real(*a, **k)
+        assert isinstance(out, planes.WindowOut)
+        last[:] = [out]
+        return out
+
+    monkeypatch.setattr(planes, "route_window_planes", spy)
+    res = Router(f.rr, RouterOpts(**kw)).route(f.term)
+    assert res.success
+    two = [r for r in windows.values() if len(r) > 1]
+    assert two, windows
+    for rungs in windows.values():
+        assert [r[0] for r in rungs] == [1.0] + [0.0] * (len(rungs) - 1)
+        assert len({r[1] for r in rungs}) == 1
+        # cropped rungs first, the full canvas last
+        assert all(r[2] is not None for r in rungs[:-1])
+    assert np.array_equal(base.paths, res.paths)
+    assert np.array_equal(base.occ, res.occ)
+    assert base.wirelength == res.wirelength
+    judged = check_route(f.rr, f.term, res.paths, occ=res.occ)
+    assert judged["wirelength"] == res.wirelength > 0
 
 
-@pytest.mark.parametrize("fused", [False, True],
-                         ids=["per_rung", "fused"])
 @pytest.mark.parametrize("name", _GRAPHS)
-def test_bf16_route_commits_and_is_legal(name, fused):
+def test_bf16_route_commits_and_is_legal(name):
     """plane_dtype="bf16" is the dtype that is committed: the route
     converges, check_route (the independent legality oracle) accepts
     its trees against its own occupancy, and the oracle's wirelength
@@ -261,8 +298,7 @@ def test_bf16_route_commits_and_is_legal(name, fused):
     reg = set_metrics(MetricsRegistry())
     try:
         res = Router(f.rr, RouterOpts(
-            batch_size=32, plane_dtype="bf16",
-            fused_dispatch=fused)).route(f.term)
+            batch_size=32, plane_dtype="bf16")).route(f.term)
         assert reg.gauge("route.kernel.plane_dtype").value == "bf16"
     finally:
         set_metrics(old)
@@ -287,3 +323,61 @@ def test_deleted_option_values_are_refused(kw, names):
         Router(f.rr, RouterOpts(batch_size=32, **kw)).route(f.term)
     for n in names:
         assert n in str(ei.value)
+
+
+def _deleted_field_cases():
+    from parallel_eda_tpu.route import RouterOpts
+    from parallel_eda_tpu.serve.daemon import DaemonOpts
+    from parallel_eda_tpu.serve.fleet import FleetOpts
+    from parallel_eda_tpu.serve.service import RouteService
+    return {
+        "RouterOpts": (lambda: RouterOpts(fused_dispatch=True),
+                       "fused_dispatch"),
+        "DaemonOpts": (lambda: DaemonOpts(fused=True), "fused"),
+        "FleetOpts": (lambda: FleetOpts(fused=True), "fused"),
+        "RouteService": (lambda: RouteService(None, fused=True), "fused"),
+    }
+
+
+@pytest.mark.parametrize(
+    "who", ["RouterOpts", "DaemonOpts", "FleetOpts", "RouteService"])
+def test_deleted_option_fields_are_refused(who):
+    """The fields that selected the fused window program and the batch
+    scheduler are gone, and nothing in the package refuses them in
+    their name: the dataclass (the constructor) itself raises a
+    TypeError that names the key, so a job script or a fleet
+    configuration that still asks for the deleted scheduler fails
+    loudly and never runs the interleaved one in its place."""
+    build, key = _deleted_field_cases()[who]
+    with pytest.raises(TypeError) as ei:
+        build()
+    assert repr(key) in str(ei.value)
+
+
+@pytest.mark.parametrize("argv,flag", [
+    (["serve", "--fused"], "--fused"),
+    (["daemon", "run", "--inbox", "box", "--fused"], "--fused"),
+    (["daemon", "fleet", "--inbox", "box", "--fused"], "--fused"),
+    (["bench.py", "--cpu", "--fused_dispatch"], "--fused_dispatch"),
+], ids=["serve", "daemon_run", "daemon_fleet", "bench"])
+def test_deleted_cli_flags_exit_2(argv, flag, capsys):
+    """``--fused`` at the three serving front ends and
+    ``--fused_dispatch`` at bench.py are unknown to the parser: exit
+    code 2, the flag named, nothing routed."""
+    import os
+    import subprocess
+    import sys
+    if argv[0] == "bench.py":
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        p = subprocess.run([sys.executable, os.path.join(repo, "bench.py")]
+                           + argv[1:], capture_output=True, text=True,
+                           timeout=300)
+        code, err = p.returncode, p.stderr
+        assert p.stdout.strip() == ""
+    else:
+        from parallel_eda_tpu.__main__ import main
+        with pytest.raises(SystemExit) as ei:
+            main(list(argv))
+        code, err = ei.value.code, capsys.readouterr().err
+    assert code == 2
+    assert f"unrecognized arguments: {flag}" in err

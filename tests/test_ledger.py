@@ -223,8 +223,6 @@ def test_early_ending_walk_routes_as_the_full_budget_walk(
     from parallel_eda_tpu.route import planes
 
     programs = (planes.route_window_planes,
-                planes.route_window_planes_fused,
-                planes.route_window_planes_multi,
                 planes.route_batch_resident_planes)
 
     def forget():

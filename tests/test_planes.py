@@ -621,8 +621,6 @@ def _assert_route_unmoved_by(f, monkeypatch, refs):
     from parallel_eda_tpu.route import planes
 
     programs = (planes.route_window_planes,
-                planes.route_window_planes_fused,
-                planes.route_window_planes_multi,
                 planes.route_batch_resident_planes)
 
     def route():

@@ -12,14 +12,12 @@ Three layers, mirroring tests/test_kernel_pack.py's parity discipline:
   later, and the strict-< update keeps whichever arrived first — the
   router's per-(net,node) jitter makes shortest paths unique, which
   is why ROUTE-level parity below is exact.
-* window parity — ONE window of the per-rung program
-  (route_window_planes, the `_window_body` every planes program
-  shares) under a two-shard RowMesh and under the GSPMD `net` mesh
+* window parity — ONE window of the window program
+  (route_window_planes) under a two-shard RowMesh and under the GSPMD `net` mesh
   must leave the one-device window's state and ledger, bit for bit;
   tier-1 runs these (the whole-route gates below are `slow`).
 * route parity — a mesh-sharded Router run must produce bit-identical
-  paths/occ/wirelength to the single-device baseline (incl. fused
-  dispatch), a bf16 one must equal the single-device bf16 route, and
+  paths/occ/wirelength to the single-device baseline, a bf16 one must equal the single-device bf16 route, and
   the halo ledger must be populated.
 * degradation — an injected backend.loss must land the resilience
   ladder's "mesh" dimension on the single_chip floor and still finish
@@ -263,12 +261,6 @@ def test_route_parity_mesh4():
         assert (mv.get("route.mesh.mesh_demotions") or 0) == 0
     finally:
         set_metrics(old)
-
-
-@needs_mesh
-@pytest.mark.slow
-def test_route_parity_mesh4_fused():
-    _assert_route_parity(mesh_shards=4, fused_dispatch=True)
 
 
 @needs_mesh

@@ -257,7 +257,6 @@ def test_ladder_levels_records_and_floor():
     lad = DegradationLadder()
     assert lad.snapshot() == {"pipeline": "pipelined",
                               "program": "aot",
-                              "dispatch": "fused",
                               "mesh": "pallas_halo"}
     assert lad.step("pipeline", reason="poisoned dispatch")
     assert lad.level("pipeline") == 1
@@ -268,7 +267,7 @@ def test_ladder_levels_records_and_floor():
     assert v["route.resil.level.pipeline"] == 1
     assert v["route.resil.level.program"] == 0
     assert v["route.resil.degradation_steps"] == 2
-    assert set(DIMS) == {"pipeline", "program", "dispatch", "mesh"}
+    assert set(DIMS) == {"pipeline", "program", "mesh"}
 
 
 # ---- queue backoff vs deadline (fake clock; no jax) ----------------

@@ -109,6 +109,43 @@ def test_queue_retry_backoff_then_failed():
     assert v["route.serve.jobs_failed"] == 1
 
 
+def test_queue_runner_without_a_verdict_is_a_failure():
+    """A runner that returns no (verdict, value) for a job fails that
+    job -- silence is never success -- and the queue goes on to the
+    next; a verdict the state machine does not know is the caller's
+    bug and raises out of run()."""
+    q = JobQueue()
+    ghost = q.admit(_job(priority=1))
+    ok = q.admit(_job())
+    q.run(lambda j: None if j is ghost else ("done", {}))
+    assert ghost.state == JobState.FAILED and ghost.error
+    assert ok.state == JobState.DONE
+    assert get_metrics().values(
+        "route.serve.")["route.serve.jobs_failed"] == 1
+
+    q.admit(_job())
+    with pytest.raises(ValueError, match="finished"):
+        q.run(lambda j: ("finished", None))
+
+
+def test_queue_run_skips_tombstones_without_spending_a_slice():
+    """A job evicted while queued (overload shedding) is a tombstone
+    in the heap: run() never hands it to the runner, it costs no slice
+    of ``max_slices``, and it stays SHED."""
+    q = JobQueue()
+    shed = q.admit(_job(priority=9))        # would have run first
+    a = q.admit(_job())
+    b = q.admit(_job())
+    assert q.evict(shed.job_id, error="overload") is shed
+    assert q.depth() == 2
+    seen = []
+    q.run(lambda j: (seen.append(j.job_id), ("done", None))[1],
+          max_slices=2)
+    assert seen == [a.job_id, b.job_id]
+    assert shed.state == JobState.SHED and shed.slices == 0
+    assert shed.error == "overload"
+
+
 def test_queue_preemption_round_robin():
     q = JobQueue()
     a = q.admit(_job())

@@ -44,9 +44,7 @@ def _inside(child, parent):
             and child[1] + child[2] <= parent[1] + parent[2])
 
 
-@pytest.mark.parametrize("fused", [False, True],
-                         ids=["per_rung", "fused"])
-def test_profiler_trace_holds_the_routers_spans(placed, fused, tmp_path):
+def test_profiler_trace_holds_the_routers_spans(placed, tmp_path):
     """No Tracer: the spans exist only as TraceAnnotations, on one host
     line of the profiler's trace, nested route > route.window >
     plan / dispatch / stall, each with its window and route id."""
@@ -58,7 +56,7 @@ def test_profiler_trace_holds_the_routers_spans(placed, fused, tmp_path):
     opts.host_tracer_level = 1
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        f = _route(placed, fused_dispatch=fused)
+        f = _route(placed)
     finally:
         jax.profiler.stop_trace()
     assert f.route.success
@@ -146,20 +144,28 @@ def test_chrome_trace_still_checks_and_holds_the_new_spans(placed,
         == f.route.total_relax_steps
 
 
-def test_dispatch_counters_are_timed_where_the_work_happens(placed):
+def test_dispatch_counters_are_timed_where_the_work_happens(
+        placed, monkeypatch):
     import parallel_eda_tpu.route.router as router_mod
+    from parallel_eda_tpu.route import planes
 
     reg = get_metrics()
     first = reg.counter("route.dispatch.first_call_ms_total")
-    # zero at a route's start: a fused route's generator stops at its
-    # first request, before anything was dispatched
+    # zero at a route's start: what the first dispatch finds, before
+    # anything of this route was dispatched
     reg.gauge("route.pipeline.dispatch_ms_total").set(123.0)
-    gen = router_mod.Router(placed.rr, RouterOpts(
-        program="planes", batch_size=16,
-        fused_dispatch=True)).route_gen(placed.term)
-    next(gen)
-    assert reg.gauge("route.pipeline.dispatch_ms_total").value == 0.0
-    gen.close()
+    real = planes.route_window_planes
+    found = []
+
+    def spy(*a, **k):
+        found.append(reg.gauge("route.pipeline.dispatch_ms_total").value)
+        return real(*a, **k)
+
+    monkeypatch.setattr(planes, "route_window_planes", spy)
+    assert _route(placed).route.success
+    monkeypatch.undo()
+    assert found[0] == 0.0 and all(
+        a <= b for a, b in zip(found, found[1:]))
 
     # the seen-set is process state: other tests of this worker may
     # have dispatched these very variants

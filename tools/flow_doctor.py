@@ -480,87 +480,19 @@ def check_resil(doc: dict) -> tuple:
     return errs, notes
 
 
-# the machine-readable rebatch cause taxonomy (serve/batcher.py
-# REBATCH_CAUSES); any other cause string in a rebatch event is the
-# bookkeeping inventing vocabulary the tooling can't act on
-REBATCH_CAUSES = ("join", "finish", "evict", "failover")
-
-
-def check_rebatch(doc: dict, warm: bool = False) -> tuple:
-    """Continuous-batching rule set over a serve/daemon summary with a
-    ``rebatch`` section (service.rebatch_summary()).  Returns
-    (errors, notes).  The rules catch rebatch bookkeeping that is
-    lying or mute:
-
-      * every rebatch event cause must be machine-readable — one of
-        the published taxonomy (join/finish/evict/failover) with a
-        job_id;
-      * rebatch events cannot outnumber batch rounds — the pack is
-        recomputed at slice boundaries, never mid-slice;
-      * a fused run that executed rounds but recorded zero rebatch
-        events is mute (admission itself is the first join);
-      * the rebatch event counter and the event list must agree;
-      * with ``warm``: route.dispatch.compiles must be 0 — a warm
-        pack-shape library replays every join/finish/evict without a
-        single window-program compile.
-    """
-    errs, notes = [], []
-    rb = doc.get("rebatch")
-    if warm:
-        compiles = doc.get("dispatch_compiles")
-        if compiles is None:
-            errs.append("rebatch: --warm given but the summary has no "
-                        "dispatch_compiles field")
-        elif compiles:
-            errs.append(f"rebatch: warm run compiled {compiles} window "
-                        f"program(s); a warm pack-shape library must "
-                        f"serve with dispatch_compiles==0")
-        else:
-            notes.append("rebatch: warm gate ok (dispatch_compiles=0)")
-    if not isinstance(rb, dict):
-        notes.append("rebatch: no rebatch section (interleaved "
-                     "scheduler, or summary predates continuous "
-                     "batching)")
-        return errs, notes
-    events = rb.get("events") or []
-    rounds = rb.get("rounds") or 0
-    counters = rb.get("counters") or {}
-    n_causes = 0
-    for ev in events:
-        for c in ev.get("causes") or []:
-            n_causes += 1
-            if c.get("cause") not in REBATCH_CAUSES:
-                errs.append(f"rebatch: event round {ev.get('round')} "
-                            f"has unknown cause {c.get('cause')!r} "
-                            f"(taxonomy: {'/'.join(REBATCH_CAUSES)})")
-            if not c.get("job_id"):
-                errs.append(f"rebatch: event round {ev.get('round')} "
-                            f"has a cause without a job_id")
-        occ = ev.get("lane_occupancy")
-        if occ is not None and not (0.0 <= occ <= 1.0):
-            errs.append(f"rebatch: event round {ev.get('round')} "
-                        f"lane_occupancy {occ} outside [0, 1]")
-    if len(events) > rounds:
-        errs.append(f"rebatch: {len(events)} rebatch event(s) over "
-                    f"{rounds} batch round(s) — the pack may only be "
-                    f"recomputed at a slice boundary")
-    ctr = counters.get("route.serve.rebatch.events")
-    if ctr is not None and ctr != len(events):
-        errs.append(f"rebatch: counter says {ctr} event(s) but the "
-                    f"event log holds {len(events)}")
-    cause_ctr = sum(counters.get(f"route.serve.rebatch.{c}", 0)
-                    for c in REBATCH_CAUSES)
-    if counters and cause_ctr != n_causes:
-        errs.append(f"rebatch: per-cause counters sum to {cause_ctr} "
-                    f"but the event log records {n_causes} cause(s)")
-    if rb.get("fused") and rounds and not events:
-        errs.append(f"rebatch: fused scheduler ran {rounds} round(s) "
-                    f"without recording a single rebatch event — "
-                    f"admission is itself the first join")
-    notes.append(f"rebatch: fused={bool(rb.get('fused'))} "
-                 f"rounds={rounds} events={len(events)} "
-                 f"causes={n_causes}")
-    return errs, notes
+def check_warm(doc: dict) -> tuple:
+    """``--warm`` over a serve/daemon summary: route.dispatch.compiles
+    must be 0 -- a warm AOT library serves every window without a
+    single window-program compile.  Returns (errors, notes)."""
+    compiles = doc.get("dispatch_compiles")
+    if compiles is None:
+        return ["warm: --warm given but the summary has no "
+                "dispatch_compiles field"], []
+    if compiles:
+        return [f"warm: warm run compiled {compiles} window "
+                f"program(s); a warm library must serve with "
+                f"dispatch_compiles==0"], []
+    return [], ["warm: gate ok (dispatch_compiles=0)"]
 
 
 # a beat may be late by this factor x interval before the doctor calls
@@ -1164,9 +1096,8 @@ def main(argv=None) -> int:
     ap.add_argument("--warm", action="store_true",
                     help="with --serve-summary/--daemon-summary: "
                          "assert zero window-program compiles "
-                         "(dispatch_compiles==0) — the warm "
-                         "pack-shape-library acceptance gate for "
-                         "continuous batching")
+                         "(dispatch_compiles==0): the warm "
+                         "library's acceptance gate")
     ap.add_argument("--daemon-summary", dest="daemon_summary",
                     help="route daemon summary JSON to gate with the "
                          "daemon rule set (rejection reasons, shed "
@@ -1267,17 +1198,19 @@ def main(argv=None) -> int:
             se, sn = check_resil(sdoc)
             errs += se
             notes += sn
-            rbe, rbn = check_rebatch(sdoc, warm=args.warm)
-            errs += rbe
-            notes += rbn
+            if args.warm:
+                we, wn = check_warm(sdoc)
+                errs += we
+                notes += wn
         if args.daemon_summary:
             ddoc = _read_json(args.daemon_summary)
             de, dn = check_daemon(ddoc)
             errs += de
             notes += dn
-            rbe, rbn = check_rebatch(ddoc, warm=args.warm)
-            errs += rbe
-            notes += rbn
+            if args.warm:
+                we, wn = check_warm(ddoc)
+                errs += we
+                notes += wn
         if args.fleet_summary:
             fe, fn = check_fleet(_read_json(args.fleet_summary))
             errs += fe

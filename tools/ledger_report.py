@@ -25,10 +25,8 @@ ceiling overhead), and records the batch-plan shape per window:
 
 Invariant checked: useful + wasted == total, occupancy and compaction
 in (0, 1], and the wasted fraction consistent with the counters.  When
-the route.kernel dispatch-shape gauges are present, --check also
-enforces 1 <= dispatches_per_window <= fused_rungs (the fused window
-program must not issue more relaxation dispatches than it has
-populated crop rungs).
+the route.kernel dispatch-shape gauge is present, --check also
+enforces dispatches_per_window >= 1 (one dispatch per populated rung).
 """
 
 from __future__ import annotations
@@ -102,19 +100,13 @@ def validate(doc) -> list:
                 f"route.devcost.bytes_delta {bd!r} outside the "
                 f"1e±{DEVCOST_DELTA_BAND_LOG10} measured-vs-modeled "
                 f"sanity band")
-    # dispatch-shape invariant (PR-11): the fused window program issues
-    # exactly one relaxation dispatch per window, per-rung mode one per
-    # populated rung — so dispatches_per_window is in [1, fused_rungs]
+    # dispatch-shape invariant: one dispatch per populated rung, and a
+    # window populates at least one
     dpw = values.get("route.kernel.dispatches_per_window")
-    if dpw is not None:
-        fr = values.get("route.kernel.fused_rungs")
-        if not (isinstance(dpw, (int, float)) and dpw >= 1):
-            errs.append(
-                f"route.kernel.dispatches_per_window not >= 1: {dpw!r}")
-        elif isinstance(fr, (int, float)) and dpw > fr:
-            errs.append(
-                f"route.kernel.dispatches_per_window {dpw} exceeds the "
-                f"populated-rung count route.kernel.fused_rungs {fr}")
+    if dpw is not None \
+            and not (isinstance(dpw, (int, float)) and dpw >= 1):
+        errs.append(
+            f"route.kernel.dispatches_per_window not >= 1: {dpw!r}")
     pd = values.get("route.kernel.plane_dtype")
     if pd is not None and pd not in ("f32", "bf16"):
         errs.append(f"bad route.kernel.plane_dtype {pd!r}")
@@ -155,12 +147,10 @@ def summarize(doc) -> str:
                      f"(last window)")
     dpw = values.get("route.kernel.dispatches_per_window")
     if dpw is not None:
-        fr = values.get("route.kernel.fused_rungs")
         pd = values.get("route.kernel.plane_dtype")
         lines.append(
-            f"  dispatch shape (last window): {int(dpw)} dispatch(es) "
-            f"for {int(fr) if fr is not None else '?'} populated "
-            f"rung(s), planes {pd or 'f32'}")
+            f"  dispatch shape (last window): {int(dpw)} dispatch(es), "
+            f"one a populated rung, planes {pd or 'f32'}")
     ba = values.get("route.devcost.bytes_accessed")
     if ba is not None:
         bd = values.get("route.devcost.bytes_delta")
