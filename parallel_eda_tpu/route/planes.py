@@ -130,6 +130,12 @@ class PlanesGraph:
     # longest wire span in grid units (static): the bb-crop margin —
     # a wire INTERSECTING a net's bb can overhang it by max_span-1
     max_span: int = struct.field(pytree_node=False, default=1)
+    # the scans' guard against a predecessor 2-cycle inside a wire span
+    # (_scan_update; static: it selects a different relaxation program).
+    # Off in every program a route starts with; the window driver
+    # switches it on for the windows of a route that ended one with
+    # nothing over capacity and a sink unreached
+    scan_guard: bool = struct.field(pytree_node=False, default=False)
 
     @property
     def shape_x(self):
@@ -527,11 +533,25 @@ def _minplus_scan(d0, c, axis, reverse=False):
 
 
 def _scan_update(d, pred, w, cstep, wstep, self_idx, stride, axis,
-                 reverse):
+                 reverse, guard=False):
     """Run one directional scan and fold (dist, pred, wenter): improved
-    cells point at the immediate neighbor in the scan direction."""
+    cells point at the immediate neighbor in the scan direction.
+
+    ``guard`` (static): a cell is NOT improved over a free step (inside
+    a wire span) from the neighbour whose own predecessor it is.  The
+    associative scan sums a path's costs in an order that differs cell
+    to cell, so a cell reached THROUGH its span neighbour can come out
+    an ulp below it; the opposite scan would then improve the neighbour
+    from it, ``pred[A] = B`` and ``pred[B] = A``, and the traceback
+    circles between the two for its budget and leaves a sink unreached.
+    Inside a span every cell is the same node, so refusing the step
+    loses no path; a span that truly turns round (the neighbour's
+    predecessor is some other cell) is improved as before."""
     s = _minplus_scan(d, cstep, axis, reverse)
     imp = s < d
+    if guard:
+        from_me = jnp.roll(pred, -1 if reverse else 1, axis) == self_idx
+        imp &= ~((cstep == 0.0) & from_me)
     nb = self_idx + (stride if reverse else -stride)
     return (jnp.where(imp, s, d),
             jnp.where(imp, nb, pred),
@@ -572,6 +592,7 @@ class PlanesGeom:
     directional: bool = struct.field(pytree_node=False, default=False)
     inc_track: Optional[jnp.ndarray] = None     # bool [W] (shared)
     group_tracks: int = struct.field(pytree_node=False, default=0)
+    scan_guard: bool = struct.field(pytree_node=False, default=False)
 
     @property
     def shape_x(self):
@@ -602,7 +623,8 @@ def geom_full(pg: PlanesGraph) -> PlanesGeom:
         delay_y_rot1=pg.delay_y_rot1[None],
         idxx=idxx, idxy=idxy, base_par=base_par,
         stride_x=NYp1, directional=pg.directional,
-        inc_track=pg.inc_track, group_tracks=pg.group_tracks)
+        inc_track=pg.inc_track, group_tracks=pg.group_tracks,
+        scan_guard=pg.scan_guard)
 
 
 def _shift_bits(n: int):
@@ -710,7 +732,8 @@ def geom_cropped(pg: PlanesGraph, ox, oy, cnx: int,
         idxy=crop(full.idxy, cnx + 1, cny),
         base_par=crop(full.base_par, cnx + 1, cny + 1),
         stride_x=NYp1, directional=pg.directional,
-        inc_track=pg.inc_track, group_tracks=pg.group_tracks)
+        inc_track=pg.inc_track, group_tracks=pg.group_tracks,
+        scan_guard=pg.scan_guard)
 
 
 def _group_corner_min(gm: PlanesGeom, src, idx, axis: int, shift: int):
@@ -955,9 +978,9 @@ def _sweep_once(gm: PlanesGeom, s, crit_c, cc_x, cc_y, costs):
     dx, dy, predx, predy, wx, wy = s
     with device_scope("route.dev.relax.scan"):
         dx, predx, wx = _scan_update(dx, predx, wx, cfx, wfx, gm.idxx,
-                                     gm.stride_x, 2, False)
+                                     gm.stride_x, 2, False, gm.scan_guard)
         dx, predx, wx = _scan_update(dx, predx, wx, cbx, wbx, gm.idxx,
-                                     gm.stride_x, 2, True)
+                                     gm.stride_x, 2, True, gm.scan_guard)
     with device_scope("route.dev.relax.turn"):
         tv, ts, tw = _turn_triples_into_y(gm, dx, crit_c, cc_y)
         imp = tv < dy
@@ -966,9 +989,9 @@ def _sweep_once(gm: PlanesGeom, s, crit_c, cc_x, cc_y, costs):
         wy = jnp.where(imp, tw, wy)
     with device_scope("route.dev.relax.scan"):
         dy, predy, wy = _scan_update(dy, predy, wy, cfy, wfy, gm.idxy,
-                                     1, 3, False)
+                                     1, 3, False, gm.scan_guard)
         dy, predy, wy = _scan_update(dy, predy, wy, cby, wby, gm.idxy,
-                                     1, 3, True)
+                                     1, 3, True, gm.scan_guard)
     with device_scope("route.dev.relax.turn"):
         tv, ts, tw = _turn_triples_into_x(gm, dy, crit_c, cc_x)
         imp = tv < dx

@@ -80,7 +80,10 @@ def format_window_table(result) -> str:
     with fanout classes two more columns, the sweeps and waves of the
     batches of a class above the first; on a route of the window
     program one more, the distance elements its sink picks read as a
-    share of what dense picks read in the same waves.  Under it
+    share of what dense picks read in the same waves, and another, the
+    canvas cells its sweeps covered (``cell_sweeps``, in millions: the
+    sweeps x the rung's batch width x its canvas's cells); on a route
+    that switched the scans' guard on, which windows ran guarded.  Under it
     the route's wall by named interval where the result carries one
     (``RouteResult.wall``: the four add up to the ``route`` stage)."""
     head = ("window", "iter", "kind", "overused", "nets", "seconds",
@@ -124,6 +127,19 @@ def format_window_table(result) -> str:
         head += ("pick_read%",)
         rows = [r + (f"{100.0 * got / dense:.1f}" if dense else "-",)
                 for r, (got, dense) in zip(rows, reads)]
+    swept = [s.cell_sweeps for s in result.stats]
+    if any(swept):
+        swept.append(sum(swept))
+        head += ("Mcell_sweeps",)
+        rows = [r + (f"{n / 1e6:.1f}",) for r, n in zip(rows, swept)]
+    guarded = [getattr(s, "scan_guard", False) for s in result.stats]
+    if any(guarded):
+        # a route that met the predecessor 2-cycle's state: the windows
+        # whose relaxations ran with the scans guarded
+        head += ("guard",)
+        rows = [r + (g,) for r, g in zip(
+            rows, ["yes" if g else "-" for g in guarded]
+            + [f"{sum(guarded)}/{len(guarded)}"])]
     cells = [head] + [tuple(str(c) for c in r) for r in rows]
     width = [max(len(r[i]) for r in cells) for i in range(len(head))]
     lines = ["  ".join(c.ljust(w) if i == 2 else c.rjust(w)

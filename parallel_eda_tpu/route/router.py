@@ -258,12 +258,20 @@ class RouteStats:
     waves: int = 0               # relaxations run to a fixpoint
     relax_steps_cropped: int = 0  # of relax_steps, on cropped rungs
     waves_cropped: int = 0       # of waves: cropped relaxation CALLS
+    # canvas cells the sweeps covered: relax_steps x the rung's batch
+    # width x the cells of the canvas it ran on (full, or its tile)
+    cell_sweeps: int = 0
     net_routes: int = 0          # nets routed, once an iteration each
     stall_s: float = 0.0         # host blocked on the device
     plan_s: float = 0.0          # host planning, staging, dispatching
     dispatch_ms: float = 0.0     # of it, in route.pipeline.dispatch
     control_s: float = 0.0       # the host's control step AFTER it
     kept: bool = True            # False: computed and thrown away
+    # the window's relaxations ran with the scans' guard against a
+    # predecessor 2-cycle (PlanesGraph.scan_guard): on from the window
+    # after one that ended with nothing over capacity, a sink unreached
+    # and no snapshot to return
+    scan_guard: bool = False
     # fanout classes: the class of the widest net of the window's
     # batches (0: the first class, the only one of most circuits), and
     # of waves / relax_steps what batches of a class above it spent
@@ -336,6 +344,12 @@ class RouteResult:
     # what its cut and write-back are paid by (tools/crop_forms.py
     # times one)
     total_waves_cropped: int = 0
+    # canvas cells the sweeps covered, dead batch slots included: each
+    # rung's sweeps x its plan width x the cells of its canvas (the
+    # whole grid's, or its crop tile's).  Over total_net_routes it is
+    # what a net's route costs in relaxing work, the number a serial
+    # router's heap pops a net stand against
+    total_cell_sweeps: int = 0
     # of which: sweeps of the windows whose result was thrown away, the
     # stats rows past the iteration of the pre-finish snapshot when the
     # finishing pass could not re-legalise and the snapshot was restored
@@ -666,6 +680,22 @@ def _phase2_restart_due(precise: bool, full_reroute_done: bool,
     it; a restart there routes every net a second time."""
     return (precise and not full_reroute_done and not finish_done
             and n_over > 0 and widx >= 4)
+
+
+def _scan_guard_due(n_over: int, unreached: bool, has_snapshot: bool,
+                    max_span: int) -> bool:
+    """When the planes window driver switches the scans' guard on
+    (``PlanesGraph.scan_guard``, for the rest of the route): a window
+    ended with NOTHING over capacity and a sink unreached, on wires
+    longer than a tile, and no legal snapshot stands behind the route.
+    Nothing fights, so no further window of the same programs reaches
+    the sink: it is the relaxation's predecessor 2-cycle inside a wire
+    span (``planes._scan_update``), and the route would run out its
+    iterations and be returned NOT legal.  A finishing pass that meets
+    the state has ``fin_save`` to return and keeps the programs it has
+    (it loses its seconds, not the route: ROADMAP Queue 1 item 2)."""
+    return (n_over == 0 and unreached and not has_snapshot
+            and max_span > 1)
 
 
 # what a window of the planes driver IS, which decides what it costs:
@@ -1225,7 +1255,7 @@ class Router:
         w_steps = w_useful = w_steps_crop = w_waves_crop = 0
         nroutes = nexec = w_waves = 0
         w_steps_wide = w_waves_wide = 0
-        w_sink_rows = w_sink_rows_dense = 0
+        w_sink_rows = w_sink_rows_dense = w_cell_sweeps = 0
         rung_classes = bk.get("rung_classes") or [0] * len(
             bk["rung_scals"])
         mesh_info = bk.get("mesh")
@@ -1245,6 +1275,7 @@ class Router:
             if cropped:
                 w_steps_crop += int(v[SCAL_S_EXEC])
                 w_waves_crop += int(v[SCAL_WAVES])
+            w_cell_sweeps += int(v[SCAL_S_EXEC]) * bk["rung_cells"][ri]
             if rung_classes[ri]:
                 w_steps_wide += int(v[SCAL_S_EXEC])
                 w_waves_wide += int(v[SCAL_WAVES])
@@ -1263,6 +1294,7 @@ class Router:
         result.total_relax_steps_wasted += w_steps - w_useful
         result.total_relax_steps_cropped += w_steps_crop
         result.total_waves_cropped += w_waves_crop
+        result.total_cell_sweeps += w_cell_sweeps
         result.total_relax_steps_wide += w_steps_wide
         result.total_waves += w_waves
         # the device counts sink rows; a row is sink_cells elements
@@ -1277,7 +1309,8 @@ class Router:
             crit_path_delay=bk["cpd"], window=bk["widx"], kind=bk["kind"],
             precise=bk["precise"], sweep_boost=bk["sweep_boost"],
             waves=w_waves, relax_steps_cropped=w_steps_crop,
-            waves_cropped=w_waves_crop,
+            waves_cropped=w_waves_crop, cell_sweeps=w_cell_sweeps,
+            scan_guard=bk["scan_guard"],
             net_routes=nroutes, stall_s=bk["stall_s"],
             plan_s=bk["plan_s"], dispatch_ms=bk["dispatch_ms"],
             fanout_class=max(rung_classes),
@@ -1460,6 +1493,17 @@ class Router:
             valid_plan[i, :len(b)] = seg[i]
         return sel_plan, valid_plan
 
+    def _canvas_shapes(self, tile):
+        """(shape_x, shape_y) of one net's canvas pair as a rung relaxes
+        it: the whole grid's, or its (cnx, cny) crop tile's."""
+        if tile is None:
+            return self.pg.shape_x, self.pg.shape_y
+        W, (cnx, cny) = self.pg.shape_x[0], tile
+        return (W, cnx, cny + 1), (W, cnx + 1, cny)
+
+    def _canvas_cells(self, tile) -> int:
+        return sum(int(np.prod(s)) for s in self._canvas_shapes(tile))
+
     def _plan_block_nets(self, tile, nnets: int, nsw: int,
                          plane_dtype: str = "f32") -> dict:
         """Modeled layout row of one dispatch (companion of
@@ -1474,13 +1518,7 @@ class Router:
                                      unpacked_lane_occupancy)
         from .planes import plane_itemsize, xla_bytes_per_cell
 
-        W, NX, NYp1 = self.pg.shape_x
-        _, NXp1, NY = self.pg.shape_y
-        if tile is not None:
-            cnx, cny = tile
-            shx, shy = (W, cnx, cny + 1), (W, cnx + 1, cny)
-        else:
-            shx, shy = (W, NX, NYp1), (W, NXp1, NY)
+        shx, shy = self._canvas_shapes(tile)
         n = max(1, int(nnets))
         return dict(
             variant="xla",
@@ -1597,6 +1635,14 @@ class Router:
         fin_save = None
         force_all_next = False
         widx = 0
+        # the relaxation's graph as this route's windows see it: the
+        # router's own until _scan_guard_due fires at a window's end
+        # (the predecessor 2-cycle's mark), the scans' guard on from the
+        # next window to the route's end
+        pg_now = self.pg
+        # cells of a net's canvas pair by crop tile (None: the whole
+        # grid), reckoned once a route for the rows' cell_sweeps
+        canvas_cells = {}
         # only the spatially sharded mesh path keeps full canvases
         # (crops are net-local)
         crop_forced = None
@@ -1632,6 +1678,8 @@ class Router:
             precise = d["precise"]
             full_reroute_done = d["full_reroute_done"]
             finish_done = d.get("finish_done", False)
+            if d.get("scan_guard"):
+                pg_now = pg_now.replace(scan_guard=True)
             force_all_next = d["force_all_next"]
             result.widened_nets = d["widened_nets"]
             crop_full = d.get("crop_full", crop_full)
@@ -1985,12 +2033,15 @@ class Router:
                 # as a static arg or shape.  New key = a fresh XLA
                 # compile (or persistent-cache load); known key = a jit
                 # cache hit
+                # the guard rides in a key only once it is on: a route
+                # that never meets the state keeps the parent's keys
+                gkey = ("scan_guard",) if pg_now.scan_guard else ()
                 vkey = (p["tile"], K, p["nsw"], L, p["waves"],
                         p["grp_w"], p["doubling"], p["sel_shape"][0],
                         p["sel_shape"][1], p["wok"] is None, mesh_vk,
-                        bool(sta_kw), R, Smax, N, pd) + p["fkey"]
+                        bool(sta_kw), R, Smax, N, pd) + p["fkey"] + gkey
                 wp_args = (
-                    self.pg, dev, occ, acc, paths, sink_delay,
+                    pg_now, dev, occ, acc, paths, sink_delay,
                     all_reached, bb, source_d, sinks_d, crit_d,
                     *planes_tbl,
                     p["sel_d"], p["valid_d"], full_bb,
@@ -2017,7 +2068,7 @@ class Router:
                 # can AOT-relower this exact variant later
                 get_devprof().note_variant(
                     (p["tile"], K, p["nsw"], L, p["waves"],
-                     p["grp_w"]) + p["fkey"], p["kplan"],
+                     p["grp_w"]) + p["fkey"] + gkey, p["kplan"],
                     route_window_planes, wp_args, wp_kwargs)
                 with dispatching(window=widx, route=rid, rung=ri):
                     if resil_rt is not None \
@@ -2079,6 +2130,7 @@ class Router:
             outs = []
             bucket_occ = []
             kplans = []
+            rung_cells = []       # cells a sweep of the rung covers
             comp_num = comp_den = 0
             plan_s = 0.0          # host plan/stage/dispatch, this window
             plan0_s = 0.0         # rung 0's share (nothing in flight yet)
@@ -2138,6 +2190,9 @@ class Router:
                                 K=K, pipelined=False)
                 outs.append((o, tile))
                 nvalid, bg, grows = p["ledger"]
+                if tile not in canvas_cells:
+                    canvas_cells[tile] = self._canvas_cells(tile)
+                rung_cells.append(bg * canvas_cells[tile])
                 if grows:
                     bucket_occ.append(nvalid / (grows * bg))
                     comp_num += grows * bg
@@ -2248,6 +2303,16 @@ class Router:
                 # budget (feature-off runs must not accumulate state —
                 # a later resume with div>1 would be pre-promoted)
                 budget_full |= unreached
+            guarded = pg_now.scan_guard      # as this window ran
+            if (not guarded and self.mesh is None
+                    and self._row_meshes is None
+                    and _scan_guard_due(n_over, bool(unreached.any()),
+                                        fin_save is not None,
+                                        pg_now.max_span)):
+                # a static field: every program up to here is the one a
+                # route without the state runs, and the windows from
+                # here compile (or load) their guarded twins
+                pg_now = pg_now.replace(scan_guard=True)
             crit_d = out.crit_all       # donated in; stays device-resident
             # fold device-side widening into the host classification:
             # those nets must take the full-canvas window from now on
@@ -2266,6 +2331,7 @@ class Router:
                 cpd=cpd, tw0=tw0, tw1=t_st1, win_ev=win.event,
                 kind=kind, stall_s=stall_s, plan_s=plan_s,
                 dispatch_ms=disp_ms, rung_scals=rung_scals,
+                rung_cells=rung_cells, scan_guard=guarded,
                 bucket_occ=bucket_occ,
                 compaction=comp_num / max(1, comp_den), kplans=kplans,
                 colors_max=int(np.max(colors) + 1
@@ -2454,6 +2520,7 @@ class Router:
                         full_reroute_done=full_reroute_done,
                         force_all_next=force_all_next,
                         finish_done=finish_done,
+                        scan_guard=pg_now.scan_guard,
                         budget_full=budget_full.copy(),
                         widened_nets=result.widened_nets,
                         crop_full=crop_full),

@@ -229,20 +229,26 @@ def test_the_mesh_form_of_the_walk_scatters_holds_no_collective(topo):
     assert len(re.findall(r" scatter\(", text)) == 2
 
 
-@pytest.mark.parametrize("arch_fn, n, W, tile", [
-    ("k6_n10_40nm_arch", 11, 64, 8),        # route_k6n10_relaxed
+@pytest.mark.parametrize("arch_fn, n, W, tile, guard", [
+    ("k6_n10_40nm_arch", 11, 64, 8, False),     # route_k6n10_relaxed
     # route_scale: the one populated rung, 16 x 16
-    ("k6_n10_40nm_arch", 19, 88, 16),
+    ("k6_n10_40nm_arch", 19, 88, 16, False),
     # route_hetero: typed columns and tall blocks under the same wires
-    ("k6_frac_n10_mem32k_40nm_arch", 25, 64, 16),
+    ("k6_frac_n10_mem32k_40nm_arch", 25, 64, 16, False),
+    # route_scale_6k: 123,552 cells a net, as its route starts and with
+    # the scans guarded, as its last windows run
+    ("k6_n10_40nm_arch", 26, 88, 16, False),
+    ("k6_n10_40nm_arch", 26, 88, 16, True),
 ])
 def test_directional_planes_relax_compiles_at_k6n10_canvas(
-        one_chip, arch_fn, n, W, tile):
+        one_chip, arch_fn, n, W, tile, guard):
     """The directional relaxation (unidir graphs: group-min turns) at
     the canvases of the cells ``route_k6n10_relaxed`` (11 x 11, W = 64),
-    ``route_scale`` (19 x 19, W = 88) and ``route_hetero`` (25 x 25,
-    W = 64, on the heterogeneous device) of length-4 single-driver
-    wires, 64 nets -- whole and cropped."""
+    ``route_scale`` (19 x 19, W = 88), ``route_hetero`` (25 x 25,
+    W = 64, on the heterogeneous device) and ``route_scale_6k``
+    (26 x 26, W = 88; plain and with ``scan_guard``, the roll and the
+    two compares a scan) of length-4 single-driver wires, 64 nets --
+    whole and cropped."""
     import warnings
 
     from parallel_eda_tpu.arch import builtin
@@ -257,6 +263,7 @@ def test_directional_planes_relax_compiles_at_k6n10_canvas(
         pg = build_planes(build_rr_graph(arch, make_grid(arch, n, n)))
     assert pg.shape_x[:2] == (W, n)
     assert pg.directional and pg.group_tracks == 8 and pg.max_span == 4
+    pg = pg.replace(scan_guard=guard)
     fn = jax.jit(planes_relax, static_argnames=("nsweeps",))
     _fits_hbm(fn.lower(*_relax_avatars(pg, ROUTE_B, one_chip),
                        nsweeps=ROUTE_SWEEPS).compile())

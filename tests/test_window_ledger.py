@@ -142,9 +142,14 @@ def test_the_rows_add_up_to_the_result(routed):
                          ("net_routes", r.total_net_routes),
                          ("relax_steps_cropped",
                           r.total_relax_steps_cropped),
-                         ("waves_cropped", r.total_waves_cropped)):
+                         ("waves_cropped", r.total_waves_cropped),
+                         ("cell_sweeps", r.total_cell_sweeps)):
         assert sum(getattr(s, field) for s in r.stats) == total, field
     assert r.total_relax_steps > 0 and r.total_waves > 0
+    # ... so every sweep covered the whole 5 x 5 canvas, at a width of
+    # at most the batch and at least the narrowest plan
+    cells = f.route.total_cell_sweeps / r.total_relax_steps
+    assert 8 * _grid_cells(f) <= cells <= 32 * _grid_cells(f)
     # a 5 x 5 grid has no crop rung: no cropped relaxation was called
     assert r.total_waves_cropped == r.total_relax_steps_cropped == 0
     for s in r.stats:
@@ -298,17 +303,84 @@ def test_a_resumed_route_takes_its_first_kind_from_the_checkpoint():
     assert f.route.success and f.route.iterations == 22
 
 
-def test_a_route_with_a_populated_rung_books_its_cropped_waves():
+def _grid_cells(f):
+    """Cells of one net's full canvas, by the benchmark's formula."""
+    from benchmark.bytes_model import plane_cells
+
+    return plane_cells(f.rr.chan_width, f.grid.nx, f.grid.ny)
+
+
+@pytest.fixture(scope="module")
+def cropped_route():
+    """tests/test_planes.py's 19 x 19 fixture, whose 16 x 16 rung
+    every window populates, routed once with the driver's PLAN on
+    record: the width of every batch plan in the order the rungs were
+    planned, and per window each rung's executed sweeps and its tile
+    (``_book_window``'s own inputs, before it adds anything up)."""
+    from test_planes import _placed
+
+    from parallel_eda_tpu.route.planes import SCAL_S_EXEC
+
+    f = _placed("directional_l4_19x19")
+    router = Router(f.rr, RouterOpts(batch_size=16))
+    widths, windows = [], []
+    plan, book = router._plan_groups, router._book_window
+
+    def plan_groups(*a, **kw):
+        sel, valid = plan(*a, **kw)
+        widths.append(sel.shape[1])
+        return sel, valid
+
+    def book_window(bk, result, mlog):
+        windows.append([(int(np.asarray(scal)[SCAL_S_EXEC]), kp["tile"])
+                        for (scal, _), kp in zip(bk["rung_scals"],
+                                                 bk["kplans"])])
+        return book(bk, result, mlog)
+
+    router._plan_groups, router._book_window = plan_groups, book_window
+    r = router.route(f.term)
+    assert r.success
+    return f, r, widths, windows
+
+
+def test_cell_sweeps_are_sweeps_times_width_times_canvas(cropped_route):
+    """``total_cell_sweeps`` recounted from the plan: over every rung
+    of every window, the sweeps it executed x the width of its batch
+    plan x the cells of the canvas it ran on, by the benchmark's own
+    ``plane_cells`` (the 16 x 16 tile's, or the 19 x 19 grid's); by row
+    the same; and the benchmark's reader divides it by the net routes."""
+    from benchmark.bytes_model import plane_cells
+
+    f, r, widths, windows = cropped_route
+    assert len(widths) == sum(len(w) for w in windows)
+    assert {t and tuple(t) for w in windows for _, t in w} == {
+        None, (16, 16)}
+    assert len(set(widths)) > 1 and max(widths) == 16   # narrowed plans
+    W, it, want = f.rr.chan_width, iter(widths), []
+    for rungs in windows:
+        want.append(sum(
+            steps * next(it) * plane_cells(W, *(tile or (19, 19)))
+            for steps, tile in rungs))
+    assert [s.cell_sweeps for s in r.stats] == want
+    assert r.total_cell_sweeps == sum(want) > 0
+    # between every sweep on the tile and every sweep on the canvas
+    assert (plane_cells(W, 16, 16) * 8 * r.total_relax_steps
+            < r.total_cell_sweeps
+            < _grid_cells(f) * 16 * r.total_relax_steps)
+    lines = format_window_table(r).splitlines()
+    assert lines[0].split()[-1] == "Mcell_sweeps"
+    assert [ln.split()[-1] for ln in lines[1:len(r.stats) + 2]] == [
+        f"{n / 1e6:.1f}" for n in want + [sum(want)]]
+
+
+def test_a_route_with_a_populated_rung_books_its_cropped_waves(
+        cropped_route):
     """``waves_cropped``: the calls of the cropped relaxation, by row
     and on the result, on a route whose 19 x 19 grid has a 16 x 16 rung
     that every window populates (tests/test_planes.py's fixture): of a
     row's waves, those of its cropped rungs; with its cropped sweeps or
     not at all; the rows' sum the result's; a column of the table."""
-    from test_planes import _placed
-
-    f = _placed("directional_l4_19x19")
-    r = Router(f.rr, RouterOpts(batch_size=16)).route(f.term)
-    assert r.success
+    f, r, _, _ = cropped_route
     assert 0 < r.total_waves_cropped < r.total_waves
     assert sum(s.waves_cropped for s in r.stats) == r.total_waves_cropped
     for s in r.stats:
@@ -337,7 +409,7 @@ def test_the_window_table_prints_the_rows(routed, tmp_path):
     assert lines[0].split() == [
         "window", "iter", "kind", "overused", "nets", "seconds", "stall_s",
         "control_s", "sweeps", "waves", "waves_crop", "batches", "routes",
-        "routes/batch", "kept", "pick_read%"]
+        "routes/batch", "kept", "pick_read%", "Mcell_sweeps"]
     assert len(lines) == len(r.stats) + 3
     for line, s in zip(lines[1:], r.stats):
         cells = line.split()
@@ -349,11 +421,13 @@ def test_the_window_table_prints_the_rows(routed, tmp_path):
                              "%.1f" % (s.net_routes / s.batches),
                              "yes" if s.kept else "NO",
                              "%.1f" % (100.0 * s.sink_reads
-                                       / s.sink_reads_dense)]
+                                       / s.sink_reads_dense),
+                             "%.1f" % (s.cell_sweeps / 1e6)]
     assert lines[-2].split()[0] == "sum"
-    assert lines[-2].split()[-1] == "%.1f" % (
-        100.0 * r.total_sink_reads / r.total_sink_reads_dense)
-    assert lines[-2].split()[-4:-2] == [
+    assert lines[-2].split()[-2:] == [
+        "%.1f" % (100.0 * r.total_sink_reads / r.total_sink_reads_dense),
+        "%.1f" % (r.total_cell_sweeps / 1e6)]
+    assert lines[-2].split()[-5:-3] == [
         str(r.total_net_routes), "%.1f" % (
             r.total_net_routes / sum(s.batches for s in r.stats))]
     assert lines[-1].startswith("wall: prologue_s ")
