@@ -516,20 +516,39 @@ def build_planes_terminals(rr: RRGraph, source: np.ndarray,
 def _minplus_scan(d0, c, axis, reverse=False):
     """s[x] = min(d0[x], s[x-1] + c[x]) along axis (reverse: x+1 side).
 
-    First-order (min, +) recurrence via associative_scan on pairs:
-    combine((c1, m1), (c2, m2)) = (c1 + c2, min(m1 + c2, m2))."""
-    def comb(a, b):
-        ca, ma = a
-        cb, mb = b
-        return ca + cb, jnp.minimum(ma + cb, mb)
+    First-order (min, +) recurrence on pairs, combine((c1, m1),
+    (c2, m2)) = (c1 + c2, min(m1 + c2, m2)), grouped as the odd-even
+    tree of a parallel prefix scan (tests/scan_refs.py keeps the
+    library's whole-array form of it as the oracle: the same combines
+    on the same operands, so the same bits) and run on the axis's
+    n = shape[axis] per-position SLABS: static slices in, ONE
+    concatenate out, a reverse scan the list reversed.  The whole-array
+    form puts the tree's halves back together by interior pads and an
+    add of zeros a level and flips the canvases around a reverse scan;
+    on the v5e none of that fuses (a fifth to a third of the
+    relaxation's traffic).  n is 11 to 27 in the benchmark's cells:
+    some 2n combines of one slab."""
+    n = d0.shape[axis]
+    els = [(lax.slice_in_dim(c, i, i + 1, axis=axis),
+            lax.slice_in_dim(d0, i, i + 1, axis=axis)) for i in range(n)]
+    if reverse:
+        els.reverse()
 
+    def tree(els):
+        """The m half of the scan of ``els``; the c half of a scanned
+        pair feeds nothing."""
+        if len(els) < 2:
+            return [els[0][1]]
+        odd = tree([(ca + cb, jnp.minimum(ma + cb, mb))
+                    for (ca, ma), (cb, mb) in zip(els[0::2], els[1::2])])
+        even = [els[0][1]] + [jnp.minimum(m + cb, mb)
+                              for m, (cb, mb) in zip(odd, els[2::2])]
+        return [m for pair in zip(even, odd) for m in pair] + even[len(odd):]
+
+    s = tree(els)
     if reverse:
-        d0 = jnp.flip(d0, axis)
-        c = jnp.flip(c, axis)
-    _, s = lax.associative_scan(comb, (c, d0), axis=axis)
-    if reverse:
-        s = jnp.flip(s, axis)
-    return s
+        s.reverse()
+    return lax.concatenate(s, axis)
 
 
 def _scan_update(d, pred, w, cstep, wstep, self_idx, stride, axis,
@@ -539,8 +558,8 @@ def _scan_update(d, pred, w, cstep, wstep, self_idx, stride, axis,
 
     ``guard`` (static): a cell is NOT improved over a free step (inside
     a wire span) from the neighbour whose own predecessor it is.  The
-    associative scan sums a path's costs in an order that differs cell
-    to cell, so a cell reached THROUGH its span neighbour can come out
+    scan's odd-even tree sums a path's costs in an order that differs
+    cell to cell, so a cell reached THROUGH its span neighbour can come out
     an ulp below it; the opposite scan would then improve the neighbour
     from it, ``pred[A] = B`` and ``pred[B] = A``, and the traceback
     circles between the two for its budget and leaves a sink unreached.

@@ -161,6 +161,51 @@ def test_the_cropped_relaxation_loops_over_its_sweeps_alone(one_chip):
     assert loops(planes_relax_cropped_vmap) == 1 + 15 + 6 + 6
 
 
+def _unfused_scan_pads(compiled) -> int:
+    """The ``pad`` instructions of a compiled program that stand outside
+    every fusion (an instruction of their own: a pass over a canvas each)
+    and whose ``op_name`` lies under ``route.dev.relax.scan``."""
+    import re
+
+    pads = 0
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()",
+                         compiled.as_text()):
+        head, _, body = comp.partition("\n")
+        if "fused_computation" in head.split("(")[0]:
+            continue
+        pads += len(re.findall(
+            r"^\s*(?:ROOT )?%\S+ = \S+ pad\(.*"
+            r'op_name="[^"]*route\.dev\.relax\.scan', body, re.M))
+    return pads
+
+
+def test_the_relaxations_scans_interleave_by_no_pad(one_chip):
+    """The v5e compiler's program of planes_relax at the route canvas
+    holds NO unfused ``pad`` under ``route.dev.relax.scan``: the scan's
+    odd-even tree runs on slabs and joins them once.  The whole-array
+    form it replaced (tests/scan_refs.py) interleaves a level's halves
+    by two interior pads and an add, none of which the compiler fuses:
+    the guard that keeps them from coming back unnoticed, and the proof
+    that it would see them."""
+    from parallel_eda_tpu.route.planes import planes_relax
+    from scan_refs import minplus_scan_assoc, scan_form
+
+    pg = _planes(ROUTE_NX, ROUTE_W)
+
+    def pads():
+        # a function object a call: jit keeps a trace by its function
+        fn = jax.jit(lambda *a, nsweeps: planes_relax(*a, nsweeps=nsweeps),
+                     static_argnames=("nsweeps",))
+        return _unfused_scan_pads(fn.lower(
+            *_relax_avatars(pg, ROUTE_B, one_chip),
+            nsweeps=ROUTE_SWEEPS).compile())
+
+    assert pads() == 0
+    with scan_form(minplus_scan_assoc) as traced:
+        assert pads() == 28     # 24 at 22 x 22: a level more at 25 / 26
+    assert len(traced) == 4         # one sweep body, four scans
+
+
 def test_the_walk_scatters_are_one_loop_and_no_copy_of_themselves(one_chip):
     """The v5e compiler's program of planes.walk_scatters at
     route_relaxed's wave (B 64, G 8, Kw 188, 20,240 cells) holds ONE
