@@ -2183,6 +2183,138 @@ def route_batch_resident_planes(
     return (paths, sink_delay, all_reached, bb, occ, st[0])
 
 
+# the widest short list of overused nodes _mis_colors builds its
+# conflict matrix from by dense compares (ids past it: the table and the
+# scatter of _mis_colors_full).  Sized on the chip by
+# tools/mis_colors_forms.py (PERF.md section 6 PR 46; ms a call, full |
+# short at 0 / 32 / 256 nodes over): route_scale_6k's store of 11.5 M
+# slots 171 | 0.38 / 0.67 / 2.87, route_hetero's 8.4 M 128 | 0.34 / 0.60 /
+# 2.26, the K4N4 cells' 1.5 M 20.4 | 0.22 / 0.27 / 0.73.  A trip of the
+# short form's loop is an overused node (7 to 10 us at the large stores),
+# not a column, so the widths 64 / 128 / 256 cost the same at the same
+# count and the widest candidate caps the list
+MIS_SHORT_K = 256
+
+
+def _mis_rounds(U, rrm, prio, n_colors: int):
+    """The greedy colouring over a conflict matrix ``U`` [R, K] (rows in
+    ``prio``'s order, any K): colour c goes to the still-uncoloured rows
+    that hold the min ``prio`` down every column they stand in; what is
+    left after n_colors - 1 rounds shares the last class.  Returns
+    colors [R], in the rows' order."""
+    R = prio.shape[0]
+    color = jnp.full(R, n_colors - 1, jnp.int32)
+    uncol = rrm
+    for c in range(n_colors - 1):
+        Uc = U & uncol[:, None]
+        claim = jnp.min(jnp.where(Uc, prio[:, None], R), axis=0)
+        conflict = (Uc & (claim[None, :] != prio[:, None])).any(axis=1)
+        joins = uncol & ~conflict
+        color = jnp.where(joins, c, color)
+        uncol = uncol & ~joins
+    return color
+
+
+def _mis_rows(all_reached, fan):
+    """(prio, reached) of U's rows: the nets in order, or class after
+    class (``fan[1]``: a class's member nets)."""
+    if fan is None:
+        return jnp.arange(all_reached.shape[0], dtype=jnp.int32), all_reached
+    prio = jnp.concatenate(fan[1])
+    return prio, all_reached[prio]
+
+
+def _mis_by_net(rrm, color, prio, fan):
+    """Rows class after class -> by net."""
+    if fan is None:
+        return rrm, color
+    R = prio.shape[0]
+    return (jnp.zeros(R, bool).at[prio].set(rrm),
+            jnp.zeros(R, jnp.int32).at[prio].set(color))
+
+
+def _mis_colors_full(dev: DeviceRRGraph, occ, paths, all_reached,
+                     topk: int, n_colors: int, fan=None, top=None):
+    """_mis_colors over the top-K overused nodes, whatever is over
+    (``top``: the caller's ``lax.top_k(over, topk)``, where it has one).
+
+    A path slot finds its column of the conflict matrix U by ONE read
+    of a node-indexed table ``code [N + 1]``, written once a call from
+    what ``top_k`` returns: k < topk where the node is the k-th of the
+    top-K overused, topk (the dump column) where it is overused outside
+    them, topk + 1 where it is clean or the sentinel N.  The same read
+    says whether the slot is overused at all (rrm).  U's columns stand
+    in top_k's order; claim is a min down each column and conflict an
+    any across them, so no order of the columns moves a colour
+    (tests/mis_colors_refs.py keeps the searchsorted form this
+    replaced: fifteen gather rounds over the path store).  On the chip
+    the read is an element gather of R x S x L slots and U's scatter a
+    sort of as many indices: 14.8 ns a slot (PERF.md section 6 PR 46)."""
+    N = dev.num_nodes
+    over = jnp.maximum(occ - dev.capacity, 0)
+    val, ids = lax.top_k(over, topk) if top is None else top
+    code = jnp.append(jnp.where(over > 0, topk, topk + 1),
+                      topk + 1).astype(jnp.int32)
+    code = code.at[jnp.where(val > 0, ids, N + 1)].set(
+        jnp.arange(topk, dtype=jnp.int32), mode="drop")
+
+    def rows(col):
+        n = col.shape[0]
+        return jnp.zeros((n, topk + 1), bool).at[
+            jnp.arange(n)[:, None], jnp.minimum(col, topk)].set(
+            True)[:, :topk]
+
+    cols = [code[store.reshape(store.shape[0], -1)]
+            for store in ((paths,) if fan is None else paths)]
+    prio, reached = _mis_rows(all_reached, fan)
+    rrm = jnp.concatenate([(col <= topk).any(axis=1)
+                           for col in cols]) | ~reached
+    U = jnp.concatenate([rows(col) for col in cols]) & rrm[:, None]
+    return _mis_by_net(rrm, _mis_rounds(U, rrm, prio, n_colors), prio, fan)
+
+
+def _mis_colors_short(dev: DeviceRRGraph, occ, paths, all_reached,
+                      K: int, n_colors: int, fan=None, top=None):
+    """_mis_colors where at most ``K`` nodes are overused (the caller's
+    to see): column k of U is a dense compare of the path store with the
+    k-th overused node's id, one trip of a loop an overused node, so a
+    window that ends with nothing over reads no store at all.  No table,
+    no gather, no scatter; U is [R, K].  The columns past the overused
+    nodes are all-False here as they are in the full form's U and its
+    dump column is empty, so rrm and colors are the full form's.
+    ``top``: the caller's ``lax.top_k(over, k)`` of some k >= K."""
+    over = jnp.maximum(occ - dev.capacity, 0)
+    n_over = (over > 0).sum(dtype=jnp.int32)
+    ids = (lax.top_k(over, K) if top is None else top)[1]
+    flats = [store.reshape(store.shape[0], -1)
+             for store in ((paths,) if fan is None else paths)]
+    prio, reached = _mis_rows(all_reached, fan)
+
+    def column(k, Ut):
+        hit = jnp.concatenate([(flat == ids[k]).any(axis=1)
+                               for flat in flats])
+        return lax.dynamic_update_slice(Ut, hit[None, :], (k, 0))
+
+    Ut = lax.fori_loop(0, jnp.minimum(n_over, K), column,
+                       jnp.zeros((K, prio.shape[0]), bool))
+    U = Ut.T
+    rrm = U.any(axis=1) | ~reached
+    return _mis_by_net(rrm, _mis_rounds(U, rrm, prio, n_colors), prio, fan)
+
+
+def mis_short_width(topk: int) -> int:
+    """The most overused nodes the short form takes: beyond ``topk``
+    the full form drops nodes into its dump column."""
+    return min(MIS_SHORT_K, topk)
+
+
+def _mis_short_due(dev: DeviceRRGraph, occ, topk: int):
+    """Whether so few nodes are over capacity that _mis_colors takes its
+    short form (a traced bool)."""
+    return ((occ > dev.capacity).sum(dtype=jnp.int32)
+            <= mis_short_width(topk))
+
+
 @device_scope("route.dev.mis_colors")
 def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
                 topk: int, n_colors: int, fan=None):
@@ -2195,68 +2327,53 @@ def _mis_colors(dev: DeviceRRGraph, occ, paths, all_reached,
     every contested node among the still-uncolored).  Nets left after
     n_colors-1 rounds share the last class.
 
-    A path slot finds its column of the conflict matrix U by ONE read
-    of a node-indexed table ``code [N + 1]``, written once a call from
-    what ``top_k`` returns: k < topk where the node is the k-th of the
-    top-K overused, topk (the dump column) where it is overused outside
-    them, topk + 1 where it is clean or the sentinel N.  The same read
-    says whether the slot is overused at all (rrm).  U's columns stand
-    in top_k's order; claim is a min down each column and conflict an
-    any across them, so no order of the columns moves a colour
-    (tests/mis_colors_refs.py keeps the searchsorted form this
-    replaced: fifteen gather rounds over the path store).
+    ONE conflict matrix U [net, overused node], built one of two ways by
+    what the program sees in ``occ``: with at most
+    ``mis_short_width(topk)`` nodes over, by dense compares of the store
+    against that short list (_mis_colors_short); else through a
+    node-indexed table, a gather of the store and a scatter
+    (_mis_colors_full).  Same rrm, same colours, bit for bit
+    (tests/test_mis_colors_forms.py).
 
     With fanout classes (``fan`` = (local, members), ``paths`` a store a
-    class) each store is read once, at its own width, and the rows of U
+    class) each store is read at its own width and the rows of U
     stand class after class: a claim is a min of net ids down a column
     and a conflict an any across columns, so the order of the rows
     moves no colour either, and the net ids ride in ``prio``.  The
     conflict picture is ONE picture of all classes.
 
     Returns (rrm [R], colors [R])."""
-    N = dev.num_nodes
+    # the chip's top_k is a sort of the N nodes: once, for both forms
+    top = lax.top_k(jnp.maximum(occ - dev.capacity, 0), topk)
+    return lax.cond(
+        _mis_short_due(dev, occ, topk),
+        lambda: _mis_colors_short(dev, occ, paths, all_reached,
+                                  mis_short_width(topk), n_colors, fan, top),
+        lambda: _mis_colors_full(dev, occ, paths, all_reached, topk,
+                                 n_colors, fan, top))
+
+
+# what window_colours ran, the last entry of a window's ``scal``
+MIS_SKIPPED, MIS_SHORT, MIS_FULL = 0, 1, 2
+
+
+def window_colours(dev: DeviceRRGraph, occ, paths, all_reached, topk: int,
+                   n_colors: int, read, fan=None):
+    """A rung's (rrm, colors, form): _mis_colors where the host reads
+    the answer (``read``: the rung is its window's last), zeros and no
+    pass over the store where it does not (MIS_SKIPPED)."""
     R = all_reached.shape[0]
-    over = jnp.maximum(occ - dev.capacity, 0)
-    val, ids = lax.top_k(over, topk)
-    code = jnp.append(jnp.where(over > 0, topk, topk + 1),
-                      topk + 1).astype(jnp.int32)
-    code = code.at[jnp.where(val > 0, ids, N + 1)].set(
-        jnp.arange(topk, dtype=jnp.int32), mode="drop")
+    form = jnp.where(_mis_short_due(dev, occ, topk), MIS_SHORT,
+                     MIS_FULL).astype(jnp.int32)
 
-    def columns(store):
-        return code[store.reshape(store.shape[0], -1)]
+    def coloured():
+        return _mis_colors(dev, occ, paths, all_reached, topk, n_colors,
+                           **({} if fan is None else {"fan": fan})) + (form,)
 
-    def rows(col):
-        n = col.shape[0]
-        return jnp.zeros((n, topk + 1), bool).at[
-            jnp.arange(n)[:, None], jnp.minimum(col, topk)].set(
-            True)[:, :topk]
-
-    if fan is None:
-        col = columns(paths)
-        rrm = (col <= topk).any(axis=1) | ~all_reached
-        U = rows(col) & rrm[:, None]
-        prio = jnp.arange(R, dtype=jnp.int32)
-    else:
-        cols = [columns(store) for store in paths]
-        prio = jnp.concatenate(fan[1])
-        rrm = jnp.concatenate([(col <= topk).any(axis=1)
-                               for col in cols]) | ~all_reached[prio]
-        U = jnp.concatenate([rows(col) for col in cols]) & rrm[:, None]
-    color = jnp.full(R, n_colors - 1, jnp.int32)
-    uncol = rrm
-    for c in range(n_colors - 1):
-        Uc = U & uncol[:, None]
-        claim = jnp.min(jnp.where(Uc, prio[:, None], R), axis=0)
-        conflict = (Uc & (claim[None, :] != prio[:, None])).any(axis=1)
-        joins = uncol & ~conflict
-        color = jnp.where(joins, c, color)
-        uncol = uncol & ~joins
-    if fan is not None:
-        # class after class -> by net
-        rrm = jnp.zeros(R, bool).at[prio].set(rrm)
-        color = jnp.zeros(R, jnp.int32).at[prio].set(color)
-    return rrm, color
+    return lax.cond(
+        read, coloured,
+        lambda: (jnp.zeros(R, bool), jnp.zeros(R, jnp.int32),
+                 jnp.int32(MIS_SKIPPED)))
 
 
 def repack_plan(sel_plan, seg_plan, live):
@@ -2351,7 +2468,8 @@ class WindowOut(NamedTuple):
     #                     (walk steps run and the Kw budgeted per
     #                     executed wave, the executed waves, the sink
     #                     pick's rows read and the B * S a dense pick
-    #                     reads, the walk slots the two scatters read)
+    #                     reads, the walk slots the two scatters read),
+    #                     then the colouring's form (SCAL_MIS_FORM)
 
 
 @functools.partial(
@@ -2375,7 +2493,8 @@ def route_window_planes(
         crit_exp: float = 1.0, max_crit: float = 0.99,
         use_sdc: bool = False,
         crop_tile=None, bb0_all=None, widen_ok=None,
-        plane_dtype: str = "f32", fan=None, fclass: int = 0):
+        plane_dtype: str = "f32", fan=None, fclass: int = 0,
+        colours_read=True):
     """A WINDOW of K_iters complete PathFinder iterations as ONE device
     program: per iteration, every batch group in sel_plan [G, B] runs the
     fused rip-up/route/commit step (clean nets no-op via the device-side
@@ -2401,6 +2520,14 @@ def route_window_planes(
     that loop closes inside one XLA program).  crit_all is loop state
     (donated) and the per-iteration crit-path delays come back in
     dmax_hist [K_iters].
+
+    ``colours_read`` (a TRACED bool: no program of its own) says whether
+    the host reads this dispatch's rrm / colors: it does of a window's
+    LAST rung alone, and the driver passes False on the others, which
+    then skip the conflict colouring (window_colours) and return rrm,
+    colors and their bits of ``status`` as zeros, and max_span (taken
+    over rrm, read of the last rung alone) as 0.  Every other entry of
+    the summary is a rung's own either way.
 
     Returns a WindowOut."""
     G = sel_plan.shape[0]
@@ -2532,8 +2659,8 @@ def route_window_planes(
          jnp.zeros((STEP_LEDGER_LEN,), jnp.int32)))
     s_exec, s_useful = led[0], led[1]
 
-    rrm, colors = _mis_colors(dev, occ, paths, all_reached, topk, n_colors,
-                              **({} if fan is None else {"fan": fan}))
+    rrm, colors, mis_form = window_colours(
+        dev, occ, paths, all_reached, topk, n_colors, colours_read, fan)
     with device_scope("route.dev.window_summary"):
         over = jnp.maximum(occ - dev.capacity, 0)
         # max bb half-perimeter of a still-dirty net: the host compares it
@@ -2579,7 +2706,7 @@ def route_window_planes(
         scal = jnp.concatenate([
             jnp.stack([n_over_s, over_tot_s, nroutes, nexec,
                        max_span.astype(jnp.int32)]).astype(jnp.int32),
-            led])
+            led, mis_form[None]])
     return WindowOut(
         occ, acc, paths, sink_delay, all_reached, bb, pres, rrm,
         colors, n_over_s, over_tot_s, nroutes, nexec, crit_all,
@@ -2602,7 +2729,8 @@ SCAL_WAVES = 9
 SCAL_SINK_ROWS = 10
 SCAL_SINK_ROWS_DENSE = 11
 SCAL_WALK_SLOTS = 12
-SCAL_LEN = 13
+SCAL_MIS_FORM = 13    # what window_colours ran: MIS_SKIPPED / _SHORT / _FULL
+SCAL_LEN = 14
 
 
 def unpack_window_status(status):

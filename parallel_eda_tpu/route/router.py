@@ -776,6 +776,10 @@ def enable_persistent_compile_cache(cache_dir: Optional[str] = None,
 _DISPATCH_VARIANTS = set()
 
 
+# planes.MIS_SKIPPED / MIS_SHORT / MIS_FULL -> route.mis_colors.<counter>
+_MIS_FORM_COUNTERS = ("skipped_total", "short_total", "full_total")
+
+
 def _note_dispatch_variant(key) -> bool:
     """Record one canonicalized dispatch signature; returns True when
     the variant is NEW (this dispatch pays an XLA compile, or a
@@ -1246,8 +1250,8 @@ class Router:
         it inline at the old program point.  Every field of ``bk`` is a
         value captured at that window's control step — later control
         mutations (pres, plateau state, widened_nets) cannot leak in."""
-        from .planes import (SCAL_NEXEC, SCAL_NROUTES, SCAL_S_EXEC,
-                             SCAL_S_USEFUL, SCAL_SINK_ROWS,
+        from .planes import (SCAL_MIS_FORM, SCAL_NEXEC, SCAL_NROUTES,
+                             SCAL_S_EXEC, SCAL_S_USEFUL, SCAL_SINK_ROWS,
                              SCAL_SINK_ROWS_DENSE, SCAL_WALK_BUDGET,
                              SCAL_WALK_SLOTS, SCAL_WALK_STEPS,
                              SCAL_WAVES)
@@ -1260,8 +1264,13 @@ class Router:
             bk["rung_scals"])
         mesh_info = bk.get("mesh")
         halo_b = halo_ex = 0
+        reg = get_metrics()
         for ri, (scal_d, cropped) in enumerate(bk["rung_scals"]):
             v = np.asarray(scal_d)
+            # the form of the rung's conflict colouring
+            # (planes.window_colours): the three sum to calls_total
+            reg.counter("route.mis_colors." + _MIS_FORM_COUNTERS[
+                int(v[SCAL_MIS_FORM])]).inc()
             nroutes += int(v[SCAL_NROUTES])
             nexec += int(v[SCAL_NEXEC])
             w_steps += int(v[SCAL_S_EXEC])
@@ -1317,7 +1326,6 @@ class Router:
             waves_wide=w_waves_wide, relax_steps_wide=w_steps_wide,
             sink_reads=sink_reads, sink_reads_dense=sink_reads_dense)
         result.stats.append(row)
-        reg = get_metrics()
         reg.counter(f"route.window.seconds_total.{row.kind}").inc(
             row.route_time_s)
         reg.counter(f"route.window.sweeps_total.{row.kind}").inc(w_steps)
@@ -1761,8 +1769,9 @@ class Router:
         sinks_disp = reg.counter("route.fanout.sinks_dispatched_total")
         slots_disp = reg.counter(
             "route.fanout.sink_slots_dispatched_total")
-        # conflict colourings run (one a dispatched _window_body, so one
-        # a rung) / read (one a window: the last rung's summary)
+        # window programs dispatched (one a rung) / conflict colourings
+        # read (one a window: the last rung's summary, the one rung that
+        # runs _mis_colors; _book_window counts each rung's form)
         mis_calls = reg.counter("route.mis_colors.calls_total")
         mis_reads = reg.counter("route.mis_colors.read_total")
         # the endgame's full rebuilds: finishing passes started, phase-2
@@ -2061,6 +2070,9 @@ class Router:
                 wp_kwargs = dict(
                     crop_tile=p["tile"], bb0_all=bb0_d,
                     widen_ok=p["wok"], plane_dtype=pd,
+                    # traced, like acc_fac by rung: the host reads the
+                    # colours of the window's last rung alone
+                    colours_read=jnp.bool_(ri == len(dispatch) - 1),
                     **fan_kw, **sta_kw,
                     **({"fclass": p["fcls"]} if fan_kw else {}))
                 # device-truth profiling: avatarize the REAL call args

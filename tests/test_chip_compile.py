@@ -320,6 +320,93 @@ def test_directional_planes_relax_compiles_at_k6n10_canvas(
                        cnx=tile, cny=tile).compile())
 
 
+# ---- the conflict colouring's two forms under their cond -----------
+
+def _hlo_computations(text):
+    """{name: its lines} of a compiled module's text, and each
+    computation's callees."""
+    import re
+
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    calls = {n: set(re.findall(r"%([\w.\-]+)", " ".join(
+        re.findall(r"(?:calls|to_apply|body|condition|"
+                   r"branch_computations|true_computation|"
+                   r"false_computation)=\{?([^}\s,]*(?:, [^}\s,]*)*)",
+                   " ".join(lines))))) & set(comps)
+             for n, lines in comps.items()}
+    return comps, calls
+
+
+def test_the_short_colouring_reads_the_store_by_compares_alone(one_chip):
+    """`planes.window_colours` at `route_relaxed`'s path store (962 x 12
+    x 128 slots, 29,656 nodes, topk 4,096) before the v5e compiler:
+    ONE conditional on the rung's flag around ONE on the count of
+    overused nodes; the short branch holds a loop, no sort, no scatter
+    and no array of store x width elements (378 M at the width 256); the
+    full branch holds the scatter and the sort of as many indices as the
+    store has slots (the guard sees the form it guards against)."""
+    import re
+    import types
+
+    from parallel_eda_tpu.route import planes
+
+    R, S, L, N, topk = 962, 12, 128, 29656, 4096
+    a = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def colours(cap, occ, paths, reached, read):
+        dev = types.SimpleNamespace(num_nodes=N, capacity=cap)
+        return planes.window_colours(dev, occ, paths, reached, topk, 5, read)
+
+    compiled = jax.jit(colours).lower(
+        a((N,), jnp.int32), a((N,), jnp.int32), a((R, S, L), jnp.int32),
+        a((R,), jnp.bool_), a((), jnp.bool_)).compile()
+    comps, calls = _hlo_computations(compiled.as_text())
+
+    def reach(name):
+        seen, todo = set(), [name]
+        while todo:
+            n = todo.pop()
+            if n not in seen:
+                seen.add(n)
+                todo.extend(calls[n])
+        return "\n".join(line for n in sorted(seen) for line in comps[n])
+
+    conds = [line for lines in comps.values() for line in lines
+             if " conditional(" in line]
+    assert len(conds) == 2
+    branches = [b for line in conds for b in re.findall(
+        r"%([\w.\-]+)", re.search(
+            r"(?:branch_computations=\{([^}]*)\}|"
+            r"true_computation=(\S+), false_computation=(\S+))",
+            line).group(0))]
+    texts = [reach(b) for b in branches if b in comps]
+    # three branches hold no conditional of their own: the skipped
+    # rung's zeros, and the inner cond's two
+    Ks = planes.mis_short_width(topk)
+    leaves = [t for t in texts if " conditional(" not in t]
+    assert len(leaves) == 3
+    (short,) = [t for t in leaves if f"pred[{Ks},{R}]" in t]
+    (full,) = [t for t in leaves if " sort(" in t]
+    assert " while(" in short
+    assert " sort(" not in short and " scatter(" not in short
+    assert " scatter(" in full
+    assert f"[{R * S * L}]" in full.replace(",", "")
+    sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"\w+\[([\d,]+)\]", short)]
+    assert max(sizes) == R * S * L < R * S * L * Ks
+    assert f"pred[{Ks},{R}]" in short
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 # ---- the whole window program once, small --------------------------
 
 def test_route_window_program_compiles_for_every_bench_variant(one_chip):

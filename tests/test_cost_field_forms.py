@@ -12,8 +12,9 @@ import pytest
 
 from cost_field_refs import entry_fields_gather, node_cost_field_gather
 from mis_colors_refs import mis_colors_searchsorted
-from parallel_eda_tpu.route.planes import (_mis_colors, entry_fields,
-                                           live_pick_rungs,
+from parallel_eda_tpu.route.planes import (_mis_colors, _mis_colors_full,
+                                           _mis_colors_short, entry_fields,
+                                           live_pick_rungs, mis_short_width,
                                            node_cost_field, sink_pick,
                                            sink_pick_live, sink_pick_wave,
                                            sink_pin_costs)
@@ -182,11 +183,13 @@ def test_sink_pin_costs_gather_a_cost_per_pin_of_a_sink(cell):
 
 @pytest.mark.parametrize("cell", sorted(MIS_SHAPES))
 def test_mis_colors_gather_the_path_store_once(cell):
-    """Once a window rung: ONE read of R * Smax * L slots out of the
-    node-indexed table, and nothing that loops or sorts.  The
-    searchsorted form read the store twice in the open and thirteen
-    times inside its search's loop: half of ``route_scale``'s traced
-    slice on the chip (PERF.md, PR 31)."""
+    """Once a window: with many nodes over, ONE read of R * Smax * L
+    slots out of the node-indexed table, and nothing that loops or
+    sorts; with few (PR 46), a loop of dense compares an overused node
+    that gathers nothing of the store's size, sorts nothing and
+    scatters nothing.  The searchsorted form read the store twice in
+    the open and thirteen times inside its search's loop: half of
+    ``route_scale``'s traced slice on the chip (PERF.md, PR 31)."""
     import types
 
     R, S, L, N = MIS_SHAPES[cell]
@@ -203,9 +206,18 @@ def test_mis_colors_gather_the_path_store_once(cell):
     def names(fn):
         return {e.primitive.name for e in _eqns(fn, *avals)}
 
-    new = form(_mis_colors)
+    new = form(_mis_colors_full)
     assert gather_index_rows(new, *avals) == [R * S * L]
     assert not names(new) & {"while", "scan", "sort"}
+    short = form(lambda dev, occ, paths, reached, topk, n_colors:
+                 _mis_colors_short(dev, occ, paths, reached,
+                                   mis_short_width(topk), n_colors))
+    assert max(gather_index_rows(short, *avals), default=0) <= 1
+    assert "while" in names(short)
+    assert not names(short) & {"sort", "scatter", "scan"}
+    # the two under their cond: the store is gathered in ONE branch
+    assert [r for r in gather_index_rows(form(_mis_colors), *avals)
+            if r > 1] == [R * S * L]
     # the guard sees the form it guards against: three reads of the
     # store, one of them inside the search's loop, and the ids' sort
     ref = form(mis_colors_searchsorted)

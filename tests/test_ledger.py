@@ -171,7 +171,8 @@ def test_walk_slot_ledger_counts_and_repeats(routed):
                                                STEP_LEDGER_LEN, WALK_CHUNK)
 
     res, _, _ = routed
-    assert (STEP_LEDGER_LEN, SCAL_WALK_SLOTS, SCAL_LEN) == (8, 12, 13)
+    # (the colouring's form rides behind the ledger, PR 46)
+    assert (STEP_LEDGER_LEN, SCAL_WALK_SLOTS, SCAL_LEN) == (8, 12, 14)
     assert 0 < res.total_walk_slots_read < res.total_walk_budget
     # no wave read a chunk's worth more than it walked (it reads less
     # where its longest walk overran and was not kept)

@@ -681,13 +681,23 @@ def _assert_route_unmoved_by(f, monkeypatch, refs):
 
 
 def _mis_counters():
-    """(colourings run, colourings the host read) so far in this
-    process."""
+    """(window programs dispatched, colourings the host read) so far in
+    this process."""
     from parallel_eda_tpu.obs import get_metrics
 
     v = get_metrics().values("route.mis_colors.")
     return (v.get("route.mis_colors.calls_total", 0),
             v.get("route.mis_colors.read_total", 0))
+
+
+def _mis_forms():
+    """(skipped, short, full): the programs by what their conflict
+    colouring ran, so far in this process."""
+    from parallel_eda_tpu.obs import get_metrics
+
+    v = get_metrics().values("route.mis_colors.")
+    return tuple(v.get(f"route.mis_colors.{form}_total", 0)
+                 for form in ("skipped", "short", "full"))
 
 
 def test_directional_route_equals_the_route_under_gathered_fields(
@@ -713,9 +723,16 @@ def test_directional_cropped_route_equals_the_full_canvas_route(
     ``crop="off"`` the dispatch itself differs: one subset of all the
     nets where ``auto`` hands each rung its own, so the net groups and
     with them the negotiation are others; that route is held to be
-    legal and to count no cropped sweep.)"""
+    legal and to count no cropped sweep.)
+
+    The reference route also colours EVERY rung, by the full form
+    (ISSUE 46): the route that colours the window's last rung alone, by
+    the short list where few nodes are over, is that route too, and its
+    three form counters say what it skipped."""
+    import jax.numpy as jnp
+
     from parallel_eda_tpu.obs import get_metrics
-    from parallel_eda_tpu.route import planes
+    from parallel_eda_tpu.route import planes, router
 
     def full_canvas(pg, d0, cc, crit_c, wenter0, nsweeps, ox, oy, cnx,
                     cny, plane_dtype="f32", cut=None):
@@ -723,16 +740,50 @@ def test_directional_cropped_route_equals_the_full_canvas_route(
         return planes.planes_relax(pg, d0, cc, crit_c, wenter0, nsweeps,
                                    None, plane_dtype)
 
+    def full_form_every_rung(dev, occ, paths, all_reached, topk, n_colors,
+                             read, fan=None):
+        return planes._mis_colors_full(
+            dev, occ, paths, all_reached, topk, n_colors, fan) + (
+            jnp.int32(planes.MIS_FULL),)
+
     f = _placed("directional_l4_19x19")
     assert (f.grid.nx, f.grid.ny) == (19, 19)
     calls0, reads0 = _mis_counters()
+    forms0 = _mis_forms()
+    variants0 = set(router._DISPATCH_VARIANTS)
+    traces = []
+    window_colours = planes.window_colours
+
+    def traced(*a, **kw):
+        traces.append(1)
+        return window_colours(*a, **kw)
+
+    monkeypatch.setattr(planes, "window_colours", traced)
+    # a short list so short that the early windows, with tens of nodes
+    # over, colour by the table: both forms in one route
+    monkeypatch.setattr(planes, "MIS_SHORT_K", 4)
     res = _assert_route_unmoved_by(
-        f, monkeypatch, {"planes_relax_cropped": full_canvas})
+        f, monkeypatch, {"planes_relax_cropped": full_canvas,
+                         "window_colours": full_form_every_rung})
     check_route(f.rr, f.term, res.paths, res.occ)
-    # a colouring ran once a rung and the host read one a window (the
-    # helper routed twice): the cropped rung's were dropped unread
+    # a program ran once a rung and the host read one a window (the
+    # helper routed twice): the cropped rung's colours nobody reads
     calls, reads = _mis_counters()
     assert calls - calls0 > reads - reads0 == 2 * len(res.stats)
+    # the first route skipped them, coloured some windows' ends by the
+    # short list and some by the table; the reference ran the full form
+    # on every rung.  The three counters sum to the programs
+    skipped, short, full = (a - b for a, b in zip(_mis_forms(), forms0))
+    one = (calls - calls0) // 2
+    assert skipped + short + full == calls - calls0 == 2 * one
+    assert skipped == one - len(res.stats) > 0
+    assert short > 0 and full > one
+    # the flag is traced: a rung that is its window's last in one window
+    # and not in another is ONE program, so the first route traced the
+    # window program once a dispatch variant (and the variants' keys
+    # hold no flag: they are the parent's)
+    new = set(router._DISPATCH_VARIANTS) - variants0
+    assert len(traces) == len(new) > 1
     # not by bypass: a cropped rung was dispatched, and so was the
     # full canvas
     assert 0 < res.total_relax_steps_cropped < res.total_relax_steps

@@ -125,7 +125,7 @@ def test_the_chunk_is_a_module_constant():
     assert not [f.name for f in dataclasses.fields(RouterOpts)
                 if "walk" in f.name or "chunk" in f.name]
     assert planes.STEP_LEDGER_LEN == 8
-    assert planes.SCAL_WALK_SLOTS == planes.SCAL_LEN - 1 \
+    assert planes.SCAL_WALK_SLOTS == planes.SCAL_MIS_FORM - 1 \
         == planes.SCAL_S_EXEC + planes.STEP_LEDGER_LEN - 1
 
 

@@ -39,7 +39,9 @@ PARAMS = (
     "force_until", "K_iters", "nsweeps", "max_len", "num_waves", "group",
     "doubling", "topk", "n_colors", "mesh", "tdev", "req_seed",
     "sta_depth", "crit_exp", "max_crit", "use_sdc", "crop_tile",
-    "bb0_all", "widen_ok", "plane_dtype", "fan", "fclass")
+    "bb0_all", "widen_ok", "plane_dtype", "fan", "fclass",
+    # an ARRAY argument (PR 46): whether the host reads this rung's colours
+    "colours_read")
 STATICS = ("K_iters", "nsweeps", "max_len", "num_waves", "group",
            "doubling", "topk", "n_colors", "mesh", "sta_depth",
            "crit_exp", "max_crit", "use_sdc", "crop_tile", "plane_dtype",
@@ -106,8 +108,13 @@ def test_scal_is_indexed_as_documented(windows):
              "steps_exec": planes.SCAL_S_EXEC,
              "steps_useful": planes.SCAL_S_USEFUL}
     assert sorted(names.values()) == list(range(7))
-    assert planes.SCAL_LEN == 5 + planes.STEP_LEDGER_LEN
+    # five scalars, the step's ledger, the colouring's form
+    assert planes.SCAL_LEN == 5 + planes.STEP_LEDGER_LEN + 1
+    assert planes.SCAL_MIS_FORM == planes.SCAL_LEN - 1
     for _, _, h, _ in outs:
+        # one rung a window here: every colouring was read
+        assert h["scal"][planes.SCAL_MIS_FORM] in (planes.MIS_SHORT,
+                                                    planes.MIS_FULL)
         for n, i in names.items():
             assert int(h["scal"][i]) == int(h[n]), n
         assert h["scal"][planes.SCAL_WALK_STEPS] <= \
@@ -173,3 +180,52 @@ def test_the_window_loop_is_a_plain_method():
                    for n in ast.walk(node)):
                 yields.add(node.name)
     assert yields == {"dispatching"}
+
+
+def test_a_rung_nobody_reads_skips_the_colouring_and_nothing_else():
+    """``colours_read`` False (a rung that is not its window's last):
+    rrm, colors, their bits of ``status`` and max_span come back zero,
+    ``scal`` says MIS_SKIPPED, and every other result is the result of
+    the same call with the flag True -- by ONE traced program."""
+    import jax.numpy as jnp
+
+    import __graft_entry__ as graft
+
+    fn = planes.route_window_planes
+
+    def call(read):
+        p = graft.planes_step_problem()
+        occ, acc, paths, sink_delay, all_reached, bb = p["state"]
+        # route the plan's first net alone and call another unreached:
+        # the colouring has a net to mark
+        valid = p["valid"].at[1:].set(False)
+        all_reached = all_reached.at[p["sel"][1]].set(False)
+        out = fn(
+            p["pg"], p["dev"], occ, acc, paths, sink_delay, all_reached,
+            bb, *p["nets"], p["sel"][None], valid[None],
+            p["full_bb"], jnp.float32(0.5), jnp.float32(1.0),
+            jnp.float32(1.0), jnp.float32(0.0), jnp.int32(0), jnp.int32(1),
+            1, p["nsweeps"], p["max_len"], p["num_waves"], p["group"],
+            True, topk=64, colours_read=jnp.bool_(read))
+        return {n: np.asarray(getattr(out, n)) for n in FIELDS}
+
+    size0 = fn._cache_size()
+    read, skipped = call(True), call(False)
+    assert fn._cache_size() == size0 + 1
+    assert read["rrm"].any() and read["max_span"] > 0
+    assert read["scal"][planes.SCAL_MIS_FORM] == planes.MIS_SHORT
+    assert skipped["scal"][planes.SCAL_MIS_FORM] == planes.MIS_SKIPPED
+    for n in ("rrm", "colors", "max_span"):
+        assert not skipped[n].any(), n
+    assert skipped["scal"][planes.SCAL_MAX_SPAN] == 0
+    rrm, colors, *rest = planes.unpack_window_status(skipped["status"])
+    assert not rrm.any() and not colors.any()
+    for a, b in zip(rest, planes.unpack_window_status(read["status"])[2:]):
+        assert np.array_equal(a, b)
+    own = [n for n in FIELDS
+           if n not in ("rrm", "colors", "max_span", "status", "scal")]
+    for n in own:
+        assert np.array_equal(read[n], skipped[n], equal_nan=True), n
+    keep = [i for i in range(planes.SCAL_LEN)
+            if i not in (planes.SCAL_MAX_SPAN, planes.SCAL_MIS_FORM)]
+    assert np.array_equal(read["scal"][keep], skipped["scal"][keep])
